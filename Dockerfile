@@ -1,7 +1,7 @@
 # The single runtime image: controller-manager, SCI servers, and the
 # contract containers (load/train/serve entrypoints) all live in this
 # package — commands select the role (see config/ and controller/crs.py).
-# TPU nodes get the libtpu wheel via the tpu extra at deploy time.
+# The tpu extra pins the libtpu that matches the pinned jax (pyproject.toml).
 FROM python:3.12-slim AS build
 RUN apt-get update && apt-get install -y --no-install-recommends g++ \
     && rm -rf /var/lib/apt/lists/*
@@ -14,8 +14,6 @@ COPY --from=build /usr/local/bin/nbwatch /usr/local/bin/nbwatch
 WORKDIR /app
 COPY pyproject.toml ./
 COPY substratus_tpu ./substratus_tpu
-RUN pip install --no-cache-dir ".[grpc]" && pip install --no-cache-dir \
-    "jax[tpu]" -f https://storage.googleapis.com/jax-releases/libtpu_releases.html \
-    || pip install --no-cache-dir jax
+RUN pip install --no-cache-dir ".[grpc,tpu]"
 WORKDIR /content
 ENTRYPOINT ["python", "-m", "substratus_tpu.serve.main"]

@@ -3,7 +3,7 @@
 
 PY ?= python
 
-.PHONY: test test-int lint lint-fast metrics-lint trace-lint manifests api-docs protogen nbwatch spm bench bench-train bench-smoke bench-compare gateway-smoke fleet-smoke journey-smoke autoscale-smoke rollout-smoke gateway-bench adapter-bench disagg-bench overlap-bench spec-bench prefix-bench batchgen-bench graft image install-manifests
+.PHONY: test test-int lint lint-fast metrics-lint trace-lint manifests api-docs protogen nbwatch spm bench bench-train bench-smoke bench-compare gateway-smoke fleet-smoke journey-smoke autoscale-smoke rollout-smoke gateway-bench adapter-bench disagg-bench overlap-bench spec-bench prefix-bench batchgen-bench chip-smoke chip-smoke-rehearse image install-manifests
 
 test:
 	$(PY) -m pytest tests/ -x -q
@@ -72,13 +72,12 @@ bench:
 bench-train:
 	$(PY) tools/bench_train.py
 
-# CPU-scaled captures of BOTH baseline primary metrics plus the
-# 2-process lockstep gang bench, each piped through the schema validator
-# — proves every capture path emits one valid JSON line without a chip.
+# CPU-scaled runs of both bench scripts plus the 2-process lockstep gang
+# bench, each piped through the schema validator — proves every script
+# emits one valid JSON line (platform "cpu": a shape check, not a speed).
 bench-smoke:
 	JAX_PLATFORMS=cpu $(PY) bench.py --config tiny --batch 4 --cache-len 128 \
-	  --steps 8 --quantize int8 --no-fallback --probe-timeout 60 \
-	  --probe-budget 120 | $(PY) hack/bench_compare.py --validate -
+	  --steps 8 --quantize int8 | $(PY) hack/bench_compare.py --validate -
 	JAX_PLATFORMS=cpu $(PY) tools/bench_train.py --smoke \
 	  | $(PY) hack/bench_compare.py --validate -
 	JAX_PLATFORMS=cpu $(PY) tools/engine_bench.py --smoke --gang 2 \
@@ -203,13 +202,20 @@ batchgen-bench:
 	  | $(PY) hack/bench_compare.py --validate -
 
 # Bench JSON schema + >10% regression gate (hack/bench_compare.py):
-# self-tests that a synthetic 20% regression fails and that the repo's
-# historical BENCH_* trajectory still loads.
+# self-tests that a synthetic 20% regression fails and that any
+# BENCH_*.json history beside it loads.
 bench-compare:
 	$(PY) hack/bench_compare.py --self-test
 
-graft:
-	$(PY) __graft_entry__.py
+# Does the system still start on the chip? Serve and finetune
+# TinyLlama-1.1B through the normal entry points and run every Pallas
+# kernel on the attached TPU (one chip; fails where JAX finds none).
+# `chip-smoke-rehearse` is the same control flow on the CPU at tiny size.
+chip-smoke:
+	$(PY) chip_smoke.py
+
+chip-smoke-rehearse:
+	$(PY) chip_smoke.py --rehearse
 
 image:
 	docker build -t ghcr.io/substratus-tpu/runtime:latest .

@@ -27,10 +27,6 @@ def main(argv=None) -> int:
     ap.add_argument("--name", default=None)
     args = ap.parse_args(argv)
 
-    from substratus_tpu.utils.jaxenv import honor_requested_platform
-
-    honor_requested_platform()
-
     p = {}
     if os.path.exists(args.params):
         with open(args.params) as f:

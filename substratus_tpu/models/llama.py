@@ -35,7 +35,6 @@ from substratus_tpu.ops.basics import (
     swiglu,
 )
 from substratus_tpu.ops.quant import materialize, qeinsum, qeinsum_w8a8
-from substratus_tpu.utils import jaxcompat
 
 Params = Dict[str, Any]
 
@@ -66,18 +65,20 @@ class LlamaConfig:
     #   "xla"   — einsum + masked softmax (always correct; CPU tests)
     #   "flash" — Pallas blockwise kernel (ops/flash_attention.py, TPU)
     #   "ring"  — sequence-parallel ring attention (ops/ring_attention.py);
-    #             requires an ambient mesh (jax.sharding.use_mesh) with a
+    #             requires an ambient mesh (jax.set_mesh) with a
     #             "sequence" axis
     attn_impl: str = "xla"
     # Decode-with-cache attention implementation (ops/decode_attention.py):
-    #   "xla"    — scale-after-dot einsums (default; also fastest measured)
-    #   "pallas" — fused int8-dequant flash-decode Mosaic kernel
+    #   "xla"    — scale-after-dot einsums (default)
+    #   "pallas" — int8-dequant Mosaic kernel over the dense slot cache
+    #   "fused"  — cache write + attention in one kernel (does not lower
+    #              on a v5e; ops/fused_decode.py)
     decode_attn_impl: str = "xla"
     # Multi-token cached attention (chunked prefill / speculative verify):
     #   "xla"   — dequantize cache + reference attention (default)
     #   "flash" — blockwise Pallas kernel (ops/flash_attention.py::
-    #             flash_cached_attention); opt-in via params.json until
-    #             its Mosaic lowering is validated on a chip
+    #             flash_cached_attention); opt-in via params.json: it
+    #             compiles and matches on the chip, its speed is not measured
     chunk_attn_impl: str = "xla"
     # W8A8: dynamically quantize activations per token so quantized matmuls
     # run in the MXU's native s8xs8 mode (ops/quant.py::qeinsum_w8a8).
@@ -329,7 +330,7 @@ def _self_attention(
             )
 
         spec = P(None, "sequence", None, None)
-        sharded = jaxcompat.shard_map(
+        sharded = jax.shard_map(
             lambda q, k, v: fn(q, k, v, axis_name="sequence"),
             in_specs=(spec, spec, spec),
             out_specs=spec,

@@ -12,7 +12,7 @@ kernel want to read.
 
 Two scale tricks keep int8 dequantization off the critical path (the
 naive dequant materializes a bf16 copy of the whole cache in HBM every
-step — measured 2x+ step-time on v5e):
+step):
 
 * k_scale commutes out of the QK contraction (it is per (kv-head, pos),
   constant over head_dim): scores = (q . k_int8) * k_scale.
@@ -23,12 +23,13 @@ conversion is the operand read itself.
 
 Implementations:
 * impl="xla": einsums with f32 accumulation; always correct, runs
-  everywhere; the serving default (empirically fastest on the dev chip).
+  everywhere; the serving default.
 * impl="pallas": fused Mosaic kernel — one program per (batch, s-block),
   all kv heads per program (leading-dim slices are relayout-free),
   online softmax in VMEM scratch, causal/validity masking from the
-  per-row position. Validated bit-for-bit against the XLA path on a real
-  v5e chip (MHA/GQA/MQA and multi-block S).
+  per-row position. The sequence block comes from a VMEM budget
+  (pick_block_s); compiled and compared with the XLA path on the chip by
+  chip_smoke.py's kernel phase (ops/kernel_cases.py).
 """
 from __future__ import annotations
 
@@ -51,12 +52,14 @@ def decode_attention(
     v_scale: Optional[jnp.ndarray] = None,  # [B, KH, S] f32
     *,
     impl: str = "xla",
-    block_s: int = 512,
+    block_s: Optional[int] = None,
     interpret: bool = False,
 ) -> jnp.ndarray:
     """Single-token attention against the full cache. Slots at position
     > positions[b] are masked (freshly written current token included via
-    <=). Returns [B, 1, H, D] in q.dtype.
+    <=). Returns [B, 1, H, D] in q.dtype. block_s=None sizes the Pallas
+    kernel's sequence block from its VMEM budget (pick_block_s); tests
+    pass a small one to force several blocks.
 
     impl="pallas" routes through a custom_partitioning rule (the kernel
     is local per (batch, kv-head) shard), so it survives GSPMD-sharded
@@ -210,6 +213,35 @@ def _kernel(
             o_ref[0, h] = (acc_scratch[sl] / l).astype(o_ref.dtype)
 
 
+# VMEM the pipelined K/V (and scale) blocks may take, both buffers of
+# each counted: half of the 16 MiB a v5e kernel gets by default. The other
+# half is left to the per-head f32 temporaries and the scratch.
+_KV_VMEM_BUDGET = 8 * 1024 * 1024
+
+
+def pick_block_s(s_len: int, kh: int, d: int, itemsize: int,
+                 quantized: bool) -> int:
+    """Sequence block of the Pallas decode kernel: the whole cache when it
+    fits the budget, else the largest multiple of 128 that divides it and
+    fits (the [B, KH, S] scale block puts S on the lanes, where Mosaic
+    takes a multiple of 128 or the whole axis). Raises ValueError for a
+    cache length no such block divides, so Engine construction can refuse
+    it before the compiler does."""
+    row = 2 * 2 * kh * (d * itemsize + (4 if quantized else 0))
+    fit = _KV_VMEM_BUDGET // row
+    if s_len <= fit:
+        return s_len
+    for block in range(fit // 128 * 128, 0, -128):
+        if s_len % block == 0:
+            return block
+    raise ValueError(
+        f"decode_attn_impl=pallas cannot tile a cache of length {s_len} "
+        f"({kh} kv heads of {d}): it needs {row * s_len} bytes of VMEM "
+        f"whole (budget {_KV_VMEM_BUDGET}) and no multiple of 128 within "
+        "the budget divides it; make max_seq_len a multiple of 128"
+    )
+
+
 def _pallas(q, k, v, positions, k_scale, v_scale, block_s, interpret):
     from jax.experimental.pallas import tpu as pltpu
 
@@ -218,11 +250,12 @@ def _pallas(q, k, v, positions, k_scale, v_scale, block_s, interpret):
     kh, s_len = k.shape[1], k.shape[2]
     group = h // kh
     g8 = max(group, 8)
-    block_s = min(block_s, s_len)
-    while s_len % block_s:  # largest divisor <= requested block
-        block_s -= 1
-    nsb = s_len // block_s
     quantized = k_scale is not None
+    if block_s is None:
+        block_s = pick_block_s(s_len, kh, d, k.dtype.itemsize, quantized)
+    elif s_len % block_s:
+        raise ValueError(f"block_s {block_s} does not divide cache {s_len}")
+    nsb = s_len // block_s
     qr = q.reshape(b, kh, group, d)
     kernel = functools.partial(
         _kernel, scale=d ** -0.5, kh=kh, group=group,
@@ -266,6 +299,26 @@ def _pallas(q, k, v, positions, k_scale, v_scale, block_s, interpret):
         interpret=interpret,
     )(positions.astype(jnp.int32), *operands)
     return out.reshape(b, 1, h, d)
+
+
+def check_cache_tiling(decode_impl: str, chunk_impl: str, kh: int, d: int,
+                       cache_len: int, itemsize: int, quantized: bool) -> None:
+    """Raise at Engine construction what the chip's compiler would
+    otherwise raise inside the first jitted step: a dense cache length the
+    selected Pallas kernel cannot tile (ValueError), or the fused kernel
+    on a TPU backend (NotImplementedError, ops/fused_decode.py)."""
+    if decode_impl == "fused":
+        from substratus_tpu.ops.fused_decode import check_lowers
+
+        check_lowers()
+    elif decode_impl == "pallas":
+        pick_block_s(cache_len, kh, d, itemsize, quantized)
+    if chunk_impl == "flash":
+        from substratus_tpu.ops.flash_attention import (
+            DEFAULT_BLOCK_K, cached_block_k,
+        )
+
+        cached_block_k(DEFAULT_BLOCK_K, cache_len, quantized)
 
 
 def update_cache_and_attend(

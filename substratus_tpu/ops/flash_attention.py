@@ -25,10 +25,15 @@ query head and the per-head partials are summed over the group afterwards
 (group-sized HBM transient; zero-cost for MHA).
 
 Numerics: dots run in the input dtype (bf16 is the MXU's native mode; an
-f32 upcast would be truncated back to bf16 under default precision —
-measured 7e-3 on chip) with f32 accumulation; genuine f32 inputs request
-Precision.HIGHEST, making the kernel f32-exact (1.1e-6 vs the oracle on a
-real v5e).
+f32 upcast would be truncated back to bf16 under default precision) with
+f32 accumulation; genuine f32 inputs request Precision.HIGHEST. On a v5e
+(chip_smoke.py, PR 21) the bf16 forward is within 0.005 and the backward
+within 0.007 of the f32 XLA reference, relative to the largest value, at
+TinyLlama-1.1B's and Llama-2-7B's widths up to 2048 tokens.
+
+One chip compiles these kernels. Under a mesh of several chips the
+compiler refuses the custom_partitioning wrapper that carries them
+(tests/test_chip_compile.py), so flash stays a one-chip path for now.
 """
 from __future__ import annotations
 
@@ -43,9 +48,10 @@ from jax.experimental import pallas as pl
 DEFAULT_BLOCK_Q = 256
 DEFAULT_BLOCK_K = 256
 
-# Independent grid tune for the backward dK/dV kernel (ROUND_NOTES r2:
-# dkv ran 0.92x vs XLA at 8k/16h while dq won — the dkv kernel loops over
-# q blocks per kv block, so its sweet spot differs from dq's). None =
+# Independent grid tune for the backward dK/dV kernel (the builders'
+# round-2 self-report had dkv at 0.92x of XLA at 8k/16h while dq won — the
+# dkv kernel loops over q blocks per kv block, so its sweet spot differs
+# from dq's; not measured since). None =
 # inherit (block_q, block_k); set via set_dkv_blocks() or the env var
 # SUBSTRATUS_FLASH_DKV_BLOCKS="bq,bk"; swept by tools/flash_dkv_tune.py.
 _DKV_BLOCKS = None
@@ -587,6 +593,24 @@ def _cached_sp(quantized, has_len, block_q, block_k, interpret):
     return f
 
 
+def cached_block_k(block_k: int, sk: int, quantized: bool,
+                   interpret: bool = False) -> int:
+    """Cache block of the cached-chunk kernel. The int8 cache's scale
+    block [1, 8, block_k] puts the cache length on the lanes, where Mosaic
+    takes a multiple of 128 or the whole axis; halving down to a divisor
+    of a length that is no multiple of 128 lands below that. Raises
+    ValueError then, so Engine construction can refuse the length before
+    the compiler does (interpret mode has no such rule)."""
+    block_k = _fit_block(block_k, sk)
+    if quantized and not interpret and block_k % 128 and block_k != sk:
+        raise ValueError(
+            f"chunk_attn_impl=flash cannot tile an int8 cache of length "
+            f"{sk}: its largest power-of-two block is {block_k}, not a "
+            "multiple of 128; make max_seq_len a multiple of 128"
+        )
+    return block_k
+
+
 def _cached_impl(
     q, k, v, q_positions, k_scale, v_scale, kv_length,
     block_q, block_k, interpret,
@@ -595,10 +619,10 @@ def _cached_impl(
     kh, sk = k.shape[1], k.shape[2]
     assert h % kh == 0
     group = h // kh
-    block_q = _fit_block(block_q, sq)
-    block_k = _fit_block(block_k, sk)
-    nq, nk = sq // block_q, sk // block_k
     quantized = k_scale is not None
+    block_q = _fit_block(block_q, sq)
+    block_k = cached_block_k(block_k, sk, quantized, interpret)
+    nq, nk = sq // block_q, sk // block_k
 
     qt = q.transpose(0, 2, 1, 3).reshape(b * h, sq, d)
     kt = k.reshape(b * kh, sk, d)

@@ -1,0 +1,360 @@
+"""One list of Pallas kernel cases at the widths the repo serves and trains.
+
+Shared by tests/test_chip_compile.py (each case AOT-compiled by the
+chip's compiler for a described, unattached v5e) and chip_smoke.py's
+kernel phase (each case compiled, run on the attached chip and compared
+with the XLA reference in ops/attention.py / ops/quant.py / Q4Tensor.
+dequant). A case the chip's compiler refuses carries the compiler's
+message in `refused`: the compile test marks it strict-xfail and the
+smoke prints it as "not run".
+
+Widths: TinyLlama-1.1B (32 heads / 4 kv heads of 64, dim 2048, hidden
+5632) and Llama-2-7B (32/32 heads of 128, dim 4096, hidden 11008);
+prefill lengths are the engine's power-of-two buckets up to
+EngineConfig.max_prefill_len (16..512) plus the trainer's 1024/2048;
+cache lengths are EngineConfig.max_seq_len (1024) and the old bench's
+512; batches are the engine's default 8 and the old bench's 24.
+"""
+from __future__ import annotations
+
+import dataclasses
+from dataclasses import dataclass
+from functools import partial
+from typing import Any, Callable, List, Optional
+
+import jax
+import jax.numpy as jnp
+
+from substratus_tpu.ops.attention import dot_product_attention
+from substratus_tpu.ops.quant import dequantize_kv, quantize_kv
+
+BF16 = jnp.bfloat16
+
+
+@dataclass(frozen=True)
+class KernelCase:
+    """make_args(key) -> positional arrays; kernel(*args, interpret=...)
+    and reference(*args) return the same pytree. `tol` bounds
+    max|kernel - reference| / max(1, max|reference|) over every leaf."""
+
+    name: str
+    make_args: Callable[[jax.Array], tuple]
+    kernel: Callable[..., Any]
+    reference: Callable[..., Any]
+    tol: float
+    refused: Optional[str] = None
+
+
+def max_error(got: Any, want: Any) -> float:
+    """Largest difference over all leaves, relative to the reference's
+    largest magnitude (floored at 1 so near-zero outputs compare
+    absolutely)."""
+    worst = 0.0
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        g32, w32 = g.astype(jnp.float32), w.astype(jnp.float32)
+        denom = jnp.maximum(1.0, jnp.max(jnp.abs(w32)))
+        worst = max(worst, float(jnp.max(jnp.abs(g32 - w32)) / denom))
+    return worst
+
+
+def _normal(key, shape, dtype=BF16):
+    return jax.random.normal(key, shape, jnp.float32).astype(dtype)
+
+
+# --- flash attention (no-cache prefill / training) -------------------------
+
+
+def _flash_args(b, s, h, kh, d, key):
+    kq, kk, kv, kg = jax.random.split(key, 4)
+    return (
+        _normal(kq, (b, s, h, d)), _normal(kk, (b, s, kh, d)),
+        _normal(kv, (b, s, kh, d)), _normal(kg, (b, s, h, d)),
+    )
+
+
+def flash_fwd(tag, b, s, h, kh, d) -> KernelCase:
+    from substratus_tpu.ops.flash_attention import (
+        DEFAULT_BLOCK_K, DEFAULT_BLOCK_Q, flash_attention,
+    )
+
+    def kernel(q, k, v, g, interpret=False):
+        return flash_attention(
+            q, k, v, True, None, DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K, interpret
+        )
+
+    def reference(q, k, v, g):
+        return dot_product_attention(q, k, v, causal=True)
+
+    return KernelCase(
+        f"flash_fwd/{tag}/b{b}-s{s}", partial(_flash_args, b, s, h, kh, d),
+        kernel, reference, tol=2e-2,
+    )
+
+
+def flash_bwd(tag, b, s, h, kh, d) -> KernelCase:
+    from substratus_tpu.ops.flash_attention import (
+        DEFAULT_BLOCK_K, DEFAULT_BLOCK_Q, flash_attention,
+    )
+
+    def grads(attend, q, k, v, g):
+        def loss(q, k, v):
+            return jnp.sum(
+                attend(q, k, v).astype(jnp.float32) * g.astype(jnp.float32)
+            )
+
+        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    def kernel(q, k, v, g, interpret=False):
+        return grads(
+            lambda q, k, v: flash_attention(
+                q, k, v, True, None, DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K,
+                interpret,
+            ),
+            q, k, v, g,
+        )
+
+    def reference(q, k, v, g):
+        return grads(
+            lambda q, k, v: dot_product_attention(q, k, v, causal=True),
+            q, k, v, g,
+        )
+
+    return KernelCase(
+        f"flash_bwd/{tag}/b{b}-s{s}", partial(_flash_args, b, s, h, kh, d),
+        kernel, reference, tol=4e-2,
+    )
+
+
+# --- attention against the dense slot cache --------------------------------
+
+
+def _cache_args(b, sq, h, kh, d, cache_len, int8, key):
+    """q [B, Sq, H, D], cache k/v [B, KH, S, D] (+ scales), and per-row
+    positions that put the queries at the END of a nearly full cache."""
+    kq, kk, kv = jax.random.split(key, 3)
+    q = _normal(kq, (b, sq, h, d))
+    k = _normal(kk, (b, kh, cache_len, d))
+    v = _normal(kv, (b, kh, cache_len, d))
+    start = cache_len - sq - jnp.arange(b, dtype=jnp.int32) * 3
+    positions = start[:, None] + jnp.arange(sq, dtype=jnp.int32)[None, :]
+    if not int8:
+        return q, k, v, positions
+    kq8, ks = quantize_kv(k)
+    vq8, vs = quantize_kv(v)
+    return q, kq8, vq8, positions, ks[..., 0], vs[..., 0]
+
+
+def _cache_reference(q, k, v, positions, k_scale=None, v_scale=None):
+    if k_scale is not None:
+        k = dequantize_kv(k, k_scale[..., None], q.dtype)
+        v = dequantize_kv(v, v_scale[..., None], q.dtype)
+    return dot_product_attention(
+        q, k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3),
+        causal=True, q_positions=positions,
+    )
+
+
+def _kv_tag(int8: bool) -> str:
+    return "int8kv" if int8 else "bf16kv"
+
+
+def flash_cached(tag, b, sq, h, kh, d, cache_len, int8) -> KernelCase:
+    from substratus_tpu.ops.flash_attention import flash_cached_attention
+
+    def kernel(q, k, v, positions, ks=None, vs=None, interpret=False):
+        return flash_cached_attention(
+            q, k, v, positions, ks, vs, interpret=interpret
+        )
+
+    return KernelCase(
+        f"flash_cached/{tag}/b{b}-q{sq}-s{cache_len}-{_kv_tag(int8)}",
+        partial(_cache_args, b, sq, h, kh, d, cache_len, int8),
+        kernel, _cache_reference, tol=2e-2,
+    )
+
+
+def decode_pallas(tag, b, h, kh, d, cache_len, int8) -> KernelCase:
+    from substratus_tpu.ops.decode_attention import decode_attention
+
+    def kernel(q, k, v, positions, ks=None, vs=None, interpret=False):
+        return decode_attention(
+            q, k, v, positions[:, 0], ks, vs, impl="pallas",
+            interpret=interpret,
+        )
+
+    return KernelCase(
+        f"decode_pallas/{tag}/b{b}-s{cache_len}-{_kv_tag(int8)}",
+        partial(_cache_args, b, 1, h, kh, d, cache_len, int8),
+        kernel, _cache_reference, tol=2e-2,
+    )
+
+
+def _fused_args(b, h, kh, d, cache_len, int8, key):
+    """fused_decode_attention's operands: the cache WITHOUT the fresh row,
+    the fresh k/v row, and (int8) the scale cache with the fresh scale
+    already scattered, as update_cache_and_attend hands them over."""
+    kc, kn = jax.random.split(key)
+    q, ck, cv, positions, *scales = _cache_args(
+        b, 1, h, kh, d, cache_len, int8, kc
+    )
+    positions = positions[:, 0]
+    k1, k2 = jax.random.split(kn)
+    nk, nv = _normal(k1, (b, kh, 1, d)), _normal(k2, (b, kh, 1, d))
+    if not int8:
+        return q, nk, nv, ck, cv, positions
+    nk, nks = quantize_kv(nk)
+    nv, nvs = quantize_kv(nv)
+    nks, nvs = nks[..., 0], nvs[..., 0]
+    cks = _scatter_rows(scales[0], nks, positions)
+    cvs = _scatter_rows(scales[1], nvs, positions)
+    return q, nk, nv, ck, cv, positions, nks, nvs, cks, cvs
+
+
+def _scatter_rows(cache, fresh, positions):
+    b, kh = cache.shape[:2]
+    return cache.at[
+        jnp.arange(b)[:, None, None], jnp.arange(kh)[None, :, None],
+        positions[:, None, None],
+    ].set(fresh)
+
+
+def fused_decode(tag, b, h, kh, d, cache_len, int8,
+                 refused=None) -> KernelCase:
+    from substratus_tpu.ops.fused_decode import fused_decode_attention
+
+    def kernel(*args, interpret=False):
+        return fused_decode_attention(*args, interpret=interpret)
+
+    def reference(q, nk, nv, ck, cv, positions, nks=None, nvs=None,
+                  cks=None, cvs=None):
+        ck2 = _scatter_rows(ck, nk, positions)
+        cv2 = _scatter_rows(cv, nv, positions)
+        attn = _cache_reference(q, ck2, cv2, positions[:, None], cks, cvs)
+        return attn, ck2, cv2
+
+    return KernelCase(
+        f"fused_decode/{tag}/b{b}-s{cache_len}-{_kv_tag(int8)}",
+        partial(_fused_args, b, h, kh, d, cache_len, int8),
+        kernel, reference, tol=2e-2, refused=refused,
+    )
+
+
+# --- int4 unpack-dequant matmul ---------------------------------------------
+
+
+def q4_matmul(tag, m, c, n) -> KernelCase:
+    from substratus_tpu.ops.quant4 import Q4Tensor, _matmul, quantize4
+
+    def make_args(key):
+        kx, kw = jax.random.split(key)
+        w = quantize4(_normal(kw, (c, n), jnp.float32) * 0.02, (0,))
+        return _normal(kx, (m, c)), w.packed, w.scale
+
+    def kernel(x, packed, scale, interpret=False):
+        return _matmul(x, packed, scale, c // scale.shape[0],
+                       interpret=interpret)
+
+    def reference(x, packed, scale):
+        w = Q4Tensor(packed, scale, pack_axis=-2, block=c // scale.shape[0])
+        return jnp.einsum(
+            "mc,cn->mn", x.astype(jnp.float32), w.dequant(jnp.float32)
+        ).astype(x.dtype)
+
+    return KernelCase(
+        f"q4_matmul/{tag}/m{m}-c{c}-n{n}", make_args, kernel, reference,
+        tol=2e-2,
+    )
+
+
+# --- the lists ---------------------------------------------------------------
+
+# Mosaic's words (jax 0.9.0, libtpu 0.0.34) for the fused decode kernel.
+FUSED_BF16_REFUSED = (
+    "Slice shape along dimension 2 must be aligned to tiling (2), but is 1"
+)
+FUSED_INT8_REFUSED = (
+    "last two dimensions of your block shape are divisible by 8 and 128"
+)
+
+# What the chip's compiler says of a kernel wrapped in custom_partitioning
+# (ops/kernel_partition.py) once its operands are sharded over a mesh of
+# several chips: the partitioner never runs, and the wrapper reaches the
+# TPU emitter as it is. One chip compiles the same kernels.
+SHARDED_REFUSED = "Custom emitter for CustomSPMDPartitioning not found"
+
+TINYLLAMA = dict(h=32, kh=4, d=64)
+SMALL = dict(h=4, kh=2, d=64)  # the CPU rehearsal's widths
+LLAMA7B = dict(h=32, kh=32, d=128)
+
+
+def chip_cases() -> List[KernelCase]:
+    """Every kernel at TinyLlama-1.1B's and Llama-2-7B's widths."""
+    cases: List[KernelCase] = []
+    for tag, w in (("tinyllama", TINYLLAMA), ("llama2-7b", LLAMA7B)):
+        for s in (16, 128, 384, 512, 2048):
+            cases.append(flash_fwd(tag, 1, s, **w))
+        cases.append(flash_bwd(tag, 2, 512, **w))
+        cases.append(flash_bwd(tag, 1, 2048, **w))
+        for int8 in (False, True):
+            # A bucket-padded prefill chunk, and a spec_k=4 verify pass
+            # over the whole decode batch.
+            cases.append(flash_cached(tag, 1, 512, cache_len=1024, int8=int8, **w))
+            cases.append(flash_cached(tag, 8, 5, cache_len=1024, int8=int8, **w))
+            cases.append(decode_pallas(tag, 8, cache_len=1024, int8=int8, **w))
+            cases.append(decode_pallas(tag, 24, cache_len=512, int8=int8, **w))
+            cases.append(fused_decode(
+                tag, 8, cache_len=1024, int8=int8,
+                refused=FUSED_INT8_REFUSED if int8 else FUSED_BF16_REFUSED,
+                **w,
+            ))
+    # A cache length that is no multiple of 128: TinyLlama's fits the
+    # decode kernel's VMEM budget whole (Llama-2-7B's is refused before
+    # the compiler, tests/test_chip_compile.py).
+    cases.append(decode_pallas("tinyllama", 8, cache_len=1000, int8=True, **TINYLLAMA))
+    for tag, dim, hidden, kv_dim in (
+        ("tinyllama", 2048, 5632, 256), ("llama2-7b", 4096, 11008, 4096)
+    ):
+        for m in (8, 24, 512):
+            cases.append(q4_matmul(f"{tag}-wq", m, dim, dim))
+            cases.append(q4_matmul(f"{tag}-up", m, dim, hidden))
+            cases.append(q4_matmul(f"{tag}-down", m, hidden, dim))
+        cases.append(q4_matmul(f"{tag}-wk", 8, dim, kv_dim))
+        cases.append(q4_matmul(f"{tag}-lm_head", 8, dim, 32000))
+    return cases
+
+
+def rehearsal_cases() -> List[KernelCase]:
+    """One small case per kernel for the CPU rehearsal (interpret mode)."""
+    w = SMALL
+    return [
+        flash_fwd("small", 1, 128, **w),
+        flash_bwd("small", 1, 128, **w),
+        flash_cached("small", 1, 16, cache_len=128, int8=False, **w),
+        flash_cached("small", 2, 5, cache_len=128, int8=True, **w),
+        decode_pallas("small", 2, cache_len=128, int8=False, **w),
+        decode_pallas("small", 2, cache_len=128, int8=True, **w),
+        fused_decode("small", 2, cache_len=128, int8=False, **w),
+        fused_decode("small", 2, cache_len=128, int8=True, **w),
+        q4_matmul("small", 8, 256, 128),
+    ]
+
+
+def sharded_flash_case(n_devices: int, s: int = 512,
+                       widths: Optional[dict] = None) -> KernelCase:
+    """The trainer's flash forward with one sequence per device (what
+    fsdp=n_devices hands the kernel); TinyLlama's widths by default."""
+    case = flash_fwd("sharded", n_devices, s, **(widths or TINYLLAMA))
+    return dataclasses.replace(case, refused=SHARDED_REFUSED)
+
+
+def shard_batch(args: tuple, mesh) -> tuple:
+    """Shard every operand's leading (batch) axis over the mesh's first
+    axis; `args` may be arrays or ShapeDtypeStructs."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    sharding = NamedSharding(mesh, P(mesh.axis_names[0]))
+    return tuple(
+        jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding)
+        if isinstance(a, jax.ShapeDtypeStruct) else jax.device_put(a, sharding)
+        for a in args
+    )

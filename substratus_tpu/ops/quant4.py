@@ -4,15 +4,14 @@ The reference's flagship 70B example serves 4-bit on a single GPU
 (reference: examples/llama2-70b/server.yaml:10, `MODEL_LOAD_IN_4BIT` via
 bitsandbytes; examples/llama2-13b-chat-gguf serves 4-bit GGUF through
 llama.cpp). Here 4-bit is a first-class TPU op: decode is HBM-bandwidth
-bound, and int4 halves the dominant weight stream relative to int8
-(practical HBM on the dev v5e measures ~370-400 GB/s, so weight bytes are
-the decode roofline — ROUND_NOTES.md r2).
+bound, and int4 halves the dominant weight stream relative to int8.
+Whether that wins on the chip is not measured (ROADMAP.md S3).
 
 Storage
 -------
 Two int4 values nibble-pack into one uint8 along the LAST contracting dim
-of the weight (native jnp.int4 arrays crash the device transport —
-tools/int4_probe.py — so packing is explicit). Packing is *block-folded*:
+of the weight (packing is explicit rather than a native jnp.int4 array,
+so the kernel controls the layout). Packing is *block-folded*:
 within each block of `block` consecutive rows, byte r holds original rows
 (r, r + block/2) as (low, high) nibbles. Unpacking a block is then a
 concatenate of the two sign-extended nibble planes — no sublane
@@ -24,12 +23,15 @@ group size (128) that keeps 4-bit quality at 7B-70B scale.
 
 Compute
 -------
-* `q4einsum` — einsum with the packed weight. On an unsharded TPU backend
-  it tiles a Pallas kernel: packed bytes stream HBM->VMEM, nibble unpack +
-  group-scale dequant happen in VMEM right next to the MXU dot, and only
-  the f32 accumulator leaves. Everywhere else (CPU tests, pjit meshes) it
-  lowers to two fused XLA einsums over the nibble planes — elementwise
-  producers + dots the SPMD partitioner shards like any dense matmul.
+* `q4einsum` — einsum with the packed weight. On a TPU backend (sharded
+  or not, via the custom_partitioning rule) it tiles a Pallas kernel:
+  packed bytes stream HBM->VMEM, nibble unpack + group-scale dequant
+  happen in VMEM right next to the MXU dot, and only the f32 accumulator
+  leaves. On other backends it lowers to two fused XLA einsums over the
+  nibble planes — elementwise producers + dots the SPMD partitioner
+  shards like any dense matmul. The kernel never picks interpret mode:
+  a CPU test that forces the kernel path asks for it itself
+  (tests/conftest.py::pallas_interpret).
 * Equations whose contracted dims are not (trailing in x, leading in w,
   same order) dequantize and fall back (MoE expert einsums).
 
@@ -166,7 +168,7 @@ def quantize4(w: jnp.ndarray, contracting: Sequence[int]) -> Q4Tensor:
 
 
 def _matmul_kernel(x_ref, p_ref, s_ref, o_ref, acc_ref, *,
-                   block: int, nk: int):
+                   block: int, nk: int, groups: int):
     ik = pl.program_id(2)
 
     @pl.when(ik == 0)
@@ -187,6 +189,17 @@ def _matmul_kernel(x_ref, p_ref, s_ref, o_ref, acc_ref, *,
     )  # [m, block, bn] — natural row order thanks to the block-fold pack
     s = s_ref[...]  # [m, bn] f32
     x = x_ref[...]
+    if groups % m:
+        # Ragged last k tile (C is not a multiple of bk): what the block
+        # reads past C is unspecified, so zero the scale rows and the x
+        # columns beyond it. The nibbles themselves are always finite.
+        first = ik * m
+        live = first + lax.broadcasted_iota(jnp.int32, (m, 1), 0) < groups
+        s = jnp.where(live, s, 0.0)
+        cols = first * block + lax.broadcasted_iota(
+            jnp.int32, (1, 2 * bk2), 1
+        )
+        x = jnp.where(cols < groups * block, x, jnp.zeros_like(x))
     wf = (w.astype(jnp.float32) * s[:, None, :]).reshape(2 * bk2, bn)
     acc_ref[...] += lax.dot_general(
         x, wf.astype(x.dtype), (((1,), (0,)), ((), ())),
@@ -205,6 +218,18 @@ def _pick(total: int, prefs: Sequence[int]) -> int:
     return total
 
 
+def _pick_bk(C: int, block: int) -> int:
+    """k tile: the scale block is (bk // block, bn), and Mosaic takes a
+    second-minor block dim only when it is a multiple of 8 or the whole
+    axis. So 16 or 8 groups where that divides C, the whole of a C under
+    8 groups, and otherwise 8 groups with a ragged, masked last tile
+    (Llama-2-7B's down projection: 11008 = 86 groups)."""
+    for m in (16, 8):
+        if C % (block * m) == 0:
+            return block * m
+    return C if C < 8 * block else 8 * block
+
+
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
 def _matmul(x2: jnp.ndarray, packed: jnp.ndarray, scale: jnp.ndarray,
             block: int, interpret: bool = False):
@@ -213,9 +238,11 @@ def _matmul(x2: jnp.ndarray, packed: jnp.ndarray, scale: jnp.ndarray,
     N = packed.shape[1]
     bm = _pick(M, (256, 128, 64, 32, 24, 16, 8))
     bn = _pick(N, (512, 256, 128))
-    bk = _pick(C, tuple(block * m for m in (16, 8, 4, 2, 1)))
-    nk = C // bk
-    kernel = functools.partial(_matmul_kernel, block=block, nk=nk)
+    bk = _pick_bk(C, block)
+    nk = pl.cdiv(C, bk)
+    kernel = functools.partial(
+        _matmul_kernel, block=block, nk=nk, groups=C // block
+    )
     return pl.pallas_call(
         kernel,
         grid=(M // bm, N // bn, nk),
@@ -279,8 +306,7 @@ def _local_q4_matmul(x2, p2, s2, block: int) -> jnp.ndarray:
     N = p2.shape[1]
     if M >= 8 and N % 128 == 0 and C % (2 * block) == 0:
         _KERNEL_TRACES += 1
-        interpret = jax.default_backend() != "tpu"
-        return _matmul(x2, p2, s2, block, interpret=interpret)
+        return _matmul(x2, p2, s2, block)
     return _q4_xla_2d(x2, p2, s2, block).astype(x2.dtype)
 
 
@@ -417,10 +443,7 @@ def set_q4_impl(impl: Optional[str]) -> Optional[str]:
 def _use_pallas() -> bool:
     if _FORCE_IMPL is not None:
         return _FORCE_IMPL == "pallas"
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # sublint: allow[broad-except]: backend init failure of any kind means no TPU; fall back to XLA
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def q4einsum(eq: str, x: jnp.ndarray, w: Q4Tensor,

@@ -42,7 +42,6 @@ from substratus_tpu.models import llama
 from substratus_tpu.models.llama import LlamaConfig, Params
 from substratus_tpu.ops.basics import rms_norm
 from substratus_tpu.ops.quant import materialize
-from substratus_tpu.utils import jaxcompat
 
 AXIS = "stage"
 
@@ -123,7 +122,7 @@ def pipeline_forward(
         init = jnp.zeros((mb, S, cfg.dim), cfg.dtype)
         # Mark the carry as stage-varying: the scan's output (post-ppermute)
         # is device-varying, and scan requires carry types to match.
-        init = jaxcompat.pcast(init, (AXIS,), to="varying")
+        init = lax.pcast(init, (AXIS,), to="varying")
         _, (collected, auxes) = lax.scan(step, init, jnp.arange(M + n - 1))
         # Valid outputs live at steps n-1 .. n-1+M-1; broadcast them off the
         # last stage to every stage (zeros elsewhere -> psum is a select).
@@ -134,7 +133,7 @@ def pipeline_forward(
         aux_total = lax.psum(auxes.sum(), AXIS) / (cfg.n_layers * M)
         return outs, aux_total  # [M, mb, S, D], scalar
 
-    outs, aux = jaxcompat.shard_map(
+    outs, aux = jax.shard_map(
         pipelined,
         in_specs=(layers_spec, P()),
         out_specs=(P(), P()),
@@ -229,8 +228,8 @@ def pipeline_train_step_1f1b(
         # psum over stages — which would silently sum the masked-out
         # garbage gradients from invalid ticks on other stages into the
         # valid one's BEFORE the validity mask can drop them.
-        norm_w = jaxcompat.pcast(norm_w, (AXIS,), to="varying")
-        lm_head = jaxcompat.pcast(lm_head, (AXIS,), to="varying")
+        norm_w = lax.pcast(norm_w, (AXIS,), to="varying")
+        lm_head = lax.pcast(lm_head, (AXIS,), to="varying")
         s = lax.axis_index(AXIS)
         is_last = s == n - 1
         is_first = s == 0
@@ -309,26 +308,26 @@ def pipeline_train_step_1f1b(
 
         zeros_act = jnp.zeros((mb, S, cfg.dim), dt)
         init = (
-            jaxcompat.pcast(zeros_act, (AXIS,), to="varying"),
-            jaxcompat.pcast(zeros_act, (AXIS,), to="varying"),
-            jaxcompat.pcast(jnp.zeros((K, mb, S, cfg.dim), dt), (AXIS,), to="varying"),
-            jaxcompat.pcast(
+            lax.pcast(zeros_act, (AXIS,), to="varying"),
+            lax.pcast(zeros_act, (AXIS,), to="varying"),
+            lax.pcast(jnp.zeros((K, mb, S, cfg.dim), dt), (AXIS,), to="varying"),
+            lax.pcast(
                 jax.tree.map(
                     lambda a: jnp.zeros(a.shape, jnp.float32), local
                 ), (AXIS,), to="varying",
             ),
-            jaxcompat.pcast(
+            lax.pcast(
                 jax.tree.map(
                     lambda a: jnp.zeros(a.shape, jnp.float32),
                     (norm_w, lm_head),
                 ), (AXIS,), to="varying",
             ),
-            jaxcompat.pcast(
+            lax.pcast(
                 jnp.zeros((cfg.vocab_size, cfg.dim), jnp.float32),
                 (AXIS,), to="varying",
             ),
-            jaxcompat.pcast(jnp.zeros((), jnp.float32), (AXIS,), to="varying"),
-            jaxcompat.pcast(jnp.zeros((), jnp.float32), (AXIS,), to="varying"),
+            lax.pcast(jnp.zeros((), jnp.float32), (AXIS,), to="varying"),
+            lax.pcast(jnp.zeros((), jnp.float32), (AXIS,), to="varying"),
         )
         T = M + 2 * n - 2
         carry, _ = lax.scan(tick, init, jnp.arange(T))
@@ -343,7 +342,7 @@ def pipeline_train_step_1f1b(
         g_layers = jax.tree.map(lambda g: g[None], g_layers)
         return nll, aux, g_layers, g_head, g_embed
 
-    loss, aux, g_layers, g_head, g_embed = jaxcompat.shard_map(
+    loss, aux, g_layers, g_head, g_embed = jax.shard_map(
         pipelined,
         in_specs=(layers_spec, P(), P(), P(), P()),
         out_specs=(P(), P(), layers_spec, P(), P()),

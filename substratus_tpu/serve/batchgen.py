@@ -578,10 +578,6 @@ def main(argv=None) -> int:
     ap.add_argument("--params", default="/content/params.json")
     args = ap.parse_args(argv)
 
-    from substratus_tpu.utils.jaxenv import honor_requested_platform
-
-    honor_requested_platform()
-
     import jax
 
     from substratus_tpu.observability.propagation import context_from_env
@@ -598,6 +594,9 @@ def main(argv=None) -> int:
     from substratus_tpu.serve.tokenizer import load_tokenizer
 
     maybe_initialize()
+    from substratus_tpu.utils.jaxstart import jax_startup
+
+    jax_startup()
 
     params_json = load_params_json(args.params)
     from substratus_tpu.utils.params import warn_unknown_keys

@@ -493,6 +493,16 @@ class Engine:
             raise ValueError(
                 f"role={ec.role!r} requires the paged kv layout"
             )
+        if not self.paged and hasattr(cfg, "decode_attn_impl"):
+            # The Pallas attention kernels read the dense slot cache: refuse
+            # here what the chip's compiler would refuse in the first step.
+            from substratus_tpu.ops.decode_attention import check_cache_tiling
+
+            check_cache_tiling(
+                cfg.decode_attn_impl, cfg.chunk_attn_impl, cfg.n_kv_heads,
+                cfg.head_size, S,
+                1 if kv_int8 else jnp.dtype(cfg.dtype).itemsize, kv_int8,
+            )
         self.handoff = handoff
         if ec.role == "prefill":
             if handoff is None:

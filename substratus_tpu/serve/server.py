@@ -35,6 +35,7 @@ from substratus_tpu.observability.tracing import tracer
 from substratus_tpu.serve.adapters import UnknownAdapter
 from substratus_tpu.serve.engine import Engine, EngineOverloaded, Request
 from substratus_tpu.serve.tokenizer import Tokenizer
+from substratus_tpu.utils.jaxstart import device_memory
 
 # Structured access log: one JSON line per traced request, carrying the
 # trace id so log pipelines join lines to span exports
@@ -847,6 +848,7 @@ def build_app(state: ServerState) -> web.Application:
         if getattr(eng, "paged", False):
             METRICS.set("substratus_serve_kv_pages_total", eng.n_pages)
             METRICS.set("substratus_serve_kv_pages_free", eng.alloc.free_pages)
+        device_memory()  # substratus_device_* gauges (utils/jaxstart.py)
         # The versioned content type Prometheus negotiates for (the
         # controller endpoint in observability/health.py already sends it;
         # a bare text/plain leaves the scraper guessing the format version).

@@ -33,9 +33,9 @@ def main(argv=None) -> int:
     ap.add_argument("--params", default="/content/params.json")
     args = ap.parse_args(argv)
 
-    from substratus_tpu.utils.jaxenv import honor_requested_platform
+    from substratus_tpu.utils.jaxstart import jax_startup, print_device_memory
 
-    honor_requested_platform()
+    jax_startup()
 
     p = {}
     if os.path.exists(args.params):
@@ -244,6 +244,8 @@ def main(argv=None) -> int:
         ),
         tokens_per_step=batch_size * seq_len,
         peak_flops=device_peak_flops(),
+        # A run of a few steps (a smoke) shows every one of them.
+        log_every=10 if steps > 10 else 1,
     )
     # Distributed tracing: the controller stamps a TRACEPARENT env var
     # into the training Job's container (controller/workloads.py), so this
@@ -324,6 +326,7 @@ def main(argv=None) -> int:
         )
         print(f"adapter artifact saved to {args.out}/adapter", flush=True)
     print(f"artifact saved to {args.out}", flush=True)
+    print_device_memory()
     return 0
 
 

@@ -55,9 +55,9 @@ for _name, _help in (
 ):
     METRICS.describe(_name, _help, type="gauge")
 
-# Per-chip dense peak FLOPs (bf16), for the MFU denominator. Unlisted
-# device kinds (CPU test meshes included) report mfu=0 rather than a
-# number computed against a made-up peak.
+# Per-chip dense peak FLOPs (bf16), for the MFU denominator (Google Cloud
+# documentation of each part). A CPU run reports mfu=0; a TPU whose kind
+# is not listed is an error, not a run whose MFU silently drops out.
 PEAK_FLOPS = {
     "TPU v4": 275e12,
     "TPU v5 lite": 197e12,
@@ -69,11 +69,19 @@ PEAK_FLOPS = {
 
 
 def device_peak_flops() -> Optional[float]:
-    """Aggregate peak FLOPs of every addressable-or-not device in the run,
-    or None when the device kind has no table entry."""
+    """Aggregate peak FLOPs of every addressable-or-not device in the run;
+    None off the TPU (CPU test meshes). KeyError for a TPU device kind
+    with no table entry."""
     devices = jax.devices()
-    per_chip = PEAK_FLOPS.get(devices[0].device_kind)
-    return per_chip * len(devices) if per_chip else None
+    kind = devices[0].device_kind
+    if kind not in PEAK_FLOPS:
+        if devices[0].platform != "tpu":
+            return None
+        raise KeyError(
+            f"no peak FLOPs for device_kind {kind!r}; add it to "
+            "train/telemetry.py::PEAK_FLOPS with its source"
+        )
+    return PEAK_FLOPS[kind] * len(devices)
 
 
 class StepLogger:

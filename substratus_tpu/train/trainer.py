@@ -39,7 +39,6 @@ from substratus_tpu.parallel.sharding import (
     sharding_tree,
 )
 from substratus_tpu.train import lora as lora_lib
-from substratus_tpu.utils.jaxcompat import ambient_mesh
 
 
 @dataclass(frozen=True)
@@ -360,7 +359,7 @@ class Trainer:
         trainable = self.lora if self.lora is not None else self.params
         # Ambient mesh: the ring-attention path (cfg.attn_impl == "ring")
         # opens a shard_map over the "sequence" axis inside the jitted step.
-        with ambient_mesh(self.mesh):
+        with jax.set_mesh(self.mesh):
             trainable, self.opt_state, loss = self._train_step(
                 trainable, self.params if self.lora is not None else None,
                 self.opt_state, batch,

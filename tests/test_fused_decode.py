@@ -76,7 +76,7 @@ def test_fused_matches_unfused_int8():
     np.testing.assert_array_equal(cvo, cv2)
 
 
-def test_update_cache_and_attend_fused_path():
+def test_update_cache_and_attend_fused_path(pallas_interpret):
     """The impl="fused" branch of the shared cached-attention entry point
     returns the same attn + cache dict as impl="xla", int8 cache."""
     B, h, kh, S, D = 2, 4, 2, 64, 16
@@ -126,7 +126,7 @@ def test_resolve_kv_layout_routes_fused_to_dense():
         )
 
 
-def test_fused_decode_step_through_model():
+def test_fused_decode_step_through_model(pallas_interpret):
     """Greedy decode logits through the llama debug model are identical
     with decode_attn_impl='fused' vs 'xla' (the end-to-end surface the
     serving engine drives)."""
@@ -211,7 +211,7 @@ def test_block_fit_halves_for_non_pow2_cache():
     np.testing.assert_array_equal(cvo, cv2)
 
 
-def test_drifted_position_quantized_scale_and_row_agree():
+def test_drifted_position_quantized_scale_and_row_agree(pallas_interpret):
     """Code-review r5: the position clamp must be shared by the scale
     scatters (XLA, caller side) and the k/v row write (inside the
     kernel). If they disagree, row S-1 of a quantized cache pairs fresh
@@ -243,7 +243,7 @@ def test_drifted_position_quantized_scale_and_row_agree():
         np.testing.assert_array_equal(kv_drift[key], kv_ref[key])
 
 
-def test_fused_decode_engine_under_mesh():
+def test_fused_decode_engine_under_mesh(pallas_interpret):
     """Round-5: the fused kernel's custom_partitioning rule keeps it
     per-shard under a (data x tensor) serving mesh — the engine with
     kv_layout=dense + decode_attn_impl=fused over 4 devices must be

@@ -9,7 +9,6 @@ from substratus_tpu.models import llama
 from substratus_tpu.parallel.mesh import build_mesh
 from substratus_tpu.parallel.pipeline import pipeline_forward, stage_params
 from substratus_tpu.train.trainer import cross_entropy_loss
-from substratus_tpu.utils.jaxcompat import ambient_mesh
 
 
 @pytest.fixture(scope="module")
@@ -27,7 +26,7 @@ def test_pipeline_forward_matches_plain(setup, n_stages, n_micro):
 
     mesh = build_mesh(stage=n_stages, data=8 // n_stages)
     staged = stage_params(params, n_stages)
-    with ambient_mesh(mesh):
+    with jax.set_mesh(mesh):
         out, aux = jax.jit(
             lambda p, t: pipeline_forward(p, t, cfg, n_stages, n_micro)
         )(staged, tokens)
@@ -52,7 +51,7 @@ def test_pipeline_backward_matches_plain(setup):
 
     g_plain = jax.grad(loss_plain)(params)
     staged = stage_params(params, n_stages)
-    with ambient_mesh(mesh):
+    with jax.set_mesh(mesh):
         g_pp = jax.jit(jax.grad(loss_pp))(staged)
 
     # Compare a few representative leaves (reshape staged grads back).
@@ -82,7 +81,7 @@ def test_pipeline_moe_matches_plain():
 
     mesh = build_mesh(stage=2, data=4)
     staged = stage_params(params, 2)
-    with ambient_mesh(mesh):
+    with jax.set_mesh(mesh):
         out, aux = jax.jit(
             lambda p, t: pipeline_forward(p, t, cfg, 2, 4)
         )(staged, tokens)
@@ -105,7 +104,7 @@ def test_pipeline_moe_matches_plain():
             + cfg.router_aux_weight * aux
         )
 
-    with ambient_mesh(mesh):
+    with jax.set_mesh(mesh):
         loss, grads = jax.jit(jax.value_and_grad(loss_pp))(staged)
     assert np.isfinite(float(loss))
     assert np.isfinite(np.asarray(grads["layers"]["router"])).all()
@@ -137,7 +136,7 @@ def test_1f1b_matches_gpipe_loss_and_grads():
         logits, _ = pipeline_forward(p, tokens, cfg, 2, 4, train=True)
         return cross_entropy_loss(logits[:, :-1], tokens[:, 1:])
 
-    with ambient_mesh(mesh):
+    with jax.set_mesh(mesh):
         loss_g, grads_g = jax.jit(jax.value_and_grad(gpipe_loss))(staged)
         loss_f, grads_f, aux = jax.jit(
             lambda p: pipeline_train_step_1f1b(p, tokens, cfg, 2, 4)
@@ -186,7 +185,7 @@ def test_1f1b_moe_runs_and_matches_gpipe_loss():
             + cfg.router_aux_weight * aux
         )
 
-    with ambient_mesh(mesh):
+    with jax.set_mesh(mesh):
         loss_g, grads_g = jax.jit(jax.value_and_grad(gpipe_obj))(staged)
         loss_f, grads_f, aux = jax.jit(
             lambda p: pipeline_train_step_1f1b(p, tokens, cfg, 2, 2)

@@ -207,7 +207,7 @@ def test_int4_engine_end_to_end():
         eng.stop()
 
 
-def test_int4_fused_decode_int8kv_engine_end_to_end():
+def test_int4_fused_decode_int8kv_engine_end_to_end(pallas_interpret):
     """The throughput configuration the hardware bench runs — int4
     weights + int8 KV cache + fused flash-decode — produces the same
     greedy tokens through the engine as the plain xla decode path with

@@ -74,11 +74,13 @@ def test_tensor_parallel_int4_engine_matches_single_device(setup):
     assert "tensor" in str(spec), spec
 
 
-def test_tensor_parallel_int4_pallas_kernel_under_mesh(setup):
+def test_tensor_parallel_int4_pallas_kernel_under_mesh(
+    setup, pallas_interpret
+):
     """Round-5 closure of the 'kernels are inert under sharding' gap:
     with the custom_partitioning rule, q4einsum keeps the Pallas
     unpack-dequant kernel per-shard under a (data x tensor) mesh
-    (interpret mode on CPU) — and the result is token-exact vs the
+    (interpret mode asked for by the fixture) — token-exact vs the
     single-device XLA engine. kernel_trace_count proves the kernel was
     actually lowered, not silently swapped for the fallback."""
     from substratus_tpu.ops import quant4

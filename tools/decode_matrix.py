@@ -8,7 +8,7 @@ import jax.numpy as jnp
 
 sys.path.insert(0, "/root/repo")
 from substratus_tpu.models import llama
-from bench import random_quantized_params, hard_sync
+from bench import random_quantized_params
 
 B = 16
 
@@ -21,19 +21,19 @@ def measure(cfg, params, cache_len, kv_dtype, impl, steps=24):
     tokens = jnp.ones((B,), jnp.int32)
     positions = jnp.full((B,), 16, jnp.int32)
     logits, cache = llama.decode_step(params, cache, tokens, positions, cfg)
-    hard_sync(logits)
+    jax.block_until_ready(logits)
     t0 = time.perf_counter()
     for i in range(steps):
         positions = jnp.full((B,), 17 + i, jnp.int32)
         logits, cache = llama.decode_step(params, cache, tokens, positions, cfg)
-    hard_sync(logits)
+    jax.block_until_ready(logits)
     return (time.perf_counter() - t0) / steps
 
 
 def main():
     cfg = llama.CONFIGS["llama2-7b"]
     params = jax.jit(lambda k: random_quantized_params(cfg, k))(jax.random.key(0))
-    hard_sync(params)
+    jax.block_until_ready(params)
     for cache_len, kv_dtype, impl in [
         (64, "int8", "xla"),
         (512, "int8", "xla"),

@@ -1,4 +1,4 @@
-"""On-chip sweep of the flash backward dK/dV grid (ROUND_NOTES r2: dkv
+"""On-chip sweep of the flash backward dK/dV grid (round-2 self-report: dkv
 0.92x vs XLA at 8k/16h — the one shape where flash loses).
 
 Sweeps (block_q, block_k) for the dkv kernel at the losing shape (and a

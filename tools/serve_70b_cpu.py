@@ -22,9 +22,6 @@ sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 def main() -> None:
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    from substratus_tpu.utils.jaxenv import honor_requested_platform
-
-    honor_requested_platform()
     import jax
     import jax.numpy as jnp
 

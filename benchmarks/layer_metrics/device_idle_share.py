@@ -1,0 +1,9 @@
+"""Share of the traced window in which no operation ran on the device:
+1 - union of the device-op intervals / traced window, mean over chips."""
+
+
+def read(run):
+    t = run["trace"]
+    if not t or not t.get("window_s"):
+        return None
+    return 100.0 * (1.0 - t["busy_s"] / t["window_s"])

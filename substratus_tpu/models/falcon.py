@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from substratus_tpu.ops import scopes
 from substratus_tpu.ops.attention import dot_product_attention
 from substratus_tpu.ops.basics import layer_norm, rope, lora_delta
 
@@ -153,12 +154,13 @@ def cache_logical_axes(cfg: FalconConfig, quantized: bool = False) -> Params:
 def _block(x, lp, positions, cfg, layer_cache, kv_length=None,
            lora_layers=None, lora_scale=1.0):
     lora = lora_layers or {}
-    h_attn = layer_norm(x, lp["ln1_scale"], lp["ln1_bias"], cfg.norm_eps)
-    h_mlp = (
-        layer_norm(x, lp["ln2_scale"], lp["ln2_bias"], cfg.norm_eps)
-        if cfg.separate_ln
-        else h_attn
-    )
+    with jax.named_scope(scopes.NORM):
+        h_attn = layer_norm(x, lp["ln1_scale"], lp["ln1_bias"], cfg.norm_eps)
+        h_mlp = (
+            layer_norm(x, lp["ln2_scale"], lp["ln2_bias"], cfg.norm_eps)
+            if cfg.separate_ln
+            else h_attn
+        )
 
     def proj(name, eq, lora_eq):
         out = jnp.einsum(eq, h_attn, lp[name])
@@ -166,14 +168,18 @@ def _block(x, lp, positions, cfg, layer_cache, kv_length=None,
             out = out + lora_delta(h_attn, lora[name], lora_scale, lora_eq)
         return out
 
-    q = proj("wq", "bsd,dhk->bshk", "bsr,rhk->bshk")
-    kk = proj("wk", "bsd,dhk->bshk", "bsr,rhk->bshk")
-    vv = proj("wv", "bsd,dhk->bshk", "bsr,rhk->bshk")
-    q = rope(q, positions, cfg.rope_theta)
-    kk = rope(kk, positions, cfg.rope_theta)
+    with jax.named_scope(scopes.ATTN_QKV):
+        q = proj("wq", "bsd,dhk->bshk", "bsr,rhk->bshk")
+        kk = proj("wk", "bsd,dhk->bshk", "bsr,rhk->bshk")
+        vv = proj("wv", "bsd,dhk->bshk", "bsr,rhk->bshk")
+        q = rope(q, positions, cfg.rope_theta)
+        kk = rope(kk, positions, cfg.rope_theta)
 
     if layer_cache is None:
-        attn = dot_product_attention(q, kk, vv, causal=True, q_positions=positions)
+        with jax.named_scope(scopes.ATTN_CORE):
+            attn = dot_product_attention(
+                q, kk, vv, causal=True, q_positions=positions
+            )
         kv_out = {"k": kk, "v": vv}
     else:
         from substratus_tpu.ops.decode_attention import update_cache_and_attend
@@ -182,19 +188,23 @@ def _block(x, lp, positions, cfg, layer_cache, kv_length=None,
             layer_cache, q, kk, vv, positions, kv_length=kv_length,
         )
 
-    attn_out = jnp.einsum("bshk,hkd->bsd", attn, lp["wo"])
-    if "wo" in lora:
-        b, s = x.shape[:2]
-        attn_out = attn_out + lora_delta(
-            attn.reshape(b, s, -1), lora["wo"], lora_scale, "bsr,rd->bsd"
+    with jax.named_scope(scopes.ATTN_OUT):
+        attn_out = jnp.einsum("bshk,hkd->bsd", attn, lp["wo"])
+        if "wo" in lora:
+            b, s = x.shape[:2]
+            attn_out = attn_out + lora_delta(
+                attn.reshape(b, s, -1), lora["wo"], lora_scale, "bsr,rd->bsd"
+            )
+    with jax.named_scope(scopes.MLP):
+        mlp_out = jnp.einsum(
+            "bsm,md->bsd",
+            jax.nn.gelu(
+                jnp.einsum("bsd,dm->bsm", h_mlp, lp["fc1"]), approximate=False
+            ),
+            lp["fc2"],
         )
-    mlp_out = jnp.einsum(
-        "bsm,md->bsd",
-        jax.nn.gelu(jnp.einsum("bsd,dm->bsm", h_mlp, lp["fc1"]), approximate=False),
-        lp["fc2"],
-    )
-    # Parallel block: one residual add for both sublayers.
-    return x + attn_out + mlp_out, kv_out
+        # Parallel block: one residual add for both sublayers.
+        return x + attn_out + mlp_out, kv_out
 
 
 def forward(
@@ -213,7 +223,8 @@ def forward(
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
 
-    x = params["tok_embed"][tokens]
+    with jax.named_scope(scopes.EMBED):
+        x = params["tok_embed"][tokens]
 
     lora_scale = lora["scale"] if lora is not None else 1.0
 
@@ -231,13 +242,15 @@ def forward(
         xs["lora"] = lora["layers"]
     if remat:
         body = jax.checkpoint(body)
-    x, kv = lax.scan(body, x, xs)
+    with jax.named_scope(scopes.LAYERS):
+        x, kv = lax.scan(body, x, xs)
 
-    x = layer_norm(
-        x, params["final_ln_scale"], params["final_ln_bias"], cfg.norm_eps
-    )
-    logits = jnp.einsum("bsd,vd->bsv", x, params["tok_embed"])  # tied head
-    return logits.astype(jnp.float32), kv
+    with jax.named_scope(scopes.LM_HEAD):
+        x = layer_norm(
+            x, params["final_ln_scale"], params["final_ln_bias"], cfg.norm_eps
+        )
+        logits = jnp.einsum("bsd,vd->bsv", x, params["tok_embed"])  # tied head
+        return logits.astype(jnp.float32), kv
 
 
 @partial(jax.jit, static_argnames=("cfg",), donate_argnames=("cache",))

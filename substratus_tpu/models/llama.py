@@ -34,6 +34,7 @@ from substratus_tpu.ops.basics import (
     rope,
     swiglu,
 )
+from substratus_tpu.ops import scopes
 from substratus_tpu.ops.quant import materialize, qeinsum, qeinsum_w8a8
 
 Params = Dict[str, Any]
@@ -383,62 +384,67 @@ def _moe_ffn(
             ) * lora_scale
         return out
 
-    logits = jnp.einsum(
-        "bsd,de->bse", h.astype(jnp.float32),
-        materialize(lp["router"], jnp.float32),
-    )
-    probs = jax.nn.softmax(logits, axis=-1)  # [B,S,E]
-    top_w, top_idx = jax.lax.top_k(probs, k)  # [B,S,k]
-    top_w = top_w / jnp.sum(top_w, axis=-1, keepdims=True)  # Mixtral renorm
+    with jax.named_scope(scopes.MOE_ROUTER):
+        logits = jnp.einsum(
+            "bsd,de->bse", h.astype(jnp.float32),
+            materialize(lp["router"], jnp.float32),
+        )
+        probs = jax.nn.softmax(logits, axis=-1)  # [B,S,E]
+        top_w, top_idx = jax.lax.top_k(probs, k)  # [B,S,k]
+        top_w = top_w / jnp.sum(top_w, axis=-1, keepdims=True)  # Mixtral renorm
 
-    # Switch-style load-balancing aux: fraction of tokens routed to each
-    # expert (top-1 assignment) x mean router prob, scaled by E.
-    assigned = jax.nn.one_hot(top_idx[..., 0], E, dtype=jnp.float32)
-    aux = jnp.sum(
-        assigned.mean(axis=(0, 1)) * probs.mean(axis=(0, 1))
-    ) * E
+        # Switch-style load-balancing aux: fraction of tokens routed to each
+        # expert (top-1 assignment) x mean router prob, scaled by E.
+        assigned = jax.nn.one_hot(top_idx[..., 0], E, dtype=jnp.float32)
+        aux = jnp.sum(
+            assigned.mean(axis=(0, 1)) * probs.mean(axis=(0, 1))
+        ) * E
 
     if not train:
-        # Exact dropless mix: per-token expert weights [B,S,E].
-        w_full = jnp.sum(
-            jax.nn.one_hot(top_idx, E, dtype=jnp.float32)
-            * top_w[..., None],
-            axis=2,
-        )
-        gate = eproj("w_gate", h, "bsd,edm->bsem", "bsd,edr->bser",
-                     "bser,erm->bsem")
-        up = eproj("w_up", h, "bsd,edm->bsem", "bsd,edr->bser",
-                   "bser,erm->bsem")
-        out = eproj("w_down", swiglu(gate, up), "bsem,emd->bsed",
-                    "bsem,emr->bser", "bser,erd->bsed")
-        y = jnp.einsum("bsed,bse->bsd", out, w_full.astype(dt))
+        with jax.named_scope(scopes.MOE_ROUTER):
+            # Exact dropless mix: per-token expert weights [B,S,E].
+            w_full = jnp.sum(
+                jax.nn.one_hot(top_idx, E, dtype=jnp.float32)
+                * top_w[..., None],
+                axis=2,
+            )
+        with jax.named_scope(scopes.MOE_EXPERTS):
+            gate = eproj("w_gate", h, "bsd,edm->bsem", "bsd,edr->bser",
+                         "bser,erm->bsem")
+            up = eproj("w_up", h, "bsd,edm->bsem", "bsd,edr->bser",
+                       "bser,erm->bsem")
+            out = eproj("w_down", swiglu(gate, up), "bsem,emd->bsed",
+                        "bsem,emr->bser", "bser,erd->bsed")
+            y = jnp.einsum("bsed,bse->bsd", out, w_full.astype(dt))
         return y.astype(dt), aux
 
     t = s * k
     capacity = max(1, int(cfg.capacity_factor * s * k / E))
-    # Flatten (token, choice) pairs; compute each pair's slot within its
-    # expert's capacity buffer.
-    onehot = jax.nn.one_hot(top_idx, E, dtype=jnp.float32)  # [B,S,k,E]
-    flat = onehot.reshape(b, t, E)
-    pos = jnp.cumsum(flat, axis=1) - flat  # arrival order per expert
-    keep = (pos < capacity).astype(jnp.float32) * flat  # [B,T,E]
-    dispatch = keep[..., None] * jax.nn.one_hot(
-        pos.astype(jnp.int32), capacity, dtype=jnp.float32
-    )  # [B,T,E,C]
-    combine = dispatch * top_w.reshape(b, t)[..., None, None]
+    with jax.named_scope(scopes.MOE_ROUTER):
+        # Flatten (token, choice) pairs; compute each pair's slot within
+        # its expert's capacity buffer.
+        onehot = jax.nn.one_hot(top_idx, E, dtype=jnp.float32)  # [B,S,k,E]
+        flat = onehot.reshape(b, t, E)
+        pos = jnp.cumsum(flat, axis=1) - flat  # arrival order per expert
+        keep = (pos < capacity).astype(jnp.float32) * flat  # [B,T,E]
+        dispatch = keep[..., None] * jax.nn.one_hot(
+            pos.astype(jnp.int32), capacity, dtype=jnp.float32
+        )  # [B,T,E,C]
+        combine = dispatch * top_w.reshape(b, t)[..., None, None]
 
-    h_rep = jnp.repeat(h, k, axis=1)  # [B,T,D] (token order matches flatten)
-    expert_in = jnp.einsum(
-        "btec,btd->ebcd", dispatch.astype(dt), h_rep
-    )  # [E,B,C,D]
-    gate = eproj("w_gate", expert_in, "ebcd,edm->ebcm", "ebcd,edr->ebcr",
-                 "ebcr,erm->ebcm")
-    up = eproj("w_up", expert_in, "ebcd,edm->ebcm", "ebcd,edr->ebcr",
-               "ebcr,erm->ebcm")
-    out = eproj("w_down", swiglu(gate, up), "ebcm,emd->ebcd",
-                "ebcm,emr->ebcr", "ebcr,erd->ebcd")
-    y = jnp.einsum("ebcd,btec->btd", out, combine.astype(dt))  # [B,T,D]
-    y = y.reshape(b, s, k, d).sum(axis=2)
+    with jax.named_scope(scopes.MOE_EXPERTS):
+        h_rep = jnp.repeat(h, k, axis=1)  # [B,T,D] (token order matches flatten)
+        expert_in = jnp.einsum(
+            "btec,btd->ebcd", dispatch.astype(dt), h_rep
+        )  # [E,B,C,D]
+        gate = eproj("w_gate", expert_in, "ebcd,edm->ebcm", "ebcd,edr->ebcr",
+                     "ebcr,erm->ebcm")
+        up = eproj("w_up", expert_in, "ebcd,edm->ebcm", "ebcd,edr->ebcr",
+                   "ebcr,erm->ebcm")
+        out = eproj("w_down", swiglu(gate, up), "ebcm,emd->ebcd",
+                    "ebcm,emr->ebcr", "ebcr,erd->ebcd")
+        y = jnp.einsum("ebcd,btec->btd", out, combine.astype(dt))  # [B,T,D]
+        y = y.reshape(b, s, k, d).sum(axis=2)
     return y.astype(dt), aux
 
 
@@ -480,15 +486,18 @@ def _block(
                 out = out + lora_delta(inp, lora[name], lora_scale, lora_eq)
         return out
 
-    h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-    q = proj("wq", h, "bsd,dhk->bshk", "bsr,rhk->bshk")
-    kk = proj("wk", h, "bsd,dhk->bshk", "bsr,rhk->bshk")
-    vv = proj("wv", h, "bsd,dhk->bshk", "bsr,rhk->bshk")
-    q = rope(q, positions, cfg.rope_theta)
-    kk = rope(kk, positions, cfg.rope_theta)
+    with jax.named_scope(scopes.NORM):
+        h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
+    with jax.named_scope(scopes.ATTN_QKV):
+        q = proj("wq", h, "bsd,dhk->bshk", "bsr,rhk->bshk")
+        kk = proj("wk", h, "bsd,dhk->bshk", "bsr,rhk->bshk")
+        vv = proj("wv", h, "bsd,dhk->bshk", "bsr,rhk->bshk")
+        q = rope(q, positions, cfg.rope_theta)
+        kk = rope(kk, positions, cfg.rope_theta)
 
     if layer_cache is None:
-        attn = _self_attention(q, kk, vv, positions, cfg)
+        with jax.named_scope(scopes.ATTN_CORE):
+            attn = _self_attention(q, kk, vv, positions, cfg)
         kv_out = {"k": kk, "v": vv}
     elif block_table is not None:
         from substratus_tpu.ops.kvcache import paged_update_and_read
@@ -496,10 +505,11 @@ def _block(
         kv_out, k_cache, v_cache = paged_update_and_read(
             layer_cache, block_table, positions, kk, vv, dt
         )
-        attn = dot_product_attention(
-            q, k_cache, v_cache, causal=True, q_positions=positions,
-            kv_length=kv_length,
-        )
+        with jax.named_scope(scopes.ATTN_CORE):
+            attn = dot_product_attention(
+                q, k_cache, v_cache, causal=True, q_positions=positions,
+                kv_length=kv_length,
+            )
     else:
         from substratus_tpu.ops.decode_attention import update_cache_and_attend
 
@@ -510,26 +520,33 @@ def _block(
         )
 
     b, s = x.shape[:2]
-    attn_flat = attn.reshape(b, s, -1)
-    o = qeinsum("bshk,hkd->bsd", attn, lp["wo"], dt)
-    if "wo" in lora:
-        if adapter_ids is not None:
-            o = o + lora_delta_indexed(
-                attn_flat, lora["wo"], lora_scale, "bsr,rd->bsd", adapter_ids
-            )
-        else:
-            o = o + lora_delta(
-                attn_flat, lora["wo"], lora_scale, "bsr,rd->bsd"
-            )
-    x = x + o
-    h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
+    with jax.named_scope(scopes.ATTN_OUT):
+        attn_flat = attn.reshape(b, s, -1)
+        o = qeinsum("bshk,hkd->bsd", attn, lp["wo"], dt)
+        if "wo" in lora:
+            if adapter_ids is not None:
+                o = o + lora_delta_indexed(
+                    attn_flat, lora["wo"], lora_scale, "bsr,rd->bsd",
+                    adapter_ids,
+                )
+            else:
+                o = o + lora_delta(
+                    attn_flat, lora["wo"], lora_scale, "bsr,rd->bsd"
+                )
+        x = x + o
+    with jax.named_scope(scopes.NORM):
+        h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
     if cfg.n_experts > 0:
         y, aux = _moe_ffn(h, lp, cfg, train, lora, lora_scale)
-        x = x + y
+        with jax.named_scope(scopes.MOE_EXPERTS):
+            x = x + y
     else:
-        gate = proj("w_gate", h, "bsd,dm->bsm", "bsr,rm->bsm")
-        up = proj("w_up", h, "bsd,dm->bsm", "bsr,rm->bsm")
-        x = x + proj("w_down", swiglu(gate, up), "bsm,md->bsd", "bsr,rd->bsd")
+        with jax.named_scope(scopes.MLP):
+            gate = proj("w_gate", h, "bsd,dm->bsm", "bsr,rm->bsm")
+            up = proj("w_up", h, "bsd,dm->bsm", "bsr,rm->bsm")
+            x = x + proj(
+                "w_down", swiglu(gate, up), "bsm,md->bsd", "bsr,rd->bsd"
+            )
         aux = jnp.zeros((), jnp.float32)
     return x, kv_out, aux
 
@@ -565,7 +582,8 @@ def forward(
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
 
-    x = materialize(params["tok_embed"], cfg.dtype)[tokens]
+    with jax.named_scope(scopes.EMBED):
+        x = materialize(params["tok_embed"], cfg.dtype)[tokens]
 
     lora_scale = lora["scale"] if lora is not None else 1.0
 
@@ -592,24 +610,27 @@ def forward(
         xs["lora"] = lora["layers"]
     if remat:
         body = jax.checkpoint(body)
-    x, ys = lax.scan(body, x, xs)
+    with jax.named_scope(scopes.LAYERS):
+        x, ys = lax.scan(body, x, xs)
 
-    x = rms_norm(x, params["out_norm"], cfg.norm_eps)
-    if cfg.tie_embeddings:
-        logits = jnp.einsum(
-            "bsd,vd->bsv", x, materialize(params["tok_embed"], cfg.dtype)
-        )
-    else:
-        logits = (qeinsum_w8a8 if cfg.quant_activations else qeinsum)(
-            "bsd,dv->bsv", x, params["lm_head"], cfg.dtype
-        )
+    with jax.named_scope(scopes.LM_HEAD):
+        x = rms_norm(x, params["out_norm"], cfg.norm_eps)
+        if cfg.tie_embeddings:
+            logits = jnp.einsum(
+                "bsd,vd->bsv", x, materialize(params["tok_embed"], cfg.dtype)
+            )
+        else:
+            logits = (qeinsum_w8a8 if cfg.quant_activations else qeinsum)(
+                "bsd,dv->bsv", x, params["lm_head"], cfg.dtype
+            )
+        logits = logits.astype(jnp.float32)
     kv = ys["kv"]  # stacked over layers; same structure as the cache
     if cfg.n_experts > 0 and cache is None:
         # Per-layer router load-balancing losses (training/prefill only —
         # the decode cache must keep a stable structure for buffer
         # donation); the trainer adds router_aux_weight * mean.
         kv["moe_aux"] = ys["aux"]
-    return logits.astype(jnp.float32), kv
+    return logits, kv
 
 
 @partial(jax.jit, static_argnames=("cfg",), donate_argnames=("cache",))

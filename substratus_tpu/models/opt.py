@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from substratus_tpu.ops import scopes
 from substratus_tpu.ops.attention import dot_product_attention
 from substratus_tpu.ops.basics import layer_norm, lora_delta
 
@@ -152,7 +153,8 @@ def cache_logical_axes(cfg: OPTConfig, quantized: bool = False) -> Params:
 def _block(x, lp, positions, cfg, layer_cache, kv_length=None,
            lora_layers=None, lora_scale=1.0):
     lora = lora_layers or {}
-    h = layer_norm(x, lp["ln1_scale"], lp["ln1_bias"], cfg.norm_eps)
+    with jax.named_scope(scopes.NORM):
+        h = layer_norm(x, lp["ln1_scale"], lp["ln1_bias"], cfg.norm_eps)
 
     def proj(name, bias, eq, lora_eq):
         out = jnp.einsum(eq, h, lp[name]) + lp[bias]
@@ -160,12 +162,16 @@ def _block(x, lp, positions, cfg, layer_cache, kv_length=None,
             out = out + lora_delta(h, lora[name], lora_scale, lora_eq)
         return out
 
-    q = proj("wq", "bq", "bsd,dhk->bshk", "bsr,rhk->bshk")
-    kk = proj("wk", "bk", "bsd,dhk->bshk", "bsr,rhk->bshk")
-    vv = proj("wv", "bv", "bsd,dhk->bshk", "bsr,rhk->bshk")
+    with jax.named_scope(scopes.ATTN_QKV):
+        q = proj("wq", "bq", "bsd,dhk->bshk", "bsr,rhk->bshk")
+        kk = proj("wk", "bk", "bsd,dhk->bshk", "bsr,rhk->bshk")
+        vv = proj("wv", "bv", "bsd,dhk->bshk", "bsr,rhk->bshk")
 
     if layer_cache is None:
-        attn = dot_product_attention(q, kk, vv, causal=True, q_positions=positions)
+        with jax.named_scope(scopes.ATTN_CORE):
+            attn = dot_product_attention(
+                q, kk, vv, causal=True, q_positions=positions
+            )
         kv_out = (kk, vv)
     else:
         from substratus_tpu.ops.decode_attention import update_cache_and_attend
@@ -177,16 +183,19 @@ def _block(x, lp, positions, cfg, layer_cache, kv_length=None,
         )
         kv_out = (kv["k"], kv["v"])
 
-    o = jnp.einsum("bshk,hkd->bsd", attn, lp["wo"]) + lp["bo"]
-    if "wo" in lora:
-        b, s = x.shape[:2]
-        o = o + lora_delta(
-            attn.reshape(b, s, -1), lora["wo"], lora_scale, "bsr,rd->bsd"
-        )
-    x = x + o
-    h = layer_norm(x, lp["ln2_scale"], lp["ln2_bias"], cfg.norm_eps)
-    h = jax.nn.relu(jnp.einsum("bsd,dm->bsm", h, lp["fc1"]) + lp["fc1_b"])
-    x = x + jnp.einsum("bsm,md->bsd", h, lp["fc2"]) + lp["fc2_b"]
+    with jax.named_scope(scopes.ATTN_OUT):
+        o = jnp.einsum("bshk,hkd->bsd", attn, lp["wo"]) + lp["bo"]
+        if "wo" in lora:
+            b, s = x.shape[:2]
+            o = o + lora_delta(
+                attn.reshape(b, s, -1), lora["wo"], lora_scale, "bsr,rd->bsd"
+            )
+        x = x + o
+    with jax.named_scope(scopes.NORM):
+        h = layer_norm(x, lp["ln2_scale"], lp["ln2_bias"], cfg.norm_eps)
+    with jax.named_scope(scopes.MLP):
+        h = jax.nn.relu(jnp.einsum("bsd,dm->bsm", h, lp["fc1"]) + lp["fc1_b"])
+        x = x + jnp.einsum("bsm,md->bsd", h, lp["fc2"]) + lp["fc2_b"]
     return x, kv_out
 
 
@@ -206,7 +215,11 @@ def forward(
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
 
-    x = params["tok_embed"][tokens] + params["pos_embed"][positions + POS_OFFSET]
+    with jax.named_scope(scopes.EMBED):
+        x = (
+            params["tok_embed"][tokens]
+            + params["pos_embed"][positions + POS_OFFSET]
+        )
 
     lora_scale = lora["scale"] if lora is not None else 1.0
 
@@ -225,13 +238,15 @@ def forward(
         xs["lora"] = lora["layers"]
     if remat:
         body = jax.checkpoint(body)
-    x, (ks, vs) = lax.scan(body, x, xs)
+    with jax.named_scope(scopes.LAYERS):
+        x, (ks, vs) = lax.scan(body, x, xs)
 
-    x = layer_norm(
-        x, params["final_ln_scale"], params["final_ln_bias"], cfg.norm_eps
-    )
-    logits = jnp.einsum("bsd,vd->bsv", x, params["tok_embed"])  # tied head
-    return logits.astype(jnp.float32), {"k": ks, "v": vs}
+    with jax.named_scope(scopes.LM_HEAD):
+        x = layer_norm(
+            x, params["final_ln_scale"], params["final_ln_bias"], cfg.norm_eps
+        )
+        logits = jnp.einsum("bsd,vd->bsv", x, params["tok_embed"])  # tied head
+        return logits.astype(jnp.float32), {"k": ks, "v": vs}
 
 
 @partial(jax.jit, static_argnames=("cfg",), donate_argnames=("cache",))

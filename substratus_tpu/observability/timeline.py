@@ -29,9 +29,19 @@ iteration wall over a sliding window — self-calibrating against the
 best the hardware recently did, so production bubbles are measured
 against reality, not a config guess.
 
-Thread contract: ``record_iteration`` is called by the engine
-scheduler thread only; readers (``/debug/stepz``, the bench) snapshot
-under the same lock.
+One timing site per scheduler phase: ``phase(name)`` opens a
+``jax.profiler.TraceAnnotation("engine.<name>")`` (a flag check unless a
+profiler capture is running, and then a span on the capture's clock, above
+the device's ops), adds the phase's wall time to the iteration in
+progress, and observes ``substratus_serve_phase_seconds`` where that
+histogram has the phase. ``commit`` turns what the phases of one
+iteration accumulated into a ``record_iteration`` call, so this recorder,
+the histogram and a ``POST /debug/profile`` capture share one measurement
+(docs/observability.md "Scheduler phases").
+
+Thread contract: ``phase``, ``pool_dry``, ``commit`` and
+``record_iteration`` are called by the engine scheduler thread only;
+readers (``/debug/stepz``, the bench) snapshot under the lock.
 """
 from __future__ import annotations
 
@@ -52,6 +62,42 @@ METRICS.describe(
 )
 
 BUBBLE_CAUSES = ("host_overrun", "flush", "admission_stall", "pool_dry")
+
+# Phases that substratus_serve_phase_seconds carries, under its label.
+# (The lockstep transport observes phase="broadcast" itself, around the
+# collective alone: serve/multihost.py.)
+HISTOGRAM_PHASE = {
+    "admit": "admission",
+    "prefill": "prefill",
+    "sample": "sample",
+    "dispatch": "decode",
+}
+
+
+class _Phase:
+    """One entered phase (StepTimeline.phase). ``observe`` may be cleared
+    inside the block to keep this sample out of the histogram; ``seconds``
+    holds the wall time once the block is left."""
+
+    __slots__ = ("_timeline", "name", "observe", "seconds", "_span", "_t0")
+
+    def __init__(self, timeline: "StepTimeline", name: str, observe: bool,
+                 attrs: dict):
+        self._timeline = timeline
+        self.name = name
+        self.observe = observe
+        self.seconds = 0.0
+        self._span = timeline._annotation("engine." + name, **attrs)
+
+    def __enter__(self) -> "_Phase":
+        self._span.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.seconds = time.perf_counter() - self._t0
+        self._span.__exit__(*exc)
+        self._timeline._leave(self)
 
 
 class StepTimeline:
@@ -74,8 +120,70 @@ class StepTimeline:
         # wall clock for Chrome-trace ts values.
         self._epoch_perf = time.perf_counter()
         self._epoch_wall = time.time()
+        # The iteration in progress (scheduler thread only): seconds by
+        # phase name since the last "iter" phase was entered.
+        from jax.profiler import TraceAnnotation
+
+        self._annotation = TraceAnnotation
+        self._t_iter = self._epoch_perf
+        self._phase_s: Dict[str, float] = {}
+        self._drain_off_s = 0.0
+        self._flush_reasons: List[str] = []
+        self._pool_dry = False
 
     # -- writer (engine scheduler thread) ---------------------------------
+
+    def phase(self, name: str, observe: bool = True, **attrs) -> _Phase:
+        """Context manager around one scheduler phase; ``attrs`` become
+        the annotation's arguments in a profiler capture, ``observe``
+        (also settable inside the block) keeps the sample in or out of the
+        phase histogram. Entering "iter" starts a new iteration."""
+        ph = _Phase(self, name, observe, attrs)
+        if name == "iter":
+            self._t_iter = time.perf_counter()
+            self._phase_s = {}
+            self._drain_off_s = 0.0
+            self._flush_reasons = []
+            self._pool_dry = False
+        elif name == "drain":
+            self._drain_off_s = time.perf_counter() - self._t_iter
+        elif name == "flush":
+            self._flush_reasons.append(str(attrs.get("reason", "")))
+        return ph
+
+    def _leave(self, ph: _Phase) -> None:
+        self._phase_s[ph.name] = self._phase_s.get(ph.name, 0.0) + ph.seconds
+        label = HISTOGRAM_PHASE.get(ph.name)
+        if label is not None and ph.observe:
+            METRICS.observe(
+                "substratus_serve_phase_seconds", ph.seconds, {"phase": label}
+            )
+
+    def pool_dry(self) -> None:
+        """This iteration's admission held a request for KV pages: its
+        admission time is a capacity bubble, not host speed."""
+        self._pool_dry = True
+
+    def commit(self, *, admitted: int, active_slots: int, max_slots: int,
+               configured_floor_s: float = 0.0) -> dict:
+        """Record the iteration in progress from what its phases
+        accumulated (called inside the "iter" phase, as its last act)."""
+        s = self._phase_s
+        return self.record_iteration(
+            t_start=self._t_iter,
+            wall_s=time.perf_counter() - self._t_iter,
+            admit_s=s.get("admit", 0.0),
+            admitted=admitted,
+            dispatch_s=s.get("dispatch", 0.0),
+            drain_s=s.get("drain", 0.0),
+            drain_off_s=self._drain_off_s,
+            flush_s=s.get("flush", 0.0),
+            flush_reasons=self._flush_reasons,
+            pool_dry=self._pool_dry,
+            active_slots=active_slots,
+            max_slots=max_slots,
+            configured_floor_s=configured_floor_s,
+        )
 
     def record_iteration(
         self,

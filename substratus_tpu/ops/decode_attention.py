@@ -40,6 +40,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from substratus_tpu.ops import scopes
+
 NEG_INF = -1e30
 
 
@@ -297,6 +299,7 @@ def _pallas(q, k, v, positions, k_scale, v_scale, block_s, interpret):
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name=scopes.ATTN_CORE,
     )(positions.astype(jnp.int32), *operands)
     return out.reshape(b, 1, h, d)
 
@@ -375,74 +378,80 @@ def update_cache_and_attend(
 
         kv_out = {}
         if quantized:
-            kq, kscale = quantize_kv(kkT)
-            vq, vscale = quantize_kv(vvT)
-            kv_out["k_scale"] = (
-                layer_cache["k_scale"].at[bidx, hidx, sidx]
-                .set(kscale[..., 0])
-            )
-            kv_out["v_scale"] = (
-                layer_cache["v_scale"].at[bidx, hidx, sidx]
-                .set(vscale[..., 0])
-            )
-            attn, kv_out["k"], kv_out["v"] = fused_decode_attention(
-                q, kq, vq, layer_cache["k"], layer_cache["v"],
-                positions[:, 0], kscale[..., 0], vscale[..., 0],
-                kv_out["k_scale"], kv_out["v_scale"],
-            )
+            with jax.named_scope(scopes.KV_WRITE):
+                kq, kscale = quantize_kv(kkT)
+                vq, vscale = quantize_kv(vvT)
+                kv_out["k_scale"] = (
+                    layer_cache["k_scale"].at[bidx, hidx, sidx]
+                    .set(kscale[..., 0])
+                )
+                kv_out["v_scale"] = (
+                    layer_cache["v_scale"].at[bidx, hidx, sidx]
+                    .set(vscale[..., 0])
+                )
+            with jax.named_scope(scopes.ATTN_CORE):
+                attn, kv_out["k"], kv_out["v"] = fused_decode_attention(
+                    q, kq, vq, layer_cache["k"], layer_cache["v"],
+                    positions[:, 0], kscale[..., 0], vscale[..., 0],
+                    kv_out["k_scale"], kv_out["v_scale"],
+                )
         else:
-            attn, kv_out["k"], kv_out["v"] = fused_decode_attention(
-                q,
-                kkT.astype(layer_cache["k"].dtype),
-                vvT.astype(layer_cache["v"].dtype),
-                layer_cache["k"], layer_cache["v"], positions[:, 0],
-            )
+            with jax.named_scope(scopes.ATTN_CORE):
+                attn, kv_out["k"], kv_out["v"] = fused_decode_attention(
+                    q,
+                    kkT.astype(layer_cache["k"].dtype),
+                    vvT.astype(layer_cache["v"].dtype),
+                    layer_cache["k"], layer_cache["v"], positions[:, 0],
+                )
         return attn, kv_out
 
     kv_out = {}
-    if quantized:
-        kq, kscale = quantize_kv(kkT)  # scale [B, KH, S, 1]
-        vq, vscale = quantize_kv(vvT)
-        kv_out["k"] = layer_cache["k"].at[bidx, hidx, sidx].set(kq)
-        kv_out["v"] = layer_cache["v"].at[bidx, hidx, sidx].set(vq)
-        kv_out["k_scale"] = (
-            layer_cache["k_scale"].at[bidx, hidx, sidx].set(kscale[..., 0])
-        )
-        kv_out["v_scale"] = (
-            layer_cache["v_scale"].at[bidx, hidx, sidx].set(vscale[..., 0])
-        )
-    else:
-        kv_out["k"] = (
-            layer_cache["k"].at[bidx, hidx, sidx]
-            .set(kkT.astype(layer_cache["k"].dtype))
-        )
-        kv_out["v"] = (
-            layer_cache["v"].at[bidx, hidx, sidx]
-            .set(vvT.astype(layer_cache["v"].dtype))
-        )
+    with jax.named_scope(scopes.KV_WRITE):
+        if quantized:
+            kq, kscale = quantize_kv(kkT)  # scale [B, KH, S, 1]
+            vq, vscale = quantize_kv(vvT)
+            new = {"k": kq, "v": vq,
+                   "k_scale": kscale[..., 0], "v_scale": vscale[..., 0]}
+        else:
+            new = {"k": kkT, "v": vvT}
+        for name, vals in new.items():
+            slot = layer_cache[name]
+            kv_out[name] = slot.at[bidx, hidx, sidx].set(
+                vals.astype(slot.dtype)
+            )
     if s == 1 and kv_length is None:
-        attn = decode_attention(
-            q, kv_out["k"], kv_out["v"], positions[:, 0],
-            kv_out.get("k_scale"), kv_out.get("v_scale"),
-            impl=impl,
-        )
+        with jax.named_scope(scopes.ATTN_CORE):
+            attn = decode_attention(
+                q, kv_out["k"], kv_out["v"], positions[:, 0],
+                kv_out.get("k_scale"), kv_out.get("v_scale"),
+                impl=impl,
+            )
     elif chunk_impl == "flash":
         from substratus_tpu.ops.flash_attention import flash_cached_attention
 
-        attn = flash_cached_attention(
-            q, kv_out["k"], kv_out["v"], positions,
-            kv_out.get("k_scale"), kv_out.get("v_scale"), kv_length,
-        )
+        with jax.named_scope(scopes.ATTN_CORE):
+            attn = flash_cached_attention(
+                q, kv_out["k"], kv_out["v"], positions,
+                kv_out.get("k_scale"), kv_out.get("v_scale"), kv_length,
+            )
     else:
+        k_cache, v_cache = kv_out["k"], kv_out["v"]
         if quantized:
-            k_cache = dequantize_kv(kv_out["k"], kv_out["k_scale"][..., None], dt)
-            v_cache = dequantize_kv(kv_out["v"], kv_out["v_scale"][..., None], dt)
-        else:
-            k_cache, v_cache = kv_out["k"], kv_out["v"]
-        attn = dot_product_attention(
-            q, k_cache.transpose(0, 2, 1, 3), v_cache.transpose(0, 2, 1, 3),
-            causal=True, q_positions=positions, kv_length=kv_length,
-        )
+            # the dense layout's stand-in for the paged gather: a model-
+            # dtype copy of the whole slot cache
+            with jax.named_scope(scopes.KV_GATHER):
+                k_cache = dequantize_kv(
+                    k_cache, kv_out["k_scale"][..., None], dt
+                )
+                v_cache = dequantize_kv(
+                    v_cache, kv_out["v_scale"][..., None], dt
+                )
+        with jax.named_scope(scopes.ATTN_CORE):
+            attn = dot_product_attention(
+                q, k_cache.transpose(0, 2, 1, 3),
+                v_cache.transpose(0, 2, 1, 3),
+                causal=True, q_positions=positions, kv_length=kv_length,
+            )
     return attn, kv_out
 
 

@@ -45,6 +45,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from substratus_tpu.ops import scopes
+
 DEFAULT_BLOCK_Q = 256
 DEFAULT_BLOCK_K = 256
 
@@ -691,6 +693,7 @@ def _cached_impl(
         ],
         compiler_params=_compiler_params(),
         interpret=interpret,
+        name=scopes.ATTN_CORE,
     )(*operands)
     return out.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
 

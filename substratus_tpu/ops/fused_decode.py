@@ -46,6 +46,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from substratus_tpu.ops import scopes
+
 NEG_INF = -1e30
 
 # What Mosaic (jax 0.9.0, libtpu 0.0.34, described v5e) says of this
@@ -343,6 +345,7 @@ def _fused_impl(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name=scopes.ATTN_CORE,
     )(positions.astype(jnp.int32), *operands)
     attn = out[:, :, :g, :].reshape(b, 1, h, d)
     return attn, ck, cv

@@ -24,8 +24,10 @@ from __future__ import annotations
 
 from typing import Dict, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 
+from substratus_tpu.ops import scopes
 from substratus_tpu.ops.quant import dequantize_kv, quantize_kv
 
 
@@ -50,48 +52,46 @@ def paged_update_and_read(
     def flat(a):
         return a.reshape((pages * bs,) + a.shape[2:])
 
-    # Writes past the block table's reach (speculative verify near the
-    # context window) are redirected to the trash page (physical page 0)
-    # instead of silently aliasing the last page via index clamping.
-    page_idx = positions // bs
-    oob = page_idx >= m
-    pid = jnp.take_along_axis(
-        block_table, jnp.minimum(page_idx, m - 1), axis=1
-    )
-    pid = jnp.where(oob, 0, pid)
-    idx = pid * bs + positions % bs  # [B, S] flat token index
-    ctx_idx = (
-        block_table[:, :, None] * bs
-        + jnp.arange(bs, dtype=block_table.dtype)[None, None, :]
-    ).reshape(b, m * bs)
-
     quantized = "k_scale" in layer_cache
     out: Dict[str, jnp.ndarray] = {}
-    if quantized:
-        kq, ks = quantize_kv(k_new)
-        vq, vs = quantize_kv(v_new)
-        for name, vals in (
-            ("k", kq), ("v", vq), ("k_scale", ks), ("v_scale", vs)
-        ):
-            out[name] = (
-                flat(layer_cache[name]).at[idx].set(vals)
-                .reshape(layer_cache[name].shape)
-            )
-        k_ctx = dequantize_kv(
-            flat(out["k"])[ctx_idx], flat(out["k_scale"])[ctx_idx], dtype
+    with jax.named_scope(scopes.KV_WRITE):
+        # Writes past the block table's reach (speculative verify near the
+        # context window) are redirected to the trash page (physical page
+        # 0) instead of silently aliasing the last page via index clamping.
+        page_idx = positions // bs
+        oob = page_idx >= m
+        pid = jnp.take_along_axis(
+            block_table, jnp.minimum(page_idx, m - 1), axis=1
         )
-        v_ctx = dequantize_kv(
-            flat(out["v"])[ctx_idx], flat(out["v_scale"])[ctx_idx], dtype
-        )
-    else:
-        for name, vals in (("k", k_new), ("v", v_new)):
-            cdtype = layer_cache[name].dtype
+        pid = jnp.where(oob, 0, pid)
+        idx = pid * bs + positions % bs  # [B, S] flat token index
+        if quantized:
+            kq, ks = quantize_kv(k_new)
+            vq, vs = quantize_kv(v_new)
+            new = {"k": kq, "v": vq, "k_scale": ks, "v_scale": vs}
+        else:
+            new = {"k": k_new, "v": v_new}
+        for name, vals in new.items():
+            pool = layer_cache[name]
             out[name] = (
-                flat(layer_cache[name]).at[idx].set(vals.astype(cdtype))
-                .reshape(layer_cache[name].shape)
+                flat(pool).at[idx].set(vals.astype(pool.dtype))
+                .reshape(pool.shape)
             )
-        k_ctx = flat(out["k"])[ctx_idx]
-        v_ctx = flat(out["v"])[ctx_idx]
+    with jax.named_scope(scopes.KV_GATHER):
+        ctx_idx = (
+            block_table[:, :, None] * bs
+            + jnp.arange(bs, dtype=block_table.dtype)[None, None, :]
+        ).reshape(b, m * bs)
+        if quantized:
+            k_ctx = dequantize_kv(
+                flat(out["k"])[ctx_idx], flat(out["k_scale"])[ctx_idx], dtype
+            )
+            v_ctx = dequantize_kv(
+                flat(out["v"])[ctx_idx], flat(out["v_scale"])[ctx_idx], dtype
+            )
+        else:
+            k_ctx = flat(out["k"])[ctx_idx]
+            v_ctx = flat(out["v"])[ctx_idx]
     return out, k_ctx, v_ctx
 
 

@@ -48,6 +48,7 @@ from substratus_tpu.observability.tracing import (
     current_trace_id,
     tracer,
 )
+from substratus_tpu.ops import scopes
 from substratus_tpu.ops.sampling import sample
 
 # Serving latency/utilization histograms (docs/observability.md). Declared
@@ -72,17 +73,20 @@ METRICS.histogram(
 )
 METRICS.histogram(
     "substratus_serve_kv_page_utilization_ratio",
-    "Allocated KV pages / pool size, sampled once per scheduler iteration "
-    "(paged layout only).",
+    "KV pages / pool size by state, sampled once per scheduler iteration "
+    "that decodes (paged layout only): live (referenced by an active "
+    "slot) or cached (held by the prefix registry alone, reclaimable).",
     buckets=RATIO_BUCKETS,
 )
 METRICS.histogram(
     "substratus_serve_phase_seconds",
     "Wall time of one scheduler phase (seconds), labeled by phase: "
-    "broadcast (lockstep event sync, serve/multihost.py), admission "
-    "(queue -> slots, prefill included), prefill (device prefill inside "
-    "admission), sample (first-token sampling + host read), decode (the "
-    "batched decode/verify dispatch of one iteration).",
+    "broadcast (the lockstep collective, serve/multihost.py), admission "
+    "(queue -> slots, prefill included; iterations that boarded someone), "
+    "prefill (one prefill or chunk dispatch inside admission), sample "
+    "(first-token sampling + host read), decode (the host side of the "
+    "batched decode/verify launch alone: no drain, no device wait). Each "
+    "is one engine.<phase> span of observability/timeline.py.",
 )
 METRICS.describe(
     "substratus_serve_first_compile_seconds",
@@ -617,6 +621,11 @@ class Engine:
             "adapter_requests": 0,
             "handoffs": 0,
             "migrations_in": 0,
+            # Added to once per scheduler iteration that decodes (paged
+            # layout): a window's mean live share of the pool is the
+            # ratio of the two deltas.
+            "kv_live_pages_sum": 0,
+            "kv_pool_pages_sum": 0,
         }
 
         # Speculative decoding state. The draft pool shares the target's
@@ -746,8 +755,9 @@ class Engine:
         # engine (written only by the scheduler thread; /debug/stepz
         # and the bench read it), one SLO tracker fed from _emit whose
         # sketches ride load_snapshot() to the gateway's fleet
-        # aggregator. The per-iteration accumulators below are
-        # scheduler-thread-only scratch, reset at each loop top.
+        # aggregator. Every scheduler phase is timed once, by
+        # self.timeline.phase(): the recorder, the phase histogram and a
+        # profiler capture's engine.* spans share that measurement.
         self.timeline = StepTimeline()
         self.slo = SLOTracker({
             "ttft": ec.slo_ttft_s,
@@ -765,13 +775,6 @@ class Engine:
         # atomic under the GIL, and load_snapshot() is called from
         # HTTP handler threads concurrently.
         self._load_seq = itertools.count(1)
-        self._tl_flush_s = 0.0
-        self._tl_flush_reasons: List[str] = []
-        self._tl_dispatch_s = 0.0
-        self._tl_drain_s = 0.0
-        self._tl_drain_off_s = 0.0
-        self._tl_pool_dry = False
-        self._tl_iter_t0 = 0.0
 
         self._decode_fn = self._build_decode()
         self._sample1_fn = self._build_first_sample()
@@ -914,14 +917,17 @@ class Engine:
                 **({"block_table": block_table} if paged else {}),
                 **Engine._lora_kw(lora, adapter_ids),
             )
-            choices = logits.argmax(-1).astype(jnp.int32)
-            key, subkey = jax.random.split(jax.random.wrap_key_data(key_data))
-            sampled = sample(
-                logits[:, 0], subkey, temps, top_k=ec.top_k, top_p=top_ps
-            )
-            choices, sampled, kd = self._replicated(
-                choices, sampled, jax.random.key_data(key)
-            )
+            with jax.named_scope(scopes.SAMPLE):
+                choices = logits.argmax(-1).astype(jnp.int32)
+                key, subkey = jax.random.split(
+                    jax.random.wrap_key_data(key_data)
+                )
+                sampled = sample(
+                    logits[:, 0], subkey, temps, top_k=ec.top_k, top_p=top_ps
+                )
+                choices, sampled, kd = self._replicated(
+                    choices, sampled, jax.random.key_data(key)
+                )
             return choices, sampled, cache, kd
 
         return verify
@@ -1077,13 +1083,16 @@ class Engine:
                 **({"block_table": block_table} if paged else {}),
                 **Engine._lora_kw(lora, adapter_ids),
             )
-            key, subkey = jax.random.split(jax.random.wrap_key_data(key_data))
-            next_tokens = sample(
-                logits[:, 0], subkey, temps, top_k=ec.top_k, top_p=top_ps
-            )
-            next_tokens, kd = self._replicated(
-                next_tokens, jax.random.key_data(key)
-            )
+            with jax.named_scope(scopes.SAMPLE):
+                key, subkey = jax.random.split(
+                    jax.random.wrap_key_data(key_data)
+                )
+                next_tokens = sample(
+                    logits[:, 0], subkey, temps, top_k=ec.top_k, top_p=top_ps
+                )
+                next_tokens, kd = self._replicated(
+                    next_tokens, jax.random.key_data(key)
+                )
             return next_tokens, cache, kd
 
         return decode
@@ -1096,14 +1105,15 @@ class Engine:
             """Sample the first generated token from prefill logits;
             returns (token [1], new key data), both replicated for the
             scheduler's host read."""
-            key, subkey = jax.random.split(
-                jax.random.wrap_key_data(key_data)
-            )
-            first = sample(
-                last_logits[None, :], subkey, temp, top_k=ec.top_k,
-                top_p=top_p,
-            )
-            return self._replicated(first, jax.random.key_data(key))
+            with jax.named_scope(scopes.SAMPLE):
+                key, subkey = jax.random.split(
+                    jax.random.wrap_key_data(key_data)
+                )
+                first = sample(
+                    last_logits[None, :], subkey, temp, top_k=ec.top_k,
+                    top_p=top_p,
+                )
+                return self._replicated(first, jax.random.key_data(key))
 
         return first_sample
 
@@ -1596,7 +1606,6 @@ class Engine:
                     if req.submit_ts and not req.last_emit_ts else 0
                 )
                 req.journey.record("admit", slot=slot, wait_us=wait_us)
-            t_prefill = time.perf_counter()
             with tracer.span(
                 "engine.prefill", parent=req.trace_ctx,
                 request_id=req.id, slot=slot,
@@ -1606,11 +1615,6 @@ class Engine:
                     ok = self._admit_paged(req, slot)
                 else:
                     ok = self._admit_dense(req, slot)
-            METRICS.observe(
-                "substratus_serve_phase_seconds",
-                time.perf_counter() - t_prefill,
-                {"phase": "prefill"},
-            )
             self._admitting = None
             if not ok:
                 # Pool dry even after eviction: hold the request at the
@@ -1620,10 +1624,7 @@ class Engine:
                     req.journey.record_once("pool_wait")
                 self._release_adapter_pin(req)
                 self._resume.insert(0, req)
-                # Timeline: this iteration's admission time was spent
-                # waiting on pages, not prefilling — attribute the
-                # bubble to capacity (pool_dry), not host speed.
-                self._tl_pool_dry = True
+                self.timeline.pool_dry()
                 break
             admitted += 1
         self.stats["max_active"] = max(
@@ -1659,7 +1660,7 @@ class Engine:
             if not self._install_migration(mig):
                 self._release_adapter_pin(mig.req)
                 self._resume_migrations.insert(0, mig)
-                self._tl_pool_dry = True  # held for pages, same bubble
+                self.timeline.pool_dry()
                 break
             admitted += 1
         return admitted
@@ -1730,10 +1731,11 @@ class Engine:
         ids = np.zeros((cap,), np.int32)
         ids[:n] = pages
         frag = self._export_fn(self.cache, ids)
-        host = {
-            key: np.asarray(v)[:, :n]  # sublint: allow[hostsync]: the handoff IS a device->host transfer — one gather read per migrated request
-            for key, v in frag.items()
-        }
+        with self.timeline.phase("wait.handoff"):
+            host = {
+                key: np.asarray(v)[:, :n]  # sublint: allow[hostsync]: the handoff IS a device->host transfer — one gather read per migrated request
+                for key, v in frag.items()
+            }
         self.slot_pages.release(slot, self.alloc)
         self.block_table[slot] = 0
         self._release_adapter_pin(req)
@@ -1805,12 +1807,18 @@ class Engine:
             padded, true_len = _pad_to_bucket(
                 prompt, self.ec.max_prefill_len
             )
-            last_logits, kv = self._prefill_fn(
-                self.params, padded, true_len, lora, ids1
-            )
-            self.cache = self._insert_fn(self.cache, kv, slot)
+            with self.timeline.phase(
+                "prefill", request_id=req.id, bucket=padded.shape[1],
+                chunk=0, tokens=true_len,
+            ):
+                last_logits, kv = self._prefill_fn(
+                    self.params, padded, true_len, lora, ids1
+                )
+                self.cache = self._insert_fn(self.cache, kv, slot)
         else:
-            last_logits = self._chunked_prefill(prompt, slot, lora, ids1)
+            last_logits = self._chunked_prefill(
+                req.id, prompt, slot, lora, ids1
+            )
         self.stats["prefill_tokens"] += true_len
         METRICS.inc("substratus_serve_prefill_tokens_total", by=true_len)
         if req.journey is not None:
@@ -1874,8 +1882,8 @@ class Engine:
 
         lora, ids1 = self._prefill_lora(req)
         last_logits, self.cache = self._run_chunks(
-            self._chunk_fn, self.params, self.cache, prompt, reuse, bt_row,
-            lora=lora, adapter_ids=ids1,
+            req.id, self._chunk_fn, self.params, self.cache, prompt, reuse,
+            bt_row, lora=lora, adapter_ids=ids1,
         )
         self.stats["prefill_tokens"] += true_len - reuse
         self.stats["prefix_hit_tokens"] += reuse
@@ -1904,8 +1912,8 @@ class Engine:
             # positions >= true_len, past every registered full page), so
             # the invariant holds inductively from the first admission.
             _, self.draft_cache = self._run_chunks(
-                self._draft_chunk_fn, self.draft_params, self.draft_cache,
-                prompt, reuse, bt_row,
+                req.id, self._draft_chunk_fn, self.draft_params,
+                self.draft_cache, prompt, reuse, bt_row,
             )
 
         n_full = true_len // bs
@@ -1914,48 +1922,54 @@ class Engine:
         self._finalize_admit(req, slot, last_logits, true_len)
         return True
 
-    def _run_chunks(self, fn, params, cache, prompt, start: int, bt_row,
-                    lora=None, adapter_ids=None):
-        """Chunked prefill of prompt[start:] through a block-table row;
-        returns (last real token's logits, updated cache)."""
+    def _run_chunks(self, rid, fn, params, cache, prompt, start: int,
+                    bt_row, lora=None, adapter_ids=None):
+        """Chunked prefill of request `rid`'s prompt[start:] through a
+        block-table row; returns (last real token's logits, updated
+        cache). One engine.prefill phase per chunk dispatch."""
         chunk = self.ec.max_prefill_len
         offset, last_logits = start, None
         while offset < len(prompt):
-            t0 = time.perf_counter()
             padded, clen = _pad_to_bucket(
                 prompt[offset : offset + chunk], chunk
             )
-            last_logits, cache = fn(
-                params, cache, padded, offset, clen, block_table=bt_row,
-                lora=lora, adapter_ids=adapter_ids,
-            )
+            with self.timeline.phase(
+                "prefill", request_id=rid, bucket=padded.shape[1],
+                chunk=(offset - start) // chunk, tokens=clen,
+            ) as ph:
+                last_logits, cache = fn(
+                    params, cache, padded, offset, clen, block_table=bt_row,
+                    lora=lora, adapter_ids=adapter_ids,
+                )
             offset += clen
-            dt = time.perf_counter() - t0
-            if self.ec.step_floor_s > dt:
-                # Simulated device-step latency applies to prefill chunks
-                # too: on a real accelerator every chunk occupies the
-                # device, which is exactly the decode-stalling contention
-                # the disaggregated split removes (see EngineConfig).
-                time.sleep(self.ec.step_floor_s - dt)
+            # Simulated device-step latency applies to prefill chunks
+            # too: on a real accelerator every chunk occupies the device,
+            # which is exactly the decode-stalling contention the
+            # disaggregated split removes (see EngineConfig).
+            self._floor_wait(ph.seconds)
         return last_logits, cache
+
+    def _floor_wait(self, spent_s: float) -> None:
+        """Sleep out what is left of the simulated device step
+        (EngineConfig.step_floor_s; 0 on a real accelerator)."""
+        if self.ec.step_floor_s > spent_s:
+            with self.timeline.phase("wait.floor"):
+                time.sleep(self.ec.step_floor_s - spent_s)
 
     def _finalize_admit(self, req: Request, slot: int, last_logits,
                         true_len: int) -> None:
         # Sample the first generated token from the prefill logits.
-        t_sample = time.perf_counter()
-        first, key_out = self._sample1_fn(
-            last_logits,
-            self.key,
-            np.array([req.temperature], np.float32),
-            np.array([req.top_p], np.float32),
-        )
-        self.key = np.asarray(key_out)  # sublint: allow[hostsync]: first-token sample + key readback, once per admission (the "sample" phase)
-        first_id = int(first[0])
-        METRICS.observe(
-            "substratus_serve_phase_seconds",
-            time.perf_counter() - t_sample,
-            {"phase": "sample"},
-        )
+        with self.timeline.phase("sample"):
+            first, key_out = self._sample1_fn(
+                last_logits,
+                self.key,
+                np.array([req.temperature], np.float32),
+                np.array([req.top_p], np.float32),
+            )
+            # The read waits for the prefill programs dispatched above.
+            with self.timeline.phase("wait.first_token"):
+                self.key = np.asarray(key_out)  # sublint: allow[hostsync]: first-token sample + key readback, once per admission (the "sample" phase)
+                first_id = int(first[0])
 
         if self.ec.role == "prefill":
             self._handoff_request(req, slot, first_id, true_len)
@@ -2121,7 +2135,8 @@ class Engine:
             # gangs (overlap off) need the host copy below.
             self.key = key_out
         else:
-            self.key = np.asarray(key_out)  # sublint: allow[hostsync]: overlap-off (lockstep) fallback only — the key rides host-side so every gang process feeds identical replicated inputs; the overlapped path above keeps it on device
+            with self.timeline.phase("wait.key"):
+                self.key = np.asarray(key_out)  # sublint: allow[hostsync]: overlap-off (lockstep) fallback only — the key rides host-side so every gang process feeds identical replicated inputs; the overlapped path above keeps it on device
         self._dev_tokens = next_tokens
         self._token_fresh[:] = False
         # Clamp at the last cache row: active slots are released at the
@@ -2143,31 +2158,34 @@ class Engine:
             t_dispatch=time.perf_counter(),
         )
 
-    def _drain(self, step: _InFlightStep) -> None:
-        """Host half of one decode step: THE deferred host read, then
+    def _drain(self, step: _InFlightStep, wait: str = "drain") -> None:
+        """Host half of one decode step: THE deferred host read (the
+        engine.wait.<wait> phase: "drain", or "flush" from _flush), then
         per-slot emits, EOS/budget/window release, and cancellation
         handling for the slots that were active at dispatch. A slot
         whose request was released after that dispatch (EOS at the
         previous drain, preemption, kill) fails the identity check and
         its in-flight token — the pipeline's one wasted token per
         finished stream — never reaches a consumer."""
-        host_tokens = np.asarray(step.tokens)  # sublint: allow[hostsync]: THE one host read per decode step — deferred to drain() so under overlap it lands after the NEXT dispatch, hiding every emit under device compute
+        with self.timeline.phase("wait." + wait):
+            host_tokens = np.asarray(step.tokens)  # sublint: allow[hostsync]: THE one host read per decode step — deferred to drain() so under overlap it lands after the NEXT dispatch, hiding every emit under device compute
         t_drained = time.perf_counter()
-        for slot, req in step.slots:
-            if self.slot_req[slot] is not req:
-                continue  # EOS-lag mask: released or re-admitted slot
-            if req.journey is not None:
-                # Journey events for a dispatch are stamped at drain —
-                # the overlap pipeline never stalls for forensics.
-                req.journey.record(
-                    "drain",
-                    lat_us=int((t_drained - step.t_dispatch) * 1e6),
+        with self.timeline.phase("emit"):
+            for slot, req in step.slots:
+                if self.slot_req[slot] is not req:
+                    continue  # EOS-lag mask: released or re-admitted slot
+                if req.journey is not None:
+                    # Journey events for a dispatch are stamped at drain
+                    # — the overlap pipeline never stalls for forensics.
+                    req.journey.record(
+                        "drain",
+                        lat_us=int((t_drained - step.t_dispatch) * 1e6),
+                    )
+                self.tokens[slot] = host_tokens[slot]
+                self._emit(
+                    slot, int(host_tokens[slot]),
+                    pos_next=int(step.pos_next[slot]),
                 )
-            self.tokens[slot] = host_tokens[slot]
-            self._emit(
-                slot, int(host_tokens[slot]),
-                pos_next=int(step.pos_next[slot]),
-            )
         if not self.overlap:
             # Synchronous path (gangs, forced-sync): the next dispatch
             # must feed pure host-side numpy — in lockstep every process
@@ -2196,12 +2214,10 @@ class Engine:
         for slot, req in pending.slots:
             if self.slot_req[slot] is req and req.journey is not None:
                 req.journey.record("flush", reason=reason)
-        t_flush = time.perf_counter()
-        self._drain_any(pending)
-        # Timeline bubble accounting: a flush's drain is host work the
-        # pipeline could NOT hide (the device sits settled through it).
-        self._tl_flush_s += time.perf_counter() - t_flush
-        self._tl_flush_reasons.append(reason)
+        # A flush's drain is host work the pipeline could NOT hide (the
+        # device sits settled through it): the timeline's "flush" bubble.
+        with self.timeline.phase("flush", reason=reason):
+            self._drain_any(pending, wait="flush")
         # The batch is settled; the next dispatch feeds host tokens for
         # every slot (on-device feedback resumes with the step after).
         self._dev_tokens = None
@@ -2214,13 +2230,13 @@ class Engine:
         these two so both step kinds share one pipeline skeleton."""
         return self._spec_dispatch() if self.spec else self._dispatch()
 
-    def _drain_any(self, step) -> None:
+    def _drain_any(self, step, wait: str = "drain") -> None:
         """The matching drain half, type-dispatched on the in-flight
         bookkeeping (a flush may drain either kind)."""
         if isinstance(step, _InFlightSpecStep):
-            self._spec_drain(step)
+            self._spec_drain(step, wait)
         else:
-            self._drain(step)
+            self._drain(step, wait)
 
     def _decode_step(self) -> None:
         """One synchronous iteration: dispatch, model the device step's
@@ -2229,20 +2245,18 @@ class Engine:
         device-step floor lands BEFORE the host read and the emits: on a
         real accelerator tokens only exist once the device step
         finishes, so a slot freed by an emit is admissible in the very
-        next iteration with no artificial dead time. _loop's own floor
-        check then sees dt >= floor and never double-sleeps."""
-        t_step = time.perf_counter()
-        pending = self._dispatch_any()
-        self._tl_dispatch_s = time.perf_counter() - t_step
+        next iteration with no artificial dead time."""
+        # The compiling first launch stays out of phase="decode"
+        # (substratus_serve_first_compile_seconds has it).
+        with self.timeline.phase(
+            "dispatch", observe=self._first_decode_done
+        ) as ph:
+            pending = self._dispatch_any()
         if pending is None:
             return
-        dt_step = time.perf_counter() - t_step
-        if self.ec.step_floor_s > dt_step:
-            time.sleep(self.ec.step_floor_s - dt_step)
-        t_drain = time.perf_counter()
-        self._drain_any(pending)
-        self._tl_drain_off_s = t_drain - self._tl_iter_t0
-        self._tl_drain_s = time.perf_counter() - t_drain
+        self._floor_wait(ph.seconds)
+        with self.timeline.phase("drain"):
+            self._drain_any(pending)
 
     def _step_overlapped(self) -> None:
         """One pipelined iteration: launch step N, then run step N-1's
@@ -2257,25 +2271,20 @@ class Engine:
         # dispatch's capacity handling may _flush("preempt") the
         # previous step itself, and draining it again here would emit
         # duplicate tokens.
-        launched = self._dispatch_any()
-        self._tl_dispatch_s = time.perf_counter() - t_step
+        with self.timeline.phase("dispatch", observe=self._first_decode_done):
+            launched = self._dispatch_any()
         prev, self._pending = self._pending, launched
         if prev is not None:
-            t_drain = time.perf_counter()
-            self._drain_any(prev)
-            self._tl_drain_off_s = t_drain - self._tl_iter_t0
-            self._tl_drain_s = time.perf_counter() - t_drain
+            with self.timeline.phase("drain") as ph:
+                self._drain_any(prev)
             if self._pending is not None:
                 # Host work actually hidden under an in-flight step —
                 # the overlapped scheduler's win, exported so operators
                 # can see how much host time the pipeline absorbs.
                 METRICS.observe(
-                    "substratus_serve_host_overlap_seconds",
-                    time.perf_counter() - t_drain,
+                    "substratus_serve_host_overlap_seconds", ph.seconds
                 )
-        dt_step = time.perf_counter() - t_step
-        if self.ec.step_floor_s > dt_step:
-            time.sleep(self.ec.step_floor_s - dt_step)
+        self._floor_wait(time.perf_counter() - t_step)
 
     @staticmethod
     def _prompt_lookup(ctx, k: int, max_n: int = 3):
@@ -2462,7 +2471,8 @@ class Engine:
             # would block on the verify just launched).
             self.key = key_out
         else:
-            self.key = np.asarray(key_out)  # sublint: allow[hostsync]: overlap-off fallback only — the key rides host-side so every lockstep process feeds identical replicated inputs; the overlapped path above keeps it on device
+            with self.timeline.phase("wait.key"):
+                self.key = np.asarray(key_out)  # sublint: allow[hostsync]: overlap-off fallback only — the key rides host-side so every lockstep process feeds identical replicated inputs; the overlapped path above keeps it on device
         if width > 1:
             # Width-1 rounds are plain decode steps, not verify passes —
             # tokens_per_verify must keep meaning "emitted per wide
@@ -2484,7 +2494,8 @@ class Engine:
             t_dispatch=time.perf_counter(),
         )
 
-    def _spec_drain(self, step: _InFlightSpecStep) -> None:
+    def _spec_drain(self, step: _InFlightSpecStep,
+                    wait: str = "drain") -> None:
         """Host half of one speculative round: THE deferred host read,
         the per-slot acceptance walk, emits, EOS/budget/window release,
         and the adaptive-k EWMA update. Greedy rows emit the longest
@@ -2500,10 +2511,24 @@ class Engine:
         carries its own dispatch-time position snapshot (pos0 + i) so
         the context-window release stays token-exact even though the
         live arrays then jump by the whole accepted run."""
-        chs = np.asarray(step.choices)  # sublint: allow[hostsync]: THE deferred per-spec-round host read — the acceptance walk + emits land here, under the next round's device window
-        smp = np.asarray(step.sampled)  # sublint: allow[hostsync]: same deferred read as chs; one transfer per speculative round
-        props = np.asarray(step.props)  # sublint: allow[hostsync]: draft proposals reach host with the round's one deferred read (lookup proposals are already host numpy — a no-op there)
+        with self.timeline.phase("wait." + wait):
+            chs = np.asarray(step.choices)  # sublint: allow[hostsync]: THE deferred per-spec-round host read — the acceptance walk + emits land here, under the next round's device window
+            smp = np.asarray(step.sampled)  # sublint: allow[hostsync]: same deferred read as chs; one transfer per speculative round
+            props = np.asarray(step.props)  # sublint: allow[hostsync]: draft proposals reach host with the round's one deferred read (lookup proposals are already host numpy — a no-op there)
         t_drained = time.perf_counter()
+        with self.timeline.phase("emit"):
+            self._spec_walk(step, chs, smp, props, t_drained)
+        if not self.overlap:
+            # Synchronous path (gangs, forced-sync): the next dispatch
+            # must feed pure host-side numpy — every lockstep process
+            # replicates identical input arrays. Device chaining is
+            # overlap-only.
+            self._dev_tokens = None
+            self._token_fresh[:] = True
+
+    def _spec_walk(self, step: _InFlightSpecStep, chs, smp, props,
+                   t_drained: float) -> None:
+        """The acceptance walk and emits of one drained round."""
         d = self.ec.spec_ewma_decay
         for slot, req in step.slots:
             if self.slot_req[slot] is not req:
@@ -2563,13 +2588,6 @@ class Engine:
             npos = min(pos0 + len(emit_list), self.ec.max_seq_len - 1)
             self.host_positions[slot] = npos
             self.positions[slot] = npos
-        if not self.overlap:
-            # Synchronous path (gangs, forced-sync): the next dispatch
-            # must feed pure host-side numpy — every lockstep process
-            # replicates identical input arrays. Device chaining is
-            # overlap-only.
-            self._dev_tokens = None
-            self._token_fresh[:] = True
 
     def _release_slot(self, slot: int) -> None:
         self.active[slot] = False
@@ -2588,14 +2606,14 @@ class Engine:
             # the allocator may hand to someone else.
             self.block_table[slot] = 0
 
-    def _chunked_prefill(self, prompt, slot: int, lora=None,
+    def _chunked_prefill(self, rid, prompt, slot: int, lora=None,
                          adapter_ids=None):
         """Prefill a prompt longer than one bucket: run bucket-sized chunks
         against the slot's cache (each chunk attends everything before it),
         then restore the slot into the decode cache."""
         slot_cache = self._extract_slot(self.cache, slot)
         last_logits, slot_cache = self._run_chunks(
-            self._chunk_fn, self.params, slot_cache, prompt, 0, None,
+            rid, self._chunk_fn, self.params, slot_cache, prompt, 0, None,
             lora=lora, adapter_ids=adapter_ids,
         )
         self.cache = self._restore_slot(self.cache, slot_cache, slot)
@@ -2698,99 +2716,78 @@ class Engine:
         else:
             self._decode_step()
 
+    def _iterate(self) -> None:
+        """One pass of the scheduler loop (the engine.iter phase): admit,
+        then one decode step for every active slot."""
+        tl = self.timeline
+        with tl.phase("admit") as ph:
+            admitted = self._admit()
+            # Only iterations that boarded someone observe the admission
+            # phase — an idle engine waking on its empty queue would
+            # otherwise flood the histogram with ~0 s samples.
+            ph.observe = admitted > 0
+        if not self.active.any():
+            # Nothing decoding implies nothing in flight either
+            # (pipelined slots stay active until drained). Block on the
+            # wake event instead of poll-spinning: submit()/resubmit()/
+            # submit_migration()/set_source()/stop() set it, so
+            # first-token admission latency is event-driven, not a
+            # poll-tick coin flip. Lockstep gangs keep the 20ms tick —
+            # every iteration pays a collective, and a follower's wake
+            # event never fires for leader-side submissions.
+            with tl.phase("idle"):
+                if self.sync is not None:
+                    time.sleep(0.02)
+                else:
+                    self._wake.wait(timeout=self._idle_wait_s)
+                    self._wake.clear()
+            return
+        n_active = self.active.sum()  # host numpy mirror
+        METRICS.observe(
+            "substratus_serve_batch_occupancy_ratio",
+            float(n_active) / self.ec.max_batch,
+        )
+        if self.paged:
+            live = self.slot_pages.live_pages
+            self.stats["kv_live_pages_sum"] += live
+            self.stats["kv_pool_pages_sum"] += self.n_pages
+            METRICS.observe(
+                "substratus_serve_kv_page_utilization_ratio",
+                live / self.n_pages, {"state": "live"},
+            )
+            METRICS.observe(
+                "substratus_serve_kv_page_utilization_ratio",
+                (self.alloc.used_pages - live) / self.n_pages,
+                {"state": "cached"},
+            )
+        if not self._first_decode_done:
+            # The first decode iteration is dominated by the executable
+            # compile; record it separately so the steady-state decode
+            # histogram stays unpolluted.
+            t_decode = time.perf_counter()
+            with tracer.span("engine.first_compile") as span:
+                self._step()
+                dt = time.perf_counter() - t_decode
+                span.set_attribute("seconds", round(dt, 6))
+            self._first_decode_done = True
+            METRICS.set("substratus_serve_first_compile_seconds", dt)
+            return
+        self._step()
+        tl.commit(
+            admitted=admitted, active_slots=n_active,
+            max_slots=self.ec.max_batch,
+            configured_floor_s=self.ec.step_floor_s,
+        )
+
     def _loop(self):
         try:
-            while self._sync_iterate():
-                t_iter = time.perf_counter()
-                # Reset the step-timeline accumulators (observability/
-                # timeline.py): _flush/_dispatch/_drain/_admit fill
-                # them during this iteration; record_iteration below
-                # turns them into one flight-recorder entry with
-                # bubble attribution.
-                self._tl_iter_t0 = t_iter
-                self._tl_flush_s = 0.0
-                self._tl_flush_reasons = []
-                self._tl_dispatch_s = 0.0
-                self._tl_drain_s = 0.0
-                self._tl_drain_off_s = 0.0
-                self._tl_pool_dry = False
-                t_admit = time.perf_counter()
-                admitted = self._admit()
-                admit_s = time.perf_counter() - t_admit
-                if admitted:
-                    # Only iterations that boarded someone observe the
-                    # admission phase — an idle engine waking on its
-                    # empty queue would otherwise flood the histogram
-                    # with ~0 s samples.
-                    METRICS.observe(
-                        "substratus_serve_phase_seconds",
-                        admit_s,
-                        {"phase": "admission"},
-                    )
-                if not self.active.any():
-                    # Nothing decoding implies nothing in flight either
-                    # (pipelined slots stay active until drained). Block
-                    # on the wake event instead of poll-spinning:
-                    # submit()/resubmit()/submit_migration()/
-                    # set_source()/stop() set it, so first-token
-                    # admission latency is event-driven, not a poll-tick
-                    # coin flip. Lockstep gangs keep the 20ms tick —
-                    # every iteration pays a collective, and a
-                    # follower's wake event never fires for leader-side
-                    # submissions.
-                    if self.sync is not None:
-                        time.sleep(0.02)
-                    else:
-                        self._wake.wait(timeout=self._idle_wait_s)
-                        self._wake.clear()
-                    continue
-                n_active = self.active.sum()  # host numpy mirror
-                METRICS.observe(
-                    "substratus_serve_batch_occupancy_ratio",
-                    float(n_active) / self.ec.max_batch,
-                )
-                if self.paged:
-                    METRICS.observe(
-                        "substratus_serve_kv_page_utilization_ratio",
-                        (self.n_pages - self.alloc.free_pages) / self.n_pages,
-                    )
-                t_decode = time.perf_counter()
-                if not self._first_decode_done:
-                    # The first decode iteration is dominated by the
-                    # executable compile; record it separately so the
-                    # steady-state decode histogram stays unpolluted.
-                    with tracer.span("engine.first_compile") as span:
-                        self._step()
-                        dt = time.perf_counter() - t_decode
-                        span.set_attribute("seconds", round(dt, 6))
-                    self._first_decode_done = True
-                    METRICS.set("substratus_serve_first_compile_seconds", dt)
-                    continue
-                self._step()
-                dt_decode = time.perf_counter() - t_decode
-                METRICS.observe(
-                    "substratus_serve_phase_seconds",
-                    dt_decode,
-                    {"phase": "decode"},
-                )
-                if self.ec.step_floor_s > dt_decode:
-                    # Simulated device-step latency (see EngineConfig).
-                    time.sleep(self.ec.step_floor_s - dt_decode)
-                self.timeline.record_iteration(
-                    t_start=t_iter,
-                    wall_s=time.perf_counter() - t_iter,
-                    admit_s=admit_s,
-                    admitted=admitted,
-                    dispatch_s=self._tl_dispatch_s,
-                    drain_s=self._tl_drain_s,
-                    drain_off_s=self._tl_drain_off_s,
-                    flush_s=self._tl_flush_s,
-                    flush_reasons=self._tl_flush_reasons,
-                    pool_dry=self._tl_pool_dry,
-                    active_slots=n_active,
-                    max_slots=self.ec.max_batch,
-                    configured_floor_s=self.ec.step_floor_s,
-                )
+            while True:
+                with self.timeline.phase("broadcast"):
+                    go = self._sync_iterate()
+                if not go:
+                    break
+                with self.timeline.phase("iter"):
+                    self._iterate()
             # Clean stop with a step still in flight (stop() during
             # decode, a gang stop event, server drain): deliver its
             # tokens before the thread exits — consumers of in-flight

@@ -161,16 +161,30 @@ class SlotPages:
     def __init__(self, max_batch: int):
         self.pages: List[List[int]] = [[] for _ in range(max_batch)]
         self.shared: List[int] = [0] * max_batch
+        self._slot_refs: Dict[int, int] = {}  # page id -> slots holding it
+
+    @property
+    def live_pages(self) -> int:
+        """Distinct pages some slot holds (a shared prefix page counts
+        once); pages only the prefix registry holds are not live."""
+        return len(self._slot_refs)
 
     def assign(self, slot: int, shared: List[int], owned: List[int]) -> None:
         self.pages[slot] = list(shared) + list(owned)
         self.shared[slot] = len(shared)
+        for pid in self.pages[slot]:
+            self._slot_refs[pid] = self._slot_refs.get(pid, 0) + 1
 
     def append(self, slot: int, pid: int) -> None:
         self.pages[slot].append(pid)
+        self._slot_refs[pid] = self._slot_refs.get(pid, 0) + 1
 
     def release(self, slot: int, alloc: PageAllocator) -> None:
         for pid in self.pages[slot]:
             alloc.decref(pid)
+            if self._slot_refs[pid] == 1:
+                del self._slot_refs[pid]
+            else:
+                self._slot_refs[pid] -= 1
         self.pages[slot] = []
         self.shared[slot] = 0

@@ -6,7 +6,9 @@
   JAX reads it itself and this code sets no directory. Otherwise, on an
   accelerator, the cache lives in one fixed, git-ignored directory of the
   checkout: the path is part of the cache key, so a directory made from
-  tempfile, a pid or the time would never hit. The CPU backend gets no
+  tempfile, a pid or the time would never hit. Metadata (region names,
+  source lines) is part of the key too, so that a profiler capture names
+  the code that runs and not an older build's. The CPU backend gets no
   default cache: nobody deploys it, and jaxlib 0.9.0 logs ~6 KB of
   machine-feature warnings to stderr for every XLA:CPU executable it
   reloads, which fills the pipe of any parent that does not drain it.
@@ -103,6 +105,15 @@ def configure_compile_cache() -> Optional[str]:
         jax.config.update("jax_compilation_cache_dir", cache_dir)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    # An executable keeps the metadata it was compiled with: the region
+    # names (ops/scopes.py) and source lines a profiler capture shows. By
+    # default the key leaves metadata out, so an entry written by an older
+    # build with the same arithmetic would be loaded and the capture would
+    # show that build's names (seen on the chip, PR 24: first_sample came
+    # back without its `sample` region). With it in the key a capture
+    # names the code that runs; the price is a recompile after an edit
+    # that only moves lines.
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     return cache_dir
 
 

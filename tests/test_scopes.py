@@ -1,0 +1,134 @@
+"""The region vocabulary (ops/scopes.py) in the engine's compiled programs:
+every name that applies is on some op's `op_name`, every dot, gather and
+scatter sits under one, and the jitted functions keep the names the
+benchmark's readers find them by. CPU only: a scope is HLO metadata."""
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from substratus_tpu.models import llama
+from substratus_tpu.ops import scopes
+from substratus_tpu.serve.engine import Engine, EngineConfig
+
+DENSE_LLAMA = {scopes.EMBED, scopes.LAYERS, scopes.NORM, scopes.ATTN_QKV,
+               scopes.KV_WRITE, scopes.ATTN_CORE, scopes.ATTN_OUT, scopes.MLP,
+               scopes.LM_HEAD}
+CASES = {
+    # name: (model config, kv layout, the names that apply in both programs)
+    "llama-paged": ("tiny", "paged", DENSE_LLAMA | {scopes.KV_GATHER}),
+    "llama-dense-cache": ("tiny", "dense", DENSE_LLAMA),
+    "moe-paged": ("tiny-moe", "paged",
+                  (DENSE_LLAMA - {scopes.MLP})
+                  | {scopes.KV_GATHER, scopes.MOE_ROUTER, scopes.MOE_EXPERTS}),
+    "moe-dense-cache": ("tiny-moe", "dense",
+                        (DENSE_LLAMA - {scopes.MLP})
+                        | {scopes.MOE_ROUTER, scopes.MOE_EXPERTS}),
+}
+OP_RE = re.compile(
+    r"^\s*(?:ROOT )?%?[\w.\-]+ = \S+ ([\w\-]+)\(.*?op_name=\"([^\"]*)\"", re.M)
+
+
+def _engine(config: str, layout: str) -> Engine:
+    cfg = llama.CONFIGS[config].replace(dtype=jnp.float32)
+    params = llama.init_params(cfg, jax.random.key(0))
+    return Engine(cfg, params, EngineConfig(
+        max_batch=2, max_seq_len=64, max_prefill_len=16, kv_layout=layout))
+
+
+def _compiled(e: Engine):
+    """The engine's decode and chunk-prefill programs as the compiler
+    leaves them, from the engine's own jits and arguments."""
+    bt = e.block_table if e.paged else None
+    decode = e._decode_fn.lower(
+        e.params, e.cache, bt, e.tokens, e.positions, e.temps, e.top_ps,
+        e.key, None, None).compile().as_text()
+    if e.paged:
+        cache, row = e.cache, e.block_table[:1]
+    else:
+        cache, row = e._extract_slot(e.cache, 0), None
+    chunk = Engine._chunk_prefill_jit.lower(
+        e.model, e.cfg, e.params, cache, np.zeros((1, 16), np.int32), 0, 16,
+        block_table=row).compile().as_text()
+    return decode, chunk
+
+
+def _scope_of(op_name: str):
+    for part in reversed(op_name.split("/")):
+        if part in scopes.ALL:
+            return part
+    return None
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_regions_in_the_compiled_decode_and_chunk_programs(case):
+    config, layout, want = CASES[case]
+    e = _engine(config, layout)
+    assert e.paged == (layout == "paged")
+    decode, chunk = _compiled(e)
+    # the names the benchmark's readers find the programs by
+    assert re.search(r"^HloModule jit_decode\b", decode, re.M)
+    assert re.search(r"^HloModule jit__chunk_prefill_jit\b", chunk, re.M)
+    for program, text, extra in (("decode", decode, {scopes.SAMPLE}),
+                                 ("chunk", chunk, set())):
+        ops = OP_RE.findall(text)
+        assert ops, program
+        found = {_scope_of(name) for _, name in ops} - {None}
+        assert found == want | extra, (program, found ^ (want | extra))
+        # the heavy ops each sit under a region
+        for opcode, name in ops:
+            if opcode in ("dot", "gather", "scatter", "convolution"):
+                assert _scope_of(name) is not None, (program, opcode, name)
+        # scopes nest only as the table says: block regions inside
+        # `layers`, the others beside it, never one block region in another
+        for _, name in ops:
+            parts = [p for p in name.split("/") if p in scopes.ALL]
+            if not parts:
+                continue
+            inner = parts[-1]
+            if inner in (scopes.EMBED, scopes.LM_HEAD, scopes.SAMPLE,
+                         scopes.LAYERS):
+                assert parts == [inner], name
+            elif name.startswith("jit("):  # reducers carry the bare scope
+                assert parts == [scopes.LAYERS, inner], name
+
+
+def test_vocabulary_is_closed_and_matches_the_benchmarks_copy():
+    from benchmarks.harness import trace_scopes
+
+    assert tuple(trace_scopes.SCOPES) == tuple(scopes.ALL)
+    assert len(set(scopes.ALL)) == len(scopes.ALL) == 13
+
+
+def test_a_region_name_is_metadata_and_changes_no_arithmetic(monkeypatch):
+    """The program as lowered is the same text with the scopes and with
+    jax.named_scope made a no-op: naming regions costs nothing when no
+    capture runs, which is why there is no switch."""
+    import contextlib
+
+    def lowered():
+        e = _engine("tiny", "paged")
+        return e._decode_fn.lower(
+            e.params, e.cache, e.block_table, e.tokens, e.positions, e.temps,
+            e.top_ps, e.key, None, None)
+
+    named = lowered()
+    assert "kv.gather" in named.compile().as_text()
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    bare = lowered()
+    assert "kv.gather" not in bare.compile().as_text()
+    assert named.as_text() == bare.as_text()
+
+
+def test_the_compile_cache_key_holds_the_names(monkeypatch):
+    """...and because the arithmetic is the same, a cache keyed without
+    metadata would hand a build with names an older build's executable,
+    and a capture would show the older names (seen on the chip, PR 24)."""
+    from substratus_tpu.utils import jaxstart
+
+    monkeypatch.delenv(jaxstart.CACHE_ENV, raising=False)
+    jaxstart.configure_compile_cache()
+    assert jax.config.jax_compilation_cache_include_metadata_in_key is True

@@ -453,18 +453,21 @@ def _block(
     lp: Params,  # single-layer params (leading L axis removed by scan)
     positions: jnp.ndarray,  # [B, S]
     cfg: LlamaConfig,
-    layer_cache: Optional[Params],  # per-layer cache dict (k, v, [scales])
+    layer_cache: Optional[Params],  # this layer's cache rows (k, v,
+    # [scales]); with a block_table, the whole stacked pool
     kv_length: Optional[jnp.ndarray] = None,  # [B] valid cache prefix
     lora_layers: Optional[Params] = None,  # single-layer adapter tree
     lora_scale: float = 1.0,
     train: bool = False,
     block_table: Optional[jnp.ndarray] = None,  # [B, M]: paged cache layout
     adapter_ids: Optional[jnp.ndarray] = None,  # [B]: slot-stacked adapters
+    layer: Optional[jnp.ndarray] = None,  # this block's index (paged only)
 ) -> Tuple[jnp.ndarray, Params, jnp.ndarray]:
     """One transformer block. Returns (x_out, kv_out, aux): kv_out is a dict
     of either the freshly computed seq entries {k, v} (no cache: training /
-    prefill) or the updated full cache rows (decode — including k_scale/
-    v_scale when the cache is int8-quantized); aux is the MoE
+    prefill), the updated full cache rows (dense decode — including
+    k_scale/v_scale when the cache is int8-quantized) or the stacked paged
+    pool with this layer's rows written in place; aux is the MoE
     load-balancing loss (0 for dense layers).
 
     With adapter_ids, the lora leaves carry a leading adapter-slot axis
@@ -503,7 +506,7 @@ def _block(
         from substratus_tpu.ops.kvcache import paged_update_and_read
 
         kv_out, k_cache, v_cache = paged_update_and_read(
-            layer_cache, block_table, positions, kk, vv, dt
+            layer_cache, layer, block_table, positions, kk, vv, dt
         )
         with jax.named_scope(scopes.ATTN_CORE):
             attn = dot_product_attention(
@@ -587,31 +590,42 @@ def forward(
 
     lora_scale = lora["scale"] if lora is not None else 1.0
 
+    # A paged pool is the scan's carry: one buffer from the donated argument
+    # to the result, written in place at each layer's offset. A dense slot
+    # cache is sliced per layer (xs) and re-stacked (ys).
+    paged = cache is not None and block_table is not None
+
     def body(carry, layer_in):
+        x, pool = carry
         x_out, kv, aux = _block(
-            carry,
+            x,
             layer_in["lp"],
             positions,
             cfg,
-            layer_in.get("cache"),
+            layer_in.get("cache", pool),
             kv_length,
             layer_in.get("lora"),
             lora_scale,
             train,
             block_table,
             adapter_ids,
+            layer_in.get("layer"),
         )
-        return x_out, {"kv": kv, "aux": aux}
+        if paged:
+            return (x_out, kv), {"aux": aux}
+        return (x_out, None), {"kv": kv, "aux": aux}
 
     xs: Dict[str, Any] = {"lp": params["layers"]}
-    if cache is not None:
+    if paged:
+        xs["layer"] = jnp.arange(cfg.n_layers, dtype=jnp.int32)
+    elif cache is not None:
         xs["cache"] = cache
     if lora is not None:
         xs["lora"] = lora["layers"]
     if remat:
         body = jax.checkpoint(body)
     with jax.named_scope(scopes.LAYERS):
-        x, ys = lax.scan(body, x, xs)
+        (x, pool), ys = lax.scan(body, (x, cache if paged else None), xs)
 
     with jax.named_scope(scopes.LM_HEAD):
         x = rms_norm(x, params["out_norm"], cfg.norm_eps)
@@ -624,7 +638,8 @@ def forward(
                 "bsd,dv->bsv", x, params["lm_head"], cfg.dtype
             )
         logits = logits.astype(jnp.float32)
-    kv = ys["kv"]  # stacked over layers; same structure as the cache
+    # Same structure as the cache: the carried pool, or the layers' stack.
+    kv = pool if paged else ys["kv"]
     if cfg.n_experts > 0 and cache is None:
         # Per-layer router load-balancing losses (training/prefill only —
         # the decode cache must keep a stable structure for buffer

@@ -2,10 +2,10 @@
 
 The reference served models through external images with per-request
 contiguous caches (SURVEY.md §2.2); the TPU-native engine instead keeps one
-global page pool per layer
+global page pool, stacked over layers,
 
-    k/v        [pages, page_size, kv_heads, head_dim]
-    (+ scales  [pages, page_size, kv_heads, 1] when int8-quantized)
+    k/v        [layers, pages, page_size, kv_heads, head_dim]
+    (+ scales  [layers, pages, page_size, kv_heads, 1] when int8-quantized)
 
 and a per-sequence block table [B, max_pages] of page ids. Shapes stay fully
 static under jit (TPU requirement): dynamism lives in the *contents* of the
@@ -13,12 +13,21 @@ block table. Memory is bounded by actual tokens in flight, not
 batch x max_seq_len, and identical prompt prefixes can share pages
 (serve/paged_kv.py owns the host-side allocator / prefix registry).
 
-This XLA implementation scatters new entries via flat token indices and
-gathers each sequence's context as a slot-local [B, max_pages*page_size]
-view, so the framework's standard masked attention applies unchanged:
-gathered index j IS the token's absolute position in its sequence, hence
-causal masking (k_pos <= q_pos) hides unwritten / foreign pages. A Pallas
-decode kernel can later read pages in place through the same block table.
+The pool is one buffer from a jit's donated argument to its result: the
+model's layer scan carries the whole stack (models/llama.py::forward) and
+layer l addresses its rows at offset l * pages * page_size of the flat token
+axis [layers * pages * page_size]. New entries are scattered there in place,
+one token row each; each sequence's context is gathered from there one table
+entry at a time, as a slice of page_size contiguous rows (row by row out of
+HBM the same gather measured 1.8x slower, and a [pages, page_size, ...] view
+of the pool made the compiler re-tile a kv_heads shard of it in every
+layer: PERF.md §6, PR 25), into a slot-local [B, max_pages*page_size] view,
+so the framework's standard masked attention applies unchanged: gathered
+index j IS the token's absolute position in its sequence, hence causal
+masking (k_pos <= q_pos) hides unwritten / foreign pages. No op slices a
+layer out of the stack or writes one back, so a step needs no second pool
+and a kernel that reads live pages through the block table can take the
+stack as its operand.
 """
 from __future__ import annotations
 
@@ -32,27 +41,31 @@ from substratus_tpu.ops.quant import dequantize_kv, quantize_kv
 
 
 def paged_update_and_read(
-    layer_cache: Dict[str, jnp.ndarray],
+    pool: Dict[str, jnp.ndarray],  # the stacked pool, [L, P, bs, ...]
+    layer: jnp.ndarray,  # scalar int32: the layer whose pages are touched
     block_table: jnp.ndarray,  # [B, M] int32 page ids
     positions: jnp.ndarray,  # [B, S] absolute (slot-local) positions
     k_new: jnp.ndarray,  # [B, S, KH, hd]
     v_new: jnp.ndarray,
     dtype,
 ) -> Tuple[Dict[str, jnp.ndarray], jnp.ndarray, jnp.ndarray]:
-    """Write new entries at `positions`, then gather the full slot-local
-    context. Returns (updated layer_cache, k_ctx, v_ctx [B, M*bs, KH, hd]).
+    """Write new entries at `positions` of `layer`, then gather that layer's
+    full slot-local context. Returns (updated pool, k_ctx, v_ctx
+    [B, M*bs, KH, hd]); the pool is scattered into in place (the caller
+    carries and donates it), never sliced per layer.
 
     Duplicate positions (bucket-padding clamps) write in unspecified order —
     only ever at the one-past-the-prompt garbage slot, which the first
     decode step overwrites before attending (engine contract).
     """
-    pages, bs = layer_cache["k"].shape[:2]
+    n_layers, pages, bs = pool["k"].shape[:3]
     b, m = block_table.shape
+    first = layer.astype(block_table.dtype) * pages  # the layer's page 0
 
-    def flat(a):
-        return a.reshape((pages * bs,) + a.shape[2:])
+    def rows(a):  # [L, P, bs, ...] -> [L * P * bs, ...], a bitcast
+        return a.reshape((n_layers * pages * bs,) + a.shape[3:])
 
-    quantized = "k_scale" in layer_cache
+    quantized = "k_scale" in pool
     out: Dict[str, jnp.ndarray] = {}
     with jax.named_scope(scopes.KV_WRITE):
         # Writes past the block table's reach (speculative verify near the
@@ -64,7 +77,7 @@ def paged_update_and_read(
             block_table, jnp.minimum(page_idx, m - 1), axis=1
         )
         pid = jnp.where(oob, 0, pid)
-        idx = pid * bs + positions % bs  # [B, S] flat token index
+        idx = (first + pid) * bs + positions % bs  # [B, S] flat token index
         if quantized:
             kq, ks = quantize_kv(k_new)
             vq, vs = quantize_kv(v_new)
@@ -72,26 +85,24 @@ def paged_update_and_read(
         else:
             new = {"k": k_new, "v": v_new}
         for name, vals in new.items():
-            pool = layer_cache[name]
+            a = pool[name]
             out[name] = (
-                flat(pool).at[idx].set(vals.astype(pool.dtype))
-                .reshape(pool.shape)
+                rows(a).at[idx].set(vals.astype(a.dtype)).reshape(a.shape)
             )
     with jax.named_scope(scopes.KV_GATHER):
-        ctx_idx = (
-            block_table[:, :, None] * bs
-            + jnp.arange(bs, dtype=block_table.dtype)[None, None, :]
-        ).reshape(b, m * bs)
+        starts = ((first + block_table) * bs).reshape(b * m)
+
+        def read(a):  # this layer's [B, M * bs, ...]: bs rows a table entry
+            ctx = jax.vmap(
+                lambda start: jax.lax.dynamic_slice_in_dim(rows(a), start, bs)
+            )(starts)
+            return ctx.reshape((b, m * bs) + a.shape[3:])
+
         if quantized:
-            k_ctx = dequantize_kv(
-                flat(out["k"])[ctx_idx], flat(out["k_scale"])[ctx_idx], dtype
-            )
-            v_ctx = dequantize_kv(
-                flat(out["v"])[ctx_idx], flat(out["v_scale"])[ctx_idx], dtype
-            )
+            k_ctx = dequantize_kv(read(out["k"]), read(out["k_scale"]), dtype)
+            v_ctx = dequantize_kv(read(out["v"]), read(out["v_scale"]), dtype)
         else:
-            k_ctx = flat(out["k"])[ctx_idx]
-            v_ctx = flat(out["v"])[ctx_idx]
+            k_ctx, v_ctx = read(out["k"]), read(out["v"])
     return out, k_ctx, v_ctx
 
 

@@ -7,8 +7,14 @@ tiling alignment, VMEM limits). One case per kernel and shape from
 ops/kernel_cases.py — the list chip_smoke.py's kernel phase runs on the
 attached chip. Cases the compiler refuses are strict xfail carrying its
 message: the day one compiles, the test fails until the mark goes.
+
+The serving programs that carry the paged KV pool are compiled the same way:
+the optimized HLO of the engine's decode and chunk programs may not copy,
+slice or re-stack the pool (models/llama.py::forward carries it in place).
 """
+import math
 import os
+import re
 from functools import partial
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
@@ -124,3 +130,124 @@ def test_fused_decode_raises_on_a_tpu_backend(monkeypatch):
     ec = EngineConfig(max_batch=2, max_seq_len=64, kv_layout="dense")
     with pytest.raises(NotImplementedError, match="aligned to tiling"):
         Engine(cfg, None, ec)
+
+
+# The chat cell's engine (benchmarks/traffic/chat.json): Mistral-7B, int8
+# weights, whole depth (the layers are one scan: depth costs no compile time).
+_POOL_PAGES, _PAGE, _B, _S, _CHUNK = 1792, 16, 32, 2048, 512
+
+
+def _pool_moving_ops(hlo: str, sizes) -> list:
+    """Every copy / dynamic-slice / dynamic-update-slice of the optimized
+    HLO (fused computations included) whose result has one of `sizes`
+    elements."""
+    found = []
+    for m in re.finditer(
+        r"= \w+\[([\d,]+)\]\S* (copy|dynamic-slice|dynamic-update-slice)\(",
+        hlo,
+    ):
+        if math.prod(map(int, m.group(1).split(","))) in sizes:
+            found.append(m.group(0))
+    return found
+
+
+@pytest.mark.parametrize(
+    "kv_cache_dtype,tensor",
+    [("model", 1), ("int8", 1), ("model", 4)],
+    ids=["bf16", "int8kv", "bf16-tensor4"],
+)
+def test_serving_programs_leave_the_kv_pool_in_place(
+    kv_cache_dtype, tensor, v5e
+):
+    """decode and the 512-token chunk, for one described chip and for the
+    four under a `tensor` mesh (pool sharded over kv_heads): no pool- or
+    layer-of-pool-sized copy or slice, and temporaries under half a pool."""
+    from jax.sharding import (
+        NamedSharding, PartitionSpec as P, SingleDeviceSharding,
+    )
+
+    from substratus_tpu.models import llama
+    from substratus_tpu.ops.quant import quantize_params
+    from substratus_tpu.parallel.mesh import build_mesh
+    from substratus_tpu.parallel.sharding import serve_rules_for, sharding_tree
+    from substratus_tpu.serve.engine import Engine, EngineConfig
+
+    cfg = llama.LlamaConfig(
+        vocab_size=32000, dim=4096, n_layers=32, n_heads=32, n_kv_heads=8,
+        hidden_dim=14336, rope_theta=1e6, max_seq_len=32768,
+    )
+    # The engine itself is built on the CPU with the smallest pool it takes
+    # (nothing can be placed on a described device); its jitted programs are
+    # then lowered for the described chips at the cell's shapes.
+    eng = Engine(cfg, None, EngineConfig(
+        max_batch=_B, max_seq_len=_S, max_prefill_len=_CHUNK,
+        kv_cache_dtype=kv_cache_dtype, page_size=_PAGE, kv_pool_tokens=1,
+    ))
+    quantized = kv_cache_dtype == "int8"
+    params = jax.eval_shape(
+        lambda key: quantize_params(
+            llama.init_params(cfg, key), llama.quant_contracting(cfg)
+        ),
+        jax.random.key(0),
+    )
+    pool = jax.eval_shape(
+        lambda: llama.init_paged_cache(
+            cfg, _POOL_PAGES + 1, _PAGE,
+            dtype=jnp.int8 if quantized else None,
+        )
+    )
+    if tensor == 1:
+        rep = SingleDeviceSharding(v5e[0])
+
+        def shardings(tree, axes):
+            return jax.tree.map(lambda _: rep, tree)
+    else:
+        eng.mesh = mesh = build_mesh(tensor=tensor, devices=v5e)
+        rep = NamedSharding(mesh, P())
+
+        def shardings(tree, axes):
+            return sharding_tree(tree, mesh, axes, serve_rules_for(mesh))
+
+    def placed(tree, axes):
+        return jax.tree.map(
+            lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+            tree, shardings(tree, axes),
+        )
+
+    def arr(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=rep)
+
+    params = placed(params, llama.param_logical_axes(cfg))
+    pool = placed(pool, llama.paged_cache_logical_axes(cfg, quantized))
+    m = _S // _PAGE
+    programs = {
+        "decode": eng._decode_fn.lower(
+            params, pool, arr((_B, m)), arr((_B,)), arr((_B,)),
+            arr((_B,), jnp.float32), arr((_B,), jnp.float32),
+            arr(eng.key.shape, eng.key.dtype),
+        ),
+        "chunk": Engine._chunk_prefill_jit.lower(
+            llama, cfg, params, pool, arr((1, _CHUNK)), arr(()), arr(()),
+            arr((1, m)),
+        ),
+    }
+    # Elements per device of each pool array and of one layer of it. The
+    # int8 pool's f32 scales [L, P, bs, KH, 1] are the exception the test
+    # records: the compiler gives that shape a pages-minor layout and lays
+    # the whole array out anew on the way in and out (1/32 of the pool's
+    # bytes each), so only a per-layer slice of them is refused.
+    sizes = set()
+    for name, s in pool.items():
+        n = math.prod(s.sharding.shard_shape(s.shape))
+        sizes |= {n // cfg.n_layers} | (set() if "scale" in name else {n})
+    # Temporaries stay under half a pool; an int8 pool is half the bytes and
+    # its step also holds K and V of max_batch x max_seq_len dequantized in
+    # f32 (ops/quant.py::dequantize_kv), which is no part of the pool.
+    limit = sum(s.dtype.itemsize * s.size for s in pool.values()) / 2
+    if quantized:
+        limit += 2 * 4 * _B * _S * cfg.n_kv_heads * cfg.head_size
+    for name, lowered in programs.items():
+        compiled = lowered.compile()
+        assert _pool_moving_ops(compiled.as_text(), sizes) == [], name
+        temp = compiled.memory_analysis().temp_size_in_bytes
+        assert temp < limit / tensor, (name, temp, limit)

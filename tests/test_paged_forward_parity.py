@@ -1,0 +1,153 @@
+"""forward() with a paged cache against a plain per-layer reference.
+
+forward carries the stacked pool through its layer scan and
+ops/kvcache.py::paged_update_and_read addresses each layer's rows at an
+offset into the flat stack. The reference here does it the plain way, one
+layer at a time with nothing taken from ops/kvcache.py: slice the layer out
+of the pool, write each new row at (page, offset) with the block table read
+on the host, gather whole pages, attend, stack the layers back.
+Moving rows changes no value, so logits and pool must agree to the bit.
+"""
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax import lax
+
+from substratus_tpu.models import llama
+from substratus_tpu.ops import kvcache
+from substratus_tpu.ops.basics import rms_norm
+from substratus_tpu.ops.quant import dequantize_kv, qeinsum, quantize_kv
+
+PAGES, BS, M = 12, 4, 4  # pool pages (+ trash page 0), page size, table width
+
+
+def _layer_update_and_read(table, positions):
+    """The reference's cache op for one layer, closed over the host's copy of
+    the block table and positions: takes _block's call in place of
+    paged_update_and_read, with that layer's own rows as `layer_cache`."""
+
+    def update_and_read(layer_cache, layer, block_table, pos, k_new, v_new, dt):
+        assert layer is None  # the reference hands _block one layer, no index
+        new = {"k": k_new, "v": v_new}
+        if "k_scale" in layer_cache:
+            new["k"], new["k_scale"] = quantize_kv(k_new)
+            new["v"], new["v_scale"] = quantize_kv(v_new)
+        out = dict(layer_cache)
+        for b, row in enumerate(positions):
+            for s, p in enumerate(row):
+                # Past the table's reach: the trash page, never a live one.
+                page = table[b, p // BS] if p // BS < M else 0
+                for name in out:
+                    out[name] = out[name].at[page, p % BS].set(
+                        new[name][b, s].astype(out[name].dtype)
+                    )
+        ctx = {
+            name: a[table].reshape((len(table), M * BS) + a.shape[2:])
+            for name, a in out.items()
+        }
+        if "k_scale" in out:
+            return (
+                out,
+                dequantize_kv(ctx["k"], ctx["k_scale"], dt),
+                dequantize_kv(ctx["v"], ctx["v_scale"], dt),
+            )
+        return out, ctx["k"], ctx["v"]
+
+    return update_and_read
+
+
+def _reference_forward(params, tokens, cfg, positions, pool, table):
+    """The pool goes through the layers the plain way: each layer's rows are
+    sliced off the stack (the scan's xs) and the written rows are stacked
+    back (its ys). The layers stay one scan so that both sides round their
+    bfloat16 intermediates alike."""
+
+    def layer(x, xs):
+        x, kv, _ = llama._block(
+            x, xs["lp"], jnp.asarray(positions), cfg, xs["cache"],
+            block_table=jnp.asarray(table),
+        )
+        return x, kv
+
+    x = params["tok_embed"][tokens]
+    x, stacked = lax.scan(layer, x, {"lp": params["layers"], "cache": pool})
+    x = rms_norm(x, params["out_norm"], cfg.norm_eps)
+    logits = qeinsum("bsd,dv->bsv", x, params["lm_head"], cfg.dtype)
+    return logits.astype(jnp.float32), stacked
+
+
+def _random_pool(cfg, quantized, key):
+    """A pool full of noise: a row gathered from the wrong place shows."""
+    pool = llama.init_paged_cache(
+        cfg, PAGES + 1, BS, dtype=jnp.int8 if quantized else None
+    )
+    out = {}
+    for i, (name, a) in enumerate(sorted(pool.items())):
+        k = jax.random.fold_in(key, i)
+        if a.dtype == jnp.int8:
+            out[name] = jax.random.randint(k, a.shape, -127, 128, jnp.int8)
+        elif "scale" in name:
+            out[name] = jax.random.uniform(k, a.shape, a.dtype, 0.01, 0.05)
+        else:
+            out[name] = jax.random.normal(k, a.shape, a.dtype)
+    return out
+
+
+# name -> (block table [B, M], positions [B, S], int8 KV)
+CASES = {
+    # One token a row at mixed positions; row 1 is an idle slot (a zero
+    # table row), whose write lands on the trash page.
+    "step": ([[3, 7, 1, 9], [0, 0, 0, 0], [5, 2, 0, 0]], [[13], [0], [6]], False),
+    # A multi-token chunk through one block-table row, across a page edge.
+    "chunk": ([[4, 11, 6, 0]], [[3, 4, 5, 6, 7, 8]], False),
+    "int8": ([[3, 7, 1, 9], [12, 8, 0, 0], [5, 2, 0, 0]], [[13], [4], [6]], True),
+    # A speculative verify at the context window's end: position 16 is past
+    # the table's reach (M * BS) and must land on the trash page.
+    "trash": ([[3, 7, 1, 9], [5, 2, 10, 0]], [[14, 15, 16], [2, 3, 4]], False),
+}
+
+
+@pytest.mark.parametrize("case", list(CASES))
+@pytest.mark.parametrize("config", ["tiny", "tiny-moe"])
+def test_paged_forward_matches_plain_per_layer_reference(
+    config, case, monkeypatch
+):
+    cfg = llama.CONFIGS[config]
+    table, positions, quantized = CASES[case]
+    table, positions = np.array(table, np.int32), np.array(positions, np.int32)
+    params = llama.init_params(cfg, jax.random.key(1))
+    pool = _random_pool(cfg, quantized, jax.random.key(2))
+    tokens = jax.random.randint(
+        jax.random.key(3), positions.shape, 0, cfg.vocab_size, jnp.int32
+    )
+
+    logits, out = llama.forward(
+        params, tokens, cfg, positions=jnp.asarray(positions), cache=pool,
+        block_table=jnp.asarray(table),
+    )
+
+    monkeypatch.setattr(
+        kvcache, "paged_update_and_read",
+        _layer_update_and_read(table, positions),
+    )
+    want_logits, want = _reference_forward(
+        params, tokens, cfg, positions, pool, table
+    )
+
+    assert jax.tree.structure(out) == jax.tree.structure(pool)
+    np.testing.assert_array_equal(np.asarray(logits), np.asarray(want_logits))
+    for name in pool:
+        assert out[name].dtype == pool[name].dtype, name
+        np.testing.assert_array_equal(
+            np.asarray(out[name]), np.asarray(want[name]), err_msg=name
+        )
+    if case == "trash":
+        # Row 0's last write went to the trash page's slot 0 in every
+        # layer; the page's other slots keep their noise.
+        assert not np.array_equal(
+            np.asarray(out["k"][:, 0, 0]), np.asarray(pool["k"][:, 0, 0])
+        )
+        np.testing.assert_array_equal(
+            np.asarray(out["k"][:, 0, 1:]), np.asarray(pool["k"][:, 0, 1:])
+        )

@@ -7,12 +7,18 @@ one per-layer metric is a file of its own, found by that name:
     benchmarks/configs/<config>.json       (the manifest gives the path)
     benchmarks/traffic/<traffic>.json
     benchmarks/layer_metrics/<metric>.py   one read(run) -> number | None
-    benchmarks/reference/<family>.py       the configuration's `family`
+    benchmarks/families/<family>.py        the configuration's `family`: its
+                                           sizes, weight tree, program
+                                           config, counts and region names
+    benchmarks/reference/<family>.py       that family's plain reference
 
-so a later PR adds files and appends entries, and edits nothing.
+so a later PR adds files and appends entries, and edits nothing. Every
+module found by name is loaded by its path under the given `root`, so one
+added in a copy of the tree is found there.
 """
 from __future__ import annotations
 
+import functools
 import importlib.util
 import json
 import re
@@ -68,23 +74,73 @@ def metrics_for(manifest: Dict[str, Any], cell_name: str, kind: str
     return [m for m in manifest[kind] if reports(m, cell_name)]
 
 
+@functools.lru_cache(maxsize=None)
+def _module(path: Path, name: str):
+    """A module of the benchmark's own, by its file; loaded once a process
+    (a reference keeps its compiled functions)."""
+    spec = importlib.util.spec_from_file_location(name, path)
+    if spec is None or spec.loader is None or not path.is_file():
+        raise FileNotFoundError(path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def layer_reader(name: str, root: Path = ROOT
                  ) -> Callable[[Any], Optional[float]]:
     """The metric's own reader: benchmarks/layer_metrics/<name>.py::read."""
     path = root / "benchmarks" / "layer_metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(
-        "benchmarks.layer_metrics." + re.sub(r"[^A-Za-z0-9_]", "_", name), path
-    )
-    if spec is None or spec.loader is None or not path.exists():
-        raise FileNotFoundError(f"no reader for per-layer metric {name}: {path}")
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    try:
+        return _module(path, "benchmarks.layer_metrics."
+                       + re.sub(r"[^A-Za-z0-9_]", "_", name)).read
+    except FileNotFoundError:
+        raise FileNotFoundError(
+            f"no reader for per-layer metric {name}: {path}") from None
 
 
-def reference_of(config: Dict[str, Any]):
+def family_file(family: str, root: Path = ROOT) -> Path:
+    return root / "benchmarks" / "families" / f"{family}.py"
+
+
+def reference_file(family: str, root: Path = ROOT) -> Path:
+    return root / "benchmarks" / "reference" / f"{family}.py"
+
+
+def family_of(config: Dict[str, Any], root: Path = ROOT):
+    """What the benchmark knows of the configuration's block, by its
+    `family`: benchmarks/families/<family>.py."""
+    return _module(family_file(config["family"], root),
+                   "benchmarks.families." + config["family"])
+
+
+def reference_of(config: Dict[str, Any], root: Path = ROOT):
     """The configuration's plain reference, by its `family`."""
-    return importlib.import_module("benchmarks.reference." + config["family"])
+    return _module(reference_file(config["family"], root),
+                   "benchmarks.reference." + config["family"])
+
+
+def families(root: Path = ROOT) -> List[Any]:
+    """Every family file present, by name."""
+    return [_module(p, "benchmarks.families." + p.stem) for p in
+            sorted((root / "benchmarks" / "families").glob("*.py"))
+            if p.stem != "__init__"]
+
+
+def _family_files(entry: Dict[str, Any], root: Path) -> List[str]:
+    """A configuration's `family` has to have both of its files."""
+    try:
+        with open(root / entry["file"]) as f:
+            family = json.load(f).get("family")
+    except OSError:
+        return []  # no file: said above
+    except ValueError as e:
+        return [f"config {entry['name']}: {entry['file']} is not JSON ({e})"]
+    if not isinstance(family, str) or not NAME_RE.match(family):
+        return [f"config {entry['name']}: no `family` in {entry['file']}"]
+    return [f"config {entry['name']}: family {family} has no "
+            f"{p.relative_to(root)}"
+            for p in (family_file(family, root), reference_file(family, root))
+            if not p.is_file()]
 
 
 def validate(manifest: Dict[str, Any], root: Path = ROOT) -> List[str]:
@@ -113,6 +169,7 @@ def validate(manifest: Dict[str, Any], root: Path = ROOT) -> List[str]:
             bad.append(f"config {c['name']}: no file {c['file']}")
         if not any(c["file"].startswith(p.rstrip("/") + "/") for p in manifest["paths"]):
             bad.append(f"config {c['name']}: file outside paths")
+        bad.extend(_family_files(c, root))
     used = set()
     pairs = set()
     for w in manifest["workloads"]:

@@ -4,8 +4,12 @@ Everything the benchmark takes from the program goes through here: the
 engine with its scheduler, paged cache, model step and kernels
 (`substratus_tpu.serve.engine.Engine`, driven through `submit`/`start` as
 `serve.main` drives it), its `Request`, its metrics registry, its
-compile-cache helper and its sharding rules. Traffic, clocks, weights,
-counts, trace reduction and the reference are the harness's own.
+compile-cache helper, its model registry and its sharding rules. Traffic,
+clocks, weights, counts, trace reduction and the reference are the
+harness's own. Which of the program's model families a configuration runs
+on, with which config, and what its weight tree looks like, the
+configuration's family file says (`benchmarks/families/<family>.py`:
+`program`, `leaf_table`); nothing here knows a block.
 """
 from __future__ import annotations
 
@@ -23,21 +27,13 @@ def startup() -> Dict[str, Any]:
     return jax_startup()
 
 
-def llama_config(cfg: Dict[str, Any]):
-    """The program's model configuration from the published keys (there is
-    no preset for Mistral-7B and none is added to the program)."""
-    from substratus_tpu.models.llama import LlamaConfig
+def model_config(family, cfg: Dict[str, Any]):
+    """The program's model configuration: the class its registry has under
+    the name the family gives, built from the family's keyword arguments."""
+    from substratus_tpu.models import registry
 
-    s = W.model_dims(cfg)
-    return LlamaConfig(
-        vocab_size=s["V"], dim=s["D"], n_layers=s["L"], n_heads=s["H"],
-        n_kv_heads=s["KH"], hidden_dim=s["M"], head_dim=s["hd"],
-        rope_theta=float(cfg["rope_theta"]),
-        norm_eps=float(cfg["rms_norm_eps"]),
-        max_seq_len=int(cfg["max_position_embeddings"]),
-        tie_embeddings=bool(cfg.get("tie_word_embeddings", False)),
-        n_experts=s["E"], n_experts_per_token=s["K"] or 2,
-    )
+    name, kwargs = family.program(cfg)
+    return registry.config_class(name)(**kwargs)
 
 
 def build_mesh(cfg: Dict[str, Any], chips: int):
@@ -74,17 +70,18 @@ def _unwrap(tree):
     return tree
 
 
-def weight_shardings(cfg: Dict[str, Any], mesh):
+def weight_shardings(family, cfg: Dict[str, Any], mesh):
     """Where the program would put each leaf (its serving rules), as a
     tree shaped like the harness's own."""
     if mesh is None:
         return None
-    from substratus_tpu.models import llama
+    from substratus_tpu.models import registry
     from substratus_tpu.parallel.sharding import serve_rules_for, sharding_tree
 
-    lcfg = llama_config(cfg)
+    module = registry.module_for(family.program(cfg)[0])
     sh = sharding_tree(
-        _wrap(W.tree_shapes(cfg)), mesh, llama.param_logical_axes(lcfg),
+        _wrap(W.tree_shapes(family.leaf_table(cfg))), mesh,
+        module.param_logical_axes(model_config(family, cfg)),
         serve_rules_for(mesh),
     )
     return _unwrap(sh)
@@ -93,23 +90,20 @@ def weight_shardings(cfg: Dict[str, Any], mesh):
 CONTROLS = ("int4", "w8a8", "int8kv")
 
 
-def lower_weights(params, cfg: Dict[str, Any]):
+def lower_weights(params, table: Dict[str, W.Leaf]):
     """The program's own int4 weights (ops/quant4.py: nibble-packed, one
-    scale per group of 128) from the harness's int8 tree, leaf by leaf.
-    Each int8 leaf is deleted as soon as its int4 copy is made, so a 7B
-    tree never holds both."""
+    scale per group of 128) from the harness's int8 tree, leaf by leaf of
+    the family's table. Each int8 leaf is deleted as soon as its int4 copy
+    is made, so a 7B tree never holds both."""
     import jax
     import jax.numpy as jnp
     from substratus_tpu.ops.quant4 import quantize4
 
-    table = W.leaf_table(cfg)
-
-    def one(name: str, leaf):
-        contracting = table[name][1]
-        if not contracting:
+    def one(entry: W.Leaf, leaf):
+        if entry.kind != "int8":
             return leaf
-        stacked = name.startswith("layers/")
-        if stacked:  # the table counts the leading layer dim
+        contracting = entry.contracting
+        if entry.stacked:  # the table counts the leading layer dim
             contracting = tuple(c - 1 for c in contracting)
 
         def layer(q, scale):
@@ -118,20 +112,18 @@ def lower_weights(params, cfg: Dict[str, Any]):
         def layers(q, scale):  # float32 of one layer at a time
             return jax.lax.map(lambda a: layer(*a), (q, scale))
 
-        fn = jax.jit(layers if stacked else layer)
+        fn = jax.jit(layers if entry.stacked else layer)
         out = jax.block_until_ready(fn(leaf["q"], leaf["scale"]))
         leaf["q"].delete()
         leaf["scale"].delete()
         return out
 
-    out = {k: one(k, v) for k, v in params.items() if k != "layers"}
-    out["layers"] = {k: one("layers/" + k, v)
-                     for k, v in params["layers"].items()}
-    return out
+    return W.nest({path: one(entry, W.at(params, path))
+                   for path, entry in table.items()})
 
 
-def build_engine(cfg: Dict[str, Any], engine_sizes: Dict[str, Any], params,
-                 mesh, control: Optional[str] = None):
+def build_engine(family, cfg: Dict[str, Any], engine_sizes: Dict[str, Any],
+                 params, mesh, control: Optional[str] = None):
     """The engine as serve.main builds it for this configuration: paged
     layout (auto), overlap auto, no speculation, prefix cache on.
 
@@ -151,28 +143,26 @@ def build_engine(cfg: Dict[str, Any], engine_sizes: Dict[str, Any], params,
         kv_cache_dtype="int8" if control == "int8kv" else "model",
         max_queue=None,
     )
-    lcfg = llama_config(cfg)
+    mcfg = model_config(family, cfg)
     if control == "w8a8":
-        lcfg = lcfg.replace(quant_activations=True)
-    return Engine(lcfg, _wrap(params), ec, mesh=mesh)
+        mcfg = mcfg.replace(quant_activations=True)
+    return Engine(mcfg, _wrap(params), ec, mesh=mesh)
 
 
-def precision_found(engine, cfg: Dict[str, Any]) -> Dict[str, str]:
+def precision_found(engine, table: Dict[str, W.Leaf]) -> Dict[str, str]:
     """The types the engine really holds, under the keys of the
     configuration's `precision`: of its matmul weights (the leaves the
-    harness's table gives contracting dims), of what its matmuls take as
-    activations, and of its KV cache's pages."""
+    family's table makes int8), of what its matmuls take as activations,
+    and of its KV cache's pages."""
     import jax.numpy as jnp
     from substratus_tpu.ops.quant import QTensor
     from substratus_tpu.ops.quant4 import Q4Tensor
 
     kinds = set()
-    for name, (_, contracting, _) in W.leaf_table(cfg).items():
-        if not contracting:
+    for path, entry in table.items():
+        if entry.kind != "int8":
             continue
-        leaf = engine.params
-        for part in name.split("/"):
-            leaf = leaf[part]
+        leaf = W.at(engine.params, path)
         if isinstance(leaf, Q4Tensor):
             kinds.add("int4")
         else:

@@ -1,9 +1,11 @@
 """A traced run by the program's own names: device time per model region
 and scheduler time per phase.
 
-The program names its regions with `jax.named_scope` (a closed vocabulary,
-copied below) and its scheduler phases with profiler annotations
-"engine.<phase>". Both land in the profiler's `.xplane.pb`:
+The program names its regions with `jax.named_scope` (a closed vocabulary:
+the base names copied below, and what a model family's block adds to them,
+listed as `SCOPES` in its `benchmarks/families/<family>.py`) and its
+scheduler phases with profiler annotations "engine.<phase>". Both land in
+the profiler's `.xplane.pb`:
 
   * every event on the TPU plane's "XLA Ops" line points at an event
     metadata whose *stats* hold the JAX name stack (`tf_op`:
@@ -30,6 +32,7 @@ prints the tables PERF.md section 5 is made of.
 from __future__ import annotations
 
 import bisect
+import functools
 import gzip
 import json
 import os
@@ -38,12 +41,14 @@ import struct
 import sys
 from typing import Any, Dict, Iterator, List, Optional, Tuple
 
+from benchmarks.harness import manifest
 from benchmarks.harness.trace_reduce import (
     MODULE_LINES, OP_LINES, _is_control_flow, _module_name, _union,
     find_xplane, short_op,
 )
 
-# substratus_tpu/ops/scopes.py, copied: the benchmark keeps its own.
+# substratus_tpu/ops/scopes.py, copied: the benchmark keeps its own. The
+# base vocabulary; `vocabulary()` adds the family files' own regions.
 SCOPES = (
     "embed", "layers", "norm", "attn.qkv", "kv.write", "kv.gather",
     "attn.core", "attn.out", "mlp", "moe.router", "moe.experts", "lm_head",
@@ -206,16 +211,25 @@ def load_file(path: str) -> List[Dict[str, Any]]:
 
 # -- device side: time by region -------------------------------------------------
 
-def scope_of(tf_op: str) -> str:
+@functools.lru_cache(maxsize=None)
+def vocabulary() -> frozenset:
+    """The base names and the `SCOPES` of every family file present."""
+    return frozenset(SCOPES).union(
+        *(getattr(f, "SCOPES", ()) for f in manifest.families()))
+
+
+def scope_of(tf_op: str, scopes=None) -> str:
     """The innermost vocabulary name on an op's JAX name stack."""
+    scopes = vocabulary() if scopes is None else scopes
     for part in reversed(tf_op.rstrip(":").split("/")):
-        if part in SCOPES:
+        if part in scopes:
             return part
     return UNSCOPED
 
 
 def _device(plane: Dict[str, Any]) -> Optional[Dict[str, Any]]:
     meta = plane["meta"]
+    scopes = vocabulary()
     ops = [e for l in plane["lines"] if l["name"] in OP_LINES
            for e in l["events"]
            if not _is_control_flow(meta[e[0]]["name"])]
@@ -233,7 +247,7 @@ def _device(plane: Dict[str, Any]) -> Optional[Dict[str, Any]]:
         if i < 0 or s >= mods[i][1] + mods[i][2]:
             continue
         m = meta[mid]
-        sc = scope_of(str(m.get("tf_op", "")))
+        sc = scope_of(str(m.get("tf_op", "")), scopes)
         r = runs[i]
         r["ns"][sc] = r["ns"].get(sc, 0.0) + d
         r["bytes"][sc] = r["bytes"].get(sc, 0) + int(m.get("bytes_accessed", 0) or 0)

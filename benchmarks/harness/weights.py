@@ -1,17 +1,19 @@
 """Seeded weights, born on the device in the type they are served in.
 
-One jitted call makes the whole tree from `--seed`: int8 values with one
-float32 scale per output channel for every matmul weight, bfloat16 for
-embeddings, norms and the router. Nothing is made on the host and no
+One jitted call makes the whole tree from `--seed`, leaf by leaf as the
+configuration's family lists them (`benchmarks/families/<family>.py::
+leaf_table`: path -> `Leaf`): int8 values with one float32 scale per output
+channel for every matmul weight, bfloat16 for embeddings, norms and
+routers, float32 for a small bias. Nothing is made on the host and no
 bfloat16 copy of a matmul weight ever exists, so a 7B tree (7.2 GB) fits
 beside its KV pool on one 16 GB chip and a 47 GB Mixtral tree is born
 already sharded (`shardings`: a tree of the same structure, given by the
 system adapter from the program's own sharding rules).
 
-The tree is plain dicts and arrays: a quantized weight is
-``{"q": int8, "scale": float32}``. The reference reads this tree as it
-is; `system.py` wraps it into the program's classes. Stacked layers lead
-every per-layer leaf, as the program scans them.
+The tree is plain dicts and arrays, nested by the leaves' paths: a
+quantized weight is ``{"q": int8, "scale": float32}``. The reference reads
+this tree as it is; `system.py` wraps it into the program's classes. A
+stack's layer dim leads its leaves, as the program scans them.
 
 Scales differ from channel to channel (0.75..1.25 of the fan-in scale), so
 a scale applied along the wrong axis changes the logits and is caught by
@@ -19,82 +21,64 @@ the comparison that decides `correct`.
 """
 from __future__ import annotations
 
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 
-def model_dims(cfg: Dict[str, Any]) -> Dict[str, int]:
-    """The sizes a builder needs, from the published keys."""
-    d = int(cfg["hidden_size"])
-    h = int(cfg["num_attention_heads"])
-    return {
-        "D": d,
-        "H": h,
-        "KH": int(cfg["num_key_value_heads"]),
-        "hd": int(cfg.get("head_dim") or d // h),
-        "M": int(cfg["intermediate_size"]),
-        "V": int(cfg["vocab_size"]),
-        "L": int(cfg["num_hidden_layers"]),
-        "E": int(cfg.get("num_local_experts", 0)),
-        "K": int(cfg.get("num_experts_per_tok", 0)),
-    }
+class Leaf(NamedTuple):
+    """One entry of a family's leaf table. The entry, not the path, says
+    what the leaf is."""
+
+    shape: Tuple[int, ...]
+    contracting: Tuple[int, ...]  # dims a matmul sums over; () unless int8
+    fan_in: int
+    # "int8": int8 values, a float32 scale per output channel
+    # "norm": bfloat16 near one; "normal": bfloat16, fan-in normal
+    # "bias": float32, small
+    kind: str
+    stacked: bool = False  # the leading dim counts a stack's layers
 
 
-def leaf_table(cfg: Dict[str, Any]) -> Dict[str, Tuple[tuple, tuple, int]]:
-    """name -> (shape, contracting dims, fan-in). Empty contracting dims
-    mean the leaf stays bfloat16. Per-layer leaves are "layers/<name>"."""
-    s = model_dims(cfg)
-    D, H, KH, hd, M, V, L, E = (s[k] for k in "D H KH hd M V L E".split())
-    t: Dict[str, Tuple[tuple, tuple, int]] = {
-        "tok_embed": ((V, D), (), 1),
-        "out_norm": ((D,), (), 0),
-        "layers/attn_norm": ((L, D), (), 0),
-        "layers/mlp_norm": ((L, D), (), 0),
-        "layers/wq": ((L, D, H, hd), (1,), D),
-        "layers/wk": ((L, D, KH, hd), (1,), D),
-        "layers/wv": ((L, D, KH, hd), (1,), D),
-        "layers/wo": ((L, H, hd, D), (1, 2), H * hd),
-    }
-    if E:
-        t["layers/router"] = ((L, D, E), (), D)
-        t["layers/w_gate"] = ((L, E, D, M), (2,), D)
-        t["layers/w_up"] = ((L, E, D, M), (2,), D)
-        t["layers/w_down"] = ((L, E, M, D), (2,), M)
-    else:
-        t["layers/w_gate"] = ((L, D, M), (1,), D)
-        t["layers/w_up"] = ((L, D, M), (1,), D)
-        t["layers/w_down"] = ((L, M, D), (1,), M)
-    if not cfg.get("tie_word_embeddings", False):
-        t["lm_head"] = ((D, V), (0,), D)
-    return t
+def scale_shape(leaf: Leaf) -> Tuple[int, ...]:
+    return tuple(1 if i in leaf.contracting else n
+                 for i, n in enumerate(leaf.shape))
 
 
-def _nest(flat: Dict[str, Any]) -> Dict[str, Any]:
-    out: Dict[str, Any] = {"layers": {}}
-    for name, v in flat.items():
-        if name.startswith("layers/"):
-            out["layers"][name.split("/", 1)[1]] = v
-        else:
-            out[name] = v
+def nest(flat: Dict[str, Any]) -> Dict[str, Any]:
+    """{"a/b/c": v} -> {"a": {"b": {"c": v}}}: paths of any depth."""
+    out: Dict[str, Any] = {}
+    for path, v in flat.items():
+        *groups, name = path.split("/")
+        node = out
+        for g in groups:
+            node = node.setdefault(g, {})
+        node[name] = v
     return out
 
 
-def tree_shapes(cfg: Dict[str, Any]) -> Dict[str, Any]:
+def at(tree: Dict[str, Any], path: str):
+    """The leaf of a nested tree at a table's path."""
+    for part in path.split("/"):
+        tree = tree[part]
+    return tree
+
+
+def tree_shapes(table: Dict[str, Leaf]) -> Dict[str, Any]:
     """The tree as ShapeDtypeStructs (for shardings and ahead-of-time
     compiles)."""
-    flat = {}
-    for name, (shape, contr, _) in leaf_table(cfg).items():
-        if not contr:
-            flat[name] = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
-        else:
-            sshape = tuple(1 if i in contr else n for i, n in enumerate(shape))
-            flat[name] = {
-                "q": jax.ShapeDtypeStruct(shape, jnp.int8),
-                "scale": jax.ShapeDtypeStruct(sshape, jnp.float32),
+    flat: Dict[str, Any] = {}
+    for path, leaf in table.items():
+        if leaf.kind == "int8":
+            flat[path] = {
+                "q": jax.ShapeDtypeStruct(leaf.shape, jnp.int8),
+                "scale": jax.ShapeDtypeStruct(scale_shape(leaf), jnp.float32),
             }
-    return _nest(flat)
+        else:
+            flat[path] = jax.ShapeDtypeStruct(
+                leaf.shape, jnp.float32 if leaf.kind == "bias" else jnp.bfloat16)
+    return nest(flat)
 
 
 def seed_key(seed: int) -> jax.Array:
@@ -118,48 +102,44 @@ def _int8_values(key, shape):
     return jax.lax.map(one, jax.random.split(key, shape[0]))
 
 
-def _make(cfg_items: tuple, key):
-    cfg = dict(cfg_items)
+def _make(table_items: tuple, key):
+    """Every leaf from its own key: the i-th path in sorted order takes
+    fold_in(key, i)."""
     flat = {}
-    for i, (name, (shape, contr, fan_in)) in enumerate(
-        sorted(leaf_table(cfg).items())
-    ):
+    for i, (path, leaf) in enumerate(table_items):
         k = jax.random.fold_in(key, i)
-        if name.endswith("norm"):
+        shape = leaf.shape
+        if leaf.kind == "norm":
             # Near one, not all equal: a norm weight left out would show.
-            flat[name] = (
+            flat[path] = (
                 1.0 + 0.1 * jax.random.normal(k, shape, jnp.float32)
             ).astype(jnp.bfloat16)
-        elif not contr:
-            flat[name] = (
-                jax.random.normal(k, shape, jnp.float32) * fan_in ** -0.5
+        elif leaf.kind == "normal":
+            flat[path] = (
+                jax.random.normal(k, shape, jnp.float32) * leaf.fan_in ** -0.5
             ).astype(jnp.bfloat16)
-        else:
+        elif leaf.kind == "bias":
+            flat[path] = 0.01 * jax.random.normal(k, shape, jnp.float32)
+        elif leaf.kind == "int8":
             kq, ks = jax.random.split(k)
             if len(shape) > 2:
                 q = _int8_values(kq, shape)
             else:
                 q = _int8_values(kq, (1,) + shape)[0]
-            sshape = tuple(1 if j in contr else n for j, n in enumerate(shape))
             # uniform int8 has a standard deviation of 127/sqrt(3)
-            base = (3.0 ** 0.5 / 127.0) * fan_in ** -0.5
-            scale = base * (
-                0.75 + 0.5 * jax.random.uniform(ks, sshape, jnp.float32)
-            )
-            flat[name] = {"q": q, "scale": scale}
-    return _nest(flat)
+            base = (3.0 ** 0.5 / 127.0) * leaf.fan_in ** -0.5
+            scale = base * (0.75 + 0.5 * jax.random.uniform(
+                ks, scale_shape(leaf), jnp.float32))
+            flat[path] = {"q": q, "scale": scale}
+        else:
+            raise ValueError(f"leaf {path}: unknown kind {leaf.kind!r}")
+    return nest(flat)
 
 
-def _hashable(cfg: Dict[str, Any]) -> tuple:
-    keys = ("hidden_size", "num_attention_heads", "num_key_value_heads",
-            "head_dim", "intermediate_size", "vocab_size",
-            "num_hidden_layers", "num_local_experts", "num_experts_per_tok",
-            "tie_word_embeddings")
-    return tuple((k, cfg[k]) for k in keys if k in cfg)
-
-
-def make_weights(cfg: Dict[str, Any], seed: int,
+def make_weights(table: Dict[str, Leaf], seed: int,
                  shardings: Optional[Any] = None) -> Dict[str, Any]:
-    """The whole tree in one jitted call; `shardings` places each leaf."""
+    """The whole tree in one jitted call; `shardings` places each leaf. The
+    table itself is the call's static key, so nothing a family reads can
+    be left out of it."""
     fn = jax.jit(_make, static_argnums=0, out_shardings=shardings)
-    return fn(_hashable(cfg), seed_key(seed))
+    return fn(tuple(sorted(table.items())), seed_key(seed))

@@ -1,12 +1,14 @@
-"""FLOPs a full prefill chunk needs (harness/counts.py: matmuls, for
-Mixtral the routed top-k experts only, causal attention against the real
-context at the mean offset of the window's prompts, the output head for
-one row) over what the chips could do in the chunk's device time."""
+"""FLOPs a full prefill chunk needs (the family's `prefill_chunk_flops`:
+matmuls, for Mixtral the routed top-k experts only, causal attention
+against the real context at the mean offset of the window's prompts, the
+output head for one row) over what the chips could do in the chunk's
+device time."""
 from benchmarks.harness import counts, manifest, peaks
 
 
 def read(run):
-    if run["rehearse"]:
+    chunk_flops = counts.of(run, "prefill_chunk_flops")
+    if run["rehearse"] or chunk_flops is None:
         return None
     ms = manifest.layer_reader("prefill_chunk_ms")(run)
     if ms is None:
@@ -22,6 +24,6 @@ def read(run):
     if not offs:
         return None
     off = sum(offs) / len(offs)
-    flops = counts.prefill_chunk_flops(run["config"], chunk, int(off))
+    flops = chunk_flops(run["config"], chunk, int(off))
     peak, _ = peaks.peak_for(run["device"]["kind"])
     return 100.0 * flops / (ms * 1e-3 * peak * run["chips"])

@@ -3,9 +3,10 @@
 Written from the published architecture, in straightforward `jax.numpy`,
 float32 with `jax.default_matmul_precision("highest")`. It imports nothing
 of the program and takes nothing the program has made: it reads the
-harness's own seeded weight tree (`harness/weights.py`: int8 values and
-float32 scales, or bfloat16) and dequantizes one layer at a time, so a 7B
-model in float32 is never resident.
+harness's own seeded weight tree (`harness/weights.py` from the table of
+`benchmarks/families/llama_family.py`, whose `dims` it shares: int8 values
+and float32 scales, or bfloat16) and dequantizes one layer at a time, so a
+7B model in float32 is never resident.
 
     x = embed[tokens]
     per layer: h = rmsnorm(x) * w
@@ -34,6 +35,8 @@ from typing import Any, Dict, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from benchmarks.families.llama_family import dims as model_dims
 
 QUERY_BLOCK = 512
 
@@ -139,8 +142,6 @@ def logits_at(weights: Dict[str, Any], cfg: Dict[str, Any],
     (default: 256 up to 1,024 tokens, 1,024 beyond; a causal model's real
     rows do not see the padding), so that few distinct programs are ever
     compiled and a checkout's compile cache soon holds them all."""
-    from benchmarks.harness.weights import model_dims
-
     dims = model_dims(cfg)
     t = len(tokens)
     if pad_to is None:

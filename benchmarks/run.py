@@ -3,8 +3,9 @@
     python3 -m benchmarks.run --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 A new process for every run: it finds the cell in BENCHMARK.json, loads the
-cell's configuration and traffic mix (data files), makes weights and
-inputs from --seed, builds the engine `serve.main` would build, warms the
+cell's configuration and traffic mix (data files) and the configuration's
+family (benchmarks/families/<family>.py), makes weights and inputs from
+--seed, builds the engine `serve.main` would build, warms the
 shapes this cell's traffic uses and no others, ramps, measures for
 --seconds, compares a sample of what the window served with the plain
 reference, and prints one JSON object as the last line of its output.
@@ -134,19 +135,22 @@ def run_once(man, cell, cfg, mix, chips: int, seed: int, seconds: float,
     t_process = perf0 - (wall0 - T_PROCESS)  # process start, on perf_counter
     sizes = mix["engine"]
     vocab = int(cfg["vocab_size"])
+    family = M.family_of(cfg)
+    table = family.leaf_table(cfg)
     annotate = None
     if trace:
         annotate = jax.profiler.TraceAnnotation
 
     # -- load: weights on the device from the seed, then the engine --------
     mesh = system.build_mesh(cfg, chips)
-    params = W.make_weights(cfg, seed, system.weight_shardings(cfg, mesh))
+    shardings = system.weight_shardings(family, cfg, mesh)
+    params = W.make_weights(table, seed, shardings)
     jax.block_until_ready(params)
     t_weights = time.perf_counter()
     if control == "int4":
-        params = system.lower_weights(params, cfg)
-    engine = system.build_engine(cfg, sizes, params, mesh, control)
-    found = system.precision_found(engine, cfg)
+        params = system.lower_weights(params, table)
+    engine = system.build_engine(family, cfg, sizes, params, mesh, control)
+    found = system.precision_found(engine, table)
     engine.start()
     counters = system.Counters(engine)
 
@@ -279,7 +283,7 @@ def run_once(man, cell, cfg, mix, chips: int, seed: int, seconds: float,
     engine.cache = None
     if control == "int4":
         engine.params = params = None
-        params = W.make_weights(cfg, seed, system.weight_shardings(cfg, mesh))
+        params = W.make_weights(table, seed, shardings)
     reference = M.reference_of(cfg)
     sample = check.sample_finished(records, w0, w1, seed,
                                    int(mix["check_requests"]))
@@ -318,7 +322,8 @@ def run_once(man, cell, cfg, mix, chips: int, seed: int, seconds: float,
         if trace_dir is not None:
             reduced = trace_reduce.reduce_dir(trace_dir)
         run = {
-            "cell": cell, "config": cfg, "mix": mix, "chips": chips,
+            "cell": cell, "config": cfg, "family": family, "mix": mix,
+            "chips": chips,
             "device": dev, "memory": mem,
             "records": records, "w0": w0, "w1": w1, "traced": traced,
             "counters": delta, "trace": reduced, "e2e": e2e,

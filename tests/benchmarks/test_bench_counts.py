@@ -1,11 +1,10 @@
-"""counts.py against hand-computed bytes and FLOPs for both
-configurations; the peak table refuses an unlisted device."""
+"""counts.py and the llama family's counts against hand-computed bytes and
+FLOPs for both configurations; the peak table refuses an unlisted device."""
 import json
 
 import pytest
 
 from benchmarks.harness import counts, manifest as M, peaks
-from benchmarks.harness.weights import leaf_table, model_dims
 
 MAN = M.load()
 def _config(name):
@@ -14,6 +13,8 @@ def _config(name):
 
 MISTRAL = _config("mistral-7b-instruct-v0.2")
 MIXTRAL = _config("mixtral-8x7b-instruct-v0.1")
+F = M.family_of(MISTRAL)  # both configurations name the one family
+model_dims, leaf_table = F.dims, F.leaf_table
 
 D, H, KH, HD, MLP, V, L = 4096, 32, 8, 128, 14336, 32000, 32
 ATTN = D * H * HD + 2 * D * KH * HD + H * HD * D          # 41,943,040
@@ -29,14 +30,14 @@ def test_dims_from_published_keys():
 def test_mistral_parameter_count():
     n = sum(
         int(__import__("math").prod(shape))
-        for shape, _, _ in leaf_table(MISTRAL).values()
+        for shape, *_ in leaf_table(MISTRAL).values()
     )
     # 7.24 B: the published size of Mistral-7B
     assert n == L * (ATTN + DENSE_MLP + 2 * D) + 2 * V * D + D == 7_241_732_096
 
 
 def test_mistral_weight_bytes():
-    wb = counts.weight_bytes(MISTRAL)
+    wb = counts.weight_bytes(leaf_table(MISTRAL))
     assert wb["layers/wq"] == L * D * H * HD + 4 * L * H * HD
     assert wb["layers/wo"] == L * H * HD * D + 4 * L * D
     assert wb["layers/w_down"] == L * MLP * D + 4 * L * D
@@ -47,17 +48,17 @@ def test_mistral_weight_bytes():
 def test_mistral_decode_step_bytes():
     ctx = [1000, 24]
     kv_row = L * 2 * KH * HD * 2  # 131,072 bytes a token
-    weights = sum(b for n, b in counts.weight_bytes(MISTRAL).items()
+    weights = sum(b for n, b in counts.weight_bytes(leaf_table(MISTRAL)).items()
                   if n != "tok_embed")
     want = weights + 2 * D * 2 + kv_row * (1024 + 2)
-    assert counts.decode_step_bytes(MISTRAL, ctx, 2) == want
+    assert F.decode_step_bytes(MISTRAL, ctx, 2) == want
     assert 7.1e9 < weights < 7.2e9  # int8: about a byte a parameter
 
 
 def test_mixtral_decode_streams_only_routable_experts():
-    one = counts.decode_step_bytes(MIXTRAL, [10], 2)
-    full = counts.decode_step_bytes(MIXTRAL, [10] * 32, 2)
-    expert_bytes = sum(b for n, b in counts.weight_bytes(MIXTRAL).items()
+    one = F.decode_step_bytes(MIXTRAL, [10], 2)
+    full = F.decode_step_bytes(MIXTRAL, [10] * 32, 2)
+    expert_bytes = sum(b for n, b in counts.weight_bytes(leaf_table(MIXTRAL)).items()
                        if n.split("/")[-1] in ("w_gate", "w_up", "w_down"))
     kv_row = L * 2 * KH * HD * 2
     assert full - one == pytest.approx(
@@ -66,19 +67,19 @@ def test_mixtral_decode_streams_only_routable_experts():
 
 
 def test_matmul_params_per_token():
-    assert counts.matmul_params_per_token(MISTRAL) == L * (ATTN + DENSE_MLP)
-    assert counts.matmul_params_per_token(MIXTRAL) == L * (
+    assert F.matmul_params_per_token(MISTRAL) == L * (ATTN + DENSE_MLP)
+    assert F.matmul_params_per_token(MIXTRAL) == L * (
         ATTN + 2 * DENSE_MLP + D * 8)
 
 
 def test_prefill_chunk_flops():
-    got = counts.prefill_chunk_flops(MISTRAL, 512, 1024)
+    got = F.prefill_chunk_flops(MISTRAL, 512, 1024)
     keys = 512 * 1024 + 512 * 513 // 2
     want = (2 * 512 * L * (ATTN + DENSE_MLP)
             + L * 4 * H * HD * keys + 2 * D * V)
     assert got == want
     # Mixtral counts the routed two of eight experts, not the dense-all path
-    ratio = counts.prefill_chunk_flops(MIXTRAL, 512, 0) / counts.prefill_chunk_flops(
+    ratio = F.prefill_chunk_flops(MIXTRAL, 512, 0) / F.prefill_chunk_flops(
         MISTRAL, 512, 0)
     assert 1.7 < ratio < 1.85
 

@@ -26,16 +26,20 @@ CONFIGS = {
 }
 
 
+def weights(cfg, seed):
+    return W.make_weights(M.family_of(cfg).leaf_table(cfg), seed)
+
+
 @pytest.fixture(scope="module", params=sorted(CONFIGS))
 def model(request):
     cfg = CONFIGS[request.param]()
-    return cfg, W.make_weights(cfg, 2**31 + 3)
+    return cfg, weights(cfg, 2**31 + 3)
 
 
 def test_weights_are_seeded_int8_with_per_channel_scales(model):
     cfg, w = model
-    again = W.make_weights(cfg, 2**31 + 3)
-    other = W.make_weights(cfg, 2**31 + 4)
+    again = weights(cfg, 2**31 + 3)
+    other = weights(cfg, 2**31 + 4)
     wq = w["layers"]["wq"]
     assert wq["q"].dtype == np.int8 and wq["scale"].dtype == np.float32
     assert np.array_equal(wq["q"], again["layers"]["wq"]["q"])
@@ -45,32 +49,30 @@ def test_weights_are_seeded_int8_with_per_channel_scales(model):
     assert s.shape == (wq["q"].shape[0], 1) + wq["q"].shape[2:]
     assert s.max() / s.min() > 1.2  # scales differ from channel to channel
     assert str(w["tok_embed"].dtype) == "bfloat16"
-    shapes = W.tree_shapes(cfg)
+    shapes = W.tree_shapes(M.family_of(cfg).leaf_table(cfg))
     assert shapes["layers"]["wq"]["q"].shape == wq["q"].shape
 
 
 def test_weight_layout_is_the_programs(model):
-    """The harness's own table of contracting dims equals the program's."""
+    """The family's own table of contracting dims equals the program's."""
     from benchmarks.harness import system
-    from substratus_tpu.models import llama
+    from substratus_tpu.models import registry
 
     cfg, _ = model
-    lcfg = system.llama_config(cfg)
-    theirs = llama.quant_contracting(lcfg)
-    for name, (shape, contr, _) in W.leaf_table(cfg).items():
-        node = theirs
-        for part in name.split("/"):
-            node = node[part]
-        assert tuple(node) == tuple(contr), name
+    family = M.family_of(cfg)
+    module = registry.module_for(family.program(cfg)[0])
+    mcfg = system.model_config(family, cfg)
+    theirs = module.quant_contracting(mcfg)
+    for path, leaf in family.leaf_table(cfg).items():
+        assert tuple(W.at(theirs, path)) == tuple(leaf.contracting), path
+        assert (leaf.kind == "int8") is bool(leaf.contracting), path
+        assert leaf.stacked is path.startswith("layers/"), path
     import jax
 
-    ref_shapes = jax.eval_shape(lambda k: llama.init_params(lcfg, k),
+    ref_shapes = jax.eval_shape(lambda k: module.init_params(mcfg, k),
                                 jax.random.key(0))
-    for name, (shape, _, _) in W.leaf_table(cfg).items():
-        node = ref_shapes
-        for part in name.split("/"):
-            node = node[part]
-        assert tuple(node.shape) == tuple(shape), name
+    for path, leaf in family.leaf_table(cfg).items():
+        assert tuple(W.at(ref_shapes, path).shape) == tuple(leaf.shape), path
 
 
 def test_reference_matches_the_programs_forward(model):
@@ -82,12 +84,13 @@ def test_reference_matches_the_programs_forward(model):
     from substratus_tpu.models import llama
 
     cfg, w = model
+    family = M.family_of(cfg)
     ref = M.reference_of(cfg)
     tokens = T.prompt_tokens(5, 0, 48, cfg["vocab_size"])
     rows = list(range(48))
     want = np.asarray(ref.logits_at(w, cfg, tokens, rows, pad_to=16))
     got, _ = llama.forward(system._wrap(w), jnp.asarray([tokens], jnp.int32),
-                           system.llama_config(cfg))
+                           system.model_config(family, cfg))
     got = np.asarray(got[0])
     # bfloat16 activations against float32: logits of about +-3 agree to a
     # few hundredths; a wrong rotary convention, scale axis, norm or expert
@@ -121,12 +124,14 @@ def test_the_engines_types_are_read_from_what_it_holds(control, key, want):
     from benchmarks.harness import system
 
     cfg = CONFIGS["mistral"]()
+    family = M.family_of(cfg)
+    table = family.leaf_table(cfg)
     sizes = M.traffic_of("chat")["rehearse"]["engine"]
-    w = W.make_weights(cfg, 5)
+    w = W.make_weights(table, 5)
     if control == "int4":
-        w = system.lower_weights(w, cfg)
-    engine = system.build_engine(cfg, sizes, w, None, control)
-    found = system.precision_found(engine, cfg)
+        w = system.lower_weights(w, table)
+    engine = system.build_engine(family, cfg, sizes, w, None, control)
+    found = system.precision_found(engine, table)
     stated = {k: cfg["precision"][k] for k in found}
     assert {k for k in found if found[k] != stated[k]} == ({key} if key else set())
     if key:
@@ -139,9 +144,9 @@ def test_the_programs_int4_weights_are_made_from_the_same_int8_values(model):
     from benchmarks.harness import system
 
     cfg, _ = model
-    w = W.make_weights(cfg, 11)
+    w = weights(cfg, 11)
     q, scale = np.asarray(w["layers"]["wq"]["q"]), np.asarray(w["layers"]["wq"]["scale"])
-    low = system.lower_weights(w, cfg)
+    low = system.lower_weights(w, M.family_of(cfg).leaf_table(cfg))
     assert w["layers"]["wq"]["q"].is_deleted()
     got = np.asarray(low["layers"]["wq"].dequant(np.float32))
     want = q.astype(np.float32) * scale

@@ -22,6 +22,8 @@ NEW_METRICS = [
     "decode_matmul_ms", "decode_weights_hbm_share", "decode_unscoped_share",
     "prefill_attn_ms", "prefill_attn_ms.tok", "sched_host_work_ms",
     "kv_pages_live_share"]
+CFG = M.config_of(M.load(), "mistral-7b-instruct-v0.2")
+FAMILY = M.family_of(CFG)
 
 
 # -- the wire format: a tiny XSpace encoded by hand ------------------------------
@@ -172,7 +174,7 @@ def test_recorded_trace_regions_phases_and_readers(recorded, tmp_path,
     with gzip.open(NEW, "rb") as src, open(d / "vm.xplane.pb", "wb") as dst:
         shutil.copyfileobj(src, dst)
     monkeypatch.chdir(tmp_path)
-    run = {"cell": {"name": "cellname"}, "rehearse": False}
+    run = {"cell": {"name": "cellname"}, "rehearse": False, "family": FAMILY}
     got = {m: M.layer_reader(m)(run) for m in NEW_METRICS[:4] + NEW_METRICS[5:9]}
     assert all(v is not None and v > 0 for v in got.values()), got
     assert got["decode_kv_gather_ms"] == pytest.approx(gather["ms"])
@@ -256,7 +258,7 @@ def test_a_new_reader_with_nothing_to_read_returns_nothing(metric, tmp_path,
     run = {"cell": {"name": "mistral-7b.chat"}, "rehearse": False,
            "records": [], "traced": (0.0, 1.0), "chips": 1,
            "counters": {"stats": {"preemptions": 0}},
-           "config": M.config_of(M.load(), "mistral-7b-instruct-v0.2"),
+           "config": CFG, "family": FAMILY,
            "device": {"kind": "TPU v5 lite"}}
     assert M.layer_reader(metric)(run) is None
     # and with the parent's trace: every op unscoped, no engine.* span
@@ -272,9 +274,10 @@ def test_weights_share_counts_each_matmul_weight_once(monkeypatch):
 
     from benchmarks.harness import counts
 
-    cfg = M.config_of(M.load(), "mistral-7b-instruct-v0.2")
-    need = sum(b for n, b in counts.weight_bytes(cfg).items()
+    cfg = CFG
+    need = sum(b for n, b in counts.weight_bytes(FAMILY.leaf_table(cfg)).items()
                if n.split("/")[-1] not in ("tok_embed", "attn_norm", "mlp_norm"))
+    assert need == FAMILY.decode_matmul_weight_bytes(cfg, 1)
     assert 7.0e9 < need < 7.3e9  # 7.24 B parameters less the embedding, int8
     read = M.layer_reader("decode_weights_hbm_share")
     real = M.layer_reader
@@ -283,7 +286,8 @@ def test_weights_share_counts_each_matmul_weight_once(monkeypatch):
         if name == "decode_matmul_ms" else real(name, root))
     decoding = types.SimpleNamespace(first=0.1, done=None)
     run = {"rehearse": False, "records": [decoding], "traced": (0.0, 1.0),
-           "config": cfg, "device": {"kind": "TPU v5 lite"}, "chips": 1}
+           "config": cfg, "family": FAMILY,
+           "device": {"kind": "TPU v5 lite"}, "chips": 1}
     assert read(run) == pytest.approx(100.0 * need / (10e-3 * 819e9))
     assert read(dict(run, records=[])) is None  # nobody decoding: no step
     assert read(dict(run, rehearse=True)) is None

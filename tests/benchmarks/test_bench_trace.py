@@ -67,8 +67,9 @@ def test_layer_metric_readers_on_the_recorded_trace(recorded, metric, want):
     "decode_step_ms", "prefill_chunk_ms", "device_idle_share",
     "collective_share", "decode_hbm_share", "prefill_mxu_share"])
 def test_a_reader_with_nothing_to_read_returns_nothing(metric):
+    cfg = M.config_of(M.load(), "mistral-7b-instruct-v0.2")
     run = {"trace": None, "rehearse": False, "records": [], "w0": 0, "w1": 1,
-           "traced": (0, 1)}
+           "traced": (0, 1), "config": cfg, "family": M.family_of(cfg)}
     assert M.layer_reader(metric)(run) is None
 
 

@@ -317,6 +317,56 @@ def convert_falcon_state_dict(sd: Mapping[str, Any], cfg, dtype=jnp.bfloat16) ->
     }
 
 
+def config_from_hf_exaone_moe(hf_cfg: Any):
+    """A transformers `exaone_moe` config.json (K-EXAONE) -> ExaoneMoeConfig:
+    the layer kinds come from its `layer_types` / `mlp_layer_types` lists.
+    Every expert is held (one program serving the whole checkpoint)."""
+    from substratus_tpu.models.exaone_moe import ExaoneMoeConfig
+
+    get = lambda name, default=None: getattr(hf_cfg, name, default)
+    n = hf_cfg.num_hidden_layers
+    rope = get("rope_parameters") or {}
+    if not isinstance(rope, dict):
+        rope = vars(rope)
+    return ExaoneMoeConfig(
+        vocab_size=hf_cfg.vocab_size,
+        dim=hf_cfg.hidden_size,
+        n_layers=n,
+        n_heads=hf_cfg.num_attention_heads,
+        n_kv_heads=get("num_key_value_heads") or hf_cfg.num_attention_heads,
+        head_dim=get("head_dim")
+        or hf_cfg.hidden_size // hf_cfg.num_attention_heads,
+        hidden_dim=hf_cfg.intermediate_size,
+        moe_hidden_dim=hf_cfg.moe_intermediate_size,
+        n_experts=hf_cfg.num_experts,
+        n_experts_per_token=hf_cfg.num_experts_per_tok,
+        n_shared_experts=get("num_shared_experts", 1),
+        routed_scaling_factor=float(get("routed_scaling_factor", 1.0)),
+        norm_topk_prob=bool(get("norm_topk_prob", True)),
+        layer_types=tuple(hf_cfg.layer_types[:n]),
+        mlp_layer_types=tuple(hf_cfg.mlp_layer_types[:n]),
+        sliding_window=hf_cfg.sliding_window,
+        rope_theta=float(rope.get("rope_theta", get("rope_theta", 1e6))),
+        norm_eps=get("rms_norm_eps", 1e-5),
+        max_seq_len=get("max_position_embeddings", 4096),
+        tie_embeddings=bool(get("tie_word_embeddings", False)),
+    )
+
+
+def convert_exaone_moe_state_dict(sd: Mapping[str, Any], cfg: Any,
+                                  dtype=jnp.bfloat16) -> Params:
+    """Not written: the tensor names of the published exaone_moe checkpoint
+    were not at hand when the family was added (no network), and a guessed
+    mapping under a real model's name is worse than none. The family is
+    served from a named config (random weights) or an orbax checkpoint of
+    models/exaone_moe.py's own tree (docs/cli.md)."""
+    raise NotImplementedError(
+        "exaone_moe: the config.json is read (config_from_hf_exaone_moe) "
+        "but no converter maps the checkpoint's tensors onto "
+        "models/exaone_moe.py's tree yet"
+    )
+
+
 def _dispatch_hf(model_type: str):
     """transformers model_type -> (config_fn, convert_fn), via the family
     registry (models/registry.py is the single dispatch table)."""
@@ -329,6 +379,8 @@ def _dispatch_hf(model_type: str):
         return config_from_hf, convert_llama_state_dict
     if family == "falcon":
         return config_from_hf_falcon, convert_falcon_state_dict
+    if family == "exaone_moe":
+        return config_from_hf_exaone_moe, convert_exaone_moe_state_dict
     raise NotImplementedError(
         f"unsupported HF model_type {model_type!r} "
         f"(supported: {sorted(HF_MODEL_TYPES)})"

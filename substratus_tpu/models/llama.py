@@ -86,8 +86,11 @@ class LlamaConfig:
     # Opt-in; weight-only int8 (qeinsum) is the default quantized path.
     quant_activations: bool = False
     # Mixture-of-experts (Mixtral family): n_experts == 0 means dense MLP.
-    # Routed top-k with GShard-style capacity dispatch; expert weights shard
-    # over the "expert" mesh axis (expert parallelism).
+    # Routed top-k; training uses GShard-style capacity dispatch with the
+    # expert dim sharded over the "expert" mesh axis. This layer always
+    # holds every expert: a layer that is told which share of the experts
+    # it holds (what a rank of expert parallelism serves with) is
+    # models/exaone_moe.py::_moe.
     n_experts: int = 0
     n_experts_per_token: int = 2
     capacity_factor: float = 1.25
@@ -363,7 +366,10 @@ def _moe_ffn(
       token, mixed by routing weights. E/k more FLOPs than dispatch, but
       decode is HBM-bandwidth-bound (all expert weights stream from HBM
       regardless of routing), and exactness makes prefill and cached decode
-      consistent — capacity dropping would make them diverge.
+      consistent — capacity dropping would make them diverge. (With many
+      small experts that product is the larger part of a prefill chunk:
+      models/exaone_moe.py::_experts_grouped multiplies pairs sorted by
+      expert instead, still dropless, and holds a share of the experts.)
 
     Returns (output [B,S,D], load-balancing aux scalar).
     """

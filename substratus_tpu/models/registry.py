@@ -10,12 +10,15 @@ from __future__ import annotations
 
 from typing import Any, Optional, Tuple
 
-from substratus_tpu.models import falcon, llama, opt
+from substratus_tpu.models import exaone_moe, falcon, llama, opt
 
 FAMILIES = {
     "llama": llama,  # Llama 2/3, Mistral, Mixtral (MoE), TinyLlama
     "opt": opt,  # facebook/opt-*
     "falcon": falcon,  # falcon-7b[-instruct], falcon-40b
+    # K-EXAONE-236B-A23B: sigmoid-routed experts beside a shared one (a
+    # program may hold a share of them), window layers beside global ones
+    "exaone_moe": exaone_moe,
 }
 
 # transformers `model_type` -> family name (HF checkpoint dispatch).
@@ -25,12 +28,14 @@ HF_MODEL_TYPES = {
     "mixtral": "llama",
     "opt": "opt",
     "falcon": "falcon",
+    "exaone_moe": "exaone_moe",
 }
 
 _CONFIG_CLASS_TO_FAMILY = {
     llama.LlamaConfig: "llama",
     opt.OPTConfig: "opt",
     falcon.FalconConfig: "falcon",
+    exaone_moe.ExaoneMoeConfig: "exaone_moe",
 }
 
 

@@ -28,6 +28,10 @@ masking (k_pos <= q_pos) hides unwritten / foreign pages. No op slices a
 layer out of the stack or writes one back, so a step needs no second pool
 and a kernel that reads live pages through the block table can take the
 stack as its operand.
+
+A layer that attends to a window of W positions keeps no pages: each decode
+slot owns a ring of W rows a window layer (`ring_read_and_update`), beside
+the pool in the same cache dict, so the pool holds the global layers alone.
 """
 from __future__ import annotations
 
@@ -104,6 +108,87 @@ def paged_update_and_read(
         else:
             k_ctx, v_ctx = read(out["k"]), read(out["v"])
     return out, k_ctx, v_ctx
+
+
+def ring_read_and_update(
+    ring: Dict[str, jnp.ndarray],  # {"wk", "wv"}: [Lw, slots, W, KH, hd]
+    layer: jnp.ndarray,  # scalar int32: index among the window layers
+    slots: jnp.ndarray,  # [B] int32: the ring row of each batch row
+    positions: jnp.ndarray,  # [B, S] absolute positions, ascending in a row
+    valid: jnp.ndarray,  # [B, S] bool: real tokens (not bucket padding,
+    # not an idle slot's filler)
+    k_new: jnp.ndarray,  # [B, S, KH, hd]
+    v_new: jnp.ndarray,
+) -> Tuple[Dict[str, jnp.ndarray], jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """The history of a layer that keeps a window of W rows a sequence.
+
+    Each slot owns W rows of every window layer; position p lives in row
+    p % W, so a row is overwritten W positions later, when no query can see
+    it any more. Reads the W rows each slot held BEFORE this call, appends
+    the new ones, and scatters the newest W real rows back in place (the
+    caller carries and donates the ring, as the paged pool; rows keep the
+    pool's [KH, hd] shape, one tile: a head-major ring made the compiler
+    lay the whole stack out anew in every step). Returns (updated ring,
+    k_ctx, v_ctx [B, W + S, KH, hd], k_pos [B, W + S]): the absolute
+    position of every context row, -1 where the row holds nothing of this
+    sequence (a ring not yet full, another request's leftovers, padding),
+    which the caller's mask must hide.
+
+    The ring is assumed to hold the positions just below positions[:, 0]:
+    true for a sequence written in order from position 0 (prefill chunks,
+    then decode steps; a preempted sequence is prefilled again from 0).
+    """
+    n_layers, n_slots, w = ring["wk"].shape[:3]
+    base = (layer.astype(jnp.int32) * n_slots + slots.astype(jnp.int32)) * w
+
+    def rows(a):  # [Lw, slots, W, ...] -> [Lw * slots * W, ...], a bitcast
+        return a.reshape((n_layers * n_slots * w,) + a.shape[3:])
+
+    with jax.named_scope(scopes.KV_RING):
+        def read(a):
+            return jax.vmap(
+                lambda start: jax.lax.dynamic_slice_in_dim(rows(a), start, w)
+            )(base)
+
+        k_old, v_old = read(ring["wk"]), read(ring["wv"])
+        # row r of a ring whose newest position is `prev` holds the largest
+        # position <= prev that is r modulo W
+        prev = positions[:, :1].astype(jnp.int32) - 1  # [B, 1]
+        r = jnp.arange(w, dtype=jnp.int32)[None, :]
+        old_pos = prev - (prev - r) % w
+        old_pos = jnp.where(old_pos >= 0, old_pos, -1)
+        new_pos = jnp.where(valid, positions.astype(jnp.int32), -1)
+        k_ctx = jnp.concatenate([k_old, k_new.astype(k_old.dtype)], axis=1)
+        v_ctx = jnp.concatenate([v_old, v_new.astype(v_old.dtype)], axis=1)
+        k_pos = jnp.concatenate([old_pos, new_pos], axis=1)
+        # Of the new rows only the newest W real ones are kept: they are
+        # distinct modulo W, so the scatter has no duplicate index; the
+        # others go out of bounds and are dropped.
+        last = jnp.max(new_pos, axis=1, keepdims=True)
+        keep = valid & (new_pos > last - w)
+        idx = jnp.where(keep, base[:, None] + new_pos % w,
+                        n_layers * n_slots * w)
+        out = {}
+        for name, vals in (("wk", k_new), ("wv", v_new)):
+            a = ring[name]
+            out[name] = (
+                rows(a).at[idx].set(vals.astype(a.dtype), mode="drop")
+                .reshape(a.shape)
+            )
+    return out, k_ctx, v_ctx, k_pos
+
+
+def init_ring_cache(
+    n_layers: int, slots: int, window: int, kv_heads: int, head_dim: int, dtype,
+) -> Dict[str, jnp.ndarray]:
+    """Per-slot rings of the window layers: wk/wv [Lw, slots, W, KH, hd]."""
+    shape = (n_layers, slots, window, kv_heads, head_dim)
+    return {"wk": jnp.zeros(shape, dtype), "wv": jnp.zeros(shape, dtype)}
+
+
+def ring_cache_logical_axes() -> Dict[str, tuple]:
+    ax = ("layers", None, None, "kv_heads", "head_dim")
+    return {"wk": ax, "wv": ax}
 
 
 def init_paged_cache(

@@ -6,10 +6,15 @@ shows them in every device op's name stack, which is how a trace of one
 commit is compared with a trace of the next after the compiler has
 renumbered every fusion (docs/observability.md "Device regions").
 
-Scopes nest only as listed: everything from NORM to MOE_EXPERTS sits
+Scopes nest only as listed: everything from NORM to MOE_SHARED sits
 inside LAYERS; EMBED, LM_HEAD and SAMPLE sit beside it. A reader charges
 an op to the innermost name on its stack. This module imports nothing, so
 models/ and ops/ take the names without pulling in anything else.
+
+A layer that keeps a window of history (models/exaone_moe.py) opens
+KV_RING and ATTN_WINDOW where a layer that keeps all of it opens KV_WRITE,
+KV_GATHER and ATTN_CORE, so the two kinds of history never share a row of
+a trace's table.
 """
 
 EMBED = "embed"              # token embedding gather
@@ -26,6 +31,10 @@ ATTN_OUT = "attn.out"        # output projection
 MLP = "mlp"                  # dense feed-forward
 MOE_ROUTER = "moe.router"    # routing logits, top-k, mixing weights
 MOE_EXPERTS = "moe.experts"  # expert matmuls and the combine
+MOE_SHARED = "moe.shared"    # the shared expert's matmuls
+KV_RING = "kv.ring"          # a window layer's per-slot ring: the read of its
+# rows and the scatter of the new ones
+ATTN_WINDOW = "attn.window"  # scores, softmax, values of a window layer
 LM_HEAD = "lm_head"          # final norm and logits
 SAMPLE = "sample"            # RNG split and ops/sampling.py::sample
 
@@ -33,3 +42,7 @@ ALL = (
     EMBED, LAYERS, NORM, ATTN_QKV, KV_WRITE, KV_GATHER, ATTN_CORE, ATTN_OUT,
     MLP, MOE_ROUTER, MOE_EXPERTS, LM_HEAD, SAMPLE,
 )
+# What one family's block adds to the thirteen every block opens (the
+# benchmark lists them in that family's file, benchmarks/families/).
+EXTRA = (MOE_SHARED, KV_RING, ATTN_WINDOW)
+EVERY = ALL + EXTRA

@@ -33,7 +33,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from substratus_tpu.models import llama
+from substratus_tpu.models import llama, registry
 from substratus_tpu.models.llama import LlamaConfig, Params
 from substratus_tpu.observability.journey import (
     JourneyLog,
@@ -123,6 +123,30 @@ METRICS.describe(
     "Prompt tokens satisfied from shared prefix pages instead of "
     "recompute (paged layout, serve/paged_kv.py).",
     type="counter",
+)
+# A family whose expert layer holds a share of the experts and whose
+# window layers keep per-slot rings (models/exaone_moe.py): what its forward
+# counts, read with the step's tokens.
+METRICS.describe(
+    "substratus_serve_moe_pairs_total",
+    "Token-expert pairs the router made, by whether the chosen expert is "
+    "held by this program (held=true: multiplied here) or by another rank "
+    "(held=false: left out of the partial sum). held / all is the share "
+    "of the routed work that lands here.",
+    type="counter",
+)
+METRICS.histogram(
+    "substratus_serve_moe_expert_pairs_max",
+    "The most token-expert pairs one held expert of one sparse layer "
+    "received in a decode step (active slots only).",
+    buckets=(1, 2, 4, 8, 16, 32, 64, 128, 256),
+)
+METRICS.histogram(
+    "substratus_serve_window_rows_live_ratio",
+    "Rows of a window layer's per-slot rings that hold a decoding "
+    "sequence's history (min(context, window) a slot) / all ring rows, "
+    "once per scheduler iteration that decodes.",
+    buckets=RATIO_BUCKETS,
 )
 # Speculative-decoding effectiveness as true counters (rate()-able): the
 # acceptance ratio accepted/proposed is the lever the adaptive per-stream
@@ -352,6 +376,7 @@ class _InFlightStep:
     slots: List[tuple]  # [(slot, Request)] active at dispatch
     pos_next: np.ndarray  # host_positions after this step's increment
     t_dispatch: float = 0.0  # host perf_counter at launch (journey drain latency)
+    stats: Any = None  # the model's per-step counters (device), or None
 
 
 @dataclass
@@ -406,14 +431,15 @@ class Engine:
         params: Params,
         ec: Optional[EngineConfig] = None,
         mesh=None,
-        model=llama,
+        model=None,
         draft: Optional[tuple] = None,  # (draft_cfg, draft_params)
         sync=None,  # serve.multihost.StepSync for multi-host lockstep
         adapters=None,  # serve.adapters.AdapterStore for multi-tenant LoRA
         handoff=None,  # serve.disagg.HandoffManager for role="prefill"
     ):
         """model: the model-family module (models.llama, models.opt, ...)
-        implementing forward/init_cache/param_logical_axes/cache_logical_axes.
+        implementing forward/init_cache/param_logical_axes/cache_logical_axes;
+        by default the family of `cfg`'s class (models/registry.py).
 
         adapters: an AdapterStore packing N tenants' LoRA adapters into
         one engine — every jitted function gains (lora_tree, adapter_ids)
@@ -437,6 +463,8 @@ class Engine:
         # default) EngineConfig instance would leak between engines.
         ec = _dc.replace(ec) if ec is not None else EngineConfig()
         self.cfg, self.params, self.ec = cfg, params, ec
+        if model is None:
+            model = registry.module_of(cfg)
         self.model = model
         # The cache may never outrun the model's position space (learned
         # position embeddings silently clamp on OOB lookups), and a prefill
@@ -491,6 +519,23 @@ class Engine:
                 f"kv_layout=paged unsupported for {model.__name__}"
             )
         self.paged = layout == "paged"
+        if not self.paged and not hasattr(model, "init_cache"):
+            raise ValueError(
+                f"kv_layout=dense unsupported for {model.__name__}"
+            )
+        # A family whose paged cache also holds state addressed by decode
+        # slot (models/exaone_moe.py: the window layers' rings). The engine
+        # tells its forward which slot a row is and which tokens are real,
+        # and takes its per-step counters; pages alone do not carry such a
+        # sequence, so what moves or shares pages is refused or off.
+        self.slot_state = self.paged and getattr(
+            model, "PAGED_SLOT_STATE", False
+        )
+        if self.slot_state and (ec.role != "both" or ec.spec_k):
+            raise ValueError(
+                f"{model.__name__} keeps per-slot window state: disaggregated "
+                "roles and speculative decoding are unsupported"
+            )
         if ec.role != "both" and not self.paged:
             # The handoff ships pool pages; the dense slot cache has no
             # page-granular export.
@@ -552,7 +597,8 @@ class Engine:
             # land there (their block-table rows are zero), never in a live
             # page. The allocator hands out ids 1..n_pages.
             pool = model.init_paged_cache(
-                cfg, self.n_pages + 1, bs, dtype=cache_dtype
+                cfg, self.n_pages + 1, bs, dtype=cache_dtype,
+                **({"slots": B} if self.slot_state else {}),
             )
             if mesh is not None:
                 pool = shard_tree(
@@ -564,8 +610,13 @@ class Engine:
             self.cache = pool
             self.block_table = np.zeros((B, self.max_pages), np.int32)
             self.alloc = PageAllocator(self.n_pages, first_page=1)
+            # Shared pages cannot hand a window layer its rows at the
+            # prefix boundary: for such a family the registry is off, and
+            # stats["prefix_reuse_refused"] counts the admissions it would
+            # have looked up.
             self.prefix = (
-                PrefixRegistry(self.alloc) if ec.prefix_cache else None
+                PrefixRegistry(self.alloc)
+                if ec.prefix_cache and not self.slot_state else None
             )
             self.slot_pages = SlotPages(B)
         elif mesh is not None:
@@ -609,6 +660,7 @@ class Engine:
         # Requests to re-admit before the queue: preempted slots (front)
         # and admission backpressure (pool dry at prefill time).
         self._resume: List[Request] = []
+        self._chunk_stats: List[Any] = []
         self.stats: Dict[str, int] = {
             "prefill_tokens": 0,
             "prefix_hit_tokens": 0,
@@ -627,6 +679,21 @@ class Engine:
             "kv_live_pages_sum": 0,
             "kv_pool_pages_sum": 0,
         }
+        if self.slot_state:
+            # What a slot-state family's forward counts (models/
+            # exaone_moe.py::forward), summed over decode steps and
+            # prefill chunks as they are drained; window rows are counted
+            # on the host, per decoding iteration like the pages above.
+            self.stats.update({
+                "moe_pairs_held": 0,
+                "moe_pairs_all": 0,
+                "moe_decode_steps": 0,
+                "moe_decode_pairs_held": 0,
+                "moe_decode_expert_pairs_max_sum": 0,
+                "window_rows_live_sum": 0,
+                "window_rows_cap_sum": 0,
+                "prefix_reuse_refused": 0,
+            })
 
         # Speculative decoding state. The draft pool shares the target's
         # block tables and page allocation: identical page ids index both
@@ -829,11 +896,13 @@ class Engine:
     @partial(jax.jit, static_argnums=(0, 1), donate_argnums=(3,))
     def _chunk_prefill_jit(model, cfg, params, slot_cache, tokens, offset,
                            true_len, block_table=None, lora=None,
-                           adapter_ids=None):
+                           adapter_ids=None, slot=None):
         """One chunk of a long prefill: tokens [1, C] (right-padded) written
         at absolute positions offset..offset+C-1 — into a single-slot dense
-        cache, or through a block-table row [1, M] into the paged pool.
-        Returns (logits of the last real token, updated cache)."""
+        cache, or through a block-table row [1, M] into the paged pool
+        (`slot`: the decode slot, for a family with per-slot state).
+        Returns (logits of the last real token, updated cache, the model's
+        counters for the chunk or None)."""
         c = tokens.shape[1]
         positions = offset + jnp.arange(c, dtype=jnp.int32)[None, :]
         # Padded tail positions all clamp onto the single slot one past the
@@ -844,10 +913,22 @@ class Engine:
         positions = jnp.minimum(positions, offset + true_len)
         kw = {} if block_table is None else {"block_table": block_table}
         kw.update(Engine._lora_kw(lora, adapter_ids))
+        if slot is not None:
+            kw["slots"] = jnp.reshape(slot, (1,)).astype(jnp.int32)
+            kw["valid"] = jnp.arange(c)[None, :] < true_len
         logits, slot_cache = model.forward(
             params, tokens, cfg, positions=positions, cache=slot_cache, **kw
         )
-        return logits[0, true_len - 1], slot_cache
+        stats = Engine._pop_step_stats(model, slot_cache)
+        return logits[0, true_len - 1], slot_cache, stats
+
+    @staticmethod
+    def _pop_step_stats(model, cache):
+        """A slot-state family's forward leaves its counters in the cache
+        dict: its `step_counters` takes them out (inside the jit) before
+        the cache is carried on. None for a family that counts nothing."""
+        take = getattr(model, "step_counters", None)
+        return take(cache) if take else None
 
     def _build_propose(self, k: int):
         model, cfg = self.model, self.draft_cfg
@@ -1073,7 +1154,8 @@ class Engine:
 
         @partial(jax.jit, donate_argnums=(1,))
         def decode(params, cache, block_table, tokens, positions, temps,
-                   top_ps, key_data, lora=None, adapter_ids=None):
+                   top_ps, key_data, lora=None, adapter_ids=None,
+                   active=None):
             logits, cache = model.forward(
                 params,
                 tokens[:, None],
@@ -1082,7 +1164,10 @@ class Engine:
                 cache=cache,
                 **({"block_table": block_table} if paged else {}),
                 **Engine._lora_kw(lora, adapter_ids),
+                # row i is decode slot i; an idle slot's token is filler
+                **({} if active is None else {"valid": active[:, None]}),
             )
+            stats = Engine._pop_step_stats(model, cache)
             with jax.named_scope(scopes.SAMPLE):
                 key, subkey = jax.random.split(
                     jax.random.wrap_key_data(key_data)
@@ -1093,7 +1178,9 @@ class Engine:
                 next_tokens, kd = self._replicated(
                     next_tokens, jax.random.key_data(key)
                 )
-            return next_tokens, cache, kd
+            if stats is None:
+                return next_tokens, cache, kd
+            return next_tokens, cache, kd, self._replicated(stats)
 
         return decode
 
@@ -1853,6 +1940,8 @@ class Engine:
         # Reuse at most the pages strictly before the last prompt token:
         # the last token must run through the model for its logits.
         max_shared = (true_len - 1) // bs
+        if self.slot_state and self.ec.prefix_cache and max_shared:
+            self.stats["prefix_reuse_refused"] += 1
         shared = (
             self.prefix.match(entries[:max_shared])
             if self.prefix is not None
@@ -1884,6 +1973,7 @@ class Engine:
         last_logits, self.cache = self._run_chunks(
             req.id, self._chunk_fn, self.params, self.cache, prompt, reuse,
             bt_row, lora=lora, adapter_ids=ids1,
+            slot=slot if self.slot_state else None,
         )
         self.stats["prefill_tokens"] += true_len - reuse
         self.stats["prefix_hit_tokens"] += reuse
@@ -1923,12 +2013,15 @@ class Engine:
         return True
 
     def _run_chunks(self, rid, fn, params, cache, prompt, start: int,
-                    bt_row, lora=None, adapter_ids=None):
+                    bt_row, lora=None, adapter_ids=None, slot=None):
         """Chunked prefill of request `rid`'s prompt[start:] through a
         block-table row; returns (last real token's logits, updated
-        cache). One engine.prefill phase per chunk dispatch."""
+        cache). One engine.prefill phase per chunk dispatch. The chunks'
+        counters (slot-state families) wait in _chunk_stats for the
+        admission's one host read (_finalize_admit)."""
         chunk = self.ec.max_prefill_len
         offset, last_logits = start, None
+        slot_kw = {} if slot is None else {"slot": np.int32(slot)}
         while offset < len(prompt):
             padded, clen = _pad_to_bucket(
                 prompt[offset : offset + chunk], chunk
@@ -1937,10 +2030,12 @@ class Engine:
                 "prefill", request_id=rid, bucket=padded.shape[1],
                 chunk=(offset - start) // chunk, tokens=clen,
             ) as ph:
-                last_logits, cache = fn(
+                last_logits, cache, stats = fn(
                     params, cache, padded, offset, clen, block_table=bt_row,
-                    lora=lora, adapter_ids=adapter_ids,
+                    lora=lora, adapter_ids=adapter_ids, **slot_kw,
                 )
+            if stats is not None:
+                self._chunk_stats.append(stats)
             offset += clen
             # Simulated device-step latency applies to prefill chunks
             # too: on a real accelerator every chunk occupies the device,
@@ -1970,6 +2065,10 @@ class Engine:
             with self.timeline.phase("wait.first_token"):
                 self.key = np.asarray(key_out)  # sublint: allow[hostsync]: first-token sample + key readback, once per admission (the "sample" phase)
                 first_id = int(first[0])
+            # the chunks have run: their counters are ready, no new wait
+            for stats in self._chunk_stats:
+                self._fold_step_stats(stats, decode=False)
+            self._chunk_stats.clear()
 
         if self.ec.role == "prefill":
             self._handoff_request(req, slot, first_id, true_len)
@@ -1996,6 +2095,26 @@ class Engine:
         self.temps[slot] = req.temperature
         self.top_ps[slot] = req.top_p
         self._emit(slot, first_id)
+
+    def _fold_step_stats(self, stats, decode: bool) -> None:
+        """Add one program's counters (models/exaone_moe.py::forward) to
+        stats and the registry. Called where the program's output is read
+        anyway, so it never waits for the device."""
+        host = {k: int(v) for k, v in jax.device_get(stats).items()}  # sublint: allow[hostsync]: read with the step's tokens (drain) or after the first-token read (admission); the program has finished
+        held, every = host["moe_pairs_held"], host["moe_pairs_all"]
+        self.stats["moe_pairs_held"] += held
+        self.stats["moe_pairs_all"] += every
+        METRICS.inc("substratus_serve_moe_pairs_total", {"held": "true"},
+                    by=held)
+        METRICS.inc("substratus_serve_moe_pairs_total", {"held": "false"},
+                    by=every - held)
+        if decode:
+            self.stats["moe_decode_steps"] += 1
+            self.stats["moe_decode_pairs_held"] += held
+            self.stats["moe_decode_expert_pairs_max_sum"] += host[
+                "moe_expert_pairs_max"]
+            METRICS.observe("substratus_serve_moe_expert_pairs_max",
+                            host["moe_expert_pairs_max"])
 
     # --- paged pool management -------------------------------------------
 
@@ -2116,7 +2235,7 @@ class Engine:
             tok_in = self._merge_tokens(
                 self._dev_tokens, self.tokens, self._token_fresh
             )
-        next_tokens, self.cache, key_out = self._decode_fn(
+        next_tokens, self.cache, key_out, *stats = self._decode_fn(
             self.params,
             self.cache,
             self.block_table if self.paged else None,
@@ -2127,6 +2246,7 @@ class Engine:
             self.key,
             lora,
             adapter_ids,
+            *((self.active.copy(),) if self.slot_state else ()),
         )
         if self.overlap:
             # The RNG key stays device-resident between steps: reading
@@ -2156,6 +2276,7 @@ class Engine:
             ],
             pos_next=self.host_positions.copy(),
             t_dispatch=time.perf_counter(),
+            stats=stats[0] if stats else None,
         )
 
     def _drain(self, step: _InFlightStep, wait: str = "drain") -> None:
@@ -2170,6 +2291,8 @@ class Engine:
         with self.timeline.phase("wait." + wait):
             host_tokens = np.asarray(step.tokens)  # sublint: allow[hostsync]: THE one host read per decode step — deferred to drain() so under overlap it lands after the NEXT dispatch, hiding every emit under device compute
         t_drained = time.perf_counter()
+        if step.stats is not None:
+            self._fold_step_stats(step.stats, decode=True)
         with self.timeline.phase("emit"):
             for slot, req in step.slots:
                 if self.slot_req[slot] is not req:
@@ -2760,6 +2883,15 @@ class Engine:
                 (self.alloc.used_pages - live) / self.n_pages,
                 {"state": "cached"},
             )
+        if self.slot_state:
+            # rows of a window layer's ring that hold a live sequence's
+            # history: min(context, window) a decoding slot
+            w = self.model.slot_rows(self.cfg)
+            rows = int(np.minimum(self.host_positions[self.active], w).sum())  # sublint: allow[hostsync]: host numpy mirrors, no device read
+            self.stats["window_rows_live_sum"] += rows
+            self.stats["window_rows_cap_sum"] += self.ec.max_batch * w
+            METRICS.observe("substratus_serve_window_rows_live_ratio",
+                            rows / (self.ec.max_batch * w))
         if not self._first_decode_done:
             # The first decode iteration is dominated by the executable
             # compile; record it separately so the steady-state decode

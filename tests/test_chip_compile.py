@@ -10,7 +10,8 @@ message: the day one compiles, the test fails until the mark goes.
 
 The serving programs that carry the paged KV pool are compiled the same way:
 the optimized HLO of the engine's decode and chunk programs may not copy,
-slice or re-stack the pool (models/llama.py::forward carries it in place).
+slice or re-stack the pool (models/llama.py::forward carries it in place),
+nor, for a family with window layers, their rings (models/exaone_moe.py).
 """
 import math
 import os
@@ -251,3 +252,94 @@ def test_serving_programs_leave_the_kv_pool_in_place(
         assert _pool_moving_ops(compiled.as_text(), sizes) == [], name
         temp = compiled.memory_analysis().temp_size_in_bytes
         assert temp < limit / tensor, (name, temp, limit)
+
+
+# The reason-mixed cell's engine (benchmarks/traffic/reason-mixed.json):
+# K-EXAONE's first 12 layers, 16 of 128 experts, an eighth of the vocabulary.
+_X_POOL_PAGES, _X_B, _X_S = 10240, 64, 4096
+
+
+def test_exaone_programs_leave_pool_and_rings_in_place(v5e):
+    """The same rule for the family whose cache holds two kinds of history:
+    decode and the 512-token chunk move neither the global layers' pool nor
+    the window layers' rings, whole or a layer of them; the pool is 12 KB a
+    token (3 global layers of 12), not 48; the chunk groups its tokens by
+    expert (a loop over blocks, no product with every held expert)."""
+    from jax.sharding import SingleDeviceSharding
+
+    from substratus_tpu.models import exaone_moe
+    from substratus_tpu.ops.quant import quantize_params
+    from substratus_tpu.serve.engine import Engine, EngineConfig
+
+    cfg = exaone_moe.ExaoneMoeConfig(
+        vocab_size=19200, n_layers=12, held_experts=(0, 16))
+    eng = Engine(cfg, None, EngineConfig(
+        max_batch=_X_B, max_seq_len=_X_S, max_prefill_len=_CHUNK,
+        page_size=_PAGE, kv_pool_tokens=1,
+    ))
+    assert eng.slot_state and eng.prefix is None
+    rep = SingleDeviceSharding(v5e[0])
+
+    def placed(tree):
+        return jax.tree.map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=rep),
+            tree)
+
+    def arr(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=rep)
+
+    params = placed(jax.eval_shape(
+        lambda key: quantize_params(
+            exaone_moe.init_params(cfg, key),
+            exaone_moe.quant_contracting(cfg)),
+        jax.random.key(0)))
+    cache = placed(jax.eval_shape(
+        lambda: exaone_moe.init_paged_cache(
+            cfg, _X_POOL_PAGES + 1, _PAGE, slots=_X_B)))
+    tokens = (_X_POOL_PAGES + 1) * _PAGE
+    pool_bytes = sum(cache[n].size * cache[n].dtype.itemsize for n in "kv")
+    assert pool_bytes == tokens * 12 * 1024
+    assert cache["wk"].shape == (9, _X_B, 128, 8, 128)
+    m = _X_S // _PAGE
+    programs = {
+        "decode": eng._decode_fn.lower(
+            params, cache, arr((_X_B, m)), arr((_X_B,)), arr((_X_B,)),
+            arr((_X_B,), jnp.float32), arr((_X_B,), jnp.float32),
+            arr(eng.key.shape, eng.key.dtype), None, None,
+            arr((_X_B,), jnp.bool_),
+        ),
+        "chunk": Engine._chunk_prefill_jit.lower(
+            exaone_moe, cfg, params, cache, arr((1, _CHUNK)), arr(()), arr(()),
+            arr((1, m)), None, None, arr(()),
+        ),
+    }
+    # Refused: any bfloat16 copy or slice the size of the pool, of a layer
+    # of it, or of the rings; and a slice the size of one layer's rings. A
+    # *copy* of that last size is the step's own read of the rings
+    # ([max_batch, W, KH, hd], by construction as large as a layer of them)
+    # laid out for the dot, as the pool's gathered context is: not refused.
+    # And no int8 weight is laid out anew: stored [D, heads, hd] or [D,
+    # heads * hd], the q, k and v stacks of every layer were copied in each
+    # program, contracted dim last (2.9 ms of a decode step: PERF.md
+    # section 6, PR 27); they are stored that way now.
+    whole = {cache[n].size for n in ("k", "wk")} | {
+        cache["k"].size // cache["k"].shape[0]}
+    ring_layer = {cache["wk"].size // cache["wk"].shape[0]}
+    limit = sum(s.dtype.itemsize * s.size for s in cache.values()) / 2
+    for name, lowered in programs.items():
+        compiled = lowered.compile()
+        hlo = compiled.as_text()
+        bf16 = "\n".join(l for l in hlo.splitlines() if "= bf16[" in l)
+        assert _pool_moving_ops(bf16, whole) == [], name
+        assert [op for op in _pool_moving_ops(bf16, ring_layer)
+                if "copy" not in op] == [], name
+        assert not re.search(r"= s8\[[\d,]+\]\S* copy\(", hlo), name
+        temp = compiled.memory_analysis().temp_size_in_bytes
+        assert temp < limit, (name, temp, limit)
+        for scope in ("kv.ring", "attn.window", "moe.shared", "moe.router",
+                      "moe.experts", "kv.gather", "attn.core"):
+            assert scope in hlo, (name, scope)
+        # the chunk multiplies pairs grouped by expert, one block of one
+        # expert's rows at a time; the decode step every held expert
+        grouped = "s8[1,1,6144,2048]" in hlo
+        assert grouped == (name == "chunk"), name

@@ -9,7 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from substratus_tpu.models import llama
+from substratus_tpu.models import exaone_moe, llama
 from substratus_tpu.ops import scopes
 from substratus_tpu.serve.engine import Engine, EngineConfig
 
@@ -26,14 +26,21 @@ CASES = {
     "moe-dense-cache": ("tiny-moe", "dense",
                         (DENSE_LLAMA - {scopes.MLP})
                         | {scopes.MOE_ROUTER, scopes.MOE_EXPERTS}),
+    # window layers beside global ones, a dense layer before sparse ones:
+    # every base name but none is missing, and the family's three
+    "exaone-moe-paged": ("tiny-exaone-moe", "paged",
+                         DENSE_LLAMA | {scopes.KV_GATHER, scopes.MOE_ROUTER,
+                                        scopes.MOE_EXPERTS}
+                         | set(scopes.EXTRA)),
 }
 OP_RE = re.compile(
     r"^\s*(?:ROOT )?%?[\w.\-]+ = \S+ ([\w\-]+)\(.*?op_name=\"([^\"]*)\"", re.M)
 
 
 def _engine(config: str, layout: str) -> Engine:
-    cfg = llama.CONFIGS[config].replace(dtype=jnp.float32)
-    params = llama.init_params(cfg, jax.random.key(0))
+    model = exaone_moe if config in exaone_moe.CONFIGS else llama
+    cfg = model.CONFIGS[config].replace(dtype=jnp.float32)
+    params = model.init_params(cfg, jax.random.key(0))
     return Engine(cfg, params, EngineConfig(
         max_batch=2, max_seq_len=64, max_prefill_len=16, kv_layout=layout))
 
@@ -44,20 +51,22 @@ def _compiled(e: Engine):
     bt = e.block_table if e.paged else None
     decode = e._decode_fn.lower(
         e.params, e.cache, bt, e.tokens, e.positions, e.temps, e.top_ps,
-        e.key, None, None).compile().as_text()
+        e.key, None, None, *((e.active,) if e.slot_state else ())
+    ).compile().as_text()
     if e.paged:
         cache, row = e.cache, e.block_table[:1]
     else:
         cache, row = e._extract_slot(e.cache, 0), None
     chunk = Engine._chunk_prefill_jit.lower(
         e.model, e.cfg, e.params, cache, np.zeros((1, 16), np.int32), 0, 16,
-        block_table=row).compile().as_text()
+        block_table=row, **({"slot": np.int32(0)} if e.slot_state else {})
+    ).compile().as_text()
     return decode, chunk
 
 
 def _scope_of(op_name: str):
     for part in reversed(op_name.split("/")):
-        if part in scopes.ALL:
+        if part in scopes.EVERY:
             return part
     return None
 
@@ -84,7 +93,7 @@ def test_regions_in_the_compiled_decode_and_chunk_programs(case):
         # scopes nest only as the table says: block regions inside
         # `layers`, the others beside it, never one block region in another
         for _, name in ops:
-            parts = [p for p in name.split("/") if p in scopes.ALL]
+            parts = [p for p in name.split("/") if p in scopes.EVERY]
             if not parts:
                 continue
             inner = parts[-1]
@@ -100,6 +109,10 @@ def test_vocabulary_is_closed_and_matches_the_benchmarks_copy():
 
     assert tuple(trace_scopes.SCOPES) == tuple(scopes.ALL)
     assert len(set(scopes.ALL)) == len(scopes.ALL) == 13
+    # what one family's block adds is listed in that family's file, and
+    # the benchmark's readers charge an op to any of them
+    assert len(set(scopes.EVERY)) == len(scopes.EVERY) == 16
+    assert set(scopes.EXTRA) <= trace_scopes.vocabulary()
 
 
 def test_a_region_name_is_metadata_and_changes_no_arithmetic(monkeypatch):

@@ -8,7 +8,7 @@ What this block has that models/llama.py's does not, and where each lives:
     whole period and scans over the periods; a layer's weights are indexed
     out of their stacks by layer number, never sliced off beforehand;
   * two kinds of history in one cache: global layers write the paged pool
-    (`k`, `v`: [Lg, P, bs, KH, hd], ops/kvcache.py::paged_update_and_read,
+    (`k`, `v`: [Lg, P, bs, KH, hd], ops/kvcache.py::paged_attention,
     as Llama), window layers a ring of `sliding_window` rows a decode slot
     (`wk`, `wv`: [Lw, slots, W, KH, hd], ops/kvcache.py::
     ring_read_and_update). The engine says which slot a batch row is
@@ -511,13 +511,10 @@ def _block(x, lp, mlp, kinds, idx, positions, cfg, cache, block_table, slots,
             attn = dot_product_attention(q, kk, vv, causal=True,
                                          q_positions=positions)
     else:
-        pool, k_ctx, v_ctx = kvcache.paged_update_and_read(
+        pool, attn = kvcache.paged_attention(
             {"k": cache["k"], "v": cache["v"]}, idx, block_table, positions,
-            kk, vv, dt)
+            q, kk, vv, dt)
         cache = {**cache, **pool}
-        with jax.named_scope(scopes.ATTN_CORE):
-            attn = dot_product_attention(q, k_ctx, v_ctx, causal=True,
-                                         q_positions=positions)
     with jax.named_scope(scopes.ATTN_OUT):
         flat = attn.reshape(attn.shape[:2] + (-1,))
         x = x + qeinsum("bsn,nd->bsd", flat, lp["wo"], dt)

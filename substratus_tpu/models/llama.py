@@ -509,16 +509,12 @@ def _block(
             attn = _self_attention(q, kk, vv, positions, cfg)
         kv_out = {"k": kk, "v": vv}
     elif block_table is not None:
-        from substratus_tpu.ops.kvcache import paged_update_and_read
+        from substratus_tpu.ops.kvcache import paged_attention
 
-        kv_out, k_cache, v_cache = paged_update_and_read(
-            layer_cache, layer, block_table, positions, kk, vv, dt
+        kv_out, attn = paged_attention(
+            layer_cache, layer, block_table, positions, q, kk, vv, dt,
+            kv_length,
         )
-        with jax.named_scope(scopes.ATTN_CORE):
-            attn = dot_product_attention(
-                q, k_cache, v_cache, causal=True, q_positions=positions,
-                kv_length=kv_length,
-            )
     else:
         from substratus_tpu.ops.decode_attention import update_cache_and_attend
 

@@ -3,8 +3,8 @@
 Shared by tests/test_chip_compile.py (each case AOT-compiled by the
 chip's compiler for a described, unattached v5e) and chip_smoke.py's
 kernel phase (each case compiled, run on the attached chip and compared
-with the XLA reference in ops/attention.py / ops/quant.py / Q4Tensor.
-dequant). A case the chip's compiler refuses carries the compiler's
+with the XLA reference in ops/attention.py / ops/kvcache.py / ops/quant.py /
+Q4Tensor.dequant). A case the chip's compiler refuses carries the compiler's
 message in `refused`: the compile test marks it strict-xfail and the
 smoke prints it as "not run".
 
@@ -239,6 +239,46 @@ def fused_decode(tag, b, h, kh, d, cache_len, int8,
     )
 
 
+# --- decode attention over the paged pool -----------------------------------
+
+
+def paged_decode(tag, b, max_seq, h, kh, d, pages, page=16,
+                 layers=2) -> KernelCase:
+    """One query a row against a stacked pool of `layers` x `pages` pages:
+    rows of every length from a full table down, the last row idle as the
+    engine leaves one (position 0, a table of the trash page); the pages
+    of a row lie scattered, and where the pool is smaller than the tables
+    rows share them."""
+    from substratus_tpu.ops.kvcache import paged_read
+    from substratus_tpu.ops.paged_attention import paged_decode_attention
+
+    m = max_seq // page
+
+    def make_args(key):
+        kq, kk, kv, kt = jax.random.split(key, 4)
+        pool = (layers, pages, page, kh, d)
+        own = jax.random.permutation(kt, jnp.arange(1, pages, dtype=jnp.int32))
+        table = own[jnp.arange(b * m) % (pages - 1)].reshape(b, m)
+        positions = (max_seq - 1) * (b - 1 - jnp.arange(b)) // max(b - 1, 1)
+        table = table.at[-1].set(0)
+        return (
+            _normal(kq, (b, h, d)), _normal(kk, pool), _normal(kv, pool),
+            jnp.int32(layers - 1), table, positions.astype(jnp.int32),
+        )
+
+    def reference(q, k, v, layer, table, positions):
+        k_ctx, v_ctx = paged_read({"k": k, "v": v}, layer, table, q.dtype)
+        return dot_product_attention(
+            q[:, None], k_ctx, v_ctx, causal=True,
+            q_positions=positions[:, None],
+        )[:, 0]
+
+    return KernelCase(
+        f"paged_decode/{tag}/b{b}-s{max_seq}-h{h}", make_args,
+        paged_decode_attention, reference, tol=2e-2,
+    )
+
+
 # --- int4 unpack-dequant matmul ---------------------------------------------
 
 
@@ -320,6 +360,11 @@ def chip_cases() -> List[KernelCase]:
             cases.append(q4_matmul(f"{tag}-down", m, hidden, dim))
         cases.append(q4_matmul(f"{tag}-wk", 8, dim, kv_dim))
         cases.append(q4_matmul(f"{tag}-lm_head", 8, dim, 32000))
+    # The decode step of the benchmark's three cells (benchmarks/traffic/):
+    # max_batch x max_seq_len, query / KV heads of 128, the cell's pool.
+    cases.append(paged_decode("mistral-chat", 32, 2048, 32, 8, 128, 1793))
+    cases.append(paged_decode("mistral-longdoc", 5, 8192, 32, 8, 128, 1921))
+    cases.append(paged_decode("k-exaone", 64, 4096, 64, 8, 128, 10241))
     return cases
 
 
@@ -336,6 +381,7 @@ def rehearsal_cases() -> List[KernelCase]:
         fused_decode("small", 2, cache_len=128, int8=False, **w),
         fused_decode("small", 2, cache_len=128, int8=True, **w),
         q4_matmul("small", 8, 256, 128),
+        paged_decode("small", 3, 128, h=4, kh=2, d=64, pages=17),
     ]
 
 

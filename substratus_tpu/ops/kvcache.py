@@ -17,17 +17,33 @@ The pool is one buffer from a jit's donated argument to its result: the
 model's layer scan carries the whole stack (models/llama.py::forward) and
 layer l addresses its rows at offset l * pages * page_size of the flat token
 axis [layers * pages * page_size]. New entries are scattered there in place,
-one token row each; each sequence's context is gathered from there one table
-entry at a time, as a slice of page_size contiguous rows (row by row out of
-HBM the same gather measured 1.8x slower, and a [pages, page_size, ...] view
-of the pool made the compiler re-tile a kv_heads shard of it in every
-layer: PERF.md §6, PR 25), into a slot-local [B, max_pages*page_size] view,
-so the framework's standard masked attention applies unchanged: gathered
-index j IS the token's absolute position in its sequence, hence causal
-masking (k_pos <= q_pos) hides unwritten / foreign pages. No op slices a
-layer out of the stack or writes one back, so a step needs no second pool
-and a kernel that reads live pages through the block table can take the
-stack as its operand.
+one token row each (`kv.write`). No op slices a layer out of the stack or
+writes one back, so a step needs no second pool.
+
+`paged_attention` is the one entry point of both model families: it writes
+the new rows and attends from q to each row's context, and reads off its
+inputs which of two realisations of that attention runs (no flag, option or
+model name decides):
+
+* **One query token a row over a bfloat16 pool, lowered for a TPU** (a decode
+  step): ops/paged_attention.py takes the stack as its HBM operand and
+  reads row b's live pages block_table[b, 0 .. positions[b] // page_size]
+  in place. Nothing of size max_batch x max_seq_len exists; a row costs what
+  it holds, an idle row (which the engine keeps at position 0) one page.
+  Under a mesh that shards the pool over kv_heads and nothing else, each
+  device runs the kernel on its own heads (`shard_map`, no collective).
+* **Everything else** (a prefill chunk or a speculative round, S > 1; an
+  int8 pool; a float32 pool; a pool sharded any other way; every platform
+  but the TPU): each sequence's context is gathered one table entry at a
+  time, as a slice of page_size contiguous rows (`kv.gather`; row by row out
+  of HBM the same gather measured 1.8x slower, and a [pages, page_size, ...]
+  view of the pool made the compiler re-tile a kv_heads shard of it in every
+  layer: PERF.md section 6, PR 25), into a slot-local [B, max_pages *
+  page_size] view, so the framework's standard masked attention applies
+  unchanged: gathered index j IS the token's absolute position in its
+  sequence, hence causal masking (k_pos <= q_pos) hides unwritten / foreign
+  pages. This is also the reference the kernel is tested against
+  (tests/test_paged_attention.py, ops/kernel_cases.py).
 
 A layer that attends to a window of W positions keeps no pages: each decode
 slot owns a ring of W rows a window layer (`ring_read_and_update`), beside
@@ -39,37 +55,26 @@ from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from substratus_tpu.ops import scopes
+from substratus_tpu.ops.attention import dot_product_attention
+from substratus_tpu.ops.paged_attention import paged_decode_attention
 from substratus_tpu.ops.quant import dequantize_kv, quantize_kv
+from substratus_tpu.parallel.sharding import SERVE_RULES
 
 
-def paged_update_and_read(
-    pool: Dict[str, jnp.ndarray],  # the stacked pool, [L, P, bs, ...]
-    layer: jnp.ndarray,  # scalar int32: the layer whose pages are touched
-    block_table: jnp.ndarray,  # [B, M] int32 page ids
-    positions: jnp.ndarray,  # [B, S] absolute (slot-local) positions
-    k_new: jnp.ndarray,  # [B, S, KH, hd]
-    v_new: jnp.ndarray,
-    dtype,
-) -> Tuple[Dict[str, jnp.ndarray], jnp.ndarray, jnp.ndarray]:
-    """Write new entries at `positions` of `layer`, then gather that layer's
-    full slot-local context. Returns (updated pool, k_ctx, v_ctx
-    [B, M*bs, KH, hd]); the pool is scattered into in place (the caller
-    carries and donates it), never sliced per layer.
+def _write(pool, layer, block_table, positions, k_new, v_new):
+    """The pool with the new entries scattered in place at `positions` of
+    `layer` (the caller carries and donates it; never sliced per layer).
 
     Duplicate positions (bucket-padding clamps) write in unspecified order —
     only ever at the one-past-the-prompt garbage slot, which the first
     decode step overwrites before attending (engine contract).
     """
     n_layers, pages, bs = pool["k"].shape[:3]
-    b, m = block_table.shape
+    m = block_table.shape[1]
     first = layer.astype(block_table.dtype) * pages  # the layer's page 0
-
-    def rows(a):  # [L, P, bs, ...] -> [L * P * bs, ...], a bitcast
-        return a.reshape((n_layers * pages * bs,) + a.shape[3:])
-
-    quantized = "k_scale" in pool
     out: Dict[str, jnp.ndarray] = {}
     with jax.named_scope(scopes.KV_WRITE):
         # Writes past the block table's reach (speculative verify near the
@@ -82,7 +87,7 @@ def paged_update_and_read(
         )
         pid = jnp.where(oob, 0, pid)
         idx = (first + pid) * bs + positions % bs  # [B, S] flat token index
-        if quantized:
+        if "k_scale" in pool:
             kq, ks = quantize_kv(k_new)
             vq, vs = quantize_kv(v_new)
             new = {"k": kq, "v": vq, "k_scale": ks, "v_scale": vs}
@@ -91,23 +96,106 @@ def paged_update_and_read(
         for name, vals in new.items():
             a = pool[name]
             out[name] = (
-                rows(a).at[idx].set(vals.astype(a.dtype)).reshape(a.shape)
+                _rows(a).at[idx].set(vals.astype(a.dtype)).reshape(a.shape)
             )
+    return out
+
+
+def _rows(a):  # [L, P, bs, ...] -> [L * P * bs, ...], a bitcast
+    return a.reshape((-1,) + a.shape[3:])
+
+
+def paged_read(pool, layer, block_table, dtype):
+    """`layer`'s slot-local context of every row: k_ctx, v_ctx
+    [B, M * bs, KH, hd], bs contiguous rows a table entry."""
+    pages, bs = pool["k"].shape[1:3]
+    b, m = block_table.shape
+    first = layer.astype(block_table.dtype) * pages
     with jax.named_scope(scopes.KV_GATHER):
         starts = ((first + block_table) * bs).reshape(b * m)
 
-        def read(a):  # this layer's [B, M * bs, ...]: bs rows a table entry
+        def read(a):
             ctx = jax.vmap(
-                lambda start: jax.lax.dynamic_slice_in_dim(rows(a), start, bs)
+                lambda start: jax.lax.dynamic_slice_in_dim(_rows(a), start, bs)
             )(starts)
             return ctx.reshape((b, m * bs) + a.shape[3:])
 
-        if quantized:
-            k_ctx = dequantize_kv(read(out["k"]), read(out["k_scale"]), dtype)
-            v_ctx = dequantize_kv(read(out["v"]), read(out["v_scale"]), dtype)
-        else:
-            k_ctx, v_ctx = read(out["k"]), read(out["v"])
-    return out, k_ctx, v_ctx
+        if "k_scale" in pool:
+            return (
+                dequantize_kv(read(pool["k"]), read(pool["k_scale"]), dtype),
+                dequantize_kv(read(pool["v"]), read(pool["v_scale"]), dtype),
+            )
+        return read(pool["k"]), read(pool["v"])
+
+
+def paged_attention(
+    pool: Dict[str, jnp.ndarray],  # the stacked pool, [L, P, bs, ...]
+    layer: jnp.ndarray,  # scalar int32: the layer whose pages are touched
+    block_table: jnp.ndarray,  # [B, M] int32 page ids
+    positions: jnp.ndarray,  # [B, S] absolute (slot-local) positions
+    q: jnp.ndarray,  # [B, S, H, hd]
+    k_new: jnp.ndarray,  # [B, S, KH, hd]
+    v_new: jnp.ndarray,
+    dtype,
+    kv_length: Optional[jnp.ndarray] = None,  # [B] valid cache prefix
+) -> Tuple[Dict[str, jnp.ndarray], jnp.ndarray]:
+    """Write the new entries at `positions` of `layer`, then attend from q
+    to each row's context 0..position. Returns (updated pool, attn
+    [B, S, H, hd]).
+
+    Which of two realisations of that attention runs is read off the
+    inputs. One query token a row over a bfloat16 pool, lowered for a TPU:
+    ops/paged_attention.py reads each row's live pages in place. Everything
+    else (a chunk or a speculative round, an int8 pool, a pool sharded over
+    several devices, any other platform): the context of every table
+    position is gathered and ops/attention.py::dot_product_attention runs
+    over it, which is also what the kernel is tested against."""
+    out = _write(pool, layer, block_table, positions, k_new, v_new)
+
+    def gathered():
+        k_ctx, v_ctx = paged_read(out, layer, block_table, dtype)
+        with jax.named_scope(scopes.ATTN_CORE):
+            return dot_product_attention(
+                q, k_ctx, v_ctx, causal=True, q_positions=positions,
+                kv_length=kv_length,
+            )
+
+    kernel = _kernel_for(out["k"]) if (
+        q.shape[1] == 1 and out["k"].dtype == jnp.bfloat16
+        and kv_length is None
+    ) else None
+    if kernel is None:
+        return out, gathered()
+
+    def in_place():
+        with jax.named_scope(scopes.ATTN_CORE):
+            return kernel(
+                q[:, 0], out["k"], out["v"], layer, block_table,
+                positions[:, 0],
+            )[:, None]
+
+    return out, jax.lax.platform_dependent(tpu=in_place, default=gathered)
+
+
+def _kernel_for(k_pool):
+    """ops/paged_attention.py's kernel as this pool's placement lets it run:
+    as it is on one device; a shard of KV heads a device where the pool is
+    sharded over them and nothing else is sharded (each device's kernel
+    reads its own heads' share of every page: no collective); None for any
+    other placement, which no kernel here is written for."""
+    mesh = jax.typeof(k_pool).sharding.mesh
+    sharded = {name: n for name, n in mesh.shape.items() if n > 1}
+    if not sharded:
+        return paged_decode_attention
+    pool = SERVE_RULES.mesh_axes(paged_cache_logical_axes()["k"])
+    heads = pool[3]  # the mesh axis of kv_heads
+    if list(sharded) != [heads] or k_pool.shape[3] % sharded[heads]:
+        return None
+    return jax.shard_map(
+        paged_decode_attention, mesh=mesh,
+        in_specs=(P(None, heads), pool, pool, P(), P(), P()),
+        out_specs=P(None, heads), check_vma=False,
+    )
 
 
 def ring_read_and_update(

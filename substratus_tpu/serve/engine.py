@@ -678,6 +678,11 @@ class Engine:
             # ratio of the two deltas.
             "kv_live_pages_sum": 0,
             "kv_pool_pages_sum": 0,
+            # Same iterations: pages a decode step's attention needs
+            # (positions // page_size + 1 a decoding slot, 1 an idle row)
+            # and max_batch x max_pages, the table it would gather whole.
+            "decode_kv_pages_read_sum": 0,
+            "decode_kv_pages_table_sum": 0,
         }
         if self.slot_state:
             # What a slot-state family's forward counts (models/
@@ -2259,15 +2264,20 @@ class Engine:
                 self.key = np.asarray(key_out)  # sublint: allow[hostsync]: overlap-off (lockstep) fallback only — the key rides host-side so every gang process feeds identical replicated inputs; the overlapped path above keeps it on device
         self._dev_tokens = next_tokens
         self._token_fresh[:] = False
-        # Clamp at the last cache row: active slots are released at the
-        # window before reaching it (_emit's hit_window), so the clamp only
-        # catches INACTIVE slots, whose positions otherwise drift past the
-        # cache every step they sit idle — with the fused decode kernel
-        # that drift would become out-of-bounds HBM writes (XLA scatter
-        # silently dropped OOB updates; the Pallas DMA does not).
+        # Only decoding slots advance. An idle slot sits at position 0
+        # (_release_slot) until an admission sets its position: its row
+        # still runs (static shapes) and writes the trash page, and
+        # attention that follows a row's own length (ops/
+        # paged_attention.py) then reads one page for it, not max_pages.
+        # The clamp at the last cache row is a guard only: active slots
+        # are released at the window before reaching it (_emit's
+        # hit_window), and past it the fused decode kernel's DMA would
+        # write out of bounds.
         last = self.ec.max_seq_len - 1
-        self.positions = np.minimum(self.positions + 1, last)
-        self.host_positions = np.minimum(self.host_positions + 1, last)
+        self.positions = np.minimum(self.positions + self.active, last)
+        self.host_positions = np.minimum(
+            self.host_positions + self.active, last
+        )
         return _InFlightStep(
             tokens=next_tokens,
             slots=[
@@ -2563,7 +2573,9 @@ class Engine:
             tok_in, pos_in = self._spec_advance(
                 p.choices, p.sampled, p.props,
                 p.k_eff.astype(np.int32), p.greedy, p.positions,
-                self.tokens, self.positions, self._token_fresh,
+                self.tokens, self.positions,
+                # idle rows too take the host's position: 0, not a drift
+                self._token_fresh | ~self.active,
             )
         if self.spec_draft:
             if width > 1:
@@ -2708,9 +2720,10 @@ class Engine:
                 self._emit(slot, tok, pos_next=pos0 + i)
                 if self.slot_req[slot] is not req:
                     break  # EOS/budget/window/cancel landed mid-run
-            npos = min(pos0 + len(emit_list), self.ec.max_seq_len - 1)
-            self.host_positions[slot] = npos
-            self.positions[slot] = npos
+            if self.slot_req[slot] is req:  # a released slot stays at 0
+                npos = min(pos0 + len(emit_list), self.ec.max_seq_len - 1)
+                self.host_positions[slot] = npos
+                self.positions[slot] = npos
 
     def _release_slot(self, slot: int) -> None:
         self.active[slot] = False
@@ -2722,6 +2735,10 @@ class Engine:
         # Idle rows gather the identity adapter: their decode writes
         # keep happening (static shapes) and must stay adapter-free.
         self.adapter_ids[slot] = 0
+        # An idle row holds nothing: position 0 (_dispatch advances
+        # decoding slots only; an admission sets the position anew).
+        self.positions[slot] = 0
+        self.host_positions[slot] = 0
         if self.paged:
             self.slot_pages.release(slot, self.alloc)
             # Point the idle slot back at the trash page; its decode writes
@@ -2874,6 +2891,12 @@ class Engine:
             live = self.slot_pages.live_pages
             self.stats["kv_live_pages_sum"] += live
             self.stats["kv_pool_pages_sum"] += self.n_pages
+            # Pages this step's attention has to read (a decoding slot's
+            # context, one page for an idle row) against the table
+            # positions a gather over every entry reads.
+            need = np.where(self.active, self.positions // self.page_size, 0)
+            self.stats["decode_kv_pages_read_sum"] += int(need.sum()) + need.size  # sublint: allow[hostsync]: host numpy mirrors, no device read
+            self.stats["decode_kv_pages_table_sum"] += need.size * self.max_pages
             METRICS.observe(
                 "substratus_serve_kv_page_utilization_ratio",
                 live / self.n_pages, {"state": "live"},

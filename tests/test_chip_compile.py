@@ -11,7 +11,9 @@ message: the day one compiles, the test fails until the mark goes.
 The serving programs that carry the paged KV pool are compiled the same way:
 the optimized HLO of the engine's decode and chunk programs may not copy,
 slice or re-stack the pool (models/llama.py::forward carries it in place),
-nor, for a family with window layers, their rings (models/exaone_moe.py).
+nor, for a family with window layers, their rings (models/exaone_moe.py);
+and the decode program over a bfloat16 pool on one chip holds the
+paged-attention kernel and no gathered context (ops/kvcache.py).
 """
 import math
 import os
@@ -152,6 +154,21 @@ def _pool_moving_ops(hlo: str, sizes) -> list:
     return found
 
 
+def _reads_pages_in_place(hlo: str, context: int) -> bool:
+    """The decode program's attention is the paged-attention kernel
+    (ops/paged_attention.py) and nothing in the program has a result of
+    `context` = max_batch x max_seq_len x kv_heads x head_dim elements: the
+    gathered K and V are gone, not moved."""
+    sized = [
+        m.group(0)
+        for m in re.finditer(r"= \w+\[([\d,]+)\]\S* [\w-]+\(", hlo)
+        if math.prod(map(int, m.group(1).split(","))) == context
+    ]
+    kernel = re.search(
+        r'custom_call_target="tpu_custom_call".*paged_decode_attention', hlo)
+    return bool(kernel) and not sized
+
+
 @pytest.mark.parametrize(
     "kv_cache_dtype,tensor",
     [("model", 1), ("int8", 1), ("model", 4)],
@@ -247,11 +264,20 @@ def test_serving_programs_leave_the_kv_pool_in_place(
     limit = sum(s.dtype.itemsize * s.size for s in pool.values()) / 2
     if quantized:
         limit += 2 * 4 * _B * _S * cfg.n_kv_heads * cfg.head_size
+    context = _B * _S * cfg.n_kv_heads * cfg.head_size
     for name, lowered in programs.items():
         compiled = lowered.compile()
-        assert _pool_moving_ops(compiled.as_text(), sizes) == [], name
+        hlo = compiled.as_text()
+        assert _pool_moving_ops(hlo, sizes) == [], name
         temp = compiled.memory_analysis().temp_size_in_bytes
         assert temp < limit / tensor, (name, temp, limit)
+        # One token a row over a bfloat16 pool reads live pages in place
+        # (under the `tensor` mesh each chip those of its own KV heads); a
+        # chunk and an int8 pool gather every table position
+        # (ops/kvcache.py).
+        in_place = name == "decode" and not quantized
+        assert _reads_pages_in_place(hlo, context // tensor) == in_place, name
+        assert ("kv.gather" in hlo) != in_place, name
 
 
 # The reason-mixed cell's engine (benchmarks/traffic/reason-mixed.json):
@@ -337,8 +363,14 @@ def test_exaone_programs_leave_pool_and_rings_in_place(v5e):
         temp = compiled.memory_analysis().temp_size_in_bytes
         assert temp < limit, (name, temp, limit)
         for scope in ("kv.ring", "attn.window", "moe.shared", "moe.router",
-                      "moe.experts", "kv.gather", "attn.core"):
+                      "moe.experts", "attn.core"):
             assert scope in hlo, (name, scope)
+        # the step's global layers read live pages in place: no gather, no
+        # K or V of max_batch x max_seq_len; the chunk gathers
+        in_place = _reads_pages_in_place(
+            hlo, _X_B * _X_S * cfg.n_kv_heads * cfg.head_size)
+        assert in_place == (name == "decode"), name
+        assert ("kv.gather" in hlo) == (name == "chunk"), name
         # the chunk multiplies pairs grouped by expert, one block of one
         # expert's rows at a time; the decode step every held expert
         grouped = "s8[1,1,6144,2048]" in hlo

@@ -175,6 +175,52 @@ def test_live_pages_are_counted_once_and_cached_pages_apart(traced_engine):
     assert eng.slot_pages.live_pages == 0 and eng.alloc.used_pages > 0
 
 
+@pytest.mark.parametrize("overlap", [False, True], ids=["lockstep", "overlap"])
+def test_a_step_counts_the_pages_it_needs_against_the_table(overlap):
+    """One long request beside an idle slot: a decoding iteration needs
+    position // page_size + 1 pages of the request's row and one of the
+    idle row's, against a table of max_batch x max_pages; the idle slot
+    sits at position 0 before, during and after."""
+    from benchmarks.layer_metrics import decode_kv_read_share
+
+    cfg = llama.CONFIGS["tiny"].replace(dtype=jnp.float32)
+    params = llama.init_params(cfg, jax.random.key(0))
+    page, prompt, new = 4, 29, 11
+    eng = Engine(cfg, params, EngineConfig(
+        max_batch=2, max_seq_len=64, max_prefill_len=16, page_size=page,
+        overlap=overlap))
+    table = 2 * (64 // page)
+    eng.start()
+    try:
+        eng.generate(list(range(1, prompt + 1)), max_tokens=new,
+                     temperature=0.0)
+    finally:
+        eng.stop()
+    st = eng.stats
+    steps, rest = divmod(st["decode_kv_pages_table_sum"], table)
+    # the first token comes from the prefill, the others one a step; under
+    # overlap one more step is in flight when the last token is read
+    assert rest == 0 and steps in (new - 1, new - 1 + overlap)
+    want = sum((prompt + i) // page + 1 + 1 for i in range(steps))
+    assert st["decode_kv_pages_read_sum"] == want
+    run = {"counters": {"stats": st}, "rehearse": False}
+    share = decode_kv_read_share.read(run)
+    assert share == pytest.approx(100.0 * want / (steps * table))
+    assert 25 < share < 35  # 8-10 pages + 1 of 32
+    # a CPU rehearsal writes nothing under a `decode_` name
+    assert decode_kv_read_share.read(dict(run, rehearse=True)) is None
+    assert not eng.active.any()
+    assert (eng.positions == 0).all() and (eng.host_positions == 0).all()
+
+
+def test_the_read_share_of_a_program_without_the_counters_is_nothing():
+    from benchmarks.layer_metrics import decode_kv_read_share
+
+    for stats in ({}, {"kv_live_pages_sum": 5, "kv_pool_pages_sum": 10}):
+        run = {"counters": {"stats": stats}, "rehearse": False}
+        assert decode_kv_read_share.read(run) is None
+
+
 def test_slot_pages_count_a_shared_page_once():
     alloc = PageAllocator(8, first_page=1)
     sp = SlotPages(3)

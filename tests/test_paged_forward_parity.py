@@ -1,7 +1,7 @@
 """forward() with a paged cache against a plain per-layer reference.
 
 forward carries the stacked pool through its layer scan and
-ops/kvcache.py::paged_update_and_read addresses each layer's rows at an
+ops/kvcache.py::paged_attention addresses each layer's rows at an
 offset into the flat stack. The reference here does it the plain way, one
 layer at a time with nothing taken from ops/kvcache.py: slice the layer out
 of the pool, write each new row at (page, offset) with the block table read
@@ -16,18 +16,20 @@ from jax import lax
 
 from substratus_tpu.models import llama
 from substratus_tpu.ops import kvcache
+from substratus_tpu.ops.attention import dot_product_attention
 from substratus_tpu.ops.basics import rms_norm
 from substratus_tpu.ops.quant import dequantize_kv, qeinsum, quantize_kv
 
 PAGES, BS, M = 12, 4, 4  # pool pages (+ trash page 0), page size, table width
 
 
-def _layer_update_and_read(table, positions):
+def _layer_attention(table, positions):
     """The reference's cache op for one layer, closed over the host's copy of
     the block table and positions: takes _block's call in place of
-    paged_update_and_read, with that layer's own rows as `layer_cache`."""
+    paged_attention, with that layer's own rows as `layer_cache`."""
 
-    def update_and_read(layer_cache, layer, block_table, pos, k_new, v_new, dt):
+    def attention(layer_cache, layer, block_table, pos, q, k_new, v_new, dt,
+                  kv_length=None):
         assert layer is None  # the reference hands _block one layer, no index
         new = {"k": k_new, "v": v_new}
         if "k_scale" in layer_cache:
@@ -46,15 +48,15 @@ def _layer_update_and_read(table, positions):
             name: a[table].reshape((len(table), M * BS) + a.shape[2:])
             for name, a in out.items()
         }
+        k_ctx, v_ctx = ctx["k"], ctx["v"]
         if "k_scale" in out:
-            return (
-                out,
-                dequantize_kv(ctx["k"], ctx["k_scale"], dt),
-                dequantize_kv(ctx["v"], ctx["v_scale"], dt),
-            )
-        return out, ctx["k"], ctx["v"]
+            k_ctx = dequantize_kv(k_ctx, ctx["k_scale"], dt)
+            v_ctx = dequantize_kv(v_ctx, ctx["v_scale"], dt)
+        return out, dot_product_attention(
+            q, k_ctx, v_ctx, causal=True, q_positions=pos, kv_length=kv_length
+        )
 
-    return update_and_read
+    return attention
 
 
 def _reference_forward(params, tokens, cfg, positions, pool, table):
@@ -128,8 +130,7 @@ def test_paged_forward_matches_plain_per_layer_reference(
     )
 
     monkeypatch.setattr(
-        kvcache, "paged_update_and_read",
-        _layer_update_and_read(table, positions),
+        kvcache, "paged_attention", _layer_attention(table, positions),
     )
     want_logits, want = _reference_forward(
         params, tokens, cfg, positions, pool, table

@@ -171,7 +171,6 @@ def run_measurement(
     config: str = "llama2-7b",
     kv_dtype: str = "int8",
     quantize: str = "int8",
-    decode_impl: str = "xla",
 ) -> None:
     """Measure and print the JSON line; raises on failure."""
     import jax
@@ -189,11 +188,6 @@ def run_measurement(
     cfg = llama.CONFIGS[config]
     if quantize == "w8a8":
         cfg = cfg.replace(quant_activations=True)
-    if decode_impl != "xla":
-        # "fused" = flash-decode (ops/fused_decode.py: in-kernel cache
-        # write + dynamic-length history stream); "pallas" = the unfused
-        # Pallas attention kernel.
-        cfg = cfg.replace(decode_attn_impl=decode_impl)
     params = jax.block_until_ready(
         jax.jit(lambda k: random_quantized_params(cfg, k, quantize))(
             jax.random.key(0)
@@ -249,7 +243,6 @@ def run_measurement(
                 ),
                 "batch": batch,
                 "cache_len": cache_len,
-                "decode_impl": decode_impl,
                 "device": device,
             }
         )
@@ -272,15 +265,9 @@ def main() -> int:
         "--quantize", default="int8", choices=["int4", "int8", "w8a8"],
         help="weight quantization",
     )
-    ap.add_argument(
-        "--decode-impl", default="xla",
-        choices=["xla", "pallas", "fused"],
-        help="decode attention path (fused does not lower on a TPU: "
-             "ops/fused_decode.py)",
-    )
     a = ap.parse_args()
     run_measurement(a.batch, a.cache_len, a.steps, a.config, a.kv_dtype,
-                    a.quantize, a.decode_impl)
+                    a.quantize)
     return 0
 
 

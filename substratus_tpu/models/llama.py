@@ -69,18 +69,6 @@ class LlamaConfig:
     #             requires an ambient mesh (jax.set_mesh) with a
     #             "sequence" axis
     attn_impl: str = "xla"
-    # Decode-with-cache attention implementation (ops/decode_attention.py):
-    #   "xla"    — scale-after-dot einsums (default)
-    #   "pallas" — int8-dequant Mosaic kernel over the dense slot cache
-    #   "fused"  — cache write + attention in one kernel (does not lower
-    #              on a v5e; ops/fused_decode.py)
-    decode_attn_impl: str = "xla"
-    # Multi-token cached attention (chunked prefill / speculative verify):
-    #   "xla"   — dequantize cache + reference attention (default)
-    #   "flash" — blockwise Pallas kernel (ops/flash_attention.py::
-    #             flash_cached_attention); opt-in via params.json: it
-    #             compiles and matches on the chip, its speed is not measured
-    chunk_attn_impl: str = "xla"
     # W8A8: dynamically quantize activations per token so quantized matmuls
     # run in the MXU's native s8xs8 mode (ops/quant.py::qeinsum_w8a8).
     # Opt-in; weight-only int8 (qeinsum) is the default quantized path.
@@ -519,9 +507,7 @@ def _block(
         from substratus_tpu.ops.decode_attention import update_cache_and_attend
 
         attn, kv_out = update_cache_and_attend(
-            layer_cache, q, kk, vv, positions,
-            kv_length=kv_length, impl=cfg.decode_attn_impl,
-            chunk_impl=cfg.chunk_attn_impl,
+            layer_cache, q, kk, vv, positions, kv_length=kv_length,
         )
 
     b, s = x.shape[:2]

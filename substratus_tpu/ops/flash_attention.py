@@ -457,247 +457,6 @@ def _flash_backward(
     return dq, dk.transpose(0, 2, 1, 3), dv.transpose(0, 2, 1, 3)
 
 
-def _cached_kernel(
-    q_ref,  # [1, bq, D] (input dtype)
-    k_ref,  # [1, bk, D] cache dtype (int8 when quantized)
-    v_ref,
-    limit_ref,  # [1, bq, 8] i32: last attendable cache index per q row
-    *rest,  # quantized: ks [1, 8, bk], vs [1, 8, bk], o_ref, 3 scratches;
-    #         else: o_ref, 3 scratches
-    scale: float,
-    block_q: int,
-    block_k: int,
-    num_k_blocks: int,
-    quantized: bool,
-):
-    if quantized:
-        ks_ref, vs_ref, o_ref = rest[:3]
-    else:
-        ks_ref = vs_ref = None
-        o_ref = rest[0]
-    m_scratch, l_scratch, acc_scratch = rest[-3:]
-    ik = pl.program_id(2)
-
-    @pl.when(ik == 0)
-    def _init():
-        m_scratch[:] = jnp.full_like(m_scratch, NEG_INF)
-        l_scratch[:] = jnp.zeros_like(l_scratch)
-        acc_scratch[:] = jnp.zeros_like(acc_scratch)
-
-    limit = limit_ref[0][:, :1]  # [bq, 1] i32
-    k_start = ik * block_k
-    # Dynamic block skip: the whole k block is dead when it starts past
-    # every row's limit (cache tail beyond the filled/causal frontier).
-    @pl.when(k_start <= jnp.max(limit))
-    def _compute():
-        q = q_ref[0]
-        dt = q.dtype
-        prec = _precision(dt)
-        k = k_ref[0].astype(dt)  # int8 cache converts in VMEM, not HBM
-        s = _dot(q, k, ((1,), (1,)), prec) * scale  # [bq, bk] f32
-        if quantized:
-            s = s * ks_ref[0][:1, :]  # k_scale commutes out of the dot
-        cols = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(cols <= limit, s, NEG_INF)
-
-        m_prev = m_scratch[:, :1]
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        # A row whose attend limit is negative (kv_length==0 padding slot)
-        # masks EVERY column, so m_new stays NEG_INF and exp(s - m_new)
-        # would be exp(0)=1 across the block; clamp those rows to 0 so l
-        # stays 0 and the finalize guard zeroes the output.
-        p = jnp.where(m_new <= NEG_INF / 2, 0.0, jnp.exp(s - m_new))
-        alpha = jnp.exp(m_prev - m_new)
-        l_scratch[:] = jnp.broadcast_to(
-            alpha * l_scratch[:, :1] + jnp.sum(p, axis=-1, keepdims=True),
-            l_scratch.shape,
-        )
-        if quantized:
-            p = p * vs_ref[0][:1, :]  # v_scale folds into the probabilities
-        acc_scratch[:] = acc_scratch[:] * alpha + _dot(
-            p.astype(dt), v_ref[0].astype(dt), ((1,), (0,)), prec
-        )
-        m_scratch[:] = jnp.broadcast_to(m_new, m_scratch.shape)
-
-    @pl.when(ik == num_k_blocks - 1)
-    def _finalize():
-        l = l_scratch[:, :1]
-        l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_scratch[:] / l).astype(o_ref.dtype)
-
-
-def flash_cached_attention(
-    q: jnp.ndarray,  # [B, Sq, H, D]
-    k: jnp.ndarray,  # [B, KH, Sk, D] slot-cache layout (int8 when scales given)
-    v: jnp.ndarray,
-    q_positions: jnp.ndarray,  # [B, Sq] absolute positions
-    k_scale: Optional[jnp.ndarray] = None,  # [B, KH, Sk] f32
-    v_scale: Optional[jnp.ndarray] = None,
-    kv_length: Optional[jnp.ndarray] = None,  # [B] valid-prefix mask
-    block_q: int = DEFAULT_BLOCK_Q,
-    block_k: int = DEFAULT_BLOCK_K,
-    interpret: bool = False,
-) -> jnp.ndarray:
-    """Blockwise attention of a multi-token chunk against the slot KV cache
-    (chunked prefill / speculative verify): flash online softmax, int8
-    cache operands converted block-at-a-time in VMEM (never a dequantized
-    HBM copy), per-row masking at min(position, kv_length-1). Inference
-    only (no vjp). Returns [B, Sq, H, D] in q.dtype.
-
-    Local per (batch, kv-head) shard like every attention kernel here —
-    the custom_partitioning route keeps it per-shard under GSPMD."""
-    quantized = k_scale is not None
-    has_len = kv_length is not None
-    f = _cached_sp(quantized, has_len, block_q, block_k, interpret)
-    args = [q, k, v, q_positions]
-    if quantized:
-        args += [k_scale, v_scale]
-    if has_len:
-        args.append(kv_length)
-    return f(*args)
-
-
-def _cached_sp(quantized, has_len, block_q, block_k, interpret):
-    key = ("cached", quantized, has_len, block_q, block_k, interpret)
-    if key in _SP_CACHE:
-        return _SP_CACHE[key]
-    from substratus_tpu.ops.kernel_partition import bh_partitioned
-
-    def impl(*args):
-        i = 4 + (2 if quantized else 0)
-        ks, vs = (args[4], args[5]) if quantized else (None, None)
-        kvl = args[i] if has_len else None
-        return _cached_impl(
-            args[0], args[1], args[2], args[3], ks, vs, kvl,
-            block_q, block_k, interpret,
-        )
-
-    arg_dims = [(0, 2), (0, 1), (0, 1), (0, None)]
-    rule_in = ["b s h d", "b k s2 d2", "b k s3 d3", "b s4"]
-    if quantized:
-        arg_dims += [(0, 1), (0, 1)]
-        rule_in += ["b k s5", "b k s6"]
-    if has_len:
-        arg_dims.append((0, None))
-        rule_in.append("b")
-    f = bh_partitioned(
-        impl,
-        arg_dims=arg_dims,
-        out_dims=[(0, 2)],
-        sharding_rule=", ".join(rule_in) + " -> b s h d",
-        # The CACHE is the committed operand in sharded serving (q is an
-        # activation whose sharding is propagation-dependent) — same ref
-        # choice as fused_decode/_pallas_sp.
-        ref=1,
-    )
-    _SP_CACHE[key] = f
-    return f
-
-
-def cached_block_k(block_k: int, sk: int, quantized: bool,
-                   interpret: bool = False) -> int:
-    """Cache block of the cached-chunk kernel. The int8 cache's scale
-    block [1, 8, block_k] puts the cache length on the lanes, where Mosaic
-    takes a multiple of 128 or the whole axis; halving down to a divisor
-    of a length that is no multiple of 128 lands below that. Raises
-    ValueError then, so Engine construction can refuse the length before
-    the compiler does (interpret mode has no such rule)."""
-    block_k = _fit_block(block_k, sk)
-    if quantized and not interpret and block_k % 128 and block_k != sk:
-        raise ValueError(
-            f"chunk_attn_impl=flash cannot tile an int8 cache of length "
-            f"{sk}: its largest power-of-two block is {block_k}, not a "
-            "multiple of 128; make max_seq_len a multiple of 128"
-        )
-    return block_k
-
-
-def _cached_impl(
-    q, k, v, q_positions, k_scale, v_scale, kv_length,
-    block_q, block_k, interpret,
-) -> jnp.ndarray:
-    b, sq, h, d = q.shape
-    kh, sk = k.shape[1], k.shape[2]
-    assert h % kh == 0
-    group = h // kh
-    quantized = k_scale is not None
-    block_q = _fit_block(block_q, sq)
-    block_k = cached_block_k(block_k, sk, quantized, interpret)
-    nq, nk = sq // block_q, sk // block_k
-
-    qt = q.transpose(0, 2, 1, 3).reshape(b * h, sq, d)
-    kt = k.reshape(b * kh, sk, d)
-    vt = v.reshape(b * kh, sk, d)
-    limit = q_positions
-    if kv_length is not None:
-        limit = jnp.minimum(limit, kv_length[:, None] - 1)
-    limit8 = jnp.broadcast_to(
-        limit.astype(jnp.int32)[:, :, None], (b, sq, 8)
-    )
-
-    def q_index(bh, iq, ik):
-        return (bh, iq, 0)
-
-    def kv_index(bh, iq, ik):
-        batch = bh // h
-        head = bh % h
-        return (batch * kh + head // group, ik, 0)
-
-    def limit_index(bh, iq, ik):
-        return (bh // h, iq, 0)
-
-    def scale_index(bh, iq, ik):
-        batch = bh // h
-        head = bh % h
-        return (batch * kh + head // group, 0, ik)
-
-    in_specs = [
-        pl.BlockSpec((1, block_q, d), q_index),
-        pl.BlockSpec((1, block_k, d), kv_index),
-        pl.BlockSpec((1, block_k, d), kv_index),
-        pl.BlockSpec((1, block_q, 8), limit_index),
-    ]
-    operands = [qt, kt, vt, limit8]
-    if quantized:
-        ks8 = jnp.broadcast_to(
-            k_scale[:, :, None, :], (b, kh, 8, sk)
-        ).reshape(b * kh, 8, sk)
-        vs8 = jnp.broadcast_to(
-            v_scale[:, :, None, :], (b, kh, 8, sk)
-        ).reshape(b * kh, 8, sk)
-        in_specs += [
-            pl.BlockSpec((1, 8, block_k), scale_index),
-            pl.BlockSpec((1, 8, block_k), scale_index),
-        ]
-        operands += [ks8, vs8]
-
-    kernel = functools.partial(
-        _cached_kernel,
-        scale=d ** -0.5,
-        block_q=block_q,
-        block_k=block_k,
-        num_k_blocks=nk,
-        quantized=quantized,
-    )
-    out = pl.pallas_call(
-        kernel,
-        grid=(b * h, nq, nk),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, block_q, d), q_index),
-        out_shape=jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
-        scratch_shapes=[
-            _vmem((block_q, 128), jnp.float32),
-            _vmem((block_q, 128), jnp.float32),
-            _vmem((block_q, d), jnp.float32),
-        ],
-        compiler_params=_compiler_params(),
-        interpret=interpret,
-        name=scopes.ATTN_CORE,
-    )(*operands)
-    return out.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
-
-
 def _vmem(shape, dtype):
     from jax.experimental.pallas import tpu as pltpu
 

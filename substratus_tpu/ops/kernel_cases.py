@@ -1,15 +1,15 @@
-"""One list of Pallas kernel cases at the widths the repo serves and trains.
+"""One list of kernel cases at the widths the repo serves and trains: the
+Pallas kernels, and the dense slot cache's attention, which is plain XLA.
 
 Shared by tests/test_chip_compile.py (each case AOT-compiled by the
 chip's compiler for a described, unattached v5e) and chip_smoke.py's
 kernel phase (each case compiled, run on the attached chip and compared
 with the XLA reference in ops/attention.py / ops/kvcache.py / ops/quant.py /
-Q4Tensor.dequant). A case the chip's compiler refuses carries the compiler's
-message in `refused`: the compile test marks it strict-xfail and the
-smoke prints it as "not run".
+Q4Tensor.dequant).
 
 Widths: TinyLlama-1.1B (32 heads / 4 kv heads of 64, dim 2048, hidden
-5632) and Llama-2-7B (32/32 heads of 128, dim 4096, hidden 11008);
+5632), Llama-2-7B (32/32 heads of 128, dim 4096, hidden 11008) and, for
+attention over a cache, Mistral-7B (32 heads / 8 kv heads of 128);
 prefill lengths are the engine's power-of-two buckets up to
 EngineConfig.max_prefill_len (16..512) plus the trainer's 1024/2048;
 cache lengths are EngineConfig.max_seq_len (1024) and the old bench's
@@ -17,7 +17,6 @@ cache lengths are EngineConfig.max_seq_len (1024) and the old bench's
 """
 from __future__ import annotations
 
-import dataclasses
 from dataclasses import dataclass
 from functools import partial
 from typing import Any, Callable, List, Optional
@@ -35,14 +34,15 @@ BF16 = jnp.bfloat16
 class KernelCase:
     """make_args(key) -> positional arrays; kernel(*args, interpret=...)
     and reference(*args) return the same pytree. `tol` bounds
-    max|kernel - reference| / max(1, max|reference|) over every leaf."""
+    max|kernel - reference| / max(1, max|reference|) over every leaf.
+    `mosaic` says whether the compiled program holds a Pallas kernel."""
 
     name: str
     make_args: Callable[[jax.Array], tuple]
     kernel: Callable[..., Any]
     reference: Callable[..., Any]
     tol: float
-    refused: Optional[str] = None
+    mosaic: bool = True
 
 
 def max_error(got: Any, want: Any) -> float:
@@ -158,84 +158,52 @@ def _kv_tag(int8: bool) -> str:
     return "int8kv" if int8 else "bf16kv"
 
 
-def flash_cached(tag, b, sq, h, kh, d, cache_len, int8) -> KernelCase:
-    from substratus_tpu.ops.flash_attention import flash_cached_attention
+def dense_attend(tag, b, sq, h, kh, d, cache_len, int8) -> KernelCase:
+    """ops/decode_attention.py::update_cache_and_attend, the one attention
+    of the dense slot cache: fresh rows written at `positions`, then a
+    one-token step (sq == 1) or a chunk / verify round attends."""
+    from substratus_tpu.ops.decode_attention import update_cache_and_attend
 
-    def kernel(q, k, v, positions, ks=None, vs=None, interpret=False):
-        return flash_cached_attention(
-            q, k, v, positions, ks, vs, interpret=interpret
-        )
+    def make_args(key):
+        kc, kk, kv = jax.random.split(key, 3)
+        fresh = (_normal(kk, (b, sq, kh, d)), _normal(kv, (b, sq, kh, d)))
+        return fresh + _cache_args(b, sq, h, kh, d, cache_len, int8, kc)
 
+    def kernel(kk, vv, q, k, v, positions, ks=None, vs=None,
+               interpret=False):
+        cache = {"k": k, "v": v}
+        if ks is not None:
+            cache.update(k_scale=ks, v_scale=vs)
+        return update_cache_and_attend(cache, q, kk, vv, positions)
+
+    def reference(kk, vv, q, k, v, positions, ks=None, vs=None):
+        # Each row's fresh positions are consecutive (_cache_args): one
+        # slice update a row, where the op scatters position by position.
+        def put(cache, rows):  # [B, KH, S, ...] <- [B, KH, sq, ...]
+            return jax.vmap(
+                lambda c, r, at: jax.lax.dynamic_update_slice_in_dim(
+                    c, r.astype(c.dtype), at, axis=1)
+            )(cache, rows, positions[:, 0])
+
+        kT, vT = kk.transpose(0, 2, 1, 3), vv.transpose(0, 2, 1, 3)
+        if ks is None:
+            cache = {"k": put(k, kT), "v": put(v, vT)}
+        else:
+            (kq, kqs), (vq, vqs) = quantize_kv(kT), quantize_kv(vT)
+            cache = {
+                "k": put(k, kq), "v": put(v, vq),
+                "k_scale": put(ks, kqs[..., 0]),
+                "v_scale": put(vs, vqs[..., 0]),
+            }
+        return _cache_reference(
+            q, cache["k"], cache["v"], positions,
+            cache.get("k_scale"), cache.get("v_scale"),
+        ), cache
+
+    q_tag = f"q{sq}-" if sq > 1 else ""
     return KernelCase(
-        f"flash_cached/{tag}/b{b}-q{sq}-s{cache_len}-{_kv_tag(int8)}",
-        partial(_cache_args, b, sq, h, kh, d, cache_len, int8),
-        kernel, _cache_reference, tol=2e-2,
-    )
-
-
-def decode_pallas(tag, b, h, kh, d, cache_len, int8) -> KernelCase:
-    from substratus_tpu.ops.decode_attention import decode_attention
-
-    def kernel(q, k, v, positions, ks=None, vs=None, interpret=False):
-        return decode_attention(
-            q, k, v, positions[:, 0], ks, vs, impl="pallas",
-            interpret=interpret,
-        )
-
-    return KernelCase(
-        f"decode_pallas/{tag}/b{b}-s{cache_len}-{_kv_tag(int8)}",
-        partial(_cache_args, b, 1, h, kh, d, cache_len, int8),
-        kernel, _cache_reference, tol=2e-2,
-    )
-
-
-def _fused_args(b, h, kh, d, cache_len, int8, key):
-    """fused_decode_attention's operands: the cache WITHOUT the fresh row,
-    the fresh k/v row, and (int8) the scale cache with the fresh scale
-    already scattered, as update_cache_and_attend hands them over."""
-    kc, kn = jax.random.split(key)
-    q, ck, cv, positions, *scales = _cache_args(
-        b, 1, h, kh, d, cache_len, int8, kc
-    )
-    positions = positions[:, 0]
-    k1, k2 = jax.random.split(kn)
-    nk, nv = _normal(k1, (b, kh, 1, d)), _normal(k2, (b, kh, 1, d))
-    if not int8:
-        return q, nk, nv, ck, cv, positions
-    nk, nks = quantize_kv(nk)
-    nv, nvs = quantize_kv(nv)
-    nks, nvs = nks[..., 0], nvs[..., 0]
-    cks = _scatter_rows(scales[0], nks, positions)
-    cvs = _scatter_rows(scales[1], nvs, positions)
-    return q, nk, nv, ck, cv, positions, nks, nvs, cks, cvs
-
-
-def _scatter_rows(cache, fresh, positions):
-    b, kh = cache.shape[:2]
-    return cache.at[
-        jnp.arange(b)[:, None, None], jnp.arange(kh)[None, :, None],
-        positions[:, None, None],
-    ].set(fresh)
-
-
-def fused_decode(tag, b, h, kh, d, cache_len, int8,
-                 refused=None) -> KernelCase:
-    from substratus_tpu.ops.fused_decode import fused_decode_attention
-
-    def kernel(*args, interpret=False):
-        return fused_decode_attention(*args, interpret=interpret)
-
-    def reference(q, nk, nv, ck, cv, positions, nks=None, nvs=None,
-                  cks=None, cvs=None):
-        ck2 = _scatter_rows(ck, nk, positions)
-        cv2 = _scatter_rows(cv, nv, positions)
-        attn = _cache_reference(q, ck2, cv2, positions[:, None], cks, cvs)
-        return attn, ck2, cv2
-
-    return KernelCase(
-        f"fused_decode/{tag}/b{b}-s{cache_len}-{_kv_tag(int8)}",
-        partial(_fused_args, b, h, kh, d, cache_len, int8),
-        kernel, reference, tol=2e-2, refused=refused,
+        f"dense_attend/{tag}/b{b}-{q_tag}s{cache_len}-{_kv_tag(int8)}",
+        make_args, kernel, reference, tol=2e-2, mosaic=False,
     )
 
 
@@ -308,14 +276,6 @@ def q4_matmul(tag, m, c, n) -> KernelCase:
 
 # --- the lists ---------------------------------------------------------------
 
-# Mosaic's words (jax 0.9.0, libtpu 0.0.34) for the fused decode kernel.
-FUSED_BF16_REFUSED = (
-    "Slice shape along dimension 2 must be aligned to tiling (2), but is 1"
-)
-FUSED_INT8_REFUSED = (
-    "last two dimensions of your block shape are divisible by 8 and 128"
-)
-
 # What the chip's compiler says of a kernel wrapped in custom_partitioning
 # (ops/kernel_partition.py) once its operands are sharded over a mesh of
 # several chips: the partitioner never runs, and the wrapper reaches the
@@ -325,32 +285,30 @@ SHARDED_REFUSED = "Custom emitter for CustomSPMDPartitioning not found"
 TINYLLAMA = dict(h=32, kh=4, d=64)
 SMALL = dict(h=4, kh=2, d=64)  # the CPU rehearsal's widths
 LLAMA7B = dict(h=32, kh=32, d=128)
+MISTRAL7B = dict(h=32, kh=8, d=128)
 
 
 def chip_cases() -> List[KernelCase]:
-    """Every kernel at TinyLlama-1.1B's and Llama-2-7B's widths."""
+    """Every kernel at TinyLlama-1.1B's and Llama-2-7B's widths; the dense
+    slot cache's attention at Mistral-7B's too (grouped queries over heads
+    of 128, which neither of the two has)."""
     cases: List[KernelCase] = []
     for tag, w in (("tinyllama", TINYLLAMA), ("llama2-7b", LLAMA7B)):
         for s in (16, 128, 384, 512, 2048):
             cases.append(flash_fwd(tag, 1, s, **w))
         cases.append(flash_bwd(tag, 2, 512, **w))
         cases.append(flash_bwd(tag, 1, 2048, **w))
+    for tag, w in (("tinyllama", TINYLLAMA), ("llama2-7b", LLAMA7B),
+                   ("mistral-7b", MISTRAL7B)):
         for int8 in (False, True):
-            # A bucket-padded prefill chunk, and a spec_k=4 verify pass
-            # over the whole decode batch.
-            cases.append(flash_cached(tag, 1, 512, cache_len=1024, int8=int8, **w))
-            cases.append(flash_cached(tag, 8, 5, cache_len=1024, int8=int8, **w))
-            cases.append(decode_pallas(tag, 8, cache_len=1024, int8=int8, **w))
-            cases.append(decode_pallas(tag, 24, cache_len=512, int8=int8, **w))
-            cases.append(fused_decode(
-                tag, 8, cache_len=1024, int8=int8,
-                refused=FUSED_INT8_REFUSED if int8 else FUSED_BF16_REFUSED,
-                **w,
-            ))
-    # A cache length that is no multiple of 128: TinyLlama's fits the
-    # decode kernel's VMEM budget whole (Llama-2-7B's is refused before
-    # the compiler, tests/test_chip_compile.py).
-    cases.append(decode_pallas("tinyllama", 8, cache_len=1000, int8=True, **TINYLLAMA))
+            # The decode batch, the old bench's, a bucket-padded prefill
+            # chunk, and a spec_k=4 verify round over the decode batch.
+            cases.append(dense_attend(tag, 8, 1, cache_len=1024, int8=int8, **w))
+            cases.append(dense_attend(tag, 24, 1, cache_len=512, int8=int8, **w))
+            cases.append(dense_attend(tag, 1, 512, cache_len=1024, int8=int8, **w))
+            cases.append(dense_attend(tag, 8, 5, cache_len=1024, int8=int8, **w))
+    # A cache length that is no multiple of 128.
+    cases.append(dense_attend("tinyllama", 8, 1, cache_len=1000, int8=True, **TINYLLAMA))
     for tag, dim, hidden, kv_dim in (
         ("tinyllama", 2048, 5632, 256), ("llama2-7b", 4096, 11008, 4096)
     ):
@@ -374,12 +332,10 @@ def rehearsal_cases() -> List[KernelCase]:
     return [
         flash_fwd("small", 1, 128, **w),
         flash_bwd("small", 1, 128, **w),
-        flash_cached("small", 1, 16, cache_len=128, int8=False, **w),
-        flash_cached("small", 2, 5, cache_len=128, int8=True, **w),
-        decode_pallas("small", 2, cache_len=128, int8=False, **w),
-        decode_pallas("small", 2, cache_len=128, int8=True, **w),
-        fused_decode("small", 2, cache_len=128, int8=False, **w),
-        fused_decode("small", 2, cache_len=128, int8=True, **w),
+        dense_attend("small", 1, 16, cache_len=128, int8=False, **w),
+        dense_attend("small", 2, 5, cache_len=128, int8=True, **w),
+        dense_attend("small", 2, 1, cache_len=128, int8=False, **w),
+        dense_attend("small", 2, 1, cache_len=128, int8=True, **w),
         q4_matmul("small", 8, 256, 128),
         paged_decode("small", 3, 128, h=4, kh=2, d=64, pages=17),
     ]
@@ -389,8 +345,7 @@ def sharded_flash_case(n_devices: int, s: int = 512,
                        widths: Optional[dict] = None) -> KernelCase:
     """The trainer's flash forward with one sequence per device (what
     fsdp=n_devices hands the kernel); TinyLlama's widths by default."""
-    case = flash_fwd("sharded", n_devices, s, **(widths or TINYLLAMA))
-    return dataclasses.replace(case, refused=SHARDED_REFUSED)
+    return flash_fwd("sharded", n_devices, s, **(widths or TINYLLAMA))
 
 
 def shard_batch(args: tuple, mesh) -> tuple:

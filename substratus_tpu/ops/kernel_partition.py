@@ -5,16 +5,15 @@ Attention-family kernels are embarrassingly parallel over batch and
 can run the identical kernel on its slice with zero collectives. GSPMD
 cannot know that about an opaque `pallas_call`, so without a rule it
 either fails to partition or all-gathers the operands. This module
-generalizes the rule used by ops/quant4.py / ops/fused_decode.py /
-ops/decode_attention.py: wrap the kernel in
+generalizes the rule used by ops/quant4.py: wrap the kernel in
 `jax.experimental.custom_partitioning`, read the mesh axes for batch
 and head off a reference operand's sharding, and force every
 operand/result spec consistent — batch/head sharded, everything else
 replicated.
 
-Used by ops/flash_attention.py (prefill forward, backward, and the
-cached-chunk kernel) so the TPU serving default (attn_impl="flash")
-and flash training survive GSPMD sharding.
+Used by ops/flash_attention.py (forward and backward) so the TPU serving
+default of the no-cache prefill (attn_impl="flash") and flash training
+survive GSPMD sharding.
 """
 from __future__ import annotations
 
@@ -32,7 +31,6 @@ def bh_partitioned(
     arg_dims: Sequence[Dims],
     out_dims: Sequence[Dims],
     sharding_rule: str,
-    ref: int = 0,
 ):
     """custom_partitioning wrapper for a kernel that is local per
     (batch, head) shard.
@@ -41,8 +39,8 @@ def bh_partitioned(
     arg_dims/out_dims: for each operand/result, which dimension index
         carries batch and which carries heads (None = not present).
     sharding_rule: Shardy propagation rule (einsum-like factor string).
-    ref: operand index whose sharding names the mesh axes (pick one the
-        caller commits, e.g. q or the cache).
+
+    The first operand's sharding (q) names the mesh axes.
     """
     from jax.experimental.custom_partitioning import custom_partitioning
 
@@ -57,13 +55,13 @@ def bh_partitioned(
 
     def axes(mesh, arg_shapes, result_shape):
         spec = tuple(
-            getattr(arg_shapes[ref].sharding, "spec", ()) or ()
+            getattr(arg_shapes[0].sharding, "spec", ()) or ()
         )
 
         def at(i):
             return spec[i] if i is not None and i < len(spec) else None
 
-        bdim, hdim = arg_dims[ref]
+        bdim, hdim = arg_dims[0]
         b, h = at(bdim), at(hdim)
 
         # One mesh axis cannot appear twice in a sharding. The overlap

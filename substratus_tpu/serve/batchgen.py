@@ -588,7 +588,6 @@ def main(argv=None) -> int:
         build_adapter_store,
         load_checkpoint,
         load_params_json,
-        resolve_kv_layout,
         _maybe_quantize,
     )
     from substratus_tpu.serve.tokenizer import load_tokenizer
@@ -652,7 +651,7 @@ def main(argv=None) -> int:
             tokenizer.eos_id if tokenizer.eos_id is not None else 2
         ),
         kv_cache_dtype=params_json.get("kv_cache_dtype", "model"),
-        kv_layout=resolve_kv_layout(params_json),
+        kv_layout=params_json.get("kv_layout", "auto"),
         step_floor_s=args.step_floor_ms / 1e3,
     )
 

@@ -263,8 +263,7 @@ class EngineConfig:
     # stream. With draft=(cfg, params) at Engine construction the proposer
     # is the draft model (paged layout only — the draft shares the
     # target's page tables); WITHOUT one it is prompt-lookup decoding
-    # (layout-agnostic, so it stacks with the dense-only fused kernel;
-    # the
+    # (layout-agnostic; the
     # continuation after the most recent match of the context's trailing
     # n-gram — zero extra model cost, wins on repetitive outputs:
     # summarization, RAG, code edits). Greedy slots stay token-exact
@@ -542,16 +541,6 @@ class Engine:
             raise ValueError(
                 f"role={ec.role!r} requires the paged kv layout"
             )
-        if not self.paged and hasattr(cfg, "decode_attn_impl"):
-            # The Pallas attention kernels read the dense slot cache: refuse
-            # here what the chip's compiler would refuse in the first step.
-            from substratus_tpu.ops.decode_attention import check_cache_tiling
-
-            check_cache_tiling(
-                cfg.decode_attn_impl, cfg.chunk_attn_impl, cfg.n_kv_heads,
-                cfg.head_size, S,
-                1 if kv_int8 else jnp.dtype(cfg.dtype).itemsize, kv_int8,
-            )
         self.handoff = handoff
         if ec.role == "prefill":
             if handoff is None:
@@ -723,8 +712,7 @@ class Engine:
             # The draft shares the target's page tables; a dense draft
             # cache has no insert path. Prompt-lookup speculation is
             # layout-agnostic (host-side proposals + a multi-token
-            # verify), which is what lets it stack with the dense-only
-            # fused decode kernel.
+            # verify).
             raise ValueError("draft-model spec_k requires the paged kv layout")
         if self.spec_draft:
             self.draft_cfg, draft_params = draft
@@ -2271,8 +2259,7 @@ class Engine:
         # paged_attention.py) then reads one page for it, not max_pages.
         # The clamp at the last cache row is a guard only: active slots
         # are released at the window before reaching it (_emit's
-        # hit_window), and past it the fused decode kernel's DMA would
-        # write out of bounds.
+        # hit_window).
         last = self.ec.max_seq_len - 1
         self.positions = np.minimum(self.positions + self.active, last)
         self.host_positions = np.minimum(

@@ -43,24 +43,6 @@ def _resolve_gguf(path: str):
     return resolve_gguf_or_exit(path)
 
 
-def resolve_kv_layout(params_json: Dict[str, Any]) -> str:
-    """The decode_attn_impl="fused" kernel lives on the DENSE slot-cache
-    path (update_cache_and_attend); paged decode has its own read path
-    and never reaches it. Asking for fused with layout auto therefore
-    resolves to dense — and asking for fused WITH paged is a config
-    contradiction, rejected loudly rather than silently serving unfused."""
-    layout = params_json.get("kv_layout", "auto")
-    fused = params_json.get("decode_attn_impl") == "fused"
-    if fused and layout == "auto":
-        return "dense"
-    if fused and layout == "paged":
-        raise SystemExit(
-            "params.json: decode_attn_impl=fused requires kv_layout=dense "
-            "(the paged decode path does not use the fused kernel)"
-        )
-    return layout
-
-
 def load_checkpoint(path: str):
     """One resolution rule for target and draft models alike (shared
     with the batch-generation entrypoint, serve/batchgen.py): a .gguf
@@ -247,10 +229,10 @@ def main(argv=None) -> int:
         (
             "model", "config", "quantize", "max_batch", "max_seq_len",
             "max_prefill_len", "kv_cache_dtype", "kv_layout", "attn_impl",
-            "chunk_attn_impl", "decode_attn_impl", "q4_impl", "tensor",
-            "sequence", "replicas", "draft_model", "spec_k", "max_queue",
-            "drain_grace", "adapters", "baseModel", "disaggregated",
-            "role", "transfer_port", "decode_peers", "batchGenerate",
+            "q4_impl", "tensor", "sequence", "replicas", "draft_model",
+            "spec_k", "max_queue", "drain_grace", "adapters", "baseModel",
+            "disaggregated", "role", "transfer_port", "decode_peers",
+            "batchGenerate",
         ),
         "serve.main",
     )
@@ -286,15 +268,7 @@ def main(argv=None) -> int:
 
     cfg, params = _maybe_quantize(family, cfg, params, quantize)
 
-    kv_layout = resolve_kv_layout(params_json)
-    if family is not llama and params_json.get("decode_attn_impl"):
-        # Same loud-not-silent policy as resolve_kv_layout and
-        # _maybe_quantize: the knob only exists on the llama family.
-        print(
-            f"decode_attn_impl ignored: {type(cfg).__name__} has no "
-            "decode attention implementation switch",
-            flush=True,
-        )
+    kv_layout = params_json.get("kv_layout", "auto")
     if family is llama:
         # Serving picks its own attention impl (never inherited from
         # training). On TPU the Pallas flash kernel is the default of the
@@ -307,18 +281,7 @@ def main(argv=None) -> int:
         # params.json {"attn_impl": ...} overrides either way.
         default_impl = "flash" if jax.default_backend() == "tpu" else "xla"
         cfg = cfg.replace(
-            attn_impl=params_json.get("attn_impl", default_impl),
-            # The cached-chunk kernel ("flash") and the unfused decode
-            # kernel ("pallas") also compile and match on one chip, dense
-            # layout only; neither has been timed against the XLA path, so
-            # both stay opt-in. A cache length they cannot tile is refused
-            # at Engine construction.
-            chunk_attn_impl=params_json.get("chunk_attn_impl", "xla"),
-            # "fused" = flash-decode (scatter+attention in one kernel,
-            # ops/fused_decode.py): the v5e compiler refuses it, so it
-            # raises on a TPU backend. Lives on the dense slot-cache path —
-            # resolve_kv_layout picks/polices the layout.
-            decode_attn_impl=params_json.get("decode_attn_impl", "xla"),
+            attn_impl=params_json.get("attn_impl", default_impl)
         )
 
     # Bounded admission (gateway contract): beyond this many waiters
@@ -376,20 +339,8 @@ def main(argv=None) -> int:
                 print("sequence>1 pins kv_layout=dense", flush=True)
                 kv_layout = "dense"
                 ec.kv_layout = "dense"
-            # The Pallas attention kernels' partition rules keep the
-            # cache sequence-REPLICATED (their online softmax is local
-            # per shard); with an S-sharded cache the XLA paths are the
-            # ones whose softmax GSPMD partitions over the sequence —
-            # otherwise every chunk would silently all-gather the cache
-            # and forfeit SP's N-times memory win.
-            if getattr(cfg, "decode_attn_impl", "xla") != "xla":
-                print("sequence>1 pins decode_attn_impl=xla", flush=True)
-                cfg = cfg.replace(decode_attn_impl="xla")
-            if getattr(cfg, "chunk_attn_impl", "xla") != "xla":
-                print("sequence>1 pins chunk_attn_impl=xla", flush=True)
-                cfg = cfg.replace(chunk_attn_impl="xla")
-        # The Pallas kernels (int4 unpack-dequant matmul, decode and
-        # prefill attention) carry custom_partitioning rules that run them
+        # The Pallas kernels (int4 unpack-dequant matmul, flash prefill
+        # attention) carry custom_partitioning rules that run them
         # per-shard on a virtual CPU mesh. The chip's compiler refuses the
         # wrapper under a multi-chip mesh (tests/test_chip_compile.py), so
         # on several chips keep the XLA paths: the defaults here, and
@@ -415,8 +366,7 @@ def main(argv=None) -> int:
         # Draft-model speculation shares the target's page tables, so it
         # needs the paged pool; warn and serve unsped rather than crash
         # at Engine construction. Prompt-lookup speculation is
-        # layout-agnostic and composes with the dense fused-decode
-        # kernel (int4 + fused + lookup stack in one config).
+        # layout-agnostic.
         print("draft spec_k needs kv_layout=paged; speculation disabled",
               flush=True)
         spec_k = 0

@@ -147,70 +147,6 @@ def test_ring_attention_matches_reference(mesh8, ring_size):
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
 
-@pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
-@pytest.mark.parametrize("kh", [4, 2], ids=["mha", "gqa"])
-def test_flash_cached_attention_matches_fallback(quantized, kh):
-    """The chunked-prefill flash kernel vs the dequantize-and-reference
-    path update_cache_and_attend uses (ops/decode_attention.py)."""
-    from substratus_tpu.ops.flash_attention import flash_cached_attention
-    from substratus_tpu.ops.quant import dequantize_kv, quantize_kv
-
-    b, sq, h, d, sk = 2, 16, 4, 32, 128
-    ks = jax.random.split(jax.random.key(3), 4)
-    q = jax.random.normal(ks[0], (b, sq, h, d), jnp.float32)
-    k_act = jax.random.normal(ks[1], (b, sk, kh, d), jnp.float32)
-    v_act = jax.random.normal(ks[2], (b, sk, kh, d), jnp.float32)
-    # Chunk occupies positions [pos0, pos0+sq); tail of the cache is junk.
-    pos0 = 64
-    positions = pos0 + jnp.arange(sq)[None, :] + jnp.zeros((b, 1), jnp.int32)
-
-    kT = k_act.transpose(0, 2, 1, 3)  # [B, KH, Sk, D] cache layout
-    vT = v_act.transpose(0, 2, 1, 3)
-    if quantized:
-        kq, kscale = quantize_kv(kT)
-        vq, vscale = quantize_kv(vT)
-        kscale, vscale = kscale[..., 0], vscale[..., 0]
-        k_cache, v_cache = kq, vq
-        k_ref_act = dequantize_kv(kq, kscale[..., None], jnp.float32)
-        v_ref_act = dequantize_kv(vq, vscale[..., None], jnp.float32)
-    else:
-        k_cache, v_cache = kT, vT
-        kscale = vscale = None
-        k_ref_act, v_ref_act = kT, vT
-
-    ref = dot_product_attention(
-        q, k_ref_act.transpose(0, 2, 1, 3), v_ref_act.transpose(0, 2, 1, 3),
-        causal=True, q_positions=positions,
-    )
-    out = flash_cached_attention(
-        q, k_cache, v_cache, positions, kscale, vscale,
-        block_q=8, block_k=32, interpret=True,
-    )
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
-
-
-def test_flash_cached_attention_kv_length():
-    from substratus_tpu.ops.flash_attention import flash_cached_attention
-
-    b, sq, h, d, sk = 1, 8, 2, 32, 64
-    ks = jax.random.split(jax.random.key(4), 3)
-    q = jax.random.normal(ks[0], (b, sq, h, d), jnp.float32)
-    k = jax.random.normal(ks[1], (b, h, sk, d), jnp.float32)
-    v = jax.random.normal(ks[2], (b, h, sk, d), jnp.float32)
-    positions = 40 + jnp.arange(sq)[None, :]
-    kv_len = jnp.array([20], jnp.int32)  # only the first 20 slots are real
-
-    ref = dot_product_attention(
-        q, k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3),
-        causal=True, q_positions=positions, kv_length=kv_len,
-    )
-    out = flash_cached_attention(
-        q, k, v, positions, kv_length=kv_len,
-        block_q=8, block_k=32, interpret=True,
-    )
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
-
-
 def test_flash_non_divisible_bucket():
     """A 384-token prefill bucket (not a multiple of the 256 default
     block) must shrink the block instead of asserting."""
@@ -218,29 +154,6 @@ def test_flash_non_divisible_bucket():
     ref = dot_product_attention(q, k, v, causal=True)
     out = flash_attention(q, k, v, True, None, 256, 256, True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
-
-
-def test_flash_cached_attention_zero_length_row():
-    """A row whose cache is entirely empty (kv_length == 0 and position
-    before the cache start) masks every column; its output must be zeros,
-    not a column-mean of V (ADVICE r2: exp(NEG_INF - NEG_INF) == 1)."""
-    from substratus_tpu.ops.flash_attention import flash_cached_attention
-
-    b, sq, h, d, sk = 2, 8, 2, 32, 64
-    ks = jax.random.split(jax.random.key(5), 3)
-    q = jax.random.normal(ks[0], (b, sq, h, d), jnp.float32)
-    k = jax.random.normal(ks[1], (b, h, sk, d), jnp.float32)
-    v = jax.random.normal(ks[2], (b, h, sk, d), jnp.float32)
-    kv_len = jnp.array([0, 20], jnp.int32)  # row 0: nothing attendable
-    positions = jnp.stack(
-        [jnp.full((sq,), -1, jnp.int32), 30 + jnp.arange(sq)], axis=0
-    )
-    out = flash_cached_attention(
-        q, k, v, positions, kv_length=kv_len,
-        block_q=8, block_k=32, interpret=True,
-    )
-    np.testing.assert_allclose(np.asarray(out[0]), 0.0, atol=1e-6)
-    assert float(jnp.abs(out[1]).max()) > 0
 
 
 def test_flash_sharded_forward_and_grad_match_unsharded():
@@ -275,36 +188,6 @@ def test_flash_sharded_forward_and_grad_match_unsharded():
     g_sh = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))(qs, ks, vs)
     for a, b in zip(g_sh, g_ref):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4)
-
-
-def test_flash_cached_sharded_matches_unsharded():
-    """The cached-chunk kernel under the same (data x tensor) mesh —
-    the chunk_attn_impl=flash serving path sharded."""
-    from jax.sharding import NamedSharding
-
-    from substratus_tpu.ops.flash_attention import flash_cached_attention
-    from substratus_tpu.parallel.mesh import build_mesh
-
-    mesh = build_mesh(data=2, tensor=2, fsdp=2)
-    b, sq, h, kh, sk, d = 2, 32, 4, 2, 128, 32
-    ks = jax.random.split(jax.random.key(3), 3)
-    q = jax.random.normal(ks[0], (b, sq, h, d), jnp.float32)
-    kc = jax.random.normal(ks[1], (b, kh, sk, d), jnp.float32)
-    vc = jax.random.normal(ks[2], (b, kh, sk, d), jnp.float32)
-    pos = jnp.broadcast_to(jnp.arange(sq)[None, :] + 40, (b, sq))
-
-    ref = flash_cached_attention(
-        q, kc, vc, pos, block_q=32, block_k=64, interpret=True
-    )
-    qs = jax.device_put(q, NamedSharding(mesh, P("data", None, "tensor")))
-    kcs = jax.device_put(kc, NamedSharding(mesh, P("data", "tensor")))
-    vcs = jax.device_put(vc, NamedSharding(mesh, P("data", "tensor")))
-    out = jax.jit(
-        lambda q, k, v, p: flash_cached_attention(
-            q, k, v, p, block_q=32, block_k=64, interpret=True
-        )
-    )(qs, kcs, vcs, pos)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
 
 def test_flash_sharded_gqa_tensor_wider_than_kv_heads():

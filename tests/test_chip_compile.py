@@ -5,15 +5,17 @@ unattached `v5e:2x2` topology, so what Mosaic would refuse on the chip it
 refuses in this test, at no chip time (interpret mode shows none of it:
 tiling alignment, VMEM limits). One case per kernel and shape from
 ops/kernel_cases.py — the list chip_smoke.py's kernel phase runs on the
-attached chip. Cases the compiler refuses are strict xfail carrying its
-message: the day one compiles, the test fails until the mark goes.
+attached chip — the dense slot cache's attention, which is plain XLA,
+among them.
 
 The serving programs that carry the paged KV pool are compiled the same way:
 the optimized HLO of the engine's decode and chunk programs may not copy,
 slice or re-stack the pool (models/llama.py::forward carries it in place),
 nor, for a family with window layers, their rings (models/exaone_moe.py);
 and the decode program over a bfloat16 pool on one chip holds the
-paged-attention kernel and no gathered context (ops/kvcache.py).
+paged-attention kernel and no gathered context (ops/kvcache.py). The dense
+slot cache's programs are compiled for every family that serves on it, and
+for a cache split over `sequence`.
 """
 import math
 import os
@@ -54,14 +56,7 @@ def v5e():
     compilation_cache.reset_cache()
 
 
-def _param(case):
-    marks = ()
-    if case.refused:
-        marks = pytest.mark.xfail(strict=True, reason=case.refused)
-    return pytest.param(case, id=case.name, marks=marks)
-
-
-@pytest.mark.parametrize("case", [_param(c) for c in chip_cases()])
+@pytest.mark.parametrize("case", chip_cases(), ids=lambda case: case.name)
 def test_kernel_compiles_for_v5e(case, v5e):
     from jax.sharding import SingleDeviceSharding
 
@@ -74,7 +69,7 @@ def test_kernel_compiles_for_v5e(case, v5e):
     compiled = (
         jax.jit(partial(case.kernel, interpret=False)).lower(*args).compile()
     )
-    assert "tpu_custom_call" in compiled.as_text()
+    assert ("tpu_custom_call" in compiled.as_text()) == case.mosaic
 
 
 @pytest.mark.xfail(strict=True, reason=SHARDED_REFUSED)
@@ -93,46 +88,29 @@ def test_sharded_kernel_compiles_for_v5e_2x2(v5e):
     jax.jit(partial(case.kernel, interpret=False)).lower(*args).compile()
 
 
-def test_untileable_cache_lengths_are_refused_before_the_compiler():
-    """Llama-2-7B widths with a cache of 1000: no multiple of 128 divides
-    it and it does not fit VMEM whole, so the block choosers raise, and
-    Engine construction raises with them (not the first jitted step)."""
-    from substratus_tpu.models import llama
-    from substratus_tpu.ops.decode_attention import pick_block_s
-    from substratus_tpu.ops.flash_attention import cached_block_k
-    from substratus_tpu.serve.engine import Engine, EngineConfig
+@pytest.mark.xfail(
+    strict=True,
+    reason="Slice shape along dimension 4 must be aligned to tiling (128), "
+           "but is 64",
+)
+def test_paged_decode_kernel_compiles_at_head_dim_64(v5e):
+    """TinyLlama-1.1B's heads are 64 wide and Mosaic refuses the paged
+    decode kernel there, so on a TPU `serve.main --config tinyllama-1.1b`
+    answers 500 since PR 28 (found by `chip_smoke.py`'s serve phase in
+    PR 29; ROADMAP.md S3c). ops/kvcache.py picks the kernel without looking
+    at the head width: the day it does, or the kernel takes 64, this
+    passes and the mark goes."""
+    from jax.sharding import SingleDeviceSharding
 
-    with pytest.raises(ValueError, match="multiple of 128"):
-        pick_block_s(1000, kh=32, d=128, itemsize=2, quantized=False)
-    with pytest.raises(ValueError, match="multiple of 128"):
-        cached_block_k(256, 1000, quantized=True)
-    # What the tiling can take stays accepted: whole-axis blocks, bf16.
-    assert pick_block_s(1000, kh=4, d=64, itemsize=1, quantized=True) == 1000
-    assert pick_block_s(512, kh=32, d=128, itemsize=2, quantized=False) == 256
-    assert cached_block_k(256, 1024, quantized=True) == 256
-    assert cached_block_k(256, 1000, quantized=False) == 8
+    from substratus_tpu.ops.kernel_cases import TINYLLAMA, paged_decode
 
-    cfg = llama.CONFIGS["llama2-7b"].replace(
-        n_layers=1, decode_attn_impl="pallas"
+    case = paged_decode("tinyllama", 8, 1024, pages=513, **TINYLLAMA)
+    one_chip = SingleDeviceSharding(v5e[0])
+    args = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        jax.eval_shape(case.make_args, jax.random.key(0)),
     )
-    ec = EngineConfig(max_batch=2, max_seq_len=1000, kv_layout="dense")
-    with pytest.raises(ValueError, match="multiple of 128"):
-        Engine(cfg, None, ec)
-
-
-def test_fused_decode_raises_on_a_tpu_backend(monkeypatch):
-    """decode_attn_impl=fused stays opt-in and refuses a TPU backend with
-    the compiler's message at Engine construction."""
-    from substratus_tpu.models import llama
-    from substratus_tpu.ops import fused_decode
-    from substratus_tpu.serve.engine import Engine, EngineConfig
-
-    fused_decode.check_lowers()  # the CPU backend: nothing to refuse
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    cfg = llama.CONFIGS["tiny"].replace(decode_attn_impl="fused")
-    ec = EngineConfig(max_batch=2, max_seq_len=64, kv_layout="dense")
-    with pytest.raises(NotImplementedError, match="aligned to tiling"):
-        Engine(cfg, None, ec)
+    jax.jit(case.kernel).lower(*args).compile()
 
 
 # The chat cell's engine (benchmarks/traffic/chat.json): Mistral-7B, int8
@@ -169,6 +147,42 @@ def _reads_pages_in_place(hlo: str, context: int) -> bool:
     return bool(kernel) and not sized
 
 
+def _described(v5e, eng, **mesh_axes):
+    """(placed, arr): abstract arguments for one described chip or, with
+    mesh axes given, sharded over the four by the serve rules (`eng.mesh` is
+    set to that mesh). placed(tree, logical_axes) places a tree of shapes;
+    arr(shape, dtype) is one replicated array."""
+    from jax.sharding import (
+        NamedSharding, PartitionSpec as P, SingleDeviceSharding,
+    )
+
+    from substratus_tpu.parallel.mesh import build_mesh
+    from substratus_tpu.parallel.sharding import serve_rules_for, sharding_tree
+
+    if math.prod(mesh_axes.values()) == 1:
+        rep = SingleDeviceSharding(v5e[0])
+
+        def shardings(tree, axes):
+            return jax.tree.map(lambda _: rep, tree)
+    else:
+        eng.mesh = mesh = build_mesh(devices=v5e, **mesh_axes)
+        rep = NamedSharding(mesh, P())
+
+        def shardings(tree, axes):
+            return sharding_tree(tree, mesh, axes, serve_rules_for(mesh))
+
+    def placed(tree, axes):
+        return jax.tree.map(
+            lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+            tree, shardings(tree, axes),
+        )
+
+    def arr(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=rep)
+
+    return placed, arr
+
+
 @pytest.mark.parametrize(
     "kv_cache_dtype,tensor",
     [("model", 1), ("int8", 1), ("model", 4)],
@@ -180,14 +194,8 @@ def test_serving_programs_leave_the_kv_pool_in_place(
     """decode and the 512-token chunk, for one described chip and for the
     four under a `tensor` mesh (pool sharded over kv_heads): no pool- or
     layer-of-pool-sized copy or slice, and temporaries under half a pool."""
-    from jax.sharding import (
-        NamedSharding, PartitionSpec as P, SingleDeviceSharding,
-    )
-
     from substratus_tpu.models import llama
     from substratus_tpu.ops.quant import quantize_params
-    from substratus_tpu.parallel.mesh import build_mesh
-    from substratus_tpu.parallel.sharding import serve_rules_for, sharding_tree
     from substratus_tpu.serve.engine import Engine, EngineConfig
 
     cfg = llama.LlamaConfig(
@@ -214,27 +222,7 @@ def test_serving_programs_leave_the_kv_pool_in_place(
             dtype=jnp.int8 if quantized else None,
         )
     )
-    if tensor == 1:
-        rep = SingleDeviceSharding(v5e[0])
-
-        def shardings(tree, axes):
-            return jax.tree.map(lambda _: rep, tree)
-    else:
-        eng.mesh = mesh = build_mesh(tensor=tensor, devices=v5e)
-        rep = NamedSharding(mesh, P())
-
-        def shardings(tree, axes):
-            return sharding_tree(tree, mesh, axes, serve_rules_for(mesh))
-
-    def placed(tree, axes):
-        return jax.tree.map(
-            lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
-            tree, shardings(tree, axes),
-        )
-
-    def arr(shape, dtype=jnp.int32):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=rep)
-
+    placed, arr = _described(v5e, eng, tensor=tensor)
     params = placed(params, llama.param_logical_axes(cfg))
     pool = placed(pool, llama.paged_cache_logical_axes(cfg, quantized))
     m = _S // _PAGE
@@ -375,3 +363,84 @@ def test_exaone_programs_leave_pool_and_rings_in_place(v5e):
         # expert's rows at a time; the decode step every held expert
         grouped = "s8[1,1,6144,2048]" in hlo
         assert grouped == (name == "chunk"), name
+
+
+# The dense slot cache [L, B, KH, S, hd] at the server's defaults (8 slots of
+# 1,024 positions, chunks of 512): the only layout Falcon and OPT have, and
+# the only one that splits over `sequence` (there at TinyLlama's whole 2,048).
+_DENSE = {
+    "llama-bf16": ("llama", "tinyllama-1.1b", "model", 1, 1024),
+    "llama-int8kv": ("llama", "tinyllama-1.1b", "int8", 1, 1024),
+    "falcon": ("falcon", "falcon-7b", "model", 1, 1024),
+    "opt": ("opt", "opt-1.3b", "model", 1, 1024),
+    "llama-sequence4": ("llama", "tinyllama-1.1b", "model", 4, 2048),
+}
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk"])
+@pytest.mark.parametrize("name", list(_DENSE))
+def test_dense_serving_programs_compile_for_v5e(name, program, v5e):
+    """The engine's decode step and 512-token chunk over the dense slot
+    cache compile for a described v5e and fit it, as plain XLA: the one
+    attention of ops/decode_attention.py, no kernel. Only the int8 cache's
+    chunk dequantizes a slot (`kv.gather`). With the cache split four ways
+    over its positions each chip holds a quarter of it and nothing gathers
+    it: the softmax's partial sums are all that cross chips.
+
+    What the compiler does put in is recorded, not refused: each program
+    copies every cache array once, whole (the layer scan takes the cache
+    as `xs` and returns it as `ys`; the paged pool is carried in place
+    since PR 25). A second whole copy of any of them fails here."""
+    import importlib
+
+    from substratus_tpu.serve.engine import Engine, EngineConfig
+
+    family, config, kv_cache_dtype, sequence, seq_len = _DENSE[name]
+    model = importlib.import_module(f"substratus_tpu.models.{family}")
+    cfg = model.CONFIGS[config]
+    quantized = kv_cache_dtype == "int8"
+    b = 8
+    # Built on the CPU with the smallest cache it takes; its jitted programs
+    # are lowered for the described chips at the shapes above.
+    eng = Engine(cfg, None, EngineConfig(
+        max_batch=1, max_seq_len=16, max_prefill_len=_CHUNK,
+        kv_cache_dtype=kv_cache_dtype, kv_layout="dense",
+    ))
+    assert not eng.paged
+    placed, arr = _described(v5e, eng, sequence=sequence)
+    params = placed(
+        jax.eval_shape(lambda key: model.init_params(cfg, key),
+                       jax.random.key(0)),
+        model.param_logical_axes(cfg),
+    )
+    slots = 1 if program == "chunk" else b  # a chunk runs on its slot's cache
+    cache = placed(
+        jax.eval_shape(lambda: model.init_cache(
+            cfg, slots, seq_len, dtype=jnp.int8 if quantized else None)),
+        model.cache_logical_axes(cfg, quantized),
+    )
+    if program == "decode":
+        lowered = eng._decode_fn.lower(
+            params, cache, None, arr((b,)), arr((b,)),
+            arr((b,), jnp.float32), arr((b,), jnp.float32),
+            arr(eng.key.shape, eng.key.dtype),
+        )
+    else:
+        lowered = Engine._chunk_prefill_jit.lower(
+            model, cfg, params, cache, arr((1, _CHUNK)), arr(()), arr(()),
+        )
+    hlo = lowered.compile().as_text()  # raises where it does not fit 16 GB
+    assert "tpu_custom_call" not in hlo
+    for scope in ("kv.write", "attn.core"):
+        assert scope in hlo, scope
+    assert ("kv.gather" in hlo) == (quantized and program == "chunk")
+    per_chip = {
+        k: math.prod(s.sharding.shard_shape(s.shape)) for k, s in cache.items()
+    }
+    assert all(n * sequence == cache[k].size for k, n in per_chip.items())
+    whole = _pool_moving_ops(
+        "\n".join(l for l in hlo.splitlines() if " copy(" in l),
+        set(per_chip.values()),
+    )
+    assert len(whole) <= len(cache), whole
+    assert not re.search(r"all-gather|all-to-all|collective-permute", hlo)

@@ -196,19 +196,6 @@ def pallas_call_spy(monkeypatch):
     monkeypatch.setattr(pl, "pallas_call", spy)
 
 
-def test_fused_decode_never_picks_interpret_mode(pallas_call_spy):
-    from substratus_tpu.ops.kernel_cases import SMALL, fused_decode
-
-    case = fused_decode("spy", 3, cache_len=64, int8=False, **SMALL)
-    args = case.make_args(jax.random.key(0))
-    with pytest.raises(_Seen) as seen:
-        case.kernel(*args)  # no interpret argument, on a CPU backend
-    assert seen.value.args == (False,)
-    with pytest.raises(_Seen) as seen:
-        case.kernel(*args, interpret=True)
-    assert seen.value.args == (True,)
-
-
 def test_q4einsum_never_picks_interpret_mode(pallas_call_spy):
     from substratus_tpu.ops.quant4 import q4einsum, quantize4, set_q4_impl
 

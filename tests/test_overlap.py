@@ -127,12 +127,11 @@ def test_greedy_parity_layouts(cfg, params, layout):
     concurrent batch (slot release lags one step under overlap — the
     wasted token must never surface)."""
     prompts = _parity_prompts()
-    on = run_engine(cfg, params, ec(kv_layout=layout, overlap=True),
-                    prompts)
-    off = run_engine(cfg, params, ec(kv_layout=layout, overlap=False),
-                     prompts)
+    kw = dict(kv_layout=layout, eos_token_id=-1)  # an eos no row can sample
+    on = run_engine(cfg, params, ec(overlap=True, **kw), prompts)
+    off = run_engine(cfg, params, ec(overlap=False, **kw), prompts)
     assert on == off, (on, off)
-    assert all(len(o) == 12 for o in on)  # eos 257 never fires
+    assert all(len(o) == 12 for o in on)
 
 
 def test_greedy_parity_chunked_prefill(cfg, params):
@@ -140,7 +139,7 @@ def test_greedy_parity_chunked_prefill(cfg, params):
     while a step may be in flight under overlap)."""
     rng = np.random.default_rng(7)
     prompts = [rng.integers(10, 250, 40).tolist() for _ in range(3)]
-    kw = dict(max_prefill_len=16, max_seq_len=64)
+    kw = dict(max_prefill_len=16, max_seq_len=64, eos_token_id=-1)
     on = run_engine(cfg, params, ec(overlap=True, **kw), prompts,
                     max_tokens=8)
     off = run_engine(cfg, params, ec(overlap=False, **kw), prompts,
@@ -300,29 +299,33 @@ def test_eos_lag_never_emits_post_stop_token(cfg, params):
     N+1 (release lags one step): the N+1 token is computed, wasted, and
     masked — the sink sees exactly the pre-stop tokens then None."""
     eng = manual_engine(cfg, params)
-    # Learn what the model decodes greedily, then stop on token #2.
-    probe = admit_one(eng, [256, 50, 60], max_tokens=6)
-    p1 = eng._dispatch()
-    eng._drain(p1)
-    p2 = eng._dispatch()
-    eng._drain(p2)
+    # Learn what the model decodes greedily, then stop on the first token
+    # that none before it equals (a request whose first token is its eos
+    # stops at admission and never holds a slot).
+    probe = admit_one(eng, [256, 50, 60], max_tokens=8)
+    for _ in range(5):
+        eng._drain(eng._dispatch())
     seen = [t for t in drain_sink(probe) if t is not None]
-    assert len(seen) == 3
+    assert len(seen) == 6
     probe.cancelled = True
-    p = eng._dispatch()
-    eng._drain(p)
+    eng._drain(eng._dispatch())
     assert not eng.active.any()
+    stop_at = next(i for i in range(1, 6) if seen[i] not in seen[:i])
 
-    req = admit_one(eng, [256, 50, 60], max_tokens=6,
-                    eos_token_id=seen[1])
+    req = admit_one(eng, [256, 50, 60], max_tokens=8,
+                    eos_token_id=seen[stop_at])
     slot = eng.slot_req.index(req)
-    p1 = eng._dispatch()            # computes seen[1] (the eos)
-    p2 = eng._dispatch()            # in-flight past the stop
-    eng._drain(p1)                  # eos observed -> release (lagged)
+    pending = eng._dispatch()       # computes seen[1]
+    for _ in range(stop_at):        # one step always in flight behind it
+        ahead = eng._dispatch()
+        eng._drain(pending)
+        pending = ahead
+    # the last step drained computed the eos -> release (lagged), with
+    # `pending` in flight past the stop
     assert not eng.active[slot]
-    eng._drain(p2)                  # wasted token: identity check masks
+    eng._drain(pending)             # wasted token: identity check masks
     toks = drain_sink(req)
-    assert toks == [seen[0], None]  # post-stop token never surfaced
+    assert toks == seen[:stop_at] + [None]  # no post-stop token surfaced
 
 
 def test_ensure_capacity_one_step_ahead(cfg, params):

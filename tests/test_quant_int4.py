@@ -207,33 +207,6 @@ def test_int4_engine_end_to_end():
         eng.stop()
 
 
-def test_int4_fused_decode_int8kv_engine_end_to_end(pallas_interpret):
-    """The throughput configuration the hardware bench runs — int4
-    weights + int8 KV cache + fused flash-decode — produces the same
-    greedy tokens through the engine as the plain xla decode path with
-    identical quantized params (the full perf stack composes)."""
-    from substratus_tpu.serve.engine import Engine, EngineConfig
-
-    cfg = llama.CONFIGS["tiny"].replace(vocab_size=258, dtype=jnp.float32)
-    params = llama.init_params(cfg, jax.random.key(0))
-    qparams = quantize4_params(params, llama.quant_contracting(cfg))
-    prompt = [256, 70, 71, 72]
-    outs = {}
-    for impl in ("xla", "fused"):
-        eng = Engine(
-            cfg.replace(decode_attn_impl=impl), qparams,
-            EngineConfig(max_batch=2, max_seq_len=64, eos_token_id=257,
-                         kv_cache_dtype="int8", kv_layout="dense"),
-        )
-        eng.start()
-        try:
-            outs[impl] = eng.generate(prompt, max_tokens=8, temperature=0.0)
-        finally:
-            eng.stop()
-    assert outs["fused"] == outs["xla"], outs
-    assert len(outs["fused"]) >= 1
-
-
 def test_merge_lora_over_int4_base():
     """merge_lora on a Q4Tensor base must produce bf16 merged weights
     (Q4's storage dtype is uint8 — casting merged floats to it would

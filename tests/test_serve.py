@@ -303,3 +303,58 @@ def test_checkpoint_roundtrip(tmp_path):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     # a non-artifact dir returns None
     assert maybe_restore_orbax(str(tmp_path)) is None
+
+
+def _serve_main_engine(monkeypatch, tmp_path, params_json):
+    """serve.main.main with a params.json, up to the point where it would
+    bind HTTP: returns the engine it built and started."""
+    from substratus_tpu.serve import main as serve_main, server
+
+    seen = []
+    monkeypatch.setattr(
+        server, "serve_forever", lambda state, **kw: seen.append(state)
+    )
+    path = tmp_path / "params.json"
+    path.write_text(json.dumps(params_json))
+    assert serve_main.main(["--config", "tiny", "--params", str(path)]) == 0
+    return seen[0].engine
+
+
+@pytest.mark.parametrize(
+    "removed",
+    [
+        {"decode_attn_impl": "pallas", "chunk_attn_impl": "flash"},
+        # what once turned kv_layout=auto into dense
+        {"decode_attn_impl": "fused"},
+    ],
+    ids=["both-keys", "fused-with-auto"],
+)
+def test_removed_attention_keys_warn_and_change_nothing(
+    removed, monkeypatch, tmp_path, capsys
+):
+    """decode_attn_impl / chunk_attn_impl are no params.json keys: the
+    server names them in its unknown-key warning, a Llama-family model
+    under kv_layout=auto still serves the paged layout, and its greedy
+    tokens are those of the same file without them."""
+    kept = {"kv_layout": "auto", "max_batch": 2, "max_seq_len": 64}
+    outs = {}
+    for name, params_json in (("kept", kept), ("all", {**kept, **removed})):
+        capsys.readouterr()
+        eng = _serve_main_engine(monkeypatch, tmp_path, params_json)
+        try:
+            # the line names the unknown keys, then every known one
+            unknown = [line.split("(typo?")[0]
+                       for line in capsys.readouterr().err.splitlines()
+                       if "ignores unrecognized params.json keys" in line]
+            assert eng.paged
+            assert not any(hasattr(eng.cfg, key) for key in removed)
+            outs[name] = (
+                unknown,
+                eng.generate([256, 10, 20, 30], max_tokens=6, temperature=0.0),
+            )
+        finally:
+            eng.stop()
+    assert outs["kept"][0] == []
+    (warned,) = outs["all"][0]
+    assert all(repr(key) in warned for key in removed), warned
+    assert outs["all"][1] == outs["kept"][1] and outs["kept"][1]

@@ -99,7 +99,7 @@ def main():
     def attn_chain(q, k, v, ks):
         def step(q, kvs):
             ki, vi, ksi = kvs
-            o = decode_attention(q, ki, vi, pos, ksi, ksi, impl="xla")
+            o = decode_attention(q, ki, vi, pos, ksi, ksi)
             return jnp.tanh(o), ()
         q, _ = jax.lax.scan(step, q, (k, v, ks))
         return q

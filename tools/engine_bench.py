@@ -140,17 +140,6 @@ def make_engine(a, mesh=None, sync=None, role="both", handoff=None,
         import jax.numpy as jnp
 
         cfg = cfg.replace(vocab_size=258, dtype=jnp.float32)
-    if a.decode_impl != "xla":
-        # The Pallas/fused decode kernels live on the dense slot-cache
-        # path; the paged decode never consults decode_attn_impl — same
-        # policy as serve.main.resolve_kv_layout, enforced so the
-        # printed metric is never mislabeled.
-        if a.kv_layout == "paged":
-            raise SystemExit(
-                f"--decode-impl {a.decode_impl} requires --kv-layout dense"
-            )
-        a.kv_layout = "dense"
-        cfg = cfg.replace(decode_attn_impl=a.decode_impl)
     if a.quantize == "none":
         params = llama.init_params(cfg, jax.random.key(0))
     else:
@@ -1307,10 +1296,6 @@ def parse_args(argv=None):
         "--kv-layout", default="auto", choices=["auto", "paged", "dense"]
     )
     ap.add_argument(
-        "--decode-impl", default="xla", choices=["xla", "pallas", "fused"],
-        help="decode attention path (fused requires --kv-layout dense)",
-    )
-    ap.add_argument(
         "--spec-k", type=int, default=0,
         help="prompt-lookup speculation (repetitive prompts benefit)",
     )
@@ -1549,7 +1534,7 @@ def passthrough_args(a) -> list:
         str(a.max_tokens), "--batch", str(a.batch),
         "--max-seq-len", str(a.max_seq_len), "--kv-dtype", a.kv_dtype,
         "--quantize", a.quantize, "--kv-layout", a.kv_layout,
-        "--decode-impl", a.decode_impl, "--spec-k", str(a.spec_k),
+        "--spec-k", str(a.spec_k),
         "--devs-per-proc", str(a.devs_per_proc),
         "--long-admission", str(a.long_admission),
         "--transport", a.transport,
@@ -1668,7 +1653,6 @@ def main() -> int:
             "requests": a.requests,
             "quantize": a.quantize,
             "kv_layout": a.kv_layout,
-            "decode_impl": a.decode_impl,
             "wall_s": leader["wall_s"],
         }
         print(json.dumps(record))
@@ -1694,7 +1678,6 @@ def main() -> int:
         "total_tok_s": result["total_tok_s"],
         "quantize": a.quantize,
         "kv_layout": a.kv_layout,
-        "decode_impl": a.decode_impl,
         "requests": a.requests,
         "wall_s": result["wall_s"],
         "ttft_p50_ms": result["ttft_ms"].get("p50"),

@@ -2,7 +2,6 @@
 variants, to find where the 2.7x-over-roofline decode step time goes."""
 import sys
 import time
-from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -69,33 +68,22 @@ def main():
     q = jax.random.normal(key, (B, 1, H, HD), jnp.bfloat16)
     pos = jnp.full((B,), S - 1, jnp.int32)
 
-    for impl in ("xla", "pallas"):
-        fn = jax.jit(partial(decode_attention, impl=impl))
-        try:
-            t = timeit(fn, q, k, v, pos, ks, vs)
-        except Exception as e:  # noqa: BLE001
-            print(f"  decode_attn {impl}: FAILED {type(e).__name__}: {e}"[:300])
-            continue
-        cache_bytes = 2 * B * KH * S * HD
-        print(
-            f"  decode_attn int8 {impl:6s} {t*1e3:7.3f}ms "
-            f"{cache_bytes/t/1e9:6.0f} GB/s (one layer; x32 = {t*32*1e3:6.1f}ms)"
-        )
+    fn = jax.jit(decode_attention)
+    t = timeit(fn, q, k, v, pos, ks, vs)
+    cache_bytes = 2 * B * KH * S * HD
+    print(
+        f"  decode_attn int8 {t*1e3:7.3f}ms "
+        f"{cache_bytes/t/1e9:6.0f} GB/s (one layer; x32 = {t*32*1e3:6.1f}ms)"
+    )
 
     kbf = jax.random.normal(key, (B, KH, S, HD), jnp.bfloat16)
     vbf = jax.random.normal(key, (B, KH, S, HD), jnp.bfloat16)
-    for impl in ("xla", "pallas"):
-        fn = jax.jit(partial(decode_attention, impl=impl))
-        try:
-            t = timeit(fn, q, kbf, vbf, pos, None, None)
-        except Exception as e:  # noqa: BLE001
-            print(f"  decode_attn bf16 {impl}: FAILED {type(e).__name__}: {e}"[:300])
-            continue
-        cache_bytes = 2 * B * KH * S * HD * 2
-        print(
-            f"  decode_attn bf16 {impl:6s} {t*1e3:7.3f}ms "
-            f"{cache_bytes/t/1e9:6.0f} GB/s (one layer; x32 = {t*32*1e3:6.1f}ms)"
-        )
+    t = timeit(fn, q, kbf, vbf, pos, None, None)
+    cache_bytes = 2 * B * KH * S * HD * 2
+    print(
+        f"  decode_attn bf16 {t*1e3:7.3f}ms "
+        f"{cache_bytes/t/1e9:6.0f} GB/s (one layer; x32 = {t*32*1e3:6.1f}ms)"
+    )
 
 
 if __name__ == "__main__":

@@ -207,7 +207,29 @@ def dense_attend(tag, b, sq, h, kh, d, cache_len, int8) -> KernelCase:
     )
 
 
-# --- decode attention over the paged pool -----------------------------------
+# --- attention over the paged pool -------------------------------------------
+
+
+def _pool_and_tables(key, b, m, pool_shape, idle_last=True):
+    """A seeded K and V pool and block tables [b, m] of scattered pages
+    (where the pool is smaller than the tables, rows share them); the last
+    row's is the trash page's where that row idles."""
+    kk, kv, kt = jax.random.split(key, 3)
+    pages = pool_shape[1]
+    own = jax.random.permutation(kt, jnp.arange(1, pages, dtype=jnp.int32))
+    table = own[jnp.arange(b * m) % (pages - 1)].reshape(b, m)
+    if idle_last:
+        table = table.at[-1].set(0)
+    return _normal(kk, pool_shape), _normal(kv, pool_shape), table
+
+
+def _gathered(q, k, v, layer, table, positions):
+    """[B, S, H, hd] x the pool -> what ops/kvcache.py's gather path gives."""
+    from substratus_tpu.ops.kvcache import paged_read
+
+    k_ctx, v_ctx = paged_read({"k": k, "v": v}, layer, table, q.dtype)
+    return dot_product_attention(
+        q, k_ctx, v_ctx, causal=True, q_positions=positions)
 
 
 def paged_decode(tag, b, max_seq, h, kh, d, pages, page=16,
@@ -217,33 +239,64 @@ def paged_decode(tag, b, max_seq, h, kh, d, pages, page=16,
     engine leaves one (position 0, a table of the trash page); the pages
     of a row lie scattered, and where the pool is smaller than the tables
     rows share them."""
-    from substratus_tpu.ops.kvcache import paged_read
     from substratus_tpu.ops.paged_attention import paged_decode_attention
 
-    m = max_seq // page
-
     def make_args(key):
-        kq, kk, kv, kt = jax.random.split(key, 4)
-        pool = (layers, pages, page, kh, d)
-        own = jax.random.permutation(kt, jnp.arange(1, pages, dtype=jnp.int32))
-        table = own[jnp.arange(b * m) % (pages - 1)].reshape(b, m)
+        kq, kp = jax.random.split(key)
+        k, v, table = _pool_and_tables(
+            kp, b, max_seq // page, (layers, pages, page, kh, d))
         positions = (max_seq - 1) * (b - 1 - jnp.arange(b)) // max(b - 1, 1)
-        table = table.at[-1].set(0)
         return (
-            _normal(kq, (b, h, d)), _normal(kk, pool), _normal(kv, pool),
-            jnp.int32(layers - 1), table, positions.astype(jnp.int32),
+            _normal(kq, (b, h, d)), k, v, jnp.int32(layers - 1), table,
+            positions.astype(jnp.int32),
         )
 
     def reference(q, k, v, layer, table, positions):
-        k_ctx, v_ctx = paged_read({"k": k, "v": v}, layer, table, q.dtype)
-        return dot_product_attention(
-            q[:, None], k_ctx, v_ctx, causal=True,
-            q_positions=positions[:, None],
-        )[:, 0]
+        return _gathered(
+            q[:, None], k, v, layer, table, positions[:, None])[:, 0]
 
     return KernelCase(
         f"paged_decode/{tag}/b{b}-s{max_seq}-h{h}", make_args,
         paged_decode_attention, reference, tol=2e-2,
+    )
+
+
+def paged_chunk(tag, b, s, max_seq, h, kh, d, pages, page=16,
+                layers=2) -> KernelCase:
+    """`s` query tokens a row against the same stacked pool: a prefill chunk
+    (b = 1: its last token is the last position of the table, so the walk
+    takes every page) or a speculative verify round (rows of every length
+    from a full table down, the last row idle: positions 0 .. s - 1, a
+    table of the trash page). The kernel is whatever
+    ops/kvcache.py::paged_attend picks for these widths on a TPU: the chunk
+    kernel for heads of 128, the gather itself for TinyLlama's 64."""
+    from substratus_tpu.ops.kvcache import paged_attend
+    from substratus_tpu.ops.paged_attention import (
+        LANES, paged_chunk_attention,
+    )
+
+    def make_args(key):
+        kq, kp = jax.random.split(key)
+        k, v, table = _pool_and_tables(
+            kp, b, max_seq // page, (layers, pages, page, kh, d),
+            idle_last=b > 1)
+        last = max_seq - 1 - (max_seq - s) * jnp.arange(b) // max(b - 1, 1)
+        positions = last[:, None] - (s - 1) + jnp.arange(s)[None, :]
+        return (
+            _normal(kq, (b, s, h, d)), k, v, jnp.int32(layers - 1), table,
+            positions.astype(jnp.int32),
+        )
+
+    def kernel(q, k, v, layer, table, positions, interpret=False):
+        if interpret:  # the CPU rehearsal: the op would take the gather
+            return paged_chunk_attention(
+                q, k, v, layer, table, positions, interpret=True)
+        return paged_attend(
+            {"k": k, "v": v}, layer, table, positions, q, q.dtype)
+
+    return KernelCase(
+        f"paged_chunk/{tag}/b{b}-q{s}-s{max_seq}-h{h}", make_args, kernel,
+        _gathered, tol=2e-2, mosaic=d % LANES == 0,
     )
 
 
@@ -323,6 +376,14 @@ def chip_cases() -> List[KernelCase]:
     cases.append(paged_decode("mistral-chat", 32, 2048, 32, 8, 128, 1793))
     cases.append(paged_decode("mistral-longdoc", 5, 8192, 32, 8, 128, 1921))
     cases.append(paged_decode("k-exaone", 64, 4096, 64, 8, 128, 10241))
+    # Their largest chunk programs' attention (the chat cell's median prompt
+    # is one chunk of 256), a spec_k=4 verify round over the chat cell's
+    # batch, and TinyLlama's chunk, which the op leaves on the gather.
+    cases.append(paged_chunk("mistral-longdoc", 1, 512, 8192, 32, 8, 128, 1921))
+    cases.append(paged_chunk("mistral-chat", 1, 256, 2048, 32, 8, 128, 1793))
+    cases.append(paged_chunk("k-exaone", 1, 512, 4096, 64, 8, 128, 10241))
+    cases.append(paged_chunk("mistral-verify", 32, 5, 2048, 32, 8, 128, 1793))
+    cases.append(paged_chunk("tinyllama", 1, 512, 1024, pages=513, **TINYLLAMA))
     return cases
 
 
@@ -338,6 +399,7 @@ def rehearsal_cases() -> List[KernelCase]:
         dense_attend("small", 2, 1, cache_len=128, int8=True, **w),
         q4_matmul("small", 8, 256, 128),
         paged_decode("small", 3, 128, h=4, kh=2, d=64, pages=17),
+        paged_chunk("small", 2, 16, 128, h=4, kh=2, d=64, pages=17),
     ]
 
 

@@ -25,25 +25,31 @@ the new rows and attends from q to each row's context, and reads off its
 inputs which of two realisations of that attention runs (no flag, option or
 model name decides):
 
-* **One query token a row over a bfloat16 pool, lowered for a TPU** (a decode
-  step): ops/paged_attention.py takes the stack as its HBM operand and
-  reads row b's live pages block_table[b, 0 .. positions[b] // page_size]
-  in place. Nothing of size max_batch x max_seq_len exists; a row costs what
-  it holds, an idle row (which the engine keeps at position 0) one page.
-  Under a mesh that shards the pool over kv_heads and nothing else, each
-  device runs the kernel on its own heads (`shard_map`, no collective).
-* **Everything else** (a prefill chunk or a speculative round, S > 1; an
-  int8 pool; a float32 pool; a pool sharded any other way; every platform
-  but the TPU): each sequence's context is gathered one table entry at a
-  time, as a slice of page_size contiguous rows (`kv.gather`; row by row out
-  of HBM the same gather measured 1.8x slower, and a [pages, page_size, ...]
-  view of the pool made the compiler re-tile a kv_heads shard of it in every
-  layer: PERF.md section 6, PR 25), into a slot-local [B, max_pages *
-  page_size] view, so the framework's standard masked attention applies
-  unchanged: gathered index j IS the token's absolute position in its
-  sequence, hence causal masking (k_pos <= q_pos) hides unwritten / foreign
-  pages. This is also the reference the kernel is tested against
-  (tests/test_paged_attention.py, ops/kernel_cases.py).
+* **A bfloat16 pool with heads a multiple of 128 wide, lowered for a TPU,
+  whatever the query length**: ops/paged_attention.py takes the stack as
+  its HBM operand and reads row b's live pages block_table[b, 0 ..
+  max(positions[b]) // page_size] in place. One query token a row (a decode
+  step) takes `paged_decode_attention`: nothing of size max_batch x
+  max_seq_len exists; a row costs what it holds, an idle row (which the
+  engine keeps at position 0) one page. More than one (a prefill chunk, a
+  speculative verify round, the draft's chunks) takes
+  `paged_chunk_attention`: the work follows the live context, not the
+  table, and no score is written to HBM. Under a mesh that shards the pool
+  over kv_heads and nothing else, each device runs the kernel on its own
+  heads (`shard_map`, no collective).
+* **Everything else** (an int8 pool; a float32 pool; heads of another width,
+  which Mosaic does not tile: TinyLlama-1.1B's 64; `kv_length` given; a pool
+  sharded any other way, or for a chunk over an odd number of KV heads a
+  device; every platform but the TPU): each sequence's context is gathered
+  one table entry at a time, as a slice of page_size contiguous rows
+  (`kv.gather`; row by row out of HBM the same gather measured 1.8x slower,
+  and a [pages, page_size, ...] view of the pool made the compiler re-tile
+  a kv_heads shard of it in every layer: PERF.md section 6, PR 25), into a
+  slot-local [B, max_pages * page_size] view, so the framework's standard
+  masked attention applies unchanged: gathered index j IS the token's
+  absolute position in its sequence, hence causal masking (k_pos <= q_pos)
+  hides unwritten / foreign pages. This is also the reference the kernels
+  are tested against (tests/test_paged_attention.py, ops/kernel_cases.py).
 
 A layer that attends to a window of W positions keeps no pages: each decode
 slot owns a ring of W rows a window layer (`ring_read_and_update`), beside
@@ -59,7 +65,9 @@ from jax.sharding import PartitionSpec as P
 
 from substratus_tpu.ops import scopes
 from substratus_tpu.ops.attention import dot_product_attention
-from substratus_tpu.ops.paged_attention import paged_decode_attention
+from substratus_tpu.ops.paged_attention import (
+    LANES, paged_chunk_attention, paged_decode_attention,
+)
 from substratus_tpu.ops.quant import dequantize_kv, quantize_kv
 from substratus_tpu.parallel.sharding import SERVE_RULES
 
@@ -144,57 +152,81 @@ def paged_attention(
     [B, S, H, hd]).
 
     Which of two realisations of that attention runs is read off the
-    inputs. One query token a row over a bfloat16 pool, lowered for a TPU:
-    ops/paged_attention.py reads each row's live pages in place. Everything
-    else (a chunk or a speculative round, an int8 pool, a pool sharded over
-    several devices, any other platform): the context of every table
-    position is gathered and ops/attention.py::dot_product_attention runs
-    over it, which is also what the kernel is tested against."""
+    inputs. A bfloat16 pool with heads a multiple of 128 wide, lowered for
+    a TPU: ops/paged_attention.py reads each row's live pages in place, a
+    decode step (S == 1) and a chunk or a speculative round (S > 1) alike.
+    Everything else (an int8 or float32 pool, another head width,
+    `kv_length` given, a placement `_kernel_for` names, any other
+    platform): the context of every table position is gathered and
+    ops/attention.py::dot_product_attention runs over it, which is also
+    what the kernels are tested against."""
     out = _write(pool, layer, block_table, positions, k_new, v_new)
+    return out, paged_attend(
+        out, layer, block_table, positions, q, dtype, kv_length)
+
+
+def paged_attend(pool, layer, block_table, positions, q, dtype,
+                 kv_length=None) -> jnp.ndarray:
+    """The attention half of `paged_attention`, over the pool as it is."""
 
     def gathered():
-        k_ctx, v_ctx = paged_read(out, layer, block_table, dtype)
+        k_ctx, v_ctx = paged_read(pool, layer, block_table, dtype)
         with jax.named_scope(scopes.ATTN_CORE):
             return dot_product_attention(
                 q, k_ctx, v_ctx, causal=True, q_positions=positions,
                 kv_length=kv_length,
             )
 
-    kernel = _kernel_for(out["k"]) if (
-        q.shape[1] == 1 and out["k"].dtype == jnp.bfloat16
-        and kv_length is None
-    ) else None
+    kernel = _kernel_for(pool["k"], q) if kv_length is None else None
     if kernel is None:
-        return out, gathered()
+        return gathered()
 
     def in_place():
         with jax.named_scope(scopes.ATTN_CORE):
             return kernel(
-                q[:, 0], out["k"], out["v"], layer, block_table,
-                positions[:, 0],
-            )[:, None]
+                q, pool["k"], pool["v"], layer, block_table, positions)
 
-    return out, jax.lax.platform_dependent(tpu=in_place, default=gathered)
+    return jax.lax.platform_dependent(tpu=in_place, default=gathered)
 
 
-def _kernel_for(k_pool):
-    """ops/paged_attention.py's kernel as this pool's placement lets it run:
-    as it is on one device; a shard of KV heads a device where the pool is
-    sharded over them and nothing else is sharded (each device's kernel
-    reads its own heads' share of every page: no collective); None for any
-    other placement, which no kernel here is written for."""
+def _one_token(q, k_pool, v_pool, layer, block_table, positions):
+    """paged_decode_attention behind the chunk kernel's signature."""
+    return paged_decode_attention(
+        q[:, 0], k_pool, v_pool, layer, block_table, positions[:, 0]
+    )[:, None]
+
+
+def _kernel_for(k_pool, q):
+    """The kernel of ops/paged_attention.py that reads this pool in place
+    for these queries ([B, S, H, hd]: the decode kernel for S == 1, the
+    chunk kernel beyond), as the pool's placement lets it run: as it is on
+    one device; a shard of KV heads a device where the pool is sharded over
+    them and nothing else is sharded (each device's kernel reads its own
+    heads' share of every page: no collective). None where no kernel here
+    is written for the case: a pool that is not bfloat16, heads Mosaic does
+    not tile (not a multiple of 128 wide), any other placement, and for the
+    chunk kernel an odd number of KV heads a device (it reads them two to a
+    32-bit word)."""
+    if k_pool.dtype != jnp.bfloat16 or k_pool.shape[4] % LANES:
+        return None
     mesh = jax.typeof(k_pool).sharding.mesh
     sharded = {name: n for name, n in mesh.shape.items() if n > 1}
-    if not sharded:
-        return paged_decode_attention
     pool = SERVE_RULES.mesh_axes(paged_cache_logical_axes()["k"])
     heads = pool[3]  # the mesh axis of kv_heads
-    if list(sharded) != [heads] or k_pool.shape[3] % sharded[heads]:
+    if sharded and (
+        list(sharded) != [heads] or k_pool.shape[3] % sharded[heads]
+    ):
         return None
+    chunk = q.shape[1] > 1
+    if chunk and k_pool.shape[3] // sharded.get(heads, 1) % 2:
+        return None
+    kernel = paged_chunk_attention if chunk else _one_token
+    if not sharded:
+        return kernel
     return jax.shard_map(
-        paged_decode_attention, mesh=mesh,
-        in_specs=(P(None, heads), pool, pool, P(), P(), P()),
-        out_specs=P(None, heads), check_vma=False,
+        kernel, mesh=mesh,
+        in_specs=(P(None, None, heads), pool, pool, P(), P(), P()),
+        out_specs=P(None, None, heads), check_vma=False,
     )
 
 

@@ -672,6 +672,12 @@ class Engine:
             # and max_batch x max_pages, the table it would gather whole.
             "decode_kv_pages_read_sum": 0,
             "decode_kv_pages_table_sum": 0,
+            # Added to once per chunk dispatch through a block-table row
+            # (_run_chunks): pages the chunk's attention needs (its last
+            # query position // page_size + 1) and max_pages, the row of
+            # the table a gather reads whole.
+            "prefill_kv_pages_read_sum": 0,
+            "prefill_kv_pages_table_sum": 0,
         }
         if self.slot_state:
             # What a slot-state family's forward counts (models/
@@ -2019,6 +2025,13 @@ class Engine:
             padded, clen = _pad_to_bucket(
                 prompt[offset : offset + chunk], chunk
             )
+            if bt_row is not None:
+                # The padded tail sits on position offset + clen
+                # (_chunk_prefill_jit); a full bucket ends one before it.
+                last = offset + min(clen, padded.shape[1] - 1)
+                self.stats["prefill_kv_pages_read_sum"] += (
+                    last // self.page_size + 1)
+                self.stats["prefill_kv_pages_table_sum"] += self.max_pages
             with self.timeline.phase(
                 "prefill", request_id=rid, bucket=padded.shape[1],
                 chunk=(offset - start) // chunk, tokens=clen,

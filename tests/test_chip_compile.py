@@ -12,8 +12,10 @@ The serving programs that carry the paged KV pool are compiled the same way:
 the optimized HLO of the engine's decode and chunk programs may not copy,
 slice or re-stack the pool (models/llama.py::forward carries it in place),
 nor, for a family with window layers, their rings (models/exaone_moe.py);
-and the decode program over a bfloat16 pool on one chip holds the
-paged-attention kernel and no gathered context (ops/kvcache.py). The dense
+and the decode and chunk programs over a bfloat16 pool hold the
+paged-attention kernels, no gathered context and no scores in HBM
+(ops/kvcache.py); at TinyLlama's head width of 64 they compile on the
+gather path. The dense
 slot cache's programs are compiled for every family that serves on it, and
 for a cache split over `sequence`.
 """
@@ -88,29 +90,29 @@ def test_sharded_kernel_compiles_for_v5e_2x2(v5e):
     jax.jit(partial(case.kernel, interpret=False)).lower(*args).compile()
 
 
-@pytest.mark.xfail(
-    strict=True,
-    reason="Slice shape along dimension 4 must be aligned to tiling (128), "
-           "but is 64",
-)
-def test_paged_decode_kernel_compiles_at_head_dim_64(v5e):
-    """TinyLlama-1.1B's heads are 64 wide and Mosaic refuses the paged
-    decode kernel there, so on a TPU `serve.main --config tinyllama-1.1b`
-    answers 500 since PR 28 (found by `chip_smoke.py`'s serve phase in
-    PR 29; ROADMAP.md S3c). ops/kvcache.py picks the kernel without looking
-    at the head width: the day it does, or the kernel takes 64, this
-    passes and the mark goes."""
+@pytest.mark.parametrize("s", [1, 512], ids=["step", "chunk"])
+def test_paged_decode_kernel_compiles_at_head_dim_64(s, v5e):
+    """TinyLlama-1.1B's heads are 64 wide and Mosaic tiles no kernel of
+    ops/paged_attention.py there ("Slice shape along dimension 4 must be
+    aligned to tiling (128), but is 64"): the op reads the width and leaves
+    such a pool on the gather path, a decode step and a chunk alike (from
+    PR 28 to PR 30 it picked the decode kernel regardless, and on a TPU
+    `serve.main --config tinyllama-1.1b` answered 500: ROADMAP.md S3c)."""
     from jax.sharding import SingleDeviceSharding
 
-    from substratus_tpu.ops.kernel_cases import TINYLLAMA, paged_decode
+    from substratus_tpu.ops import kvcache
+    from substratus_tpu.ops.kernel_cases import TINYLLAMA, paged_chunk
 
-    case = paged_decode("tinyllama", 8, 1024, pages=513, **TINYLLAMA)
+    case = paged_chunk("tinyllama", 8, s, 1024, pages=513, **TINYLLAMA)
     one_chip = SingleDeviceSharding(v5e[0])
-    args = jax.tree.map(
-        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+    q, k, v, layer, table, positions = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
         jax.eval_shape(case.make_args, jax.random.key(0)),
     )
-    jax.jit(case.kernel).lower(*args).compile()
+    assert kvcache._kernel_for(k, q) is None
+    hlo = jax.jit(case.kernel).lower(
+        q, k, v, layer, table, positions).compile().as_text()
+    assert "tpu_custom_call" not in hlo and "kv.gather" in hlo
 
 
 # The chat cell's engine (benchmarks/traffic/chat.json): Mistral-7B, int8
@@ -132,19 +134,31 @@ def _pool_moving_ops(hlo: str, sizes) -> list:
     return found
 
 
-def _reads_pages_in_place(hlo: str, context: int) -> bool:
-    """The decode program's attention is the paged-attention kernel
-    (ops/paged_attention.py) and nothing in the program has a result of
-    `context` = max_batch x max_seq_len x kv_heads x head_dim elements: the
-    gathered K and V are gone, not moved."""
-    sized = [
-        m.group(0)
-        for m in re.finditer(r"= \w+\[([\d,]+)\]\S* [\w-]+\(", hlo)
-        if math.prod(map(int, m.group(1).split(","))) == context
-    ]
-    kernel = re.search(
-        r'custom_call_target="tpu_custom_call".*paged_decode_attention', hlo)
-    return bool(kernel) and not sized
+def _reads_pages_in_place(hlo: str, kernel: str, rows: int, seq: int,
+                          kv_heads: int, head_dim: int,
+                          scores: int = 0) -> bool:
+    """The program's attention is the named kernel of
+    ops/paged_attention.py, and nothing in the program is a gathered K or V
+    (a result [..., kv_heads, head_dim] of rows x seq positions, flat or
+    as pages of _PAGE: gone, not moved) nor a float32 result of `scores` = heads x S x seq elements (a
+    chunk's scores never reach HBM)."""
+    sized = []
+    for m in re.finditer(r"= (\w+)\[([\d,]+)\]\S* [\w-]+\(", hlo):
+        dims = list(map(int, m.group(2).split(",")))
+        n = math.prod(dims)
+        lead = set(dims[:-2])
+        context = (dims[-2:] == [kv_heads, head_dim]
+                   and n == rows * seq * kv_heads * head_dim
+                   and (seq in lead or {rows * seq // _PAGE, _PAGE} <= lead))
+        if context or (m.group(1) == "f32" and n == scores):
+            sized.append(m.group(0))
+    found = re.search(
+        r'custom_call_target="tpu_custom_call".*' + kernel, hlo)
+    return bool(found) and not sized
+
+
+_KERNEL = {"decode": "paged_decode_attention",
+           "chunk": "paged_chunk_attention"}
 
 
 def _described(v5e, eng, **mesh_axes):
@@ -252,20 +266,24 @@ def test_serving_programs_leave_the_kv_pool_in_place(
     limit = sum(s.dtype.itemsize * s.size for s in pool.values()) / 2
     if quantized:
         limit += 2 * 4 * _B * _S * cfg.n_kv_heads * cfg.head_size
-    context = _B * _S * cfg.n_kv_heads * cfg.head_size
+    rows = {"decode": _B, "chunk": 1}
     for name, lowered in programs.items():
         compiled = lowered.compile()
         hlo = compiled.as_text()
         assert _pool_moving_ops(hlo, sizes) == [], name
         temp = compiled.memory_analysis().temp_size_in_bytes
         assert temp < limit / tensor, (name, temp, limit)
-        # One token a row over a bfloat16 pool reads live pages in place
-        # (under the `tensor` mesh each chip those of its own KV heads); a
-        # chunk and an int8 pool gather every table position
-        # (ops/kvcache.py).
-        in_place = name == "decode" and not quantized
-        assert _reads_pages_in_place(hlo, context // tensor) == in_place, name
-        assert ("kv.gather" in hlo) != in_place, name
+        # A bfloat16 pool is read in place, live pages only, by a decode
+        # step and by a chunk (under the `tensor` mesh each chip reads its
+        # own KV heads): no gathered K or V of rows x max_seq_len, no
+        # float32 scores of S x max_seq_len. An int8 pool gathers every
+        # table position (ops/kvcache.py).
+        scores = cfg.n_heads * _CHUNK * _S if name == "chunk" else 0
+        in_place = _reads_pages_in_place(
+            hlo, _KERNEL[name], rows[name], _S, cfg.n_kv_heads // tensor,
+            cfg.head_size, scores // tensor)
+        assert in_place == (not quantized), name
+        assert ("kv.gather" in hlo) == quantized, name
 
 
 # The reason-mixed cell's engine (benchmarks/traffic/reason-mixed.json):
@@ -353,16 +371,61 @@ def test_exaone_programs_leave_pool_and_rings_in_place(v5e):
         for scope in ("kv.ring", "attn.window", "moe.shared", "moe.router",
                       "moe.experts", "attn.core"):
             assert scope in hlo, (name, scope)
-        # the step's global layers read live pages in place: no gather, no
-        # K or V of max_batch x max_seq_len; the chunk gathers
-        in_place = _reads_pages_in_place(
-            hlo, _X_B * _X_S * cfg.n_kv_heads * cfg.head_size)
-        assert in_place == (name == "decode"), name
-        assert ("kv.gather" in hlo) == (name == "chunk"), name
+        # the global layers of the step and of the chunk read live pages
+        # in place: no gather, no K or V of rows x max_seq_len, no float32
+        # scores of 512 x max_seq_len
+        rows = _X_B if name == "decode" else 1
+        assert _reads_pages_in_place(
+            hlo, _KERNEL[name], rows, _X_S, cfg.n_kv_heads, cfg.head_size,
+            cfg.n_heads * _CHUNK * _X_S if name == "chunk" else 0), name
+        assert "kv.gather" not in hlo, name
         # the chunk multiplies pairs grouped by expert, one block of one
         # expert's rows at a time; the decode step every held expert
         grouped = "s8[1,1,6144,2048]" in hlo
         assert grouped == (name == "chunk"), name
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk"])
+def test_tinyllama_paged_programs_compile_for_v5e(program, v5e):
+    """What `serve.main --config tinyllama-1.1b` compiles on a TPU at its
+    defaults (8 slots of 1,024, chunks of 512, the paged layout): heads of
+    64, so both programs gather (S3c: the decode program held a kernel
+    Mosaic refuses, and chip_smoke.py's serve phases answered 500)."""
+    from substratus_tpu.models import llama
+    from substratus_tpu.serve.engine import Engine, EngineConfig
+
+    cfg = llama.CONFIGS["tinyllama-1.1b"]
+    b, s = 8, 1024
+    eng = Engine(cfg, None, EngineConfig(
+        max_batch=b, max_seq_len=s, max_prefill_len=_CHUNK, page_size=_PAGE,
+        kv_pool_tokens=1,
+    ))
+    assert eng.paged
+    placed, arr = _described(v5e, eng)
+    params = placed(
+        jax.eval_shape(lambda key: llama.init_params(cfg, key),
+                       jax.random.key(0)),
+        llama.param_logical_axes(cfg),
+    )
+    pool = placed(
+        jax.eval_shape(
+            lambda: llama.init_paged_cache(cfg, b * s // _PAGE + 1, _PAGE)),
+        llama.paged_cache_logical_axes(cfg, False),
+    )
+    m = s // _PAGE
+    if program == "decode":
+        lowered = eng._decode_fn.lower(
+            params, pool, arr((b, m)), arr((b,)), arr((b,)),
+            arr((b,), jnp.float32), arr((b,), jnp.float32),
+            arr(eng.key.shape, eng.key.dtype),
+        )
+    else:
+        lowered = Engine._chunk_prefill_jit.lower(
+            llama, cfg, params, pool, arr((1, _CHUNK)), arr(()), arr(()),
+            arr((1, m)),
+        )
+    hlo = lowered.compile().as_text()
+    assert "tpu_custom_call" not in hlo and "kv.gather" in hlo
 
 
 # The dense slot cache [L, B, KH, S, hd] at the server's defaults (8 slots of

@@ -236,3 +236,59 @@ def test_slot_pages_count_a_shared_page_once():
     assert sp.live_pages == 3  # `a` still held by slot 1
     sp.release(1, alloc)
     assert sp.live_pages == 0 and alloc.used_pages == 0
+
+
+@pytest.mark.parametrize("prompt,chunks", [
+    (29, [(0, 16), (16, 13)]),  # a full bucket, then a padded one
+    (48, [(0, 16), (16, 16), (32, 16)]),  # the last ends on a page's end
+    (7, [(0, 7)]),
+], ids=["two-chunks", "three-full", "one-padded"])
+def test_a_chunk_counts_the_pages_it_needs_against_its_table_row(
+    prompt, chunks
+):
+    """Every chunk dispatch adds the pages its attention needs (its last
+    query position // page_size + 1: a full bucket's last token, a padded
+    bucket's tail clamped one past the prompt) and max_pages, the row a
+    gather reads whole; a second request that hits the prefix cache starts
+    its chunks after the pages it reuses."""
+    from benchmarks.harness import manifest as M
+
+    read = M.layer_reader("prefill_kv_read_share.tok")
+    cfg = llama.CONFIGS["tiny"].replace(dtype=jnp.float32)
+    params = llama.init_params(cfg, jax.random.key(0))
+    page, bucket = 4, 16
+    eng = Engine(cfg, params, EngineConfig(
+        max_batch=2, max_seq_len=64, max_prefill_len=bucket, page_size=page))
+    max_pages = 64 // page
+    tokens = list(range(1, prompt + 1))
+    eng.start()
+    try:
+        eng.generate(tokens, max_tokens=2, temperature=0.0)
+        first = dict(eng.stats)
+        eng.generate(tokens, max_tokens=2, temperature=0.0)
+    finally:
+        eng.stop()
+
+    def pages(offset, n):
+        return (offset + min(n, bucket - 1)) // page + 1
+
+    assert first["prefill_kv_pages_table_sum"] == len(chunks) * max_pages
+    want = sum(pages(*c) for c in chunks)
+    assert first["prefill_kv_pages_read_sum"] == want
+    run = {"counters": {"stats": first}, "rehearse": False}
+    assert read(run) == pytest.approx(100.0 * want / (len(chunks) * max_pages))
+    # a CPU rehearsal writes nothing under a `prefill_` name
+    assert read(dict(run, rehearse=True)) is None
+    # a program without the counters (the parent commit) reports nothing
+    assert read({"counters": {"stats": {}}, "rehearse": False}) is None
+    # the same prompt again: its whole pages before the last token are
+    # reused, and the one chunk left starts after them
+    reused = (prompt - 1) // page * page
+    assert prompt - reused <= bucket
+    st = eng.stats
+    assert st["prefix_hit_tokens"] == reused
+    assert (st["prefill_kv_pages_table_sum"]
+            - first["prefill_kv_pages_table_sum"]) == max_pages
+    assert (st["prefill_kv_pages_read_sum"]
+            - first["prefill_kv_pages_read_sum"]
+            ) == pages(reused, prompt - reused)

@@ -1,8 +1,10 @@
-"""ops/paged_attention.py (interpret mode) against the path it replaces in a
-decode step, which is what ops/kvcache.py::paged_attention runs on the CPU:
-the context of every table position gathered (`paged_read`) and
-ops/attention.py::dot_product_attention over it. Then the whole engine, the
-kernel forced in place of the gather, token for token.
+"""ops/paged_attention.py (interpret mode) against the path it replaces,
+which is what ops/kvcache.py::paged_attention runs on the CPU: the context
+of every table position gathered (`paged_read`) and ops/attention.py::
+dot_product_attention over it. One query token a row (a decode step) takes
+the decode kernel, more (a prefill chunk, a verify round) the chunk kernel.
+Then the whole engine, the kernels forced in place of the gather, token for
+token.
 """
 import jax
 import jax.numpy as jnp
@@ -11,7 +13,7 @@ import pytest
 
 from substratus_tpu.ops import kvcache
 from substratus_tpu.ops.paged_attention import (
-    FOLD_PAGES, paged_decode_attention,
+    CHUNK_PAGES, FOLD_PAGES, paged_chunk_attention, paged_decode_attention,
 )
 
 BS, M, KH, HD, LAYERS = 16, 40, 2, 64, 3  # a table of 640 positions: two
@@ -19,42 +21,53 @@ PAGES = 1 + 4 * M                          # DMA blocks of FOLD_PAGES[-1]
 FULL = M * BS
 TOL = {jnp.bfloat16: 2e-2, jnp.float32: 1e-5}
 assert FOLD_PAGES[-1] < M < 2 * FOLD_PAGES[-1]
+assert CHUNK_PAGES < M < 2 * CHUNK_PAGES
+BUCKETS = [16, 32, 64, 128, 256, 512]  # the engine's prefill buckets
 
 
 def _normal(key, shape, dtype):
     return jax.random.normal(key, shape, jnp.float32).astype(dtype)
 
 
-def _case(dtype, group, lengths, layer=1, tables=None, seed=0):
-    """A seeded pool, a step's q and new K/V rows for rows of `lengths`
-    tokens (the new row is the last of them), and block tables of
-    scattered pages unless given."""
+def _case(dtype, group, lengths, layer=1, tables=None, seed=0, s=1, real=None,
+          kv_heads=KH):
+    """A seeded pool, a call's q and new K/V rows for rows of `lengths`
+    tokens (the `s` new rows are the last of them; of these only the first
+    `real` are a prompt's and the padded tail is clamped onto the position
+    after them, as the engine's chunk program clamps it), and block tables
+    of scattered pages unless given."""
     keys = jax.random.split(jax.random.key(seed), 6)
     b = len(lengths)
-    shape = (LAYERS, PAGES, BS, KH, HD)
+    shape = (LAYERS, PAGES, BS, kv_heads, HD)
     pool = {"k": _normal(keys[0], shape, dtype),
             "v": _normal(keys[1], shape, dtype)}
-    q = _normal(keys[2], (b, 1, KH * group, HD), dtype)
-    k_new = _normal(keys[3], (b, 1, KH, HD), dtype)
-    v_new = _normal(keys[4], (b, 1, KH, HD), dtype)
+    q = _normal(keys[2], (b, s, kv_heads * group, HD), dtype)
+    k_new = _normal(keys[3], (b, s, kv_heads, HD), dtype)
+    v_new = _normal(keys[4], (b, s, kv_heads, HD), dtype)
     if tables is None:
         tables = np.asarray(
             jax.random.permutation(keys[5], np.arange(1, PAGES))[: b * M]
         ).reshape(b, M)
+    first = jnp.asarray(lengths, jnp.int32)[:, None] - s
+    positions = first + jnp.arange(s, dtype=jnp.int32)[None, :]
+    if real is not None:
+        positions = jnp.minimum(positions, first + real)
     return (pool, jnp.int32(layer), jnp.asarray(tables, jnp.int32),
-            jnp.asarray(lengths, jnp.int32)[:, None] - 1, q, k_new, v_new)
+            positions, q, k_new, v_new)
 
 
 def _reference(pool, layer, table, positions, q, k_new, v_new):
-    out, attn = kvcache.paged_attention(
+    return kvcache.paged_attention(
         pool, layer, table, positions, q, k_new, v_new, q.dtype)
-    return out, attn[:, 0]
 
 
 def _kernel(pool, layer, table, positions, q):
+    if q.shape[1] > 1:
+        return paged_chunk_attention(
+            q, pool["k"], pool["v"], layer, table, positions, interpret=True)
     return paged_decode_attention(
         q[:, 0], pool["k"], pool["v"], layer, table, positions[:, 0],
-        interpret=True)
+        interpret=True)[:, None]
 
 
 def _close(got, want, dtype):
@@ -77,10 +90,11 @@ def test_a_row_of_every_length_matches_the_gathered_attention(
     _close(_kernel(out, layer, table, pos, q), want, dtype)
 
 
+@pytest.mark.parametrize("s", [1, 16], ids=["step", "chunk"])
 @pytest.mark.parametrize("layer", [0, 1, LAYERS - 1])
-def test_the_layer_is_an_offset_into_the_stack(layer):
+def test_the_layer_is_an_offset_into_the_stack(layer, s):
     pool, _, table, pos, q, k_new, v_new = _case(
-        jnp.bfloat16, 4, [37, FULL, 200], layer=layer, seed=layer)
+        jnp.bfloat16, 4, [37, FULL, 200], layer=layer, seed=layer, s=s)
     out, want = _reference(pool, jnp.int32(layer), table, pos, q, k_new,
                            v_new)
     _close(_kernel(out, jnp.int32(layer), table, pos, q), want, jnp.bfloat16)
@@ -91,9 +105,11 @@ def test_the_layer_is_an_offset_into_the_stack(layer):
         np.asarray(want, np.float32), atol=TOL[jnp.bfloat16])
 
 
-def test_scattered_pages_and_a_prefix_two_rows_share():
+@pytest.mark.parametrize("s", [1, 32], ids=["step", "chunk"])
+def test_scattered_pages_and_a_prefix_two_rows_share(s):
     """Rows 0 and 1 hold the same first five pages (a prefix hit) and
-    their own after them; every page lies somewhere else in the pool."""
+    their own after them; every page lies somewhere else in the pool. (A
+    chunk's rows are written after the shared pages.)"""
     tables = np.zeros((3, M), np.int32)
     order = np.random.default_rng(3).permutation(np.arange(1, PAGES))
     tables[0, :9] = order[:9]
@@ -102,23 +118,26 @@ def test_scattered_pages_and_a_prefix_two_rows_share():
     tables[2] = order[40:40 + M][::-1]
     lengths = [9 * BS - 3, 12 * BS, FULL - 7]
     pool, layer, table, pos, q, k_new, v_new = _case(
-        jnp.bfloat16, 4, lengths, tables=tables)
+        jnp.bfloat16, 4, lengths, tables=tables, s=s)
     out, want = _reference(pool, layer, table, pos, q, k_new, v_new)
     _close(_kernel(out, layer, table, pos, q), want, jnp.bfloat16)
 
 
+@pytest.mark.parametrize("s", [1, 16], ids=["step", "chunk"])
 @pytest.mark.parametrize("garbage", [float("nan"), 3e37, -3e37],
                          ids=["nan", "huge", "-huge"])
-def test_a_row_sees_its_own_pages_up_to_its_own_position(garbage):
+def test_a_row_sees_its_own_pages_up_to_its_own_position(garbage, s):
     """An idle row as the engine leaves it (position 0, a table of the
-    trash page: it costs the one page its own write landed on), a row of
-    one page and a row of a full table, in a pool where every position no
-    row may see holds garbage: other layers, pages nobody owns, the rest
-    of each row's last page, and the idle row's table past its first
-    entry pointing at pages of garbage too. The answer is the clean
-    pool's."""
-    lengths = [1, BS - 4, FULL]
-    pool, layer, table, pos, q, k_new, v_new = _case(jnp.bfloat16, 8, lengths)
+    trash page: it costs the one page its own write landed on; for a chunk,
+    a row whose `s` tokens are all it holds), a row of one page (a chunk:
+    of two, the second barely begun) and a row of a full table, in a pool
+    where every position no query of the row may see holds garbage: other
+    layers, pages nobody owns, the rest of each row's last page, and the
+    first row's table past its first entry pointing at pages of garbage
+    too. The answer is the clean pool's."""
+    lengths = [s, BS - 4 + (s > 1) * s, FULL]
+    pool, layer, table, pos, q, k_new, v_new = _case(
+        jnp.bfloat16, 8, lengths, s=s)
     table = table.at[0].set(0)
     clean, want = _reference(pool, layer, table, pos, q, k_new, v_new)
     seen = np.zeros((LAYERS, PAGES, BS), bool)
@@ -141,12 +160,69 @@ def test_a_row_sees_its_own_pages_up_to_its_own_position(garbage):
         np.asarray(got[1:], np.float32), np.asarray(alone, np.float32))
 
 
+@pytest.mark.parametrize("where", ["start", "mid-page", "table-end"])
+@pytest.mark.parametrize("bucket", BUCKETS)
+@pytest.mark.parametrize("group", [4, 8])
+def test_a_chunk_of_every_bucket_matches_the_gathered_attention(
+    group, bucket, where
+):
+    """A prefill chunk of each of the engine's buckets: the prompt's first
+    (nothing before it), one that starts in the middle of a page, and one
+    that ends on the last position of the table; beside it a row of another
+    length, whose pages the first row's walk must not touch."""
+    before = {"start": 0, "mid-page": 3 * BS + 5,
+              "table-end": FULL - bucket}[where]
+    pool, layer, table, pos, q, k_new, v_new = _case(
+        jnp.bfloat16, group, [before + bucket, bucket + BS + 3], s=bucket)
+    out, want = _reference(pool, layer, table, pos, q, k_new, v_new)
+    _close(_kernel(out, layer, table, pos, q), want, jnp.bfloat16)
+
+
+@pytest.mark.parametrize("real", [1, 19, 31])
+def test_a_chunks_padded_tail_is_clamped_onto_one_position(real):
+    """The engine pads a prompt's last chunk to its bucket and clamps the
+    tail onto the one position after the prompt: the kernel reads the
+    positions it is given, consecutive or not."""
+    s = 32
+    pool, layer, table, pos, q, k_new, v_new = _case(
+        jnp.bfloat16, 4, [3 * BS + 5 + s, FULL], s=s, real=real)
+    assert int(pos[0, -1]) == int(pos[0, real]) == 3 * BS + 5 + real
+    out, want = _reference(pool, layer, table, pos, q, k_new, v_new)
+    _close(_kernel(out, layer, table, pos, q), want, jnp.bfloat16)
+
+
+@pytest.mark.parametrize("s", [2, 5], ids=["k1", "k4"])
+def test_a_verify_round_matches_the_gathered_attention(s):
+    """A speculative round: every slot brings k + 1 consecutive positions
+    from its own length on, one of them past the table's reach (its writes
+    go to the trash page, ops/kvcache.py::_write; its queries see the whole
+    table), one idle at position 0."""
+    lengths = [s, 7 * BS + s, FULL - 1, FULL + 2]
+    pool, layer, table, pos, q, k_new, v_new = _case(
+        jnp.bfloat16, 4, lengths, s=s)
+    out, want = _reference(pool, layer, table, pos, q, k_new, v_new)
+    _close(_kernel(out, layer, table, pos, q), want, jnp.bfloat16)
+
+
+@pytest.mark.parametrize("kv_heads", [2, 4, 8])
+def test_a_chunk_reads_each_kv_head_out_of_the_pages(kv_heads):
+    """The chunk kernel takes the heads out of a page two to a 32-bit word,
+    every (KH / 2)th word-row: one pair, two and four."""
+    pool, layer, table, pos, q, k_new, v_new = _case(
+        jnp.bfloat16, 4, [5 * BS + 7, FULL], s=64, kv_heads=kv_heads)
+    out, want = _reference(pool, layer, table, pos, q, k_new, v_new)
+    _close(_kernel(out, layer, table, pos, q), want, jnp.bfloat16)
+
+
 def _force_the_kernel(monkeypatch):
     """On the CPU the platform choice (ops/kvcache.py::paged_attention)
-    takes the gather; a test steers it to the kernel, interpreted."""
+    takes the gather; a test steers it to the kernel, interpreted. The
+    chunk program is one jit for every engine of a process
+    (Engine._chunk_prefill_jit), so what was traced before is dropped."""
     monkeypatch.setattr(
         jax.lax, "platform_dependent",
         lambda *args, tpu, default: tpu(*args))
+    jax.clear_caches()
 
 
 def _greedy(model, cfg, params, prompts, max_tokens, **ec):
@@ -172,19 +248,26 @@ def _greedy(model, cfg, params, prompts, max_tokens, **ec):
 def test_the_engine_serves_the_same_tokens_through_the_kernel(
     family, monkeypatch, pallas_interpret
 ):
-    """Greedy tokens of a tiny paged engine, decode steps through the
-    kernel, equal those of the gather path: three requests of unlike
-    lengths over four slots, so one row idles throughout. (The prompts'
-    seed matters: on random weights two logits now and then lie within one
-    bfloat16 rounding of an attention output, and of twelve seeded sets two
-    flipped one request's token there.)"""
+    """Greedy tokens of a tiny paged engine, prefill chunks and decode
+    steps through the kernels, equal those of the gather path: three
+    requests of unlike lengths over four slots, so one row idles
+    throughout; the longest prompt takes three chunks. Heads are 128 wide:
+    the op leaves any other width on the gather path. The sparse family
+    routes every token to all its experts here: the kernels' outputs lie
+    within one bfloat16 rounding of the gather's, and on random weights
+    that flips a top-4-of-16 choice every few tokens, which says nothing
+    of attention. (The prompts' seed matters: on random weights two logits
+    now and then lie within one bfloat16 rounding of an attention output,
+    and of twelve seeded sets two flipped one request's token there.)"""
     from substratus_tpu.models import exaone_moe, llama
 
     if family == "llama":
-        model, cfg = llama, llama.CONFIGS["tiny"]
+        model, cfg = llama, llama.CONFIGS["tiny"].replace(dim=512)
     else:
-        model, cfg = exaone_moe, exaone_moe.CONFIGS["tiny-exaone-moe"]
-    assert cfg.dtype == jnp.bfloat16
+        model = exaone_moe
+        cfg = exaone_moe.CONFIGS["tiny-exaone-moe"].replace(
+            head_dim=128, n_experts_per_token=16)
+    assert cfg.dtype == jnp.bfloat16 and cfg.head_size == 128
     params = model.init_params(cfg, jax.random.key(0))
     toks = np.asarray(jax.random.randint(
         jax.random.key(3), (64,), 0, cfg.vocab_size))
@@ -192,7 +275,15 @@ def test_the_engine_serves_the_same_tokens_through_the_kernel(
     ec = dict(max_batch=4, max_seq_len=96, max_prefill_len=16, page_size=4)
     want, _ = _greedy(model, cfg, params, prompts, 12, **ec)
     _force_the_kernel(monkeypatch)
+    picked = []
+    for name in ("paged_chunk_attention", "_one_token"):
+        kernel = getattr(kvcache, name)
+        monkeypatch.setattr(
+            kvcache, name,
+            lambda *a, _k=kernel, _n=name: picked.append(_n) or _k(*a))
     got, eng = _greedy(model, cfg, params, prompts, 12, **ec)
+    jax.clear_caches()  # no later test meets a program traced here
+    assert set(picked) == {"paged_chunk_attention", "_one_token"}
     assert got == want
     assert all(len(ids) == 12 for ids in got)
     assert (eng.positions[~eng.active] == 0).all()

@@ -367,6 +367,58 @@ def convert_exaone_moe_state_dict(sd: Mapping[str, Any], cfg: Any,
     )
 
 
+def config_from_hf_lfm2_moe(hf_cfg: Any):
+    """A transformers `lfm2_moe` config.json (LFM2-24B-A2B) -> Lfm2MoeConfig:
+    the operators come from its `layer_types` list, the dense layers from
+    `num_dense_layers`. Every expert is held."""
+    from substratus_tpu.models.lfm2_moe import Lfm2MoeConfig
+
+    get = lambda name, default=None: getattr(hf_cfg, name, default)
+    n = hf_cfg.num_hidden_layers
+    rope = get("rope_parameters") or {}
+    if not isinstance(rope, dict):
+        rope = vars(rope)
+    if get("conv_bias", False) or not get("use_expert_bias", True):
+        raise NotImplementedError(
+            "lfm2_moe: conv_bias and a router without its expert bias are "
+            "not written")
+    return Lfm2MoeConfig(
+        vocab_size=hf_cfg.vocab_size,
+        dim=hf_cfg.hidden_size,
+        n_layers=n,
+        n_heads=hf_cfg.num_attention_heads,
+        n_kv_heads=get("num_key_value_heads") or hf_cfg.num_attention_heads,
+        head_dim=get("head_dim")
+        or hf_cfg.hidden_size // hf_cfg.num_attention_heads,
+        hidden_dim=hf_cfg.intermediate_size,
+        moe_hidden_dim=hf_cfg.moe_intermediate_size,
+        n_dense_layers=get("num_dense_layers", 0),
+        n_experts=hf_cfg.num_experts,
+        n_experts_per_token=hf_cfg.num_experts_per_tok,
+        routed_scaling_factor=float(get("routed_scaling_factor", 1.0)),
+        norm_topk_prob=bool(get("norm_topk_prob", True)),
+        layer_types=tuple(hf_cfg.layer_types[:n]),
+        conv_taps=get("conv_L_cache", 3),
+        rope_theta=float(rope.get("rope_theta", get("rope_theta", 1e6))),
+        norm_eps=get("norm_eps", 1e-5),
+        max_seq_len=get("max_position_embeddings", 4096),
+        tie_embeddings=bool(get("tie_embedding", True)),
+    )
+
+
+def convert_lfm2_moe_state_dict(sd: Mapping[str, Any], cfg: Any,
+                                dtype=jnp.bfloat16) -> Params:
+    """Not written, as convert_exaone_moe_state_dict is not: the tensor
+    names of the published lfm2_moe checkpoint were not at hand (no
+    network). The family is served from a named config (random weights) or
+    an orbax checkpoint of models/lfm2_moe.py's own tree."""
+    raise NotImplementedError(
+        "lfm2_moe: the config.json is read (config_from_hf_lfm2_moe) but no "
+        "converter maps the checkpoint's tensors onto models/lfm2_moe.py's "
+        "tree yet"
+    )
+
+
 def _dispatch_hf(model_type: str):
     """transformers model_type -> (config_fn, convert_fn), via the family
     registry (models/registry.py is the single dispatch table)."""
@@ -381,6 +433,8 @@ def _dispatch_hf(model_type: str):
         return config_from_hf_falcon, convert_falcon_state_dict
     if family == "exaone_moe":
         return config_from_hf_exaone_moe, convert_exaone_moe_state_dict
+    if family == "lfm2_moe":
+        return config_from_hf_lfm2_moe, convert_lfm2_moe_state_dict
     raise NotImplementedError(
         f"unsupported HF model_type {model_type!r} "
         f"(supported: {sorted(HF_MODEL_TYPES)})"

@@ -4,9 +4,10 @@ What this block has that models/llama.py's does not, and where each lives:
 
   * a stack that is not uniform: `mlp_layer_types` gives leading dense
     layers and then sparse ones, `layer_types` a period of window layers
-    and one global layer. `layer_plan` unrolls the layers before the first
-    whole period and scans over the periods; a layer's weights are indexed
-    out of their stacks by layer number, never sliced off beforehand;
+    and one global layer. `layer_plan` (models/hybrid.py) unrolls the
+    layers before the first whole period and scans over the periods; a
+    layer's weights are indexed out of their stacks by layer number, never
+    sliced off beforehand;
   * two kinds of history in one cache: global layers write the paged pool
     (`k`, `v`: [Lg, P, bs, KH, hd], ops/kvcache.py::paged_attention,
     as Llama), window layers a ring of `sliding_window` rows a decode slot
@@ -15,15 +16,13 @@ What this block has that models/llama.py's does not, and where each lives:
     (`slots`) and which tokens are real (`valid`);
   * RMSNorm over the head dimension of q and k; rotary on window layers
     only (global layers carry no position);
-  * sigmoid-routed experts beside a shared one (`_moe`): scores in
-    float32, the top k of score + bias chosen, weights the chosen scores
-    normalised over all k and scaled. The layer is told which experts it
-    holds (`held_experts` = (first, count) of `n_experts`): it routes over
-    all of them, computes the part its own experts give, adds the shared
-    expert, and passes that partial sum on. With every expert held that is
-    the whole layer; with a share it is what one rank of expert
-    parallelism computes before the exchange, and no code here stands in
-    for the other ranks. Dropless and exact in every path.
+  * sigmoid-routed experts beside a shared one (models/hybrid.py::moe,
+    the layer models/lfm2_moe.py runs too): scores in float32, the top k
+    of score + bias chosen, weights the chosen scores normalised over all
+    k and scaled. The layer is told which experts it holds
+    (`held_experts` = (first, count) of `n_experts`): it routes over all
+    of them, computes the part its own experts give, adds the shared
+    expert, and passes that partial sum on.
 
 The multi-token-prediction layer of the published model (an extra head for
 self-drafting) is not part of the forward pass and is not built.
@@ -33,15 +32,16 @@ from __future__ import annotations
 import dataclasses
 from dataclasses import dataclass
 from functools import partial
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax import lax
 
+from substratus_tpu.models import hybrid
+from substratus_tpu.models.hybrid import gated as _gated, take as _take
 from substratus_tpu.ops import kvcache, scopes
 from substratus_tpu.ops.attention import dot_product_attention
-from substratus_tpu.ops.basics import rms_norm, rope, swiglu
+from substratus_tpu.ops.basics import rms_norm, rope
 from substratus_tpu.ops.quant import materialize, qeinsum, qeinsum_w8a8
 
 Params = Dict[str, Any]
@@ -80,6 +80,7 @@ class ExaoneMoeConfig:
     held_experts: Optional[Tuple[int, int]] = None
     routed_scaling_factor: float = 2.5
     norm_topk_prob: bool = True
+    route_norm_eps: float = 1e-20  # added to the sum the weights divide by
     # One entry a layer. None: the published pattern, three window layers
     # and a global one, the first layer's MLP dense.
     layer_types: Optional[Tuple[str, ...]] = None
@@ -146,38 +147,9 @@ CONFIGS: Dict[str, ExaoneMoeConfig] = {
 # -- the stack's shape ---------------------------------------------------------
 
 def layer_plan(cfg: ExaoneMoeConfig) -> Tuple[int, int, int]:
-    """(head, period, periods): the first `head` layers run one by one,
-    the rest as `periods` repeats of `period` layers, scanned. Of all such
-    splits the one that traces the fewest blocks; on a tie the most
-    repeats, then the shortest head."""
-    kinds = list(zip(cfg.layer_types, cfg.mlp_layer_types))
-    n = len(kinds)
-
-    def cost(split):
-        head, period, reps = split
-        return (head + period, -reps, head)
-
-    best = (n, 0, 0)
-    for period in range(1, n + 1):
-        for head in range(n - period, -1, -1):
-            if head < n - period and kinds[head] != kinds[head + period]:
-                break  # a longer run of this period only adds mismatches
-            if (n - head) % period == 0:
-                best = min(best, (head, period, (n - head) // period),
-                           key=cost)
-    return best
-
-
-def _index_of_kind(cfg: ExaoneMoeConfig) -> List[Dict[str, int]]:
-    """For every layer, its index within each stack it reads: among the
-    window or global layers (cache), among the dense or sparse (MLP)."""
-    seen = {WINDOW: 0, GLOBAL: 0, DENSE: 0, SPARSE: 0}
-    out = []
-    for a, m in zip(cfg.layer_types, cfg.mlp_layer_types):
-        out.append({"attn": seen[a], "mlp": seen[m]})
-        seen[a] += 1
-        seen[m] += 1
-    return out
+    """(head, period, periods) of hybrid.layer_plan, a layer's kind being
+    its (attention kind, MLP kind)."""
+    return hybrid.layer_plan(zip(cfg.layer_types, cfg.mlp_layer_types))
 
 
 # -- parameters ----------------------------------------------------------------
@@ -321,12 +293,6 @@ def paged_cache_logical_axes(cfg: ExaoneMoeConfig,
 
 # -- the block -----------------------------------------------------------------
 
-def _take(tree, i):
-    """Layer i of a stack of leaves (QTensor scales ride along)."""
-    return jax.tree.map(
-        lambda a: lax.dynamic_index_in_dim(a, i, 0, keepdims=False), tree)
-
-
 def window_attention(q, k, v, q_pos, k_pos, window: int):
     """q [B, Sq, H, d] against k/v [B, Sk, KH, d]: key j is visible to
     query i iff 0 <= i - j < window, by absolute positions (k_pos < 0: the
@@ -343,136 +309,6 @@ def window_attention(q, k, v, q_pos, k_pos, window: int):
     return out.reshape(b, sq, h, d).astype(q.dtype)
 
 
-def route(h, router, bias, cfg: ExaoneMoeConfig):
-    """h [T, D] -> (chosen experts [T, k] int32, their weights [T, k]
-    float32). The bias moves the choice and never the weight; the weights
-    are normalised over all k chosen, held here or not."""
-    s = jax.nn.sigmoid(jnp.einsum(
-        "td,de->te", h.astype(jnp.float32), materialize(router, jnp.float32)))
-    _, idx = lax.top_k(s + bias.astype(jnp.float32), cfg.n_experts_per_token)
-    w = jnp.take_along_axis(s, idx, axis=-1)
-    if cfg.norm_topk_prob:
-        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
-    return idx.astype(jnp.int32), w * cfg.routed_scaling_factor
-
-
-_EXPERT_LEAVES = ("w_gate", "w_up", "w_down")
-# How a call multiplies its held experts is chosen by its static token
-# count alone (`_moe`): up to _EVERY_AT_MOST tokens, every token through
-# every held expert; above it, token-expert pairs grouped by expert,
-# _BLOCK_ROWS rows of one expert at a time. On a v5e at the published
-# widths, 16 held of 128, a layer: 64 tokens 0.99 ms every / 1.19 ms
-# grouped, 512 tokens 4.25 / 1.69 (chip run, PR 27; PERF.md §6).
-_EVERY_AT_MOST = 64
-_BLOCK_ROWS = 64
-
-
-def _gated(x, gate, up, down, eq_in, eq_out, qe, dt):
-    return qe(eq_out, swiglu(qe(eq_in, x, gate, dt), qe(eq_in, x, up, dt)),
-              down, dt)
-
-
-def _experts_every(h, local, w, mp, cfg, qe):
-    """Every token through every held expert, mixed by the routing weights
-    (zero where a token did not choose the expert)."""
-    eh = cfg.held_experts[1]
-    mix = jnp.sum(jax.nn.one_hot(local, eh, dtype=jnp.float32)
-                  * w[..., None], axis=1)  # [T, Eh]; one_hot(-1) is zero
-    out = _gated(h, mp["w_gate"], mp["w_up"], mp["w_down"],
-                 "td,edm->tem", "tem,emd->ted", qe, cfg.dtype)
-    return jnp.einsum("ted,te->td", out, mix.astype(cfg.dtype))
-
-
-def _experts_grouped(h, local, w, stack, layer, cfg, qe):
-    """Token-expert pairs sorted by expert, each held expert multiplying
-    its own rows `_BLOCK_ROWS` at a time: the work follows the pairs that
-    landed here, not tokens x held experts. A pair routed elsewhere sorts
-    last and is never multiplied. An expert's weights are indexed out of
-    the stack of all sparse layers inside the loop, by (layer, expert) at
-    once: sliced by layer beforehand, the loop would be handed a copy of
-    the layer's every expert."""
-    t, k = local.shape
-    eh, bm, dt = cfg.held_experts[1], _BLOCK_ROWS, cfg.dtype
-    n = t * k
-    key = jnp.where(local >= 0, local, eh).reshape(n)
-    order = jnp.argsort(key, stable=True)
-    tok = (order // k).astype(jnp.int32)  # the token of each sorted pair
-    counts = jnp.sum(jax.nn.one_hot(key, eh, dtype=jnp.int32), axis=0)
-    ends = jnp.cumsum(counts)
-    starts = ends - counts
-    blocks = -(-counts // bm)
-    block_ends = jnp.cumsum(blocks)
-    # block b belongs to expert e_b and starts at sorted row r0_b
-    n_max = -(-n // bm) + eh
-    b_ids = jnp.arange(n_max, dtype=jnp.int32)
-    e_b = jnp.minimum(
-        jnp.searchsorted(block_ends, b_ids, side="right"), eh - 1
-    ).astype(jnp.int32)
-    r0_b = starts[e_b] + (b_ids - (block_ends[e_b] - blocks[e_b])) * bm
-    tok_pad = jnp.concatenate([tok, jnp.zeros((bm,), jnp.int32)])
-
-    def one(b, out):
-        rows = lax.dynamic_slice_in_dim(tok_pad, r0_b[b], bm)
-        we = jax.tree.map(
-            lambda a: lax.dynamic_slice(
-                a, (layer, e_b[b]) + (0,) * (a.ndim - 2),
-                (1, 1) + a.shape[2:]).reshape(a.shape[2:]),
-            {name: stack[name] for name in _EXPERT_LEAVES})
-        y = _gated(h[rows], we["w_gate"], we["w_up"], we["w_down"],
-                   "td,dm->tm", "tm,md->td", qe, dt)
-        # rows past this expert's end are the next expert's: its own block
-        # overwrites them, and the last expert's spill lands past `ends`
-        return lax.dynamic_update_slice_in_dim(out, y, r0_b[b], axis=0)
-
-    out = lax.fori_loop(0, block_ends[-1], one,
-                        jnp.zeros((n + bm, h.shape[-1]), dt))
-    back = jnp.argsort(order)  # sorted row of pair (token, choice)
-    y = out[back].reshape(t, k, -1)
-    w = jnp.where(local >= 0, w, 0.0).astype(dt)  # hides the spill too
-    return jnp.einsum("tkd,tk->td", y, w)
-
-
-def _moe(h, stack, layer, cfg: ExaoneMoeConfig, valid, qe):
-    """The sparse layer's partial sum over the held experts plus the shared
-    expert. h [B, S, D]; `stack` the leaves of every sparse layer, `layer`
-    this one's index among them; returns (y [B, S, D], counters)."""
-    b, s, d = h.shape
-    first, eh = cfg.held_experts
-    flat = h.reshape(b * s, d)
-    mp = _take({k: v for k, v in stack.items() if k not in _EXPERT_LEAVES},
-               layer)
-    with jax.named_scope(scopes.MOE_ROUTER):
-        idx, w = route(flat, mp["router"], mp["router_bias"], cfg)
-        here = (idx >= first) & (idx < first + eh)
-        local = jnp.where(here, idx - first, -1)
-        real = valid.reshape(b * s, 1)
-        per_expert = jnp.sum(
-            jax.nn.one_hot(jnp.where(real, local, -1), eh, dtype=jnp.int32),
-            axis=(0, 1))
-        stats = {
-            "moe_pairs_held": jnp.sum(per_expert),
-            "moe_pairs_all": jnp.sum(real) * cfg.n_experts_per_token,
-            "moe_expert_pairs_max": jnp.max(per_expert),
-        }
-    with jax.named_scope(scopes.MOE_EXPERTS):
-        if b * s > _EVERY_AT_MOST:
-            y = _experts_grouped(flat, local, w, stack, layer, cfg, qe)
-        else:
-            held = _take({k: stack[k] for k in _EXPERT_LEAVES}, layer)
-            y = _experts_every(flat, local, w, held, cfg, qe)
-    with jax.named_scope(scopes.MOE_SHARED):
-        y = y + _gated(flat, mp["shared_gate"], mp["shared_up"],
-                       mp["shared_down"], "td,dm->tm", "tm,md->td", qe,
-                       cfg.dtype)
-    return y.reshape(b, s, d), stats
-
-
-def _heads_proj(h, w, heads: int, qe, dt):
-    """h [B, S, D] through w [heads * hd, D] -> [B, S, heads, hd]."""
-    out = qe("bsd,nd->bsn", h, w, dt)
-    return out.reshape(out.shape[:2] + (heads, out.shape[-1] // heads))
-
-
 def _block(x, lp, mlp, kinds, idx, positions, cfg, cache, block_table, slots,
            valid):
     """One layer. kinds = (attention kind, MLP kind), static; idx the
@@ -485,9 +321,9 @@ def _block(x, lp, mlp, kinds, idx, positions, cfg, cache, block_table, slots,
     with jax.named_scope(scopes.NORM):
         h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
     with jax.named_scope(scopes.ATTN_QKV):
-        q = _heads_proj(h, lp["wq"], cfg.n_heads, qe, dt)
-        kk = _heads_proj(h, lp["wk"], cfg.n_kv_heads, qe, dt)
-        vv = _heads_proj(h, lp["wv"], cfg.n_kv_heads, qe, dt)
+        q = hybrid.heads_proj(h, lp["wq"], cfg.n_heads, qe, dt)
+        kk = hybrid.heads_proj(h, lp["wk"], cfg.n_kv_heads, qe, dt)
+        vv = hybrid.heads_proj(h, lp["wv"], cfg.n_kv_heads, qe, dt)
     with jax.named_scope(scopes.NORM):
         q = rms_norm(q, lp["q_norm"], cfg.norm_eps)
         kk = rms_norm(kk, lp["k_norm"], cfg.norm_eps)
@@ -526,21 +362,14 @@ def _block(x, lp, mlp, kinds, idx, positions, cfg, cache, block_table, slots,
             x = x + _gated(h, mp["w_gate"], mp["w_up"], mp["w_down"],
                            "bsd,dm->bsm", "bsm,md->bsd", qe, dt)
         return x, cache, None
-    y, stats = _moe(h, *mlp, cfg, valid, qe)
+    y, stats = hybrid.moe(h, *mlp, cfg, valid, qe)
     with jax.named_scope(scopes.MOE_EXPERTS):
         x = x + y
     return x, cache, stats
 
 
-def _fold(total, stats):
-    if stats is None:
-        return total
-    return {
-        "moe_pairs_held": total["moe_pairs_held"] + stats["moe_pairs_held"],
-        "moe_pairs_all": total["moe_pairs_all"] + stats["moe_pairs_all"],
-        "moe_expert_pairs_max": jnp.maximum(
-            total["moe_expert_pairs_max"], stats["moe_expert_pairs_max"]),
-    }
+# Of hybrid.COUNTERS, those this family's forward carries.
+_COUNTERS = ("moe_pairs_held", "moe_pairs_all", "moe_expert_pairs_max")
 
 
 def forward(
@@ -579,42 +408,19 @@ def forward(
         x = materialize(params["tok_embed"], cfg.dtype)[tokens]
 
     kinds = list(zip(cfg.layer_types, cfg.mlp_layer_types))
-    at = _index_of_kind(cfg)
-    head, period, reps = layer_plan(cfg)
-    zero = jnp.zeros((), jnp.int32)
-    stats = {"moe_pairs_held": zero, "moe_pairs_all": zero,
-             "moe_expert_pairs_max": zero}
 
-    # how many layers of each kind one period adds to its stacks
-    span = kinds[head:head + period]
-    per = {kind: sum(kind in pair for pair in span)
-           for kind in (WINDOW, GLOBAL, DENSE, SPARSE)}
-
-    def layer(carry, j, i):
-        """Layer j, i periods further along (i = 0: layer j itself)."""
+    def layer(carry, j, l, at):
         x, cache, stats = carry
         attn_kind, mlp_kind = kinds[j]
-        lp = _take(params["layers"], j + i * period)
-        mlp = (params["dense" if mlp_kind == DENSE else "moe"],
-               at[j]["mlp"] + i * per[mlp_kind])
+        lp = _take(params["layers"], l)
+        mlp = (params["dense" if mlp_kind == DENSE else "moe"], at(mlp_kind))
         x, cache, st = _block(
-            x, lp, mlp, kinds[j], at[j]["attn"] + i * per[attn_kind],
-            positions, cfg, cache, block_table, slots, valid)
-        return x, cache, _fold(stats, st)
+            x, lp, mlp, kinds[j], at(attn_kind), positions, cfg, cache,
+            block_table, slots, valid)
+        return x, cache, hybrid.fold(stats, st)
 
-    carry = (x, cache, stats)
-    with jax.named_scope(scopes.LAYERS):
-        for j in range(head):
-            carry = layer(carry, j, zero)
-        if reps:
-            def body(carry, i):
-                for j in range(head, head + period):
-                    carry = layer(carry, j, i)
-                return carry, None
-
-            carry, _ = lax.scan(body, carry,
-                                jnp.arange(reps, dtype=jnp.int32))
-    x, cache, stats = carry
+    x, cache, stats = hybrid.run_stack(
+        kinds, layer, (x, cache, hybrid.zero_counters(_COUNTERS)))
 
     with jax.named_scope(scopes.LM_HEAD):
         x = rms_norm(x, params["out_norm"], cfg.norm_eps)

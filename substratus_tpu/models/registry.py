@@ -10,7 +10,7 @@ from __future__ import annotations
 
 from typing import Any, Optional, Tuple
 
-from substratus_tpu.models import exaone_moe, falcon, llama, opt
+from substratus_tpu.models import exaone_moe, falcon, lfm2_moe, llama, opt
 
 FAMILIES = {
     "llama": llama,  # Llama 2/3, Mistral, Mixtral (MoE), TinyLlama
@@ -19,6 +19,9 @@ FAMILIES = {
     # K-EXAONE-236B-A23B: sigmoid-routed experts beside a shared one (a
     # program may hold a share of them), window layers beside global ones
     "exaone_moe": exaone_moe,
+    # LFM2-24B-A2B: gated short convolutions that keep two rows of state a
+    # decode slot beside attention layers in pages, sigmoid-routed experts
+    "lfm2_moe": lfm2_moe,
 }
 
 # transformers `model_type` -> family name (HF checkpoint dispatch).
@@ -29,6 +32,7 @@ HF_MODEL_TYPES = {
     "opt": "opt",
     "falcon": "falcon",
     "exaone_moe": "exaone_moe",
+    "lfm2_moe": "lfm2_moe",
 }
 
 _CONFIG_CLASS_TO_FAMILY = {
@@ -36,6 +40,7 @@ _CONFIG_CLASS_TO_FAMILY = {
     opt.OPTConfig: "opt",
     falcon.FalconConfig: "falcon",
     exaone_moe.ExaoneMoeConfig: "exaone_moe",
+    lfm2_moe.Lfm2MoeConfig: "lfm2_moe",
 }
 
 

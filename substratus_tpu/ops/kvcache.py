@@ -54,6 +54,11 @@ model name decides):
 A layer that attends to a window of W positions keeps no pages: each decode
 slot owns a ring of W rows a window layer (`ring_read_and_update`), beside
 the pool in the same cache dict, so the pool holds the global layers alone.
+A gated short convolution of L taps keeps less still: the L - 1 rows of its
+input just before the next position, a decode slot and layer
+(`conv_read_and_update`, under `CONV_STATE` of the same dict), read and
+rewritten every step under the rings' contract: the caller says which slot
+a batch row is and which of its tokens are real.
 """
 from __future__ import annotations
 
@@ -309,6 +314,69 @@ def init_ring_cache(
 def ring_cache_logical_axes() -> Dict[str, tuple]:
     ax = ("layers", None, None, "kv_heads", "head_dim")
     return {"wk": ax, "wv": ax}
+
+
+CONV_STATE = "conv"  # the cache dict's key of the convolution layers' rows
+
+
+def conv_read_and_update(
+    state: jnp.ndarray,  # [Lc, slots, L - 1, D]
+    layer: jnp.ndarray,  # scalar int32: index among the convolution layers
+    slots: jnp.ndarray,  # [B] int32: the state row of each batch row
+    positions: jnp.ndarray,  # [B, S] absolute positions, ascending in a row
+    valid: jnp.ndarray,  # [B, S] bool: real tokens; they lead their row
+    u: jnp.ndarray,  # [B, S, D]: the convolution's input at `positions`
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """The input history of a causal depthwise convolution of L taps.
+
+    Each slot owns L - 1 rows of every convolution layer: the inputs at
+    the L - 1 positions below the next one, oldest first. Returns (updated
+    state, context [B, L - 1 + S, D]): the slot's rows as they were BEFORE
+    this call, then `u`, so output row t is the taps applied to context
+    rows t .. t + L - 1. A state row that would stand for a position below
+    0 reads as zero: a sequence that starts at position 0 never sees what
+    the slot's last occupant left, and nothing is zeroed at admission. The
+    rows scattered back in place (the caller carries and donates the state,
+    as the paged pool) are the L - 1 rows of the context that end with the
+    row's last REAL token: a chunk's padded tail never enters the state, a
+    chunk with fewer real tokens than L - 1 keeps the newest of the old
+    rows, and a row with no real token (an idle slot's filler) leaves its
+    slot's state as it was.
+
+    The state is assumed to hold the positions just below positions[:, 0]:
+    true for a sequence written in order from position 0 (prefill chunks,
+    then decode steps; a preempted sequence is prefilled again from 0).
+    """
+    n_layers, n_slots, keep, d = state.shape
+    row = layer.astype(jnp.int32) * n_slots + slots.astype(jnp.int32)  # [B]
+    with jax.named_scope(scopes.CONV_STATE):
+        flat = state.reshape(n_layers * n_slots, keep, d)
+        old = flat[row]  # [B, L - 1, D]
+        # state row r stands for position positions[:, 0] - (L - 1) + r
+        stands_for = (positions[:, :1].astype(jnp.int32) - keep
+                      + jnp.arange(keep, dtype=jnp.int32)[None, :])
+        seen = jnp.where((stands_for >= 0)[..., None], old, 0)
+        u = u.astype(state.dtype)
+        ctx = jnp.concatenate([seen, u], axis=1)
+        # the rows kept: those that end with the last real token, i.e.
+        # rows n .. n + L - 2 of [old, u] for n real tokens
+        n = jnp.sum(valid, axis=1, dtype=jnp.int32)
+        kept = jax.vmap(
+            lambda rows, start: jax.lax.dynamic_slice_in_dim(
+                rows, start, keep, axis=0)
+        )(jnp.concatenate([old, u], axis=1), n)
+        out = flat.at[row].set(kept).reshape(state.shape)
+    return out, ctx
+
+
+def init_conv_state(n_layers: int, slots: int, taps: int, dim: int, dtype
+                    ) -> Dict[str, jnp.ndarray]:
+    """Per-slot rows of the convolution layers: [Lc, slots, taps - 1, D]."""
+    return {CONV_STATE: jnp.zeros((n_layers, slots, taps - 1, dim), dtype)}
+
+
+def conv_state_logical_axes() -> Dict[str, tuple]:
+    return {CONV_STATE: ("layers", None, None, "embed")}
 
 
 def init_paged_cache(

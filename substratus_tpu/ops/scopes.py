@@ -14,7 +14,9 @@ models/ and ops/ take the names without pulling in anything else.
 A layer that keeps a window of history (models/exaone_moe.py) opens
 KV_RING and ATTN_WINDOW where a layer that keeps all of it opens KV_WRITE,
 KV_GATHER and ATTN_CORE, so the two kinds of history never share a row of
-a trace's table.
+a trace's table. A layer whose operator is a gated short convolution
+(models/lfm2_moe.py) opens CONV_IN, CONV_STATE and CONV_OUT where an
+attention layer opens the ATTN_ and KV_ names.
 """
 
 EMBED = "embed"              # token embedding gather
@@ -35,6 +37,11 @@ MOE_SHARED = "moe.shared"    # the shared expert's matmuls
 KV_RING = "kv.ring"          # a window layer's per-slot ring: the read of its
 # rows and the scatter of the new ones
 ATTN_WINDOW = "attn.window"  # scores, softmax, values of a window layer
+CONV_IN = "conv.in"          # a convolution layer's input projection and
+# the gate B * X
+CONV_STATE = "conv.state"    # the read of the slot's rows, the taps, the rows
+# written back (ops/kvcache.py::conv_read_and_update and the taps beside it)
+CONV_OUT = "conv.out"        # the gate C * v and the output projection
 LM_HEAD = "lm_head"          # final norm and logits
 SAMPLE = "sample"            # RNG split and ops/sampling.py::sample
 
@@ -43,6 +50,8 @@ ALL = (
     MLP, MOE_ROUTER, MOE_EXPERTS, LM_HEAD, SAMPLE,
 )
 # What one family's block adds to the thirteen every block opens (the
-# benchmark lists them in that family's file, benchmarks/families/).
+# benchmark lists them in that family's file, benchmarks/families/):
+# EXTRA models/exaone_moe.py's, CONV models/lfm2_moe.py's.
 EXTRA = (MOE_SHARED, KV_RING, ATTN_WINDOW)
-EVERY = ALL + EXTRA
+CONV = (CONV_IN, CONV_STATE, CONV_OUT)
+EVERY = ALL + EXTRA + CONV

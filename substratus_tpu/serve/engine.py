@@ -48,7 +48,7 @@ from substratus_tpu.observability.tracing import (
     current_trace_id,
     tracer,
 )
-from substratus_tpu.ops import scopes
+from substratus_tpu.ops import kvcache, scopes
 from substratus_tpu.ops.sampling import sample
 
 # Serving latency/utilization histograms (docs/observability.md). Declared
@@ -124,8 +124,9 @@ METRICS.describe(
     "recompute (paged layout, serve/paged_kv.py).",
     type="counter",
 )
-# A family whose expert layer holds a share of the experts and whose
-# window layers keep per-slot rings (models/exaone_moe.py): what its forward
+# A family whose paged cache also holds per-slot state (PAGED_SLOT_STATE:
+# models/exaone_moe.py's rings, models/lfm2_moe.py's convolution rows) and
+# whose expert layer may hold a share of the experts: what its forward
 # counts, read with the step's tokens.
 METRICS.describe(
     "substratus_serve_moe_pairs_total",
@@ -522,18 +523,19 @@ class Engine:
             raise ValueError(
                 f"kv_layout=dense unsupported for {model.__name__}"
             )
-        # A family whose paged cache also holds state addressed by decode
-        # slot (models/exaone_moe.py: the window layers' rings). The engine
-        # tells its forward which slot a row is and which tokens are real,
-        # and takes its per-step counters; pages alone do not carry such a
-        # sequence, so what moves or shares pages is refused or off.
+        # A family whose paged cache also holds per-slot state: rows
+        # addressed by decode slot beside the pages (window layers' rings,
+        # convolution layers' input rows). The engine tells its forward
+        # which slot a row is and which tokens are real, and takes its
+        # per-step counters; pages alone do not carry such a sequence, so
+        # what moves or shares pages is refused or off.
         self.slot_state = self.paged and getattr(
             model, "PAGED_SLOT_STATE", False
         )
         if self.slot_state and (ec.role != "both" or ec.spec_k):
             raise ValueError(
-                f"{model.__name__} keeps per-slot window state: disaggregated "
-                "roles and speculative decoding are unsupported"
+                f"{model.__name__} keeps per-slot state beside its pages: "
+                "disaggregated roles and speculative decoding are unsupported"
             )
         if ec.role != "both" and not self.paged:
             # The handoff ships pool pages; the dense slot cache has no
@@ -599,10 +601,10 @@ class Engine:
             self.cache = pool
             self.block_table = np.zeros((B, self.max_pages), np.int32)
             self.alloc = PageAllocator(self.n_pages, first_page=1)
-            # Shared pages cannot hand a window layer its rows at the
-            # prefix boundary: for such a family the registry is off, and
-            # stats["prefix_reuse_refused"] counts the admissions it would
-            # have looked up.
+            # Shared pages cannot hand a layer with per-slot state its rows
+            # at the prefix boundary: for such a family the registry is
+            # off, and stats["prefix_reuse_refused"] counts the admissions
+            # it would have looked up.
             self.prefix = (
                 PrefixRegistry(self.alloc)
                 if ec.prefix_cache and not self.slot_state else None
@@ -679,20 +681,40 @@ class Engine:
             "prefill_kv_pages_read_sum": 0,
             "prefill_kv_pages_table_sum": 0,
         }
+        # What of the per-slot state this engine observes, read off the
+        # family and its cache: rows of history a ring keeps a slot (0: the
+        # family has no ring), and whether chunks carry convolution rows.
+        self._ring_rows = (
+            model.slot_rows(cfg)
+            if self.slot_state and hasattr(model, "slot_rows") else 0
+        )
+        self._conv_state = self.slot_state and kvcache.CONV_STATE in self.cache
         if self.slot_state:
-            # What a slot-state family's forward counts (models/
-            # exaone_moe.py::forward), summed over decode steps and
-            # prefill chunks as they are drained; window rows are counted
-            # on the host, per decoding iteration like the pages above.
+            # What a slot-state family's forward counts (models/hybrid.py::
+            # COUNTERS), summed over decode steps and prefill chunks as
+            # they are drained.
             self.stats.update({
                 "moe_pairs_held": 0,
                 "moe_pairs_all": 0,
                 "moe_decode_steps": 0,
                 "moe_decode_pairs_held": 0,
                 "moe_decode_expert_pairs_max_sum": 0,
+                "prefix_reuse_refused": 0,
+            })
+        if self._ring_rows:
+            # window rows are counted on the host, per decoding iteration
+            # like the pages above
+            self.stats.update({
                 "window_rows_live_sum": 0,
                 "window_rows_cap_sum": 0,
-                "prefix_reuse_refused": 0,
+            })
+        if self._conv_state:
+            # per chunk dispatch (_run_chunks): chunks in all, and those
+            # that began at offset > 0, so from the rows the chunk before
+            # left
+            self.stats.update({
+                "conv_chunks_sum": 0,
+                "conv_chunks_resumed_sum": 0,
             })
 
         # Speculative decoding state. The draft pool shares the target's
@@ -2032,6 +2054,9 @@ class Engine:
                 self.stats["prefill_kv_pages_read_sum"] += (
                     last // self.page_size + 1)
                 self.stats["prefill_kv_pages_table_sum"] += self.max_pages
+            if self._conv_state and slot is not None:
+                self.stats["conv_chunks_sum"] += 1
+                self.stats["conv_chunks_resumed_sum"] += offset > 0
             with self.timeline.phase(
                 "prefill", request_id=rid, bucket=padded.shape[1],
                 chunk=(offset - start) // chunk, tokens=clen,
@@ -2103,9 +2128,10 @@ class Engine:
         self._emit(slot, first_id)
 
     def _fold_step_stats(self, stats, decode: bool) -> None:
-        """Add one program's counters (models/exaone_moe.py::forward) to
-        stats and the registry. Called where the program's output is read
-        anyway, so it never waits for the device."""
+        """Add one program's counters (those of models/hybrid.py::COUNTERS
+        the family's forward carries) to stats and the registry. Called
+        where the program's output is read anyway, so it never waits for
+        the device."""
         host = {k: int(v) for k, v in jax.device_get(stats).items()}  # sublint: allow[hostsync]: read with the step's tokens (drain) or after the first-token read (admission); the program has finished
         held, every = host["moe_pairs_held"], host["moe_pairs_all"]
         self.stats["moe_pairs_held"] += held
@@ -2121,6 +2147,13 @@ class Engine:
                 "moe_expert_pairs_max"]
             METRICS.observe("substratus_serve_moe_expert_pairs_max",
                             host["moe_expert_pairs_max"])
+            if "moe_experts_touched" in host:
+                # only a family whose forward carries the counter has the
+                # key: held experts that a real token of the step chose,
+                # summed over sparse layers
+                self.stats["moe_decode_experts_touched"] = (
+                    self.stats.get("moe_decode_experts_touched", 0)
+                    + host["moe_experts_touched"])
 
     # --- paged pool management -------------------------------------------
 
@@ -2906,10 +2939,10 @@ class Engine:
                 (self.alloc.used_pages - live) / self.n_pages,
                 {"state": "cached"},
             )
-        if self.slot_state:
+        if self._ring_rows:
             # rows of a window layer's ring that hold a live sequence's
             # history: min(context, window) a decoding slot
-            w = self.model.slot_rows(self.cfg)
+            w = self._ring_rows
             rows = int(np.minimum(self.host_positions[self.active], w).sum())  # sublint: allow[hostsync]: host numpy mirrors, no device read
             self.stats["window_rows_live_sum"] += rows
             self.stats["window_rows_cap_sum"] += self.ec.max_batch * w

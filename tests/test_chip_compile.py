@@ -385,6 +385,93 @@ def test_exaone_programs_leave_pool_and_rings_in_place(v5e):
         assert grouped == (name == "chunk"), name
 
 
+# The assist cell's engine (benchmarks/traffic/assist.json): LFM2-24B-A2B's
+# first 16 layers, all 64 experts, the whole vocabulary.
+_F_POOL_PAGES, _F_B, _F_S = 6144, 64, 2048
+
+
+def test_lfm2_programs_compile_and_leave_the_conv_state_in_place(v5e):
+    """The family whose cache holds pages beside convolution rows: decode
+    and the 512-token chunk compile for the chip at the published widths,
+    attend on the gather path (heads of 64: no kernel), move the
+    convolution layers' state neither whole nor a layer of it, lay no int8
+    weight out anew, and the chunk groups its tokens by expert."""
+    from jax.sharding import SingleDeviceSharding
+
+    from substratus_tpu.models import lfm2_moe
+    from substratus_tpu.ops.quant import quantize_params
+    from substratus_tpu.serve.engine import Engine, EngineConfig
+
+    cfg = lfm2_moe.Lfm2MoeConfig(
+        n_layers=16, layer_types=lfm2_moe.Lfm2MoeConfig().layer_types[:16])
+    assert (cfg.count(lfm2_moe.CONV), cfg.count(lfm2_moe.ATTN)) == (12, 4)
+    eng = Engine(cfg, None, EngineConfig(
+        max_batch=_F_B, max_seq_len=_F_S, max_prefill_len=_CHUNK,
+        page_size=_PAGE, kv_pool_tokens=1,
+    ))
+    assert eng.slot_state and eng.prefix is None
+    rep = SingleDeviceSharding(v5e[0])
+
+    def placed(tree):
+        return jax.tree.map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=rep),
+            tree)
+
+    def arr(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=rep)
+
+    params = placed(jax.eval_shape(
+        lambda key: quantize_params(
+            lfm2_moe.init_params(cfg, key), lfm2_moe.quant_contracting(cfg)),
+        jax.random.key(0)))
+    cache = placed(jax.eval_shape(
+        lambda: lfm2_moe.init_paged_cache(
+            cfg, _F_POOL_PAGES + 1, _PAGE, slots=_F_B)))
+    tokens = (_F_POOL_PAGES + 1) * _PAGE
+    pool_bytes = sum(cache[n].size * cache[n].dtype.itemsize for n in "kv")
+    assert pool_bytes == tokens * 8 * 1024  # 4 attention layers of 16
+    assert cache["conv"].shape == (12, _F_B, 2, 2048)
+    m = _F_S // _PAGE
+    programs = {
+        "decode": eng._decode_fn.lower(
+            params, cache, arr((_F_B, m)), arr((_F_B,)), arr((_F_B,)),
+            arr((_F_B,), jnp.float32), arr((_F_B,), jnp.float32),
+            arr(eng.key.shape, eng.key.dtype), None, None,
+            arr((_F_B,), jnp.bool_),
+        ),
+        "chunk": Engine._chunk_prefill_jit.lower(
+            lfm2_moe, cfg, params, cache, arr((1, _CHUNK)), arr(()), arr(()),
+            arr((1, m)), None, None, arr(()),
+        ),
+    }
+    # Refused: a copy or slice the size of the whole state, and a slice the
+    # size of one layer of it. A *copy* of that last size is the step's own
+    # read of its 64 slots' rows ([max_batch, 2, D], by construction as
+    # large as a layer of the state), as with the rings above.
+    whole, state_layer = {cache["conv"].size}, {cache["conv"].size // 12}
+    for name, lowered in programs.items():
+        compiled = lowered.compile()
+        hlo = compiled.as_text()
+        assert "tpu_custom_call" not in hlo and "kv.gather" in hlo, name
+        assert all(s in hlo for s in ("conv.in", "conv.state", "conv.out"))
+        bf16 = "\n".join(l for l in hlo.splitlines() if "= bf16[" in l)
+        # (the chunk's one slot is written by a dynamic-update-slice whose
+        # result is the state itself, updated in place: not a move)
+        assert [op for op in _pool_moving_ops(bf16, whole)
+                if "dynamic-update-slice" not in op] == [], name
+        assert [op for op in _pool_moving_ops(bf16, state_layer)
+                if "copy" not in op] == [], name
+        assert not re.search(r"= s8\[[\d,]+\]\S* copy\(", hlo), name
+        # it fits beside 9.1 GB of weights and the pool; today it holds a
+        # second pool (the device keeps a pool of 64-wide heads pages-
+        # innermost and each program lays it out anew: ROADMAP.md S13)
+        temp = compiled.memory_analysis().temp_size_in_bytes
+        assert temp < 3.5e9, (name, temp)
+    # the chunk's experts are a loop over blocks of rows, the step's a
+    # product with every expert
+    assert "moe.experts/while" in programs["chunk"].compile().as_text()
+
+
 @pytest.mark.parametrize("program", ["decode", "chunk"])
 def test_tinyllama_paged_programs_compile_for_v5e(program, v5e):
     """What `serve.main --config tinyllama-1.1b` compiles on a TPU at its

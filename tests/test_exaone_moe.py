@@ -14,6 +14,7 @@ import pytest
 
 from benchmarks.reference import exaone_moe as R
 from substratus_tpu.models import exaone_moe as M
+from substratus_tpu.models import hybrid
 from substratus_tpu.models import registry
 from substratus_tpu.ops.quant import QTensor, quantize_params
 from substratus_tpu.serve.engine import Engine, EngineConfig, Request
@@ -285,9 +286,9 @@ def test_eight_shares_add_up_to_the_uncut_layer(params, monkeypatch, seq,
     (80 tokens), there also with blocks so short that an expert takes
     several."""
     if block_rows:
-        monkeypatch.setattr(M, "_BLOCK_ROWS", block_rows)
+        monkeypatch.setattr(hybrid, "BLOCK_ROWS", block_rows)
     t = 2 * seq
-    assert (t > M._EVERY_AT_MOST) == (seq == 40)
+    assert (t > hybrid.EVERY_AT_MOST) == (seq == 40)
     mp, mw = sparse_layer(params)
     h = jax.random.normal(jax.random.key(3), (2, seq, CFG.dim), jnp.float32)
     flat = h.reshape(t, CFG.dim)
@@ -304,7 +305,7 @@ def test_eight_shares_add_up_to_the_uncut_layer(params, monkeypatch, seq,
         for name in ("w_gate", "w_up", "w_down"):
             share[name] = jax.tree.map(
                 lambda a: a[:, 2 * rank:2 * rank + 2], mp[name])
-        y, stats = M._moe(h, share, jnp.int32(0), cfg, valid, M.qeinsum)
+        y, stats = hybrid.moe(h, share, jnp.int32(0), cfg, valid, M.qeinsum)
         total += np.asarray(y).reshape(t, -1) - shared
         held += int(stats["moe_pairs_held"])
         assert int(stats["moe_pairs_all"]) == t * CFG.n_experts_per_token
@@ -348,7 +349,7 @@ def test_router_sigmoid_bias_normalisation_and_factor():
     logits = np.array([[3.0, 2.0, 1.0, 0.5, 0.0, -1.0, -2.0, -3.0]], np.float32)
     h = jnp.zeros((1, CFG.dim)).at[:, :8].set(logits)
     s = 1 / (1 + np.exp(-logits[0]))
-    idx, w = M.route(h, router, jnp.zeros((8,)), cfg)
+    idx, w = hybrid.route(h, router, jnp.zeros((8,)), cfg)
     assert sorted(np.asarray(idx[0])) == [0, 1, 2]
     np.testing.assert_allclose(np.asarray(w[0]), 2.5 * s[:3] / s[:3].sum(),
                                rtol=1e-6)
@@ -356,14 +357,14 @@ def test_router_sigmoid_bias_normalisation_and_factor():
     # a bias moves the choice (expert 7 displaces expert 2) and never the
     # weight: expert 7 weighs by its own small score
     bias = jnp.zeros((8,)).at[7].set(1.0)
-    idx, w = M.route(h, router, bias, cfg)
+    idx, w = hybrid.route(h, router, bias, cfg)
     chosen = dict(zip(np.asarray(idx[0]).tolist(), np.asarray(w[0]).tolist()))
     assert sorted(chosen) == [0, 1, 7]
     denom = s[0] + s[1] + s[7]
     np.testing.assert_allclose(chosen[7], 2.5 * s[7] / denom, rtol=1e-5)
     np.testing.assert_allclose(chosen[0], 2.5 * s[0] / denom, rtol=1e-5)
     # without normalisation the weights are the scores times the factor
-    idx, w = M.route(h, router, jnp.zeros((8,)),
+    idx, w = hybrid.route(h, router, jnp.zeros((8,)),
                      cfg.replace(norm_topk_prob=False))
     np.testing.assert_allclose(np.sort(np.asarray(w[0])), np.sort(2.5 * s[:3]),
                                rtol=1e-6)
@@ -377,9 +378,9 @@ def test_absent_experts_stay_in_the_normalisation(params):
     """A rank that holds experts 0-1 weighs them by the sum over all the
     chosen, held or not: its weights are the uncut router's, not rescaled
     to what it holds."""
-    mp = M._take(params["moe"], 0)
+    mp = hybrid.take(params["moe"], 0)
     h = jax.random.normal(jax.random.key(4), (24, CFG.dim), jnp.float32)
-    idx, w = M.route(h, mp["router"], mp["router_bias"], CFG)
+    idx, w = hybrid.route(h, mp["router"], mp["router_bias"], CFG)
     here = np.asarray(idx) < 2
     assert here.any() and not here.all()
     np.testing.assert_allclose(np.asarray(w).sum(-1), 2.5, rtol=1e-5)

@@ -9,7 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from substratus_tpu.models import exaone_moe, llama
+from substratus_tpu.models import exaone_moe, lfm2_moe, llama
 from substratus_tpu.ops import scopes
 from substratus_tpu.serve.engine import Engine, EngineConfig
 
@@ -32,13 +32,20 @@ CASES = {
                          DENSE_LLAMA | {scopes.KV_GATHER, scopes.MOE_ROUTER,
                                         scopes.MOE_EXPERTS}
                          | set(scopes.EXTRA)),
+    # convolution layers beside attention layers, two dense layers before
+    # sparse ones: every base name, and the family's three
+    "lfm2-moe-paged": ("tiny-lfm2-moe", "paged",
+                       DENSE_LLAMA | {scopes.KV_GATHER, scopes.MOE_ROUTER,
+                                      scopes.MOE_EXPERTS}
+                       | set(scopes.CONV)),
 }
 OP_RE = re.compile(
     r"^\s*(?:ROOT )?%?[\w.\-]+ = \S+ ([\w\-]+)\(.*?op_name=\"([^\"]*)\"", re.M)
 
 
 def _engine(config: str, layout: str) -> Engine:
-    model = exaone_moe if config in exaone_moe.CONFIGS else llama
+    model = next((m for m in (exaone_moe, lfm2_moe) if config in m.CONFIGS),
+                 llama)
     cfg = model.CONFIGS[config].replace(dtype=jnp.float32)
     params = model.init_params(cfg, jax.random.key(0))
     return Engine(cfg, params, EngineConfig(
@@ -111,8 +118,8 @@ def test_vocabulary_is_closed_and_matches_the_benchmarks_copy():
     assert len(set(scopes.ALL)) == len(scopes.ALL) == 13
     # what one family's block adds is listed in that family's file, and
     # the benchmark's readers charge an op to any of them
-    assert len(set(scopes.EVERY)) == len(scopes.EVERY) == 16
-    assert set(scopes.EXTRA) <= trace_scopes.vocabulary()
+    assert len(set(scopes.EVERY)) == len(scopes.EVERY) == 19
+    assert set(scopes.EXTRA + scopes.CONV) <= trace_scopes.vocabulary()
 
 
 def test_a_region_name_is_metadata_and_changes_no_arithmetic(monkeypatch):

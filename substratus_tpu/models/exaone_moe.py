@@ -270,7 +270,7 @@ def init_params(cfg: ExaoneMoeConfig, key: jax.Array) -> Params:
 
 
 def init_paged_cache(cfg: ExaoneMoeConfig, pages: int, page_size: int,
-                     dtype=None, slots: int = 1) -> Params:
+                     dtype=None, slots: int = 1, kv_shards: int = 1) -> Params:
     """The global layers' page pool (`k`, `v`: [Lg, P, bs, KH, hd]) and the
     window layers' rings (`wk`, `wv`: [Lw, slots, W, KH, hd]), one dict."""
     dtype = dtype or cfg.dtype
@@ -278,7 +278,7 @@ def init_paged_cache(cfg: ExaoneMoeConfig, pages: int, page_size: int,
         raise ValueError("exaone_moe keeps no int8 KV cache")
     cache = kvcache.init_paged_cache(
         max(cfg.count(GLOBAL), 1), pages, page_size, cfg.n_kv_heads,
-        cfg.head_dim, dtype)
+        cfg.head_dim, dtype, kv_shards=kv_shards)
     cache.update(kvcache.init_ring_cache(
         max(cfg.count(WINDOW), 1), slots, cfg.sliding_window, cfg.n_kv_heads,
         cfg.head_dim, dtype))

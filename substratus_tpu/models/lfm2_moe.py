@@ -279,16 +279,16 @@ def init_params(cfg: Lfm2MoeConfig, key: jax.Array) -> Params:
 
 
 def init_paged_cache(cfg: Lfm2MoeConfig, pages: int, page_size: int,
-                     dtype=None, slots: int = 1) -> Params:
-    """The attention layers' page pool (`k`, `v`: [La, P, bs, KH, hd]) and
-    the convolution layers' rows (`conv`: [Lc, slots, L - 1, D]), one
-    dict."""
+                     dtype=None, slots: int = 1, kv_shards: int = 1) -> Params:
+    """The attention layers' page pool (`k`, `v`: [La, P, bs, KH, hd],
+    stored two heads of 64 to a row of 128: ops/kvcache.py) and the
+    convolution layers' rows (`conv`: [Lc, slots, L - 1, D]), one dict."""
     dtype = dtype or cfg.dtype
     if dtype == jnp.int8:
         raise ValueError("lfm2_moe keeps no int8 KV cache")
     cache = kvcache.init_paged_cache(
         max(cfg.count(ATTN), 1), pages, page_size, cfg.n_kv_heads,
-        cfg.head_dim, dtype)
+        cfg.head_dim, dtype, kv_shards=kv_shards)
     cache.update(kvcache.init_conv_state(
         max(cfg.count(CONV), 1), slots, cfg.conv_taps, cfg.dim, dtype))
     return cache

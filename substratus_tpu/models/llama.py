@@ -278,16 +278,18 @@ SUPPORTS_PAGED = True
 
 
 def init_paged_cache(
-    cfg: LlamaConfig, pages: int, page_size: int, dtype=None
+    cfg: LlamaConfig, pages: int, page_size: int, dtype=None,
+    kv_shards: int = 1,
 ) -> Params:
     """Paged decode cache: a global page pool k/v [L, P, bs, KH, head_dim]
-    addressed through a per-sequence block table (ops/kvcache.py)."""
+    addressed through a per-sequence block table (ops/kvcache.py, which
+    decides the stored row: heads of 64 lie two to a row of 128)."""
     from substratus_tpu.ops import kvcache
 
     dtype = dtype or cfg.dtype
     return kvcache.init_paged_cache(
         cfg.n_layers, pages, page_size, cfg.n_kv_heads, cfg.head_size,
-        dtype, quantized=dtype == jnp.int8,
+        dtype, quantized=dtype == jnp.int8, kv_shards=kv_shards,
     )
 
 

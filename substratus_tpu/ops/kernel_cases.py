@@ -9,7 +9,8 @@ Q4Tensor.dequant).
 
 Widths: TinyLlama-1.1B (32 heads / 4 kv heads of 64, dim 2048, hidden
 5632), Llama-2-7B (32/32 heads of 128, dim 4096, hidden 11008) and, for
-attention over a cache, Mistral-7B (32 heads / 8 kv heads of 128);
+attention over a cache, Mistral-7B (32 heads / 8 kv heads of 128) and
+LFM2-24B-A2B (32 / 8 of 64, stored two to a row of 128 in the paged pool);
 prefill lengths are the engine's power-of-two buckets up to
 EngineConfig.max_prefill_len (16..512) plus the trainer's 1024/2048;
 cache lengths are EngineConfig.max_seq_len (1024) and the old bench's
@@ -211,10 +212,16 @@ def dense_attend(tag, b, sq, h, kh, d, cache_len, int8) -> KernelCase:
 
 
 def _pool_and_tables(key, b, m, pool_shape, idle_last=True):
-    """A seeded K and V pool and block tables [b, m] of scattered pages
-    (where the pool is smaller than the tables, rows share them); the last
-    row's is the trash page's where that row idles."""
+    """A seeded K and V pool of `pool_shape` = (layers, pages, page size, KV
+    heads, head width), in the shape ops/kvcache.py::init_paged_cache
+    stores it (heads of 64 two to a row), and block tables [b, m] of
+    scattered pages (where the pool is smaller than the tables, rows share
+    them); the last row's is the trash page's where that row idles."""
+    from substratus_tpu.ops.kvcache import init_paged_cache
+
     kk, kv, kt = jax.random.split(key, 3)
+    pool_shape = jax.eval_shape(
+        lambda: init_paged_cache(*pool_shape, BF16))["k"].shape
     pages = pool_shape[1]
     own = jax.random.permutation(kt, jnp.arange(1, pages, dtype=jnp.int32))
     table = own[jnp.arange(b * m) % (pages - 1)].reshape(b, m)
@@ -227,9 +234,27 @@ def _gathered(q, k, v, layer, table, positions):
     """[B, S, H, hd] x the pool -> what ops/kvcache.py's gather path gives."""
     from substratus_tpu.ops.kvcache import paged_read
 
-    k_ctx, v_ctx = paged_read({"k": k, "v": v}, layer, table, q.dtype)
+    k_ctx, v_ctx = paged_read(
+        {"k": k, "v": v}, layer, table, q.dtype, q.shape[-1])
     return dot_product_attention(
         q, k_ctx, v_ctx, causal=True, q_positions=positions)
+
+
+def _in_place(q, k, v, layer, table, positions, interpret=False):
+    """[B, S, H, hd] x the pool -> what ops/kvcache.py::paged_attend picks
+    for these operands on a TPU: the decode kernel for S == 1, the chunk
+    kernel beyond, over the pool's stored rows; the gather itself where
+    `_kernel_for` has no kernel."""
+    from substratus_tpu.ops import kvcache
+    from substratus_tpu.ops.paged_attention import paged_chunk_attention
+
+    if interpret:  # the CPU rehearsal: the op would take the gather
+        kernel = (paged_chunk_attention if q.shape[1] > 1
+                  else kvcache._one_token)
+        return kvcache._over_stored_rows(partial(kernel, interpret=True))(
+            q, k, v, layer, table, positions)
+    return kvcache.paged_attend(
+        {"k": k, "v": v}, layer, table, positions, q, q.dtype)
 
 
 def paged_decode(tag, b, max_seq, h, kh, d, pages, page=16,
@@ -239,7 +264,6 @@ def paged_decode(tag, b, max_seq, h, kh, d, pages, page=16,
     engine leaves one (position 0, a table of the trash page); the pages
     of a row lie scattered, and where the pool is smaller than the tables
     rows share them."""
-    from substratus_tpu.ops.paged_attention import paged_decode_attention
 
     def make_args(key):
         kq, kp = jax.random.split(key)
@@ -251,13 +275,16 @@ def paged_decode(tag, b, max_seq, h, kh, d, pages, page=16,
             positions.astype(jnp.int32),
         )
 
-    def reference(q, k, v, layer, table, positions):
-        return _gathered(
-            q[:, None], k, v, layer, table, positions[:, None])[:, 0]
+    def one_token(attend):
+        def run(q, k, v, layer, table, positions, **kw):
+            return attend(
+                q[:, None], k, v, layer, table, positions[:, None], **kw)[:, 0]
+
+        return run
 
     return KernelCase(
         f"paged_decode/{tag}/b{b}-s{max_seq}-h{h}", make_args,
-        paged_decode_attention, reference, tol=2e-2,
+        one_token(_in_place), one_token(_gathered), tol=2e-2,
     )
 
 
@@ -267,13 +294,7 @@ def paged_chunk(tag, b, s, max_seq, h, kh, d, pages, page=16,
     (b = 1: its last token is the last position of the table, so the walk
     takes every page) or a speculative verify round (rows of every length
     from a full table down, the last row idle: positions 0 .. s - 1, a
-    table of the trash page). The kernel is whatever
-    ops/kvcache.py::paged_attend picks for these widths on a TPU: the chunk
-    kernel for heads of 128, the gather itself for TinyLlama's 64."""
-    from substratus_tpu.ops.kvcache import paged_attend
-    from substratus_tpu.ops.paged_attention import (
-        LANES, paged_chunk_attention,
-    )
+    table of the trash page)."""
 
     def make_args(key):
         kq, kp = jax.random.split(key)
@@ -287,16 +308,9 @@ def paged_chunk(tag, b, s, max_seq, h, kh, d, pages, page=16,
             positions.astype(jnp.int32),
         )
 
-    def kernel(q, k, v, layer, table, positions, interpret=False):
-        if interpret:  # the CPU rehearsal: the op would take the gather
-            return paged_chunk_attention(
-                q, k, v, layer, table, positions, interpret=True)
-        return paged_attend(
-            {"k": k, "v": v}, layer, table, positions, q, q.dtype)
-
     return KernelCase(
-        f"paged_chunk/{tag}/b{b}-q{s}-s{max_seq}-h{h}", make_args, kernel,
-        _gathered, tol=2e-2, mosaic=d % LANES == 0,
+        f"paged_chunk/{tag}/b{b}-q{s}-s{max_seq}-h{h}", make_args, _in_place,
+        _gathered, tol=2e-2,
     )
 
 
@@ -336,6 +350,7 @@ def q4_matmul(tag, m, c, n) -> KernelCase:
 SHARDED_REFUSED = "Custom emitter for CustomSPMDPartitioning not found"
 
 TINYLLAMA = dict(h=32, kh=4, d=64)
+LFM2 = dict(h=32, kh=8, d=64)
 SMALL = dict(h=4, kh=2, d=64)  # the CPU rehearsal's widths
 LLAMA7B = dict(h=32, kh=32, d=128)
 MISTRAL7B = dict(h=32, kh=8, d=128)
@@ -371,18 +386,21 @@ def chip_cases() -> List[KernelCase]:
             cases.append(q4_matmul(f"{tag}-down", m, hidden, dim))
         cases.append(q4_matmul(f"{tag}-wk", 8, dim, kv_dim))
         cases.append(q4_matmul(f"{tag}-lm_head", 8, dim, 32000))
-    # The decode step of the benchmark's three cells (benchmarks/traffic/):
-    # max_batch x max_seq_len, query / KV heads of 128, the cell's pool.
+    # The decode step of the benchmark's four cells (benchmarks/traffic/):
+    # max_batch x max_seq_len, query / KV heads, the cell's pool. LFM2's
+    # heads of 64 lie two to a stored row of 128.
     cases.append(paged_decode("mistral-chat", 32, 2048, 32, 8, 128, 1793))
     cases.append(paged_decode("mistral-longdoc", 5, 8192, 32, 8, 128, 1921))
     cases.append(paged_decode("k-exaone", 64, 4096, 64, 8, 128, 10241))
+    cases.append(paged_decode("lfm2-assist", 64, 2048, pages=6145, **LFM2))
     # Their largest chunk programs' attention (the chat cell's median prompt
     # is one chunk of 256), a spec_k=4 verify round over the chat cell's
-    # batch, and TinyLlama's chunk, which the op leaves on the gather.
+    # batch, and TinyLlama's chunk (4 KV heads of 64: two rows a token).
     cases.append(paged_chunk("mistral-longdoc", 1, 512, 8192, 32, 8, 128, 1921))
     cases.append(paged_chunk("mistral-chat", 1, 256, 2048, 32, 8, 128, 1793))
     cases.append(paged_chunk("k-exaone", 1, 512, 4096, 64, 8, 128, 10241))
     cases.append(paged_chunk("mistral-verify", 32, 5, 2048, 32, 8, 128, 1793))
+    cases.append(paged_chunk("lfm2-assist", 1, 512, 2048, pages=6145, **LFM2))
     cases.append(paged_chunk("tinyllama", 1, 512, 1024, pages=513, **TINYLLAMA))
     return cases
 
@@ -399,7 +417,7 @@ def rehearsal_cases() -> List[KernelCase]:
         dense_attend("small", 2, 1, cache_len=128, int8=True, **w),
         q4_matmul("small", 8, 256, 128),
         paged_decode("small", 3, 128, h=4, kh=2, d=64, pages=17),
-        paged_chunk("small", 2, 16, 128, h=4, kh=2, d=64, pages=17),
+        paged_chunk("small", 2, 16, 128, h=8, kh=4, d=64, pages=17),
     ]
 
 

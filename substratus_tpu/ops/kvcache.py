@@ -7,7 +7,10 @@ global page pool, stacked over layers,
     k/v        [layers, pages, page_size, kv_heads, head_dim]
     (+ scales  [layers, pages, page_size, kv_heads, 1] when int8-quantized)
 
-and a per-sequence block table [B, max_pages] of page ids. Shapes stay fully
+(a bfloat16 pool of heads narrower than a 128-lane row is stored `pack` =
+128 // head_dim neighbouring KV heads to a row, [.., kv_heads // pack, 128]:
+the same bytes in the same order; `init_paged_cache` decides, every reader
+takes `pack` off its operands) and a per-sequence block table [B, max_pages] of page ids. Shapes stay fully
 static under jit (TPU requirement): dynamism lives in the *contents* of the
 block table. Memory is bounded by actual tokens in flight, not
 batch x max_seq_len, and identical prompt prefixes can share pages
@@ -25,8 +28,9 @@ the new rows and attends from q to each row's context, and reads off its
 inputs which of two realisations of that attention runs (no flag, option or
 model name decides):
 
-* **A bfloat16 pool with heads a multiple of 128 wide, lowered for a TPU,
-  whatever the query length**: ops/paged_attention.py takes the stack as
+* **A bfloat16 pool with rows a multiple of 128 wide (heads of 128, or
+  heads of 64 stored two to a row), lowered for a TPU, whatever the query
+  length**: ops/paged_attention.py takes the stack as
   its HBM operand and reads row b's live pages block_table[b, 0 ..
   max(positions[b]) // page_size] in place. One query token a row (a decode
   step) takes `paged_decode_attention`: nothing of size max_batch x
@@ -37,10 +41,12 @@ model name decides):
   table, and no score is written to HBM. Under a mesh that shards the pool
   over kv_heads and nothing else, each device runs the kernel on its own
   heads (`shard_map`, no collective).
-* **Everything else** (an int8 pool; a float32 pool; heads of another width,
-  which Mosaic does not tile: TinyLlama-1.1B's 64; `kv_length` given; a pool
-  sharded any other way, or for a chunk over an odd number of KV heads a
-  device; every platform but the TPU): each sequence's context is gathered
+* **Everything else** (an int8 pool; a float32 pool; heads that could not
+  be packed into rows of 128, which Mosaic does not tile: an odd number of
+  64-wide heads, a width of 96; `kv_length` given; a pool sharded any other
+  way, or a count of rows a token and device that Mosaic does not tile
+  (one, three, six); every platform but the TPU): each sequence's context
+  is gathered
   one table entry at a time, as a slice of page_size contiguous rows
   (`kv.gather`; row by row out of HBM the same gather measured 1.8x slower,
   and a [pages, page_size, ...] view of the pool made the compiler re-tile
@@ -66,6 +72,7 @@ from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from substratus_tpu.ops import scopes
@@ -108,9 +115,10 @@ def _write(pool, layer, block_table, positions, k_new, v_new):
             new = {"k": k_new, "v": v_new}
         for name, vals in new.items():
             a = pool[name]
-            out[name] = (
-                _rows(a).at[idx].set(vals.astype(a.dtype)).reshape(a.shape)
-            )
+            # the new rows as the pool stores a row (packed: [KH // pack,
+            # 128], the same bytes; a relayout of the new rows alone)
+            vals = vals.astype(a.dtype).reshape(vals.shape[:2] + a.shape[3:])
+            out[name] = _rows(a).at[idx].set(vals).reshape(a.shape)
     return out
 
 
@@ -118,27 +126,30 @@ def _rows(a):  # [L, P, bs, ...] -> [L * P * bs, ...], a bitcast
     return a.reshape((-1,) + a.shape[3:])
 
 
-def paged_read(pool, layer, block_table, dtype):
+def paged_read(pool, layer, block_table, dtype, head_dim):
     """`layer`'s slot-local context of every row: k_ctx, v_ctx
-    [B, M * bs, KH, hd], bs contiguous rows a table entry."""
+    [B, M * bs, KH, head_dim], bs contiguous rows a table entry. Of a
+    packed pool the gathered rows are split back into their KV heads, never
+    the pool ahead of the gather."""
     pages, bs = pool["k"].shape[1:3]
     b, m = block_table.shape
     first = layer.astype(block_table.dtype) * pages
     with jax.named_scope(scopes.KV_GATHER):
         starts = ((first + block_table) * bs).reshape(b * m)
 
-        def read(a):
+        def read(a, row=None):
             ctx = jax.vmap(
                 lambda start: jax.lax.dynamic_slice_in_dim(_rows(a), start, bs)
             )(starts)
-            return ctx.reshape((b, m * bs) + a.shape[3:])
+            return ctx.reshape((b, m * bs) + (row or a.shape[3:]))
 
         if "k_scale" in pool:
             return (
                 dequantize_kv(read(pool["k"]), read(pool["k_scale"]), dtype),
                 dequantize_kv(read(pool["v"]), read(pool["v_scale"]), dtype),
             )
-        return read(pool["k"]), read(pool["v"])
+        row = (-1, head_dim)
+        return read(pool["k"], row), read(pool["v"], row)
 
 
 def paged_attention(
@@ -157,10 +168,11 @@ def paged_attention(
     [B, S, H, hd]).
 
     Which of two realisations of that attention runs is read off the
-    inputs. A bfloat16 pool with heads a multiple of 128 wide, lowered for
-    a TPU: ops/paged_attention.py reads each row's live pages in place, a
-    decode step (S == 1) and a chunk or a speculative round (S > 1) alike.
-    Everything else (an int8 or float32 pool, another head width,
+    inputs. A bfloat16 pool with rows a multiple of 128 wide (heads of 128,
+    or narrower heads `init_paged_cache` packed into such rows), lowered
+    for a TPU: ops/paged_attention.py reads each row's live pages in place,
+    a decode step (S == 1) and a chunk or a speculative round (S > 1)
+    alike. Everything else (an int8 or float32 pool, heads left unpacked,
     `kv_length` given, a placement `_kernel_for` names, any other
     platform): the context of every table position is gathered and
     ops/attention.py::dot_product_attention runs over it, which is also
@@ -175,7 +187,8 @@ def paged_attend(pool, layer, block_table, positions, q, dtype,
     """The attention half of `paged_attention`, over the pool as it is."""
 
     def gathered():
-        k_ctx, v_ctx = paged_read(pool, layer, block_table, dtype)
+        k_ctx, v_ctx = paged_read(
+            pool, layer, block_table, dtype, q.shape[-1])
         with jax.named_scope(scopes.ATTN_CORE):
             return dot_product_attention(
                 q, k_ctx, v_ctx, causal=True, q_positions=positions,
@@ -194,11 +207,53 @@ def paged_attend(pool, layer, block_table, positions, q, dtype,
     return jax.lax.platform_dependent(tpu=in_place, default=gathered)
 
 
-def _one_token(q, k_pool, v_pool, layer, block_table, positions):
+def _one_token(q, k_pool, v_pool, layer, block_table, positions, scale,
+               interpret=False):
     """paged_decode_attention behind the chunk kernel's signature."""
     return paged_decode_attention(
-        q[:, 0], k_pool, v_pool, layer, block_table, positions[:, 0]
+        q[:, 0], k_pool, v_pool, layer, block_table, positions[:, 0],
+        scale=scale, interpret=interpret,
     )[:, None]
+
+
+def _over_stored_rows(kernel):
+    """`kernel` (of ops/paged_attention.py) for queries [B, S, H, hd] over
+    a pool whose rows hold `pack` = row width // hd neighbouring KV heads.
+    To the kernel a row is one KV head of 128 lanes with `pack` times the
+    query heads: q goes in widened to the row, its values in the lanes of
+    its own KV head and zeros in its neighbours' (a zero meets a finite
+    key: the score is exact), and of each output row the head's own lanes
+    are kept. The scale stays hd ** -0.5. At `pack` = 1 nothing is added."""
+
+    def attend(q, k_pool, v_pool, layer, block_table, positions):
+        h, hd = q.shape[2:]
+        scale = hd ** -0.5
+        pack = k_pool.shape[4] // hd
+        if pack == 1:
+            return kernel(
+                q, k_pool, v_pool, layer, block_table, positions, scale=scale)
+        group = h // (k_pool.shape[3] * pack)  # query heads a KV head
+        own = np.arange(h)[:, None] // group % pack == np.arange(pack)
+        own = own[:, :, None]  # [H, pack, 1]: the lanes of the head's own
+        wide = jnp.where(own, q[..., None, :], 0)  # [B, S, H, pack, hd]
+        out = kernel(
+            wide.reshape(q.shape[:3] + (pack * hd,)), k_pool, v_pool, layer,
+            block_table, positions, scale=scale)
+        out = out.reshape(q.shape[:3] + (pack, hd))
+        return jnp.where(own, out, 0).sum(axis=3)
+
+    return attend
+
+
+def _pool_spec() -> P:
+    """The pool's PartitionSpec under the serve rules; [3] is the mesh
+    axis of kv_heads."""
+    return SERVE_RULES.mesh_axes(paged_cache_logical_axes()["k"])
+
+
+def kv_head_shards(mesh) -> int:
+    """The devices a pool's kv_heads axis is split over under `mesh`."""
+    return 1 if mesh is None else mesh.shape.get(_pool_spec()[3], 1)
 
 
 def _kernel_for(k_pool, q):
@@ -208,24 +263,29 @@ def _kernel_for(k_pool, q):
     one device; a shard of KV heads a device where the pool is sharded over
     them and nothing else is sharded (each device's kernel reads its own
     heads' share of every page: no collective). None where no kernel here
-    is written for the case: a pool that is not bfloat16, heads Mosaic does
-    not tile (not a multiple of 128 wide), any other placement, and for the
-    chunk kernel an odd number of KV heads a device (it reads them two to a
-    32-bit word)."""
-    if k_pool.dtype != jnp.bfloat16 or k_pool.shape[4] % LANES:
+    is written for the case: a pool that is not bfloat16, rows Mosaic does
+    not tile (not a multiple of 128 wide: heads of 64 that
+    `init_paged_cache` could not pack) or that hold no whole number of the
+    queries' heads, any other placement, and a count of rows a token and
+    device that does not fill the sublane tile Mosaic gives a page (of 2,
+    of 4, beyond that of 8 rows: one row, three, six or twelve it refuses
+    to slice; the chunk kernel besides reads rows two to a 32-bit word)."""
+    row = k_pool.shape[4]
+    if k_pool.dtype != jnp.bfloat16 or row % LANES or row % q.shape[-1]:
         return None
     mesh = jax.typeof(k_pool).sharding.mesh
     sharded = {name: n for name, n in mesh.shape.items() if n > 1}
-    pool = SERVE_RULES.mesh_axes(paged_cache_logical_axes()["k"])
+    pool = _pool_spec()
     heads = pool[3]  # the mesh axis of kv_heads
     if sharded and (
         list(sharded) != [heads] or k_pool.shape[3] % sharded[heads]
     ):
         return None
-    chunk = q.shape[1] > 1
-    if chunk and k_pool.shape[3] // sharded.get(heads, 1) % 2:
+    rows = k_pool.shape[3] // sharded.get(heads, 1)
+    if rows % (2 if rows <= 2 else 4 if rows <= 4 else 8):
         return None
-    kernel = paged_chunk_attention if chunk else _one_token
+    chunk = q.shape[1] > 1
+    kernel = _over_stored_rows(paged_chunk_attention if chunk else _one_token)
     if not sharded:
         return kernel
     return jax.shard_map(
@@ -387,9 +447,26 @@ def init_paged_cache(
     head_dim: int,
     dtype,
     quantized: bool = False,
+    kv_shards: int = 1,
 ) -> Dict[str, jnp.ndarray]:
-    """Layers-stacked page pool: k/v [L, P, bs, KH, hd] (+ f32 scales)."""
-    shape = (n_layers, pages, page_size, kv_heads, head_dim)
+    """Layers-stacked page pool: k/v [L, P, bs, KH, hd] (+ f32 scales).
+
+    The one place that decides the stored row. A bfloat16 pool of heads
+    that divide a 128-lane row is stored `pack` = 128 // hd neighbouring
+    KV heads to a row, [L, P, bs, KH // pack, 128]: the same bytes in the
+    same order, in the one shape of them Mosaic tiles and the device keeps
+    row-major (a minor dimension of 64 it keeps pages-innermost, and every
+    program then lays the pool out anew and back: PERF.md section 6, PR
+    35). Every reader takes `pack` off its operands, the row's width over
+    q's. Stored as declared: heads of 128 and wider (`pack` = 1, the same
+    shape), an int8 or float32 pool, and a KV head count the rows do not
+    divide evenly among the `kv_shards` devices that split the axis
+    (`kv_head_shards`)."""
+    pack = LANES // head_dim if LANES % head_dim == 0 else 1
+    if (quantized or jnp.dtype(dtype) != jnp.bfloat16
+            or kv_heads % (pack * kv_shards)):
+        pack = 1
+    shape = (n_layers, pages, page_size, kv_heads // pack, head_dim * pack)
     cache = {
         "k": jnp.zeros(shape, jnp.int8 if quantized else dtype),
         "v": jnp.zeros(shape, jnp.int8 if quantized else dtype),

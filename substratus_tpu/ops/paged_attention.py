@@ -6,7 +6,10 @@ Both take the stacked pool of ops/kvcache.py,
     k/v  [layers, pages, page_size, kv_heads, head_dim]
 
 as an HBM operand as it is: no BlockSpec copies it and nothing views it in
-another shape, so the compiler has no reason to lay it out anew. Row b
+another shape, so the compiler has no reason to lay it out anew. Mosaic
+tiles a row of 128 lanes: a pool of 64-wide heads is stored two KV heads to
+such a row and comes here as half as many heads of 128, with q widened to
+the row and the scale given (ops/kvcache.py::_over_stored_rows). Row b
 reads pages block_table[b, 0 .. (its last position) // page_size] of `layer`
 by its own DMAs and stops: the work a row costs follows its own length,
 shapes stay static, and nothing of size rows x max_seq_len is written
@@ -30,7 +33,8 @@ transposed.
 
 Arithmetic is ops/attention.py::dot_product_attention's: K, V and q enter
 the dots as stored, scores, maximum, sum and output accumulate in float32,
-the scale is head_dim ** -0.5, masking is k_pos <= positions[b]. The
+the scale is head_dim ** -0.5 unless given, masking is k_pos <=
+positions[b]. The
 probabilities meet a bfloat16 V as two bfloat16 parts (value and rounding
 remainder) stacked into one left operand, so the float32 softmax loses no
 more than 2**-16 of a weight on the way and V passes the MXU once.
@@ -59,6 +63,7 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -203,7 +208,7 @@ def _kernel(layer_ref, pos_ref, bt_ref, q_ref, k_hbm, v_hbm, o_ref,
     lax.fori_loop(0, n_rows, row, 0)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
 def paged_decode_attention(
     q: jnp.ndarray,  # [B, H, hd]: one query token a row
     k_pool: jnp.ndarray,  # [L, P, bs, KH, hd]
@@ -211,9 +216,10 @@ def paged_decode_attention(
     layer: jnp.ndarray,  # scalar int32: the layer of the stack to read
     block_table: jnp.ndarray,  # [B, M] int32 page ids
     positions: jnp.ndarray,  # [B] the query's position = the last to see
+    scale: Optional[float] = None,  # of the scores; hd ** -0.5 unless given
     interpret: bool = False,
 ) -> jnp.ndarray:
-    """softmax(q . K / sqrt(hd)) V over positions 0..positions[b] of row b,
+    """softmax(q . K * scale) V over positions 0..positions[b] of row b,
     read through its block table out of `layer` of the pool; [B, H, hd] in
     q.dtype. Row b reads positions[b] // bs + 1 pages, whatever the table
     or the other rows hold."""
@@ -225,7 +231,8 @@ def paged_decode_attention(
     vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
     hbm = pl.BlockSpec(memory_space=pl.ANY)
     return pl.pallas_call(
-        functools.partial(_kernel, scale=hd ** -0.5),
+        functools.partial(
+            _kernel, scale=hd ** -0.5 if scale is None else scale),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         in_specs=[smem, smem, smem, vmem, hbm, hbm],
         out_specs=vmem,
@@ -392,7 +399,7 @@ def _round_up(x: int, n: int) -> int:
     return -(-x // n) * n
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
 def paged_chunk_attention(
     q: jnp.ndarray,  # [B, S, H, hd]: S query tokens a row
     k_pool: jnp.ndarray,  # [L, P, bs, KH, hd], bfloat16
@@ -400,9 +407,10 @@ def paged_chunk_attention(
     layer: jnp.ndarray,  # scalar int32: the layer of the stack to read
     block_table: jnp.ndarray,  # [B, M] int32 page ids
     positions: jnp.ndarray,  # [B, S]: query i sees positions 0..positions[b, i]
+    scale: Optional[float] = None,  # of the scores; hd ** -0.5 unless given
     interpret: bool = False,
 ) -> jnp.ndarray:
-    """softmax(q . K / sqrt(hd)) V for every query of every row, each over
+    """softmax(q . K * scale) V for every query of every row, each over
     positions 0..its own of its row, read through the block table out of
     `layer` of the pool; [B, S, H, hd] in q.dtype. Row b reads
     max(positions[b]) // bs + 1 pages, whatever the table holds, and no
@@ -443,7 +451,8 @@ def paged_chunk_attention(
         + 2 * 3 * keys * FOLD_QUERIES * 4  # a fold's scores, as values
     )
     out = pl.pallas_call(
-        functools.partial(_chunk_kernel, scale=hd ** -0.5),
+        functools.partial(
+            _chunk_kernel, scale=hd ** -0.5 if scale is None else scale),
         out_shape=jax.ShapeDtypeStruct((b, kh, hd, padded), q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,

@@ -116,10 +116,13 @@ class PoolSpec:
         if not getattr(engine, "paged", False):
             raise ValueError("disaggregated serving requires the paged layout")
         k = engine.cache["k"]
-        L, _, bs, kh, hd = k.shape
+        L, _, bs, rows, width = k.shape
+        # the pages' logical shape: a pool may store several KV heads to a
+        # row (ops/kvcache.py::init_paged_cache), the wire never does
+        hd = int(engine.cfg.head_size)
         return cls(
-            n_layers=int(L), page_size=int(bs), kv_heads=int(kh),
-            head_dim=int(hd), dtype=np.dtype(k.dtype).name,
+            n_layers=int(L), page_size=int(bs), kv_heads=rows * width // hd,
+            head_dim=hd, dtype=np.dtype(k.dtype).name,
             quantized="k_scale" in engine.cache,
         )
 

@@ -113,6 +113,15 @@ METRICS.describe(
 # the scrape-time substratus_serve_<stat> gauges mirror the same numbers
 # but only when a server is attached; these increment at admission.
 METRICS.describe(
+    "substratus_serve_kv_heads_per_pool_row",
+    "KV heads one stored row of the paged pool holds, set where the pool "
+    "is made (ops/kvcache.py::init_paged_cache): 2 for a bfloat16 pool of "
+    "64-wide heads, which the paged-attention kernels read in place; 1 = "
+    "stored as declared (heads of 128; or an int8 pool, an odd head count "
+    "or an uneven split of 64-wide heads, whose attention gathers).",
+    type="gauge",
+)
+METRICS.describe(
     "substratus_serve_prefill_tokens_total",
     "Prompt tokens actually prefilled through the model (prefix-cache "
     "misses; the cold-work half of the reuse ratio).",
@@ -589,7 +598,12 @@ class Engine:
             # page. The allocator hands out ids 1..n_pages.
             pool = model.init_paged_cache(
                 cfg, self.n_pages + 1, bs, dtype=cache_dtype,
+                kv_shards=kvcache.kv_head_shards(mesh),
                 **({"slots": B} if self.slot_state else {}),
+            )
+            METRICS.set(
+                "substratus_serve_kv_heads_per_pool_row",
+                pool["k"].shape[4] // cfg.head_size,
             )
             if mesh is not None:
                 pool = shard_tree(
@@ -756,7 +770,7 @@ class Engine:
             # int8 for the draft's (larger-per-token-count) traffic too.
             draft_pool = model.init_paged_cache(
                 self.draft_cfg, self.n_pages + 1, self.page_size,
-                dtype=cache_dtype,
+                dtype=cache_dtype, kv_shards=kvcache.kv_head_shards(mesh),
             )
             if mesh is not None:
                 draft_pool = shard_tree(
@@ -1116,12 +1130,20 @@ class Engine:
         compiles once."""
         from substratus_tpu.ops.quant import dequantize_kv, quantize_kv
 
+        hd = self.cfg.head_size
+
+        # Pages cross the wire in their logical shape [L, n, bs, KH, hd],
+        # whatever row the pool stores (ops/kvcache.py::init_paged_cache):
+        # the same bytes, and an int8 pool's scale is per hd vector.
+        def stored(pages, like):
+            return pages.reshape(pages.shape[:2] + like.shape[2:])
+
         @jax.jit
         def export(cache, ids):
-            return {
-                key: self._replicated(jnp.take(cache[key], ids, axis=1))
-                for key in cache
-            }
+            out = {key: jnp.take(cache[key], ids, axis=1) for key in cache}
+            for name in ("k", "v"):
+                out[name] = out[name].reshape(out[name].shape[:3] + (-1, hd))
+            return {key: self._replicated(a) for key, a in out.items()}
 
         @partial(jax.jit, static_argnums=(0,), donate_argnums=(1,))
         def import_(convert, cache, ids, frag):
@@ -1141,11 +1163,13 @@ class Engine:
                         frag[name], frag[f"{name}_scale"],
                         cache[name].dtype,
                     )
-                    out[name] = cache[name].at[:, ids].set(vals)
+                    out[name] = cache[name].at[:, ids].set(
+                        stored(vals, cache[name]))
             else:
                 for name in cache:
                     out[name] = cache[name].at[:, ids].set(
-                        frag[name].astype(cache[name].dtype)
+                        stored(frag[name].astype(cache[name].dtype),
+                               cache[name])
                     )
             return out
 

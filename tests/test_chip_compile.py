@@ -14,8 +14,8 @@ slice or re-stack the pool (models/llama.py::forward carries it in place),
 nor, for a family with window layers, their rings (models/exaone_moe.py);
 and the decode and chunk programs over a bfloat16 pool hold the
 paged-attention kernels, no gathered context and no scores in HBM
-(ops/kvcache.py); at TinyLlama's head width of 64 they compile on the
-gather path. The dense
+(ops/kvcache.py), at a head width of 64 too, where the pool stores two KV
+heads to a row of 128 (TinyLlama, LFM2). The dense
 slot cache's programs are compiled for every family that serves on it, and
 for a cache split over `sequence`.
 """
@@ -93,11 +93,14 @@ def test_sharded_kernel_compiles_for_v5e_2x2(v5e):
 @pytest.mark.parametrize("s", [1, 512], ids=["step", "chunk"])
 def test_paged_decode_kernel_compiles_at_head_dim_64(s, v5e):
     """TinyLlama-1.1B's heads are 64 wide and Mosaic tiles no kernel of
-    ops/paged_attention.py there ("Slice shape along dimension 4 must be
-    aligned to tiling (128), but is 64"): the op reads the width and leaves
-    such a pool on the gather path, a decode step and a chunk alike (from
-    PR 28 to PR 30 it picked the decode kernel regardless, and on a TPU
-    `serve.main --config tinyllama-1.1b` answered 500: ROADMAP.md S3c)."""
+    ops/paged_attention.py at that minor dimension ("Slice shape along
+    dimension 4 must be aligned to tiling (128), but is 64"): the pool
+    stores such heads two to a row of 128 (ops/kvcache.py::
+    init_paged_cache) and a decode step and a chunk take the kernels over
+    those rows. A pool handed in as `bf16[..., 4, 64]` all the same is
+    left on the gather path: the op reads the row's width (from PR 28 to
+    PR 30 it picked the decode kernel regardless, and on a TPU `serve.main
+    --config tinyllama-1.1b` answered 500: ROADMAP.md S3c)."""
     from jax.sharding import SingleDeviceSharding
 
     from substratus_tpu.ops import kvcache
@@ -109,6 +112,22 @@ def test_paged_decode_kernel_compiles_at_head_dim_64(s, v5e):
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
         jax.eval_shape(case.make_args, jax.random.key(0)),
     )
+    assert k.shape[3:] == (2, 128)
+    assert kvcache._kernel_for(k, q) is not None
+    hlo = jax.jit(case.kernel).lower(
+        q, k, v, layer, table, positions).compile().as_text()
+    name = "paged_chunk_attention" if s > 1 else "paged_decode_attention"
+    assert re.search(r'custom_call_target="tpu_custom_call".*' + name, hlo)
+    assert "kv.gather" not in hlo
+    assert _pool_moving_ops(hlo, {math.prod(k.shape)}) == []
+    # one row a token (two heads of 64; TinyLlama's four split over two
+    # chips) Mosaic does not slice: "must be aligned to tiling (2), but is 1"
+    one = jax.ShapeDtypeStruct(k.shape[:3] + (1, 128), k.dtype,
+                               sharding=one_chip)
+    assert kvcache._kernel_for(one, q) is None
+    # the same bytes declared a head a row
+    k = v = jax.ShapeDtypeStruct(
+        k.shape[:3] + (4, 64), k.dtype, sharding=one_chip)
     assert kvcache._kernel_for(k, q) is None
     hlo = jax.jit(case.kernel).lower(
         q, k, v, layer, table, positions).compile().as_text()
@@ -393,7 +412,8 @@ _F_POOL_PAGES, _F_B, _F_S = 6144, 64, 2048
 def test_lfm2_programs_compile_and_leave_the_conv_state_in_place(v5e):
     """The family whose cache holds pages beside convolution rows: decode
     and the 512-token chunk compile for the chip at the published widths,
-    attend on the gather path (heads of 64: no kernel), move the
+    read the live pages in place (heads of 64 lie two to a stored row of
+    128: the kernels, no gather, and no op moves the pool), move the
     convolution layers' state neither whole nor a layer of it, lay no int8
     weight out anew, and the chunk groups its tokens by expert."""
     from jax.sharding import SingleDeviceSharding
@@ -430,6 +450,7 @@ def test_lfm2_programs_compile_and_leave_the_conv_state_in_place(v5e):
     tokens = (_F_POOL_PAGES + 1) * _PAGE
     pool_bytes = sum(cache[n].size * cache[n].dtype.itemsize for n in "kv")
     assert pool_bytes == tokens * 8 * 1024  # 4 attention layers of 16
+    assert cache["k"].shape == (4, _F_POOL_PAGES + 1, _PAGE, 4, 128)
     assert cache["conv"].shape == (12, _F_B, 2, 2048)
     m = _F_S // _PAGE
     programs = {
@@ -449,12 +470,18 @@ def test_lfm2_programs_compile_and_leave_the_conv_state_in_place(v5e):
     # read of its 64 slots' rows ([max_batch, 2, D], by construction as
     # large as a layer of the state), as with the rings above.
     whole, state_layer = {cache["conv"].size}, {cache["conv"].size // 12}
+    pool = {cache["k"].size, cache["k"].size // 4}  # whole, or a layer
     for name, lowered in programs.items():
         compiled = lowered.compile()
         hlo = compiled.as_text()
-        assert "tpu_custom_call" not in hlo and "kv.gather" in hlo, name
+        rows = _F_B if name == "decode" else 1
+        assert _reads_pages_in_place(
+            hlo, _KERNEL[name], rows, _F_S, cfg.n_kv_heads, cfg.head_size,
+            cfg.n_heads * _CHUNK * _F_S if name == "chunk" else 0), name
+        assert "kv.gather" not in hlo, name
         assert all(s in hlo for s in ("conv.in", "conv.state", "conv.out"))
         bf16 = "\n".join(l for l in hlo.splitlines() if "= bf16[" in l)
+        assert _pool_moving_ops(bf16, pool) == [], name
         # (the chunk's one slot is written by a dynamic-update-slice whose
         # result is the state itself, updated in place: not a move)
         assert [op for op in _pool_moving_ops(bf16, whole)
@@ -462,11 +489,11 @@ def test_lfm2_programs_compile_and_leave_the_conv_state_in_place(v5e):
         assert [op for op in _pool_moving_ops(bf16, state_layer)
                 if "copy" not in op] == [], name
         assert not re.search(r"= s8\[[\d,]+\]\S* copy\(", hlo), name
-        # it fits beside 9.1 GB of weights and the pool; today it holds a
-        # second pool (the device keeps a pool of 64-wide heads pages-
-        # innermost and each program lays it out anew: ROADMAP.md S13)
+        # it fits beside 9.1 GB of weights and the pool, and holds no
+        # second pool (until PR 35 the device kept a pool of 64-wide heads
+        # pages-innermost and each program laid it out anew: 823 MB)
         temp = compiled.memory_analysis().temp_size_in_bytes
-        assert temp < 3.5e9, (name, temp)
+        assert temp < 1.5e9, (name, temp)
     # the chunk's experts are a loop over blocks of rows, the step's a
     # product with every expert
     assert "moe.experts/while" in programs["chunk"].compile().as_text()
@@ -475,9 +502,11 @@ def test_lfm2_programs_compile_and_leave_the_conv_state_in_place(v5e):
 @pytest.mark.parametrize("program", ["decode", "chunk"])
 def test_tinyllama_paged_programs_compile_for_v5e(program, v5e):
     """What `serve.main --config tinyllama-1.1b` compiles on a TPU at its
-    defaults (8 slots of 1,024, chunks of 512, the paged layout): heads of
-    64, so both programs gather (S3c: the decode program held a kernel
-    Mosaic refuses, and chip_smoke.py's serve phases answered 500)."""
+    defaults (8 slots of 1,024, chunks of 512, the paged layout): 4 KV
+    heads of 64 are two stored rows of 128 a token, and both programs read
+    them in place (S3c: from PR 28 to PR 30 the decode program held a
+    kernel over a 64-wide row, which Mosaic refuses, and chip_smoke.py's
+    serve phases answered 500)."""
     from substratus_tpu.models import llama
     from substratus_tpu.serve.engine import Engine, EngineConfig
 
@@ -512,7 +541,14 @@ def test_tinyllama_paged_programs_compile_for_v5e(program, v5e):
             arr((1, m)),
         )
     hlo = lowered.compile().as_text()
-    assert "tpu_custom_call" not in hlo and "kv.gather" in hlo
+    assert pool["k"].shape[3:] == (2, 128)
+    assert _reads_pages_in_place(
+        hlo, _KERNEL[program], b if program == "decode" else 1, s,
+        cfg.n_kv_heads, cfg.head_size,
+        cfg.n_heads * _CHUNK * s if program == "chunk" else 0)
+    assert "kv.gather" not in hlo
+    assert _pool_moving_ops(
+        hlo, {pool["k"].size, pool["k"].size // cfg.n_layers}) == []
 
 
 # The dense slot cache [L, B, KH, S, hd] at the server's defaults (8 slots of

@@ -204,6 +204,56 @@ def test_pool_spec_convert_modes():
         f32.convert_mode(other)
 
 
+def test_pages_cross_the_wire_a_head_a_row_whatever_the_pool_stores():
+    """A bfloat16 pool of 64-wide heads stores two to a row of 128
+    (ops/kvcache.py::init_paged_cache); its spec and its exported pages are
+    the logical [L, n, bs, KH, hd] all the same. Pages exported from it
+    come back to the bit through `import_`, and through an int8 pool
+    (quantized per hd vector on the way in, dequantized on the way back)
+    within int8's rounding."""
+    cfg = llama.CONFIGS["tiny"].replace(dim=256, vocab_size=258)
+    assert (cfg.n_kv_heads, cfg.head_size, cfg.dtype) == (2, 64, jnp.bfloat16)
+    make = lambda **kw: Engine(cfg, None, ec(page_size=4, **kw))  # noqa: E731
+    packed, int8 = make(), make(kv_cache_dtype="int8")
+    assert packed.cache["k"].shape[3:] == (1, 128)
+    assert int8.cache["k"].shape[3:] == (2, 64)
+    for eng in (packed, int8):
+        assert PoolSpec.from_engine(eng) == PoolSpec.from_engine_config(
+            cfg, eng.ec)
+    keys = jax.random.split(jax.random.key(0), 2)
+    packed.cache = {
+        name: jax.random.normal(k, a.shape, jnp.float32).astype(a.dtype)
+        for k, (name, a) in zip(keys, packed.cache.items())
+    }
+    ids = np.array([3, 9, 1, 0], np.int32)  # bucket-padded with the trash page
+    sent = packed._export_fn(packed.cache, ids)
+    assert sent["k"].shape == (cfg.n_layers, 4, 4, 2, 64)
+    rows = np.asarray(sent["k"].astype(jnp.float32))
+    assert (rows[:, :3].reshape(cfg.n_layers, 3, 4, 1, 128)
+            == np.asarray(packed.cache["k"][:, ids[:3]], np.float32)).all()
+
+    there = np.array([5, 2, 7, 0], np.int32)
+    other = make()
+    other.cache = other._import_fn("none", other.cache, there, dict(sent))
+    back = other._export_fn(other.cache, there)
+    np.testing.assert_array_equal(
+        np.asarray(back["k"].astype(jnp.float32))[:, :3], rows[:, :3])
+
+    int8.cache = int8._import_fn("quantize", int8.cache, there, dict(sent))
+    quantized = int8._export_fn(int8.cache, there)
+    assert quantized["k"].dtype == jnp.int8
+    assert quantized["k_scale"].shape == (cfg.n_layers, 4, 4, 2, 1)
+    other = make()
+    other.cache = other._import_fn(
+        "dequantize", other.cache, ids, dict(quantized))
+    assert other.cache["k"].shape == packed.cache["k"].shape
+    for name in ("k", "v"):
+        np.testing.assert_allclose(
+            np.asarray(other.cache[name][:, ids[:3]], np.float32),
+            np.asarray(packed.cache[name][:, ids[:3]], np.float32),
+            atol=0.05, rtol=0)
+
+
 # --- failure paths --------------------------------------------------------
 
 

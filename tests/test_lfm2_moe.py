@@ -315,6 +315,38 @@ def test_the_engine_serves_the_family_through_submit(params, tokens):
         CFG.count(M.CONV), 3, CFG.conv_taps - 1, CFG.dim)
 
 
+def test_the_engine_serves_the_same_tokens_from_a_packed_pool(
+    tokens, monkeypatch
+):
+    """The published head width, 64, in bfloat16: the attention layers'
+    pool stores two KV heads to a row of 128, and the engine serves from
+    it, through chunks and decode steps beside the convolution rows, the
+    tokens it serves from the pool stored a head a row (the layout until
+    PR 35): the same bytes through the same gather on the CPU."""
+    cfg = M.CONFIGS["tiny-lfm2-moe"].replace(head_dim=64)
+    assert cfg.dtype == jnp.bfloat16
+    p = quantize_params(M.init_params(cfg, jax.random.key(0)),
+                        M.quant_contracting(cfg))
+    prompts = [tokens[:37], tokens[3:26], tokens[40:41]]
+
+    def run():
+        eng = Engine(cfg, p, EngineConfig(
+            max_batch=3, max_seq_len=96, max_prefill_len=CHUNK,
+            page_size=PAGE), model=M)
+        eng.start()
+        outs = submit_all(eng, prompts, 12)
+        eng.stop()
+        assert eng.error is None
+        return eng, outs
+
+    eng, got = run()
+    assert eng.cache["k"].shape[3:] == (1, 128)
+    monkeypatch.setattr(kvcache, "kv_head_shards", lambda mesh: 3)
+    eng, want = run()
+    assert eng.cache["k"].shape[3:] == (2, 64)
+    assert got == want and all(len(ids) == 12 for ids in got)
+
+
 def test_a_slots_second_occupant_equals_a_fresh_engine(params, tokens):
     """One slot, two requests one after the other: the second is served
     what a fresh engine serves it, though the first left its rows in the

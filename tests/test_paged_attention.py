@@ -3,9 +3,16 @@ which is what ops/kvcache.py::paged_attention runs on the CPU: the context
 of every table position gathered (`paged_read`) and ops/attention.py::
 dot_product_attention over it. One query token a row (a decode step) takes
 the decode kernel, more (a prefill chunk, a verify round) the chunk kernel.
-Then the whole engine, the kernels forced in place of the gather, token for
-token.
+Heads are 64 wide. `declared` pools hold a head a row, [.., KH, 64], handed
+to the kernels as they are (interpret mode tiles anything); the `kh*` pools
+are stored as ops/kvcache.py::init_paged_cache stores them, two heads to a
+row of 128, and reach the kernels the way `paged_attend` takes them there
+(q widened to the row, the head's own lanes kept), against the gather over
+the same packed pool. Then the whole engine, the kernels forced in place of
+the gather, token for token.
 """
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -13,7 +20,7 @@ import pytest
 
 from substratus_tpu.ops import kvcache
 from substratus_tpu.ops.paged_attention import (
-    CHUNK_PAGES, FOLD_PAGES, paged_chunk_attention, paged_decode_attention,
+    CHUNK_PAGES, FOLD_PAGES, paged_chunk_attention,
 )
 
 BS, M, KH, HD, LAYERS = 16, 40, 2, 64, 3  # a table of 640 positions: two
@@ -23,6 +30,12 @@ TOL = {jnp.bfloat16: 2e-2, jnp.float32: 1e-5}
 assert FOLD_PAGES[-1] < M < 2 * FOLD_PAGES[-1]
 assert CHUNK_PAGES < M < 2 * CHUNK_PAGES
 BUCKETS = [16, 32, 64, 128, 256, 512]  # the engine's prefill buckets
+# KV heads of a pool stored as init_paged_cache stores it (None: declared).
+# On a TPU the op takes a kernel over 2, 4 or a multiple of 8 rows a token
+# (4 heads of 64 up); the arithmetic of one row (`kh2`) is checked for the
+# step all the same, a chunk's rows come in pairs.
+PACKED = pytest.mark.parametrize(
+    "packed", [None, 4, 8], ids=["declared", "kh4", "kh8"])
 
 
 def _normal(key, shape, dtype):
@@ -30,20 +43,26 @@ def _normal(key, shape, dtype):
 
 
 def _case(dtype, group, lengths, layer=1, tables=None, seed=0, s=1, real=None,
-          kv_heads=KH):
+          kv_heads=KH, packed=None, hd=HD):
     """A seeded pool, a call's q and new K/V rows for rows of `lengths`
     tokens (the `s` new rows are the last of them; of these only the first
     `real` are a prompt's and the padded tail is clamped onto the position
     after them, as the engine's chunk program clamps it), and block tables
-    of scattered pages unless given."""
+    of scattered pages unless given. `packed`: that many KV heads, in the
+    shape init_paged_cache stores them."""
     keys = jax.random.split(jax.random.key(seed), 6)
     b = len(lengths)
-    shape = (LAYERS, PAGES, BS, kv_heads, HD)
+    kv_heads = packed or kv_heads
+    shape = (LAYERS, PAGES, BS, kv_heads, hd)
+    if packed:
+        shape = jax.eval_shape(
+            lambda: kvcache.init_paged_cache(*shape, dtype))["k"].shape
+        assert shape[3:] == (kv_heads // 2, 128)
     pool = {"k": _normal(keys[0], shape, dtype),
             "v": _normal(keys[1], shape, dtype)}
-    q = _normal(keys[2], (b, s, kv_heads * group, HD), dtype)
-    k_new = _normal(keys[3], (b, s, kv_heads, HD), dtype)
-    v_new = _normal(keys[4], (b, s, kv_heads, HD), dtype)
+    q = _normal(keys[2], (b, s, kv_heads * group, hd), dtype)
+    k_new = _normal(keys[3], (b, s, kv_heads, hd), dtype)
+    v_new = _normal(keys[4], (b, s, kv_heads, hd), dtype)
     if tables is None:
         tables = np.asarray(
             jax.random.permutation(keys[5], np.arange(1, PAGES))[: b * M]
@@ -62,12 +81,10 @@ def _reference(pool, layer, table, positions, q, k_new, v_new):
 
 
 def _kernel(pool, layer, table, positions, q):
-    if q.shape[1] > 1:
-        return paged_chunk_attention(
-            q, pool["k"], pool["v"], layer, table, positions, interpret=True)
-    return paged_decode_attention(
-        q[:, 0], pool["k"], pool["v"], layer, table, positions[:, 0],
-        interpret=True)[:, None]
+    """The kernel as `paged_attend` calls it on a TPU, interpreted."""
+    kernel = paged_chunk_attention if q.shape[1] > 1 else kvcache._one_token
+    return kvcache._over_stored_rows(partial(kernel, interpret=True))(
+        q, pool["k"], pool["v"], layer, table, positions)
 
 
 def _close(got, want, dtype):
@@ -90,6 +107,20 @@ def test_a_row_of_every_length_matches_the_gathered_attention(
     _close(_kernel(out, layer, table, pos, q), want, dtype)
 
 
+@pytest.mark.parametrize("length", [1, BS - 1, BS, BS + 1, FULL],
+                         ids=["one", "page-1", "page", "page+1", "full"])
+@pytest.mark.parametrize("packed", [2, 4, 8], ids=["kh2", "kh4", "kh8"])
+def test_a_row_of_every_length_out_of_a_packed_pool(packed, length):
+    """The same over rows that hold two KV heads of 64 each: the query
+    heads of a pair share the pair's row and each keeps its own lanes."""
+    pool, layer, table, pos, q, k_new, v_new = _case(
+        jnp.bfloat16, 4, [length, 3 * BS + 5, FOLD_PAGES[-1] * BS + 1],
+        packed=packed)
+    out, want = _reference(pool, layer, table, pos, q, k_new, v_new)
+    assert out["k"].shape == pool["k"].shape
+    _close(_kernel(out, layer, table, pos, q), want, jnp.bfloat16)
+
+
 @pytest.mark.parametrize("s", [1, 16], ids=["step", "chunk"])
 @pytest.mark.parametrize("layer", [0, 1, LAYERS - 1])
 def test_the_layer_is_an_offset_into_the_stack(layer, s):
@@ -105,8 +136,12 @@ def test_the_layer_is_an_offset_into_the_stack(layer, s):
         np.asarray(want, np.float32), atol=TOL[jnp.bfloat16])
 
 
-@pytest.mark.parametrize("s", [1, 32], ids=["step", "chunk"])
-def test_scattered_pages_and_a_prefix_two_rows_share(s):
+@pytest.mark.parametrize(
+    "s,packed",
+    [(1, None), (1, 2), (1, 4), (1, 8), (32, None), (32, 4), (32, 8)],
+    ids=["step", "step-kh2", "step-kh4", "step-kh8", "chunk", "chunk-kh4",
+         "chunk-kh8"])
+def test_scattered_pages_and_a_prefix_two_rows_share(s, packed):
     """Rows 0 and 1 hold the same first five pages (a prefix hit) and
     their own after them; every page lies somewhere else in the pool. (A
     chunk's rows are written after the shared pages.)"""
@@ -118,15 +153,16 @@ def test_scattered_pages_and_a_prefix_two_rows_share(s):
     tables[2] = order[40:40 + M][::-1]
     lengths = [9 * BS - 3, 12 * BS, FULL - 7]
     pool, layer, table, pos, q, k_new, v_new = _case(
-        jnp.bfloat16, 4, lengths, tables=tables, s=s)
+        jnp.bfloat16, 4, lengths, tables=tables, s=s, packed=packed)
     out, want = _reference(pool, layer, table, pos, q, k_new, v_new)
     _close(_kernel(out, layer, table, pos, q), want, jnp.bfloat16)
 
 
+@pytest.mark.parametrize("packed", [None, 4], ids=["declared", "kh4"])
 @pytest.mark.parametrize("s", [1, 16], ids=["step", "chunk"])
 @pytest.mark.parametrize("garbage", [float("nan"), 3e37, -3e37],
                          ids=["nan", "huge", "-huge"])
-def test_a_row_sees_its_own_pages_up_to_its_own_position(garbage, s):
+def test_a_row_sees_its_own_pages_up_to_its_own_position(garbage, s, packed):
     """An idle row as the engine leaves it (position 0, a table of the
     trash page: it costs the one page its own write landed on; for a chunk,
     a row whose `s` tokens are all it holds), a row of one page (a chunk:
@@ -137,7 +173,7 @@ def test_a_row_sees_its_own_pages_up_to_its_own_position(garbage, s):
     too. The answer is the clean pool's."""
     lengths = [s, BS - 4 + (s > 1) * s, FULL]
     pool, layer, table, pos, q, k_new, v_new = _case(
-        jnp.bfloat16, 8, lengths, s=s)
+        jnp.bfloat16, 8, lengths, s=s, packed=packed)
     table = table.at[0].set(0)
     clean, want = _reference(pool, layer, table, pos, q, k_new, v_new)
     seen = np.zeros((LAYERS, PAGES, BS), bool)
@@ -162,9 +198,10 @@ def test_a_row_sees_its_own_pages_up_to_its_own_position(garbage, s):
 
 @pytest.mark.parametrize("where", ["start", "mid-page", "table-end"])
 @pytest.mark.parametrize("bucket", BUCKETS)
-@pytest.mark.parametrize("group", [4, 8])
+@pytest.mark.parametrize("group,packed", [(4, None), (8, None), (4, 8)],
+                         ids=["4", "8", "4-kh8"])
 def test_a_chunk_of_every_bucket_matches_the_gathered_attention(
-    group, bucket, where
+    group, packed, bucket, where
 ):
     """A prefill chunk of each of the engine's buckets: the prompt's first
     (nothing before it), one that starts in the middle of a page, and one
@@ -173,35 +210,74 @@ def test_a_chunk_of_every_bucket_matches_the_gathered_attention(
     before = {"start": 0, "mid-page": 3 * BS + 5,
               "table-end": FULL - bucket}[where]
     pool, layer, table, pos, q, k_new, v_new = _case(
-        jnp.bfloat16, group, [before + bucket, bucket + BS + 3], s=bucket)
+        jnp.bfloat16, group, [before + bucket, bucket + BS + 3], s=bucket,
+        packed=packed)
     out, want = _reference(pool, layer, table, pos, q, k_new, v_new)
     _close(_kernel(out, layer, table, pos, q), want, jnp.bfloat16)
 
 
+@PACKED
 @pytest.mark.parametrize("real", [1, 19, 31])
-def test_a_chunks_padded_tail_is_clamped_onto_one_position(real):
+def test_a_chunks_padded_tail_is_clamped_onto_one_position(real, packed):
     """The engine pads a prompt's last chunk to its bucket and clamps the
     tail onto the one position after the prompt: the kernel reads the
     positions it is given, consecutive or not."""
     s = 32
     pool, layer, table, pos, q, k_new, v_new = _case(
-        jnp.bfloat16, 4, [3 * BS + 5 + s, FULL], s=s, real=real)
+        jnp.bfloat16, 4, [3 * BS + 5 + s, FULL], s=s, real=real,
+        packed=packed)
     assert int(pos[0, -1]) == int(pos[0, real]) == 3 * BS + 5 + real
     out, want = _reference(pool, layer, table, pos, q, k_new, v_new)
     _close(_kernel(out, layer, table, pos, q), want, jnp.bfloat16)
 
 
+@PACKED
 @pytest.mark.parametrize("s", [2, 5], ids=["k1", "k4"])
-def test_a_verify_round_matches_the_gathered_attention(s):
+def test_a_verify_round_matches_the_gathered_attention(s, packed):
     """A speculative round: every slot brings k + 1 consecutive positions
     from its own length on, one of them past the table's reach (its writes
     go to the trash page, ops/kvcache.py::_write; its queries see the whole
     table), one idle at position 0."""
     lengths = [s, 7 * BS + s, FULL - 1, FULL + 2]
     pool, layer, table, pos, q, k_new, v_new = _case(
-        jnp.bfloat16, 4, lengths, s=s)
+        jnp.bfloat16, 4, lengths, s=s, packed=packed)
     out, want = _reference(pool, layer, table, pos, q, k_new, v_new)
     _close(_kernel(out, layer, table, pos, q), want, jnp.bfloat16)
+
+
+@pytest.mark.parametrize("s", [1, 16], ids=["step", "chunk"])
+@pytest.mark.parametrize(
+    "kv_heads,hd,dtype",
+    [(3, 64, jnp.bfloat16), (2, 96, jnp.bfloat16), (2, 64, jnp.int8),
+     (2, 64, jnp.float32)],
+    ids=["kh3", "hd96", "int8", "f32"])
+def test_what_is_not_packed_still_gathers(kv_heads, hd, dtype, s):
+    """An odd count of 64-wide heads, a head width that does not divide a
+    row of 128, an int8 pool and a float32 pool are stored as declared, and
+    no kernel takes them: on a TPU too the op gathers, as before."""
+    pool = kvcache.init_paged_cache(
+        LAYERS, PAGES, BS, kv_heads, hd, dtype, quantized=dtype == jnp.int8)
+    assert pool["k"].shape == (LAYERS, PAGES, BS, kv_heads, hd)
+    # nor rows that do not fill the sublane tile Mosaic gives a page: two
+    # heads of 64 packed into one row, six heads of 128
+    for heads, width in ((2, 64), (6, 128)):
+        untiled = kvcache.init_paged_cache(
+            LAYERS, PAGES, BS, heads, width, jnp.bfloat16)
+        assert untiled["k"].shape[4] == 128
+        assert kvcache._kernel_for(
+            untiled["k"], jnp.zeros((2, s, 24, width), jnp.bfloat16)) is None
+    _, layer, table, pos, q, k_new, v_new = _case(
+        jnp.bfloat16, 4, [37, FULL], s=s, kv_heads=kv_heads, hd=hd)
+    assert kvcache._kernel_for(pool["k"], q) is None
+    out, got = _reference(pool, layer, table, pos, q, k_new, v_new)
+    # the context of a pool that held nothing is the new rows alone
+    plain = kvcache.init_paged_cache(
+        LAYERS, PAGES, BS, kv_heads, hd, jnp.float32)
+    _, want = _reference(plain, layer, table, pos, q, k_new, v_new)
+    assert out["k"].dtype == dtype
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32), np.asarray(want, np.float32),
+        atol=6e-2 if dtype == jnp.int8 else 2e-2, rtol=0)
 
 
 @pytest.mark.parametrize("kv_heads", [2, 4, 8])
@@ -244,33 +320,42 @@ def _greedy(model, cfg, params, prompts, max_tokens, **ec):
     return outs, eng
 
 
-@pytest.mark.parametrize("family", ["llama", "exaone_moe"])
+@pytest.mark.parametrize("family", ["llama", "exaone_moe", "llama-hd64"])
 def test_the_engine_serves_the_same_tokens_through_the_kernel(
     family, monkeypatch, pallas_interpret
 ):
     """Greedy tokens of a tiny paged engine, prefill chunks and decode
     steps through the kernels, equal those of the gather path: three
     requests of unlike lengths over four slots, so one row idles
-    throughout; the longest prompt takes three chunks. Heads are 128 wide:
-    the op leaves any other width on the gather path. The sparse family
+    throughout; the longest prompt takes three chunks. Heads are 128 wide,
+    or 64 wide in a pool that stores them two to a row (4 KV heads: two
+    rows a token); any other pool the op leaves on the gather path. The
+    sparse family
     routes every token to all its experts here: the kernels' outputs lie
     within one bfloat16 rounding of the gather's, and on random weights
     that flips a top-4-of-16 choice every few tokens, which says nothing
     of attention. (The prompts' seed matters: on random weights two logits
     now and then lie within one bfloat16 rounding of an attention output,
-    and of twelve seeded sets two flipped one request's token there.)"""
+    and of twelve seeded sets two flipped one request's token there; at
+    heads of 64, seeds 3 and 4 of 3 .. 10 did, with logits as far from the
+    gather's as at heads of 128: 0.03.)"""
     from substratus_tpu.models import exaone_moe, llama
 
     if family == "llama":
         model, cfg = llama, llama.CONFIGS["tiny"].replace(dim=512)
+    elif family == "llama-hd64":
+        model, cfg = llama, llama.CONFIGS["tiny"].replace(
+            dim=512, n_heads=8, n_kv_heads=4)
     else:
         model = exaone_moe
         cfg = exaone_moe.CONFIGS["tiny-exaone-moe"].replace(
             head_dim=128, n_experts_per_token=16)
-    assert cfg.dtype == jnp.bfloat16 and cfg.head_size == 128
+    assert cfg.dtype == jnp.bfloat16
+    assert cfg.head_size == (64 if family == "llama-hd64" else 128)
     params = model.init_params(cfg, jax.random.key(0))
     toks = np.asarray(jax.random.randint(
-        jax.random.key(3), (64,), 0, cfg.vocab_size))
+        jax.random.key(5 if family == "llama-hd64" else 3), (64,), 0,
+        cfg.vocab_size))
     prompts = [toks[:37], toks[3:26], toks[40:49]]
     ec = dict(max_batch=4, max_seq_len=96, max_prefill_len=16, page_size=4)
     want, _ = _greedy(model, cfg, params, prompts, 12, **ec)
@@ -280,10 +365,13 @@ def test_the_engine_serves_the_same_tokens_through_the_kernel(
         kernel = getattr(kvcache, name)
         monkeypatch.setattr(
             kvcache, name,
-            lambda *a, _k=kernel, _n=name: picked.append(_n) or _k(*a))
+            lambda *a, _k=kernel, _n=name, **kw: picked.append(_n)
+            or _k(*a, **kw))
     got, eng = _greedy(model, cfg, params, prompts, 12, **ec)
     jax.clear_caches()  # no later test meets a program traced here
     assert set(picked) == {"paged_chunk_attention", "_one_token"}
+    assert eng.cache["k"].shape[3:] == (cfg.n_kv_heads * cfg.head_size // 128,
+                                        128)
     assert got == want
     assert all(len(ids) == 12 for ids in got)
     assert (eng.positions[~eng.active] == 0).all()

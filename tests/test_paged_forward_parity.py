@@ -7,6 +7,8 @@ layer at a time with nothing taken from ops/kvcache.py: slice the layer out
 of the pool, write each new row at (page, offset) with the block table read
 on the host, gather whole pages, attend, stack the layers back.
 Moving rows changes no value, so logits and pool must agree to the bit.
+A pool of 64-wide heads is stored two to a row of 128 (`tiny-hd64`): the
+reference keeps its rows a head each, and the bytes must still agree.
 """
 import jax
 import jax.numpy as jnp
@@ -110,16 +112,22 @@ CASES = {
 }
 
 
+HD64 = llama.CONFIGS["tiny"].replace(dim=256)  # 4 heads / 2 KV heads of 64
+
+
 @pytest.mark.parametrize("case", list(CASES))
-@pytest.mark.parametrize("config", ["tiny", "tiny-moe"])
+@pytest.mark.parametrize("config", ["tiny", "tiny-moe", "tiny-hd64"])
 def test_paged_forward_matches_plain_per_layer_reference(
     config, case, monkeypatch
 ):
-    cfg = llama.CONFIGS[config]
+    cfg = HD64 if config == "tiny-hd64" else llama.CONFIGS[config]
     table, positions, quantized = CASES[case]
     table, positions = np.array(table, np.int32), np.array(positions, np.int32)
     params = llama.init_params(cfg, jax.random.key(1))
     pool = _random_pool(cfg, quantized, jax.random.key(2))
+    packed = config == "tiny-hd64" and not quantized
+    assert pool["k"].shape[3:] == (
+        (1, 128) if packed else (cfg.n_kv_heads, cfg.head_size))
     tokens = jax.random.randint(
         jax.random.key(3), positions.shape, 0, cfg.vocab_size, jnp.int32
     )
@@ -132,16 +140,22 @@ def test_paged_forward_matches_plain_per_layer_reference(
     monkeypatch.setattr(
         kvcache, "paged_attention", _layer_attention(table, positions),
     )
+    # the reference's rows are a KV head each, whatever row the pool stores
     want_logits, want = _reference_forward(
-        params, tokens, cfg, positions, pool, table
+        params, tokens, cfg, positions,
+        {name: a.reshape(a.shape[:3] + (cfg.n_kv_heads, -1))
+         for name, a in pool.items()},
+        table,
     )
 
     assert jax.tree.structure(out) == jax.tree.structure(pool)
     np.testing.assert_array_equal(np.asarray(logits), np.asarray(want_logits))
     for name in pool:
         assert out[name].dtype == pool[name].dtype, name
+        assert out[name].shape == pool[name].shape, name
         np.testing.assert_array_equal(
-            np.asarray(out[name]), np.asarray(want[name]), err_msg=name
+            np.asarray(out[name]).reshape(want[name].shape),
+            np.asarray(want[name]), err_msg=name,
         )
     if case == "trash":
         # Row 0's last write went to the trash page's slot 0 in every
@@ -152,3 +166,40 @@ def test_paged_forward_matches_plain_per_layer_reference(
         np.testing.assert_array_equal(
             np.asarray(out["k"][:, 0, 1:]), np.asarray(pool["k"][:, 0, 1:])
         )
+
+
+@pytest.mark.parametrize("kv_cache_dtype", ["model", "int8"])
+def test_the_engine_serves_the_same_tokens_from_a_packed_pool(
+    kv_cache_dtype, monkeypatch
+):
+    """Greedy tokens of a paged engine over heads of 64, its pool stored
+    two heads to a row, equal those over the pool stored a head a row (the
+    layout until PR 35, which an uneven split over devices still gets):
+    the same bytes through the same gather on the CPU, so to the token. An
+    int8 pool is stored as declared either way."""
+    from substratus_tpu.serve.engine import Engine, EngineConfig
+
+    params = llama.init_params(HD64, jax.random.key(0))
+    toks = np.asarray(jax.random.randint(
+        jax.random.key(3), (64,), 0, HD64.vocab_size))
+    prompts = [[int(t) for t in p]
+               for p in (toks[:37], toks[3:26], toks[40:49])]
+
+    def serve():
+        eng = Engine(HD64, params, EngineConfig(
+            max_batch=4, max_seq_len=96, max_prefill_len=16, page_size=4,
+            kv_cache_dtype=kv_cache_dtype))
+        eng.start()
+        try:
+            return eng, [eng.generate(p, max_tokens=12, temperature=0.0)
+                         for p in prompts]
+        finally:
+            eng.stop()
+
+    eng, got = serve()
+    packed = kv_cache_dtype == "model"
+    assert eng.cache["k"].shape[3:] == ((1, 128) if packed else (2, 64))
+    monkeypatch.setattr(kvcache, "kv_head_shards", lambda mesh: 3)
+    eng, want = serve()
+    assert eng.cache["k"].shape[3:] == (2, 64)
+    assert got == want

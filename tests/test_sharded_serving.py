@@ -166,3 +166,26 @@ def test_sequence_parallel_serving_long_prompt(setup):
     assert "sequence" in spec, spec
     sharded = _run(eng, [long_prompt, short_prompt])
     assert sharded == single, (sharded, single)
+
+
+@pytest.mark.parametrize("tensor,stored", [(2, (2, 128)), (4, (4, 64))])
+def test_a_tensor_mesh_splits_whole_pool_rows(tensor, stored):
+    """A bfloat16 pool of 4 KV heads of 64 stores them two to a row of 128
+    where the `tensor` axis divides the two rows a token (one row a device),
+    and a head a row where it does not (four devices: a head a device, as
+    before PR 35): the pool stays split over its KV heads either way, and
+    the engine serves from it."""
+    cfg = llama.CONFIGS["tiny"].replace(
+        vocab_size=258, dim=512, n_heads=8, n_kv_heads=4)
+    assert (cfg.head_size, cfg.dtype) == (64, jnp.bfloat16)
+    params = llama.init_params(cfg, jax.random.key(0))
+    mesh = build_mesh(data=8 // tensor, tensor=tensor)
+    eng = Engine(cfg, params, EngineConfig(
+        max_batch=4, max_seq_len=64, eos_token_id=257, page_size=4),
+        mesh=mesh)
+    k = eng.cache["k"]
+    assert k.shape[3:] == stored
+    assert k.sharding.spec[3] == "tensor"
+    assert k.sharding.shard_shape(k.shape)[3] == stored[0] // tensor
+    outs = _run(eng, [[256, 5, 6, 7], list(range(1, 40))])
+    assert all(len(ids) == 6 for ids in outs)

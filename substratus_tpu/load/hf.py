@@ -419,6 +419,47 @@ def convert_lfm2_moe_state_dict(sd: Mapping[str, Any], cfg: Any,
     )
 
 
+def config_from_hf_brumby(hf_cfg: Any):
+    """A transformers `brumby` config.json (Brumby-14B-Base) -> BrumbyConfig.
+    The file carries Qwen3's keys; what the retention operator adds to them
+    is models/brumby.py's `assumed`."""
+    from substratus_tpu.models.brumby import BrumbyConfig
+
+    get = lambda name, default=None: getattr(hf_cfg, name, default)
+    if (get("attention_bias", False) or get("rope_scaling")
+            or get("use_sliding_window", False)):
+        raise NotImplementedError(
+            "brumby: attention_bias, rope_scaling and a sliding window are "
+            "not written")
+    return BrumbyConfig(
+        vocab_size=hf_cfg.vocab_size,
+        dim=hf_cfg.hidden_size,
+        n_layers=hf_cfg.num_hidden_layers,
+        n_heads=hf_cfg.num_attention_heads,
+        n_kv_heads=get("num_key_value_heads") or hf_cfg.num_attention_heads,
+        head_dim=get("head_dim")
+        or hf_cfg.hidden_size // hf_cfg.num_attention_heads,
+        hidden_dim=hf_cfg.intermediate_size,
+        rope_theta=float(get("rope_theta", 1e6)),
+        norm_eps=get("rms_norm_eps", 1e-6),
+        max_seq_len=get("max_position_embeddings", 32768),
+        tie_embeddings=bool(get("tie_word_embeddings", False)),
+    )
+
+
+def convert_brumby_state_dict(sd: Mapping[str, Any], cfg: Any,
+                              dtype=jnp.bfloat16) -> Params:
+    """Not written, as convert_exaone_moe_state_dict is not: the tensor
+    names of the published brumby checkpoint were not at hand (no
+    network). The family is served from a named config (random weights) or
+    an orbax checkpoint of models/brumby.py's own tree."""
+    raise NotImplementedError(
+        "brumby: the config.json is read (config_from_hf_brumby) but no "
+        "converter maps the checkpoint's tensors onto models/brumby.py's "
+        "tree yet"
+    )
+
+
 def _dispatch_hf(model_type: str):
     """transformers model_type -> (config_fn, convert_fn), via the family
     registry (models/registry.py is the single dispatch table)."""
@@ -435,6 +476,8 @@ def _dispatch_hf(model_type: str):
         return config_from_hf_exaone_moe, convert_exaone_moe_state_dict
     if family == "lfm2_moe":
         return config_from_hf_lfm2_moe, convert_lfm2_moe_state_dict
+    if family == "brumby":
+        return config_from_hf_brumby, convert_brumby_state_dict
     raise NotImplementedError(
         f"unsupported HF model_type {model_type!r} "
         f"(supported: {sorted(HF_MODEL_TYPES)})"

@@ -10,7 +10,9 @@ from __future__ import annotations
 
 from typing import Any, Optional, Tuple
 
-from substratus_tpu.models import exaone_moe, falcon, lfm2_moe, llama, opt
+from substratus_tpu.models import (
+    brumby, exaone_moe, falcon, lfm2_moe, llama, opt,
+)
 
 FAMILIES = {
     "llama": llama,  # Llama 2/3, Mistral, Mixtral (MoE), TinyLlama
@@ -22,6 +24,9 @@ FAMILIES = {
     # LFM2-24B-A2B: gated short convolutions that keep two rows of state a
     # decode slot beside attention layers in pages, sigmoid-routed experts
     "lfm2_moe": lfm2_moe,
+    # Brumby-14B-Base: every layer's operator is power retention, which
+    # keeps a float32 state a decode slot and layer and no page at all
+    "brumby": brumby,
 }
 
 # transformers `model_type` -> family name (HF checkpoint dispatch).
@@ -33,6 +38,7 @@ HF_MODEL_TYPES = {
     "falcon": "falcon",
     "exaone_moe": "exaone_moe",
     "lfm2_moe": "lfm2_moe",
+    "brumby": "brumby",
 }
 
 _CONFIG_CLASS_TO_FAMILY = {
@@ -41,6 +47,7 @@ _CONFIG_CLASS_TO_FAMILY = {
     falcon.FalconConfig: "falcon",
     exaone_moe.ExaoneMoeConfig: "exaone_moe",
     lfm2_moe.Lfm2MoeConfig: "lfm2_moe",
+    brumby.BrumbyConfig: "brumby",
 }
 
 

@@ -75,7 +75,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from substratus_tpu.ops import scopes
+from substratus_tpu.ops import retention, scopes
 from substratus_tpu.ops.attention import dot_product_attention
 from substratus_tpu.ops.paged_attention import (
     LANES, paged_chunk_attention, paged_decode_attention,
@@ -437,6 +437,99 @@ def init_conv_state(n_layers: int, slots: int, taps: int, dim: int, dtype
 
 def conv_state_logical_axes() -> Dict[str, tuple]:
     return {CONV_STATE: ("layers", None, None, "embed")}
+
+
+RET_S, RET_Z = "ret_s", "ret_z"  # the cache dict's keys of a retention state
+
+
+def retention_read_and_update(
+    state_s: jnp.ndarray,  # [L, slots, KH, F, dv] float32
+    state_z: jnp.ndarray,  # [L, slots, KH, F] float32
+    layer: jnp.ndarray,  # scalar int32: the layer's index in the stack
+    slots: Optional[jnp.ndarray],  # [B] int32, or None: row i is slot i
+    positions: jnp.ndarray,  # [B, S] absolute positions, ascending in a row
+    valid: jnp.ndarray,  # [B, S] bool: real tokens; they lead their row
+    q: jnp.ndarray,  # [B, S, H, d]
+    k: jnp.ndarray,  # [B, S, KH, d]
+    v: jnp.ndarray,  # [B, S, KH, dv]
+    log_g: jnp.ndarray,  # [B, S, KH] float32: log of the gate
+) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """The state of a power-retention layer (ops/retention.py): each slot
+    owns one `S [KH, F, dv]` and one `z [KH, F]` of every layer, the sum of
+    everything its sequence has seen, and no history beside it. Returns
+    (state_s, state_z, o [B, S, H, dv] float32), the states updated in
+    place (the caller carries and donates them, as the paged pool): one
+    token a row takes the recurrent step, more the chunked form.
+
+    Under `conv_read_and_update`'s contract. A row whose first token is
+    real and at position 0 starts from zero whatever its slot holds:
+    nothing is zeroed at admission, and nothing tells the state's age but
+    `positions`. A token that is not real (a bucket's padded tail, an idle
+    slot's filler) decays nothing and adds nothing, so a row with no real
+    token leaves its slot's state bit for bit as it was. The state is
+    assumed to hold the positions below positions[:, 0]: true for a
+    sequence written in order from position 0 (prefill chunks, then decode
+    steps; a preempted sequence is prefilled again from 0).
+
+    With `slots` None the layer's rows are read and written as one slab
+    where they lie (a decode step: every row, whichever slots are live);
+    with `slots` given, row by row."""
+    n_slots = state_s.shape[1]
+    b, s = positions.shape
+    fresh = (positions[:, 0] == 0) & valid[:, 0]
+    k = jnp.where(valid[..., None, None], k, 0)
+    log_g = jnp.where(valid[..., None], log_g.astype(jnp.float32), 0.0)
+    layer = layer.astype(jnp.int32)
+    with jax.named_scope(scopes.RET_STATE):
+        if slots is None:
+            if b != n_slots:
+                raise ValueError(f"{b} rows for {n_slots} slots: pass `slots`")
+            old = [jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False)
+                   for a in (state_s, state_z)]
+        else:
+            def row(a, i):
+                at = (layer, slots[i].astype(jnp.int32)) + (0,) * (a.ndim - 2)
+                return jax.lax.dynamic_slice(a, at, (1, 1) + a.shape[2:])[0]
+
+            old = [jnp.concatenate([row(a, i) for i in range(b)])
+                   for a in (state_s, state_z)]
+        if s == 1:
+            new_s, new_z, o = retention.step(
+                *old, q[:, 0], k[:, 0], v[:, 0], log_g[:, 0], fresh)
+            o = o[:, None]
+        else:
+            new_s, new_z, o = retention.chunk(old, q, k, v, log_g, fresh)
+        out = []
+        for a, new in ((state_s, new_s), (state_z, new_z)):
+            if slots is None:
+                a = jax.lax.dynamic_update_index_in_dim(a, new, layer, 0)
+            else:
+                for i in range(b):
+                    at = ((layer, slots[i].astype(jnp.int32))
+                          + (0,) * (a.ndim - 2))
+                    a = jax.lax.dynamic_update_slice(a, new[i][None, None], at)
+            out.append(a)
+    return out[0], out[1], o
+
+
+def init_retention_state(n_layers: int, slots: int, kv_heads: int,
+                         head_dim: int, value_dim: int
+                         ) -> Dict[str, jnp.ndarray]:
+    """Per-slot state of the retention layers, float32 whatever the
+    activations' type (a sum over thousands of rank-one terms under a decay
+    near 1): `ret_s` [L, slots, KH, F, dv], `ret_z` [L, slots, KH, F], F =
+    head_dim (head_dim + 1) / 2."""
+    f = head_dim * (head_dim + 1) // 2
+    return {
+        RET_S: jnp.zeros((n_layers, slots, kv_heads, f, value_dim),
+                         jnp.float32),
+        RET_Z: jnp.zeros((n_layers, slots, kv_heads, f), jnp.float32),
+    }
+
+
+def retention_state_logical_axes() -> Dict[str, tuple]:
+    return {RET_S: ("layers", None, "kv_heads", None, "head_dim"),
+            RET_Z: ("layers", None, "kv_heads", None)}
 
 
 def init_paged_cache(

@@ -16,7 +16,10 @@ KV_RING and ATTN_WINDOW where a layer that keeps all of it opens KV_WRITE,
 KV_GATHER and ATTN_CORE, so the two kinds of history never share a row of
 a trace's table. A layer whose operator is a gated short convolution
 (models/lfm2_moe.py) opens CONV_IN, CONV_STATE and CONV_OUT where an
-attention layer opens the ATTN_ and KV_ names.
+attention layer opens the ATTN_ and KV_ names. A layer whose operator is
+power retention (models/brumby.py) keeps a state a decode slot and no
+history at all: it opens RET_STATE and RET_INTRA between ATTN_QKV and
+ATTN_OUT.
 """
 
 EMBED = "embed"              # token embedding gather
@@ -42,6 +45,11 @@ CONV_IN = "conv.in"          # a convolution layer's input projection and
 CONV_STATE = "conv.state"    # the read of the slot's rows, the taps, the rows
 # written back (ops/kvcache.py::conv_read_and_update and the taps beside it)
 CONV_OUT = "conv.out"        # the gate C * v and the output projection
+RET_STATE = "ret.state"      # a retention layer's per-slot state: read,
+# decay, update, write-back, read-out and normaliser (ops/retention.py::step;
+# in a chunk the carried state's read-out and the state's update)
+RET_INTRA = "ret.intra"      # a chunk's in-chunk scores under the decay and
+# their product with the values
 LM_HEAD = "lm_head"          # final norm and logits
 SAMPLE = "sample"            # RNG split and ops/sampling.py::sample
 
@@ -51,7 +59,9 @@ ALL = (
 )
 # What one family's block adds to the thirteen every block opens (the
 # benchmark lists them in that family's file, benchmarks/families/):
-# EXTRA models/exaone_moe.py's, CONV models/lfm2_moe.py's.
+# EXTRA models/exaone_moe.py's, CONV models/lfm2_moe.py's, RET
+# models/brumby.py's.
 EXTRA = (MOE_SHARED, KV_RING, ATTN_WINDOW)
 CONV = (CONV_IN, CONV_STATE, CONV_OUT)
-EVERY = ALL + EXTRA + CONV
+RET = (RET_STATE, RET_INTRA)
+EVERY = ALL + EXTRA + CONV + RET

@@ -133,10 +133,11 @@ METRICS.describe(
     "recompute (paged layout, serve/paged_kv.py).",
     type="counter",
 )
-# A family whose paged cache also holds per-slot state (PAGED_SLOT_STATE:
-# models/exaone_moe.py's rings, models/lfm2_moe.py's convolution rows) and
-# whose expert layer may hold a share of the experts: what its forward
-# counts, read with the step's tokens.
+# A family whose paged cache holds per-slot state (PAGED_SLOT_STATE:
+# models/exaone_moe.py's rings, models/lfm2_moe.py's convolution rows,
+# models/brumby.py's retention state). Where it has an expert layer that may
+# hold a share of the experts (it then has `step_counters`): what its
+# forward counts, read with the step's tokens.
 METRICS.describe(
     "substratus_serve_moe_pairs_total",
     "Token-expert pairs the router made, by whether the chosen expert is "
@@ -150,6 +151,14 @@ METRICS.histogram(
     "The most token-expert pairs one held expert of one sparse layer "
     "received in a decode step (active slots only).",
     buckets=(1, 2, 4, 8, 16, 32, 64, 128, 256),
+)
+METRICS.describe(
+    "substratus_serve_slot_state_bytes",
+    "Bytes of per-slot state in the paged cache dict: every leaf beside "
+    "the page pool (window layers' rings, convolution rows, a retention "
+    "layer's state). Set once at start-up; 0 for a family that keeps "
+    "pages alone.",
+    type="gauge",
 )
 METRICS.histogram(
     "substratus_serve_window_rows_live_ratio",
@@ -532,19 +541,21 @@ class Engine:
             raise ValueError(
                 f"kv_layout=dense unsupported for {model.__name__}"
             )
-        # A family whose paged cache also holds per-slot state: rows
-        # addressed by decode slot beside the pages (window layers' rings,
-        # convolution layers' input rows). The engine tells its forward
-        # which slot a row is and which tokens are real, and takes its
-        # per-step counters; pages alone do not carry such a sequence, so
-        # what moves or shares pages is refused or off.
+        # A family whose paged cache holds per-slot state: state addressed
+        # by decode slot beside the pages or in their place (window layers'
+        # rings, convolution layers' input rows, a retention layer's
+        # matrix). The engine tells its forward which slot a row is and
+        # which tokens are real, and takes its per-step counters where it
+        # has any; pages alone do not carry such a sequence, so what moves
+        # or shares pages is refused or off.
         self.slot_state = self.paged and getattr(
             model, "PAGED_SLOT_STATE", False
         )
         if self.slot_state and (ec.role != "both" or ec.spec_k):
             raise ValueError(
-                f"{model.__name__} keeps per-slot state beside its pages: "
-                "disaggregated roles and speculative decoding are unsupported"
+                f"{model.__name__} keeps per-slot state that no page "
+                "carries: disaggregated roles and speculative decoding are "
+                "unsupported"
             )
         if ec.role != "both" and not self.paged:
             # The handoff ships pool pages; the dense slot cache has no
@@ -605,6 +616,14 @@ class Engine:
                 "substratus_serve_kv_heads_per_pool_row",
                 pool["k"].shape[4] // cfg.head_size,
             )
+            METRICS.set(
+                "substratus_serve_slot_state_bytes",
+                sum(a.nbytes for name, a in pool.items()
+                    if name not in ("k", "v", "k_scale", "v_scale")),
+            )
+            # Layers that keep pages: none for a family whose every layer
+            # keeps per-slot state, and no attention then reads a page.
+            self._page_layers = pool["k"].shape[0]
             if mesh is not None:
                 pool = shard_tree(
                     pool,
@@ -684,8 +703,9 @@ class Engine:
             "kv_live_pages_sum": 0,
             "kv_pool_pages_sum": 0,
             # Same iterations: pages a decode step's attention needs
-            # (positions // page_size + 1 a decoding slot, 1 an idle row)
-            # and max_batch x max_pages, the table it would gather whole.
+            # (positions // page_size + 1 a decoding slot, 1 an idle row;
+            # none where the pool has no layer) and max_batch x max_pages,
+            # the table it would gather whole.
             "decode_kv_pages_read_sum": 0,
             "decode_kv_pages_table_sum": 0,
             # Added to once per chunk dispatch through a block-table row
@@ -704,16 +724,23 @@ class Engine:
         )
         self._conv_state = self.slot_state and kvcache.CONV_STATE in self.cache
         if self.slot_state:
-            # What a slot-state family's forward counts (models/hybrid.py::
-            # COUNTERS), summed over decode steps and prefill chunks as
-            # they are drained.
+            self.stats.update({
+                "prefix_reuse_refused": 0,
+                # per decoding iteration (_iterate): slots decoding, and
+                # max_batch, the rows of state a step over every slot moves
+                "state_rows_live_sum": 0,
+                "state_rows_sum": 0,
+            })
+        if self.slot_state and hasattr(model, "step_counters"):
+            # What such a family's forward counts where it has an expert
+            # layer (models/hybrid.py::COUNTERS), summed over decode steps
+            # and prefill chunks as they are drained.
             self.stats.update({
                 "moe_pairs_held": 0,
                 "moe_pairs_all": 0,
                 "moe_decode_steps": 0,
                 "moe_decode_pairs_held": 0,
                 "moe_decode_expert_pairs_max_sum": 0,
-                "prefix_reuse_refused": 0,
             })
         if self._ring_rows:
             # window rows are counted on the host, per decoding iteration
@@ -2075,8 +2102,9 @@ class Engine:
                 # The padded tail sits on position offset + clen
                 # (_chunk_prefill_jit); a full bucket ends one before it.
                 last = offset + min(clen, padded.shape[1] - 1)
-                self.stats["prefill_kv_pages_read_sum"] += (
-                    last // self.page_size + 1)
+                if self._page_layers:
+                    self.stats["prefill_kv_pages_read_sum"] += (
+                        last // self.page_size + 1)
                 self.stats["prefill_kv_pages_table_sum"] += self.max_pages
             if self._conv_state and slot is not None:
                 self.stats["conv_chunks_sum"] += 1
@@ -2952,7 +2980,8 @@ class Engine:
             # context, one page for an idle row) against the table
             # positions a gather over every entry reads.
             need = np.where(self.active, self.positions // self.page_size, 0)
-            self.stats["decode_kv_pages_read_sum"] += int(need.sum()) + need.size  # sublint: allow[hostsync]: host numpy mirrors, no device read
+            if self._page_layers:
+                self.stats["decode_kv_pages_read_sum"] += int(need.sum()) + need.size  # sublint: allow[hostsync]: host numpy mirrors, no device read
             self.stats["decode_kv_pages_table_sum"] += need.size * self.max_pages
             METRICS.observe(
                 "substratus_serve_kv_page_utilization_ratio",
@@ -2963,6 +2992,9 @@ class Engine:
                 (self.alloc.used_pages - live) / self.n_pages,
                 {"state": "cached"},
             )
+        if self.slot_state:
+            self.stats["state_rows_live_sum"] += int(n_active)
+            self.stats["state_rows_sum"] += self.ec.max_batch
         if self._ring_rows:
             # rows of a window layer's ring that hold a live sequence's
             # history: min(context, window) a decoding slot

@@ -499,6 +499,81 @@ def test_lfm2_programs_compile_and_leave_the_conv_state_in_place(v5e):
     assert "moe.experts/while" in programs["chunk"].compile().as_text()
 
 
+# The longctx cell's engine (benchmarks/traffic/longctx.json): Brumby-14B-
+# Base's first 10 layers, every one power retention, the whole vocabulary.
+_R_B, _R_S = 16, 9216
+
+
+def test_brumby_programs_compile_and_leave_the_state_in_place(v5e):
+    """The family whose cache is per-slot state alone: decode and the
+    512-token chunk compile for the chip at the published widths beside a
+    page pool of no layers; the 5.45 GB of retention state is the layer
+    scan's carry, read where it lies and written where it lies. A decode
+    step keeps under a third of one layer's slab (541 MB) in temporaries,
+    so no slab of the state is copied out of the carry, and no `copy` in
+    either program has the size of the state, a layer of it or a slot of
+    it; both open the family's two regions and no attention or page one."""
+    from substratus_tpu.models import brumby
+    from substratus_tpu.ops.quant import quantize_params
+    from substratus_tpu.serve.engine import Engine, EngineConfig
+
+    cfg = brumby.BrumbyConfig(n_layers=10, gate_shift=9.0)
+    eng = Engine(cfg, None, EngineConfig(
+        max_batch=_R_B, max_seq_len=_R_S, max_prefill_len=_CHUNK,
+        page_size=_PAGE, kv_pool_tokens=1,
+    ))
+    assert eng.slot_state and eng.prefix is None and eng._page_layers == 0
+    placed, arr = _described(v5e, eng)
+    params = placed(jax.eval_shape(
+        lambda key: quantize_params(
+            brumby.init_params(cfg, key), brumby.quant_contracting(cfg)),
+        jax.random.key(0)), None)
+    cache = placed(jax.eval_shape(
+        lambda: brumby.init_paged_cache(
+            cfg, _R_B * _R_S // _PAGE + 1, _PAGE, slots=_R_B)), None)
+    assert cache["k"].shape == (0, _R_B * _R_S // _PAGE + 1, _PAGE, 8, 128)
+    assert cache["ret_s"].shape == (10, _R_B, 8, 8256, 128)
+    assert cache["ret_s"].dtype == cache["ret_z"].dtype == jnp.float32
+    state = sum(cache[n].size * 4 for n in ("ret_s", "ret_z"))
+    assert 5.45e9 < state < 5.46e9
+    m = _R_S // _PAGE
+    programs = {
+        "decode": eng._decode_fn.lower(
+            params, cache, arr((_R_B, m)), arr((_R_B,)), arr((_R_B,)),
+            arr((_R_B,), jnp.float32), arr((_R_B,), jnp.float32),
+            arr(eng.key.shape, eng.key.dtype), None, None,
+            arr((_R_B,), jnp.bool_),
+        ),
+        "chunk": Engine._chunk_prefill_jit.lower(
+            brumby, cfg, params, cache, arr((1, _CHUNK)), arr(()), arr(()),
+            arr((1, m)), None, None, arr(()),
+        ),
+    }
+    s_all = cache["ret_s"].size
+    sizes = {s_all, s_all // 10, s_all // 10 // _R_B}  # whole, layer, slot
+    sizes |= {n // 128 for n in sizes}  # the same of z
+    temp_limit = {"decode": 0.18e9, "chunk": 2.0e9}
+    for name, lowered in programs.items():
+        compiled = lowered.compile()
+        hlo = compiled.as_text()
+        assert all(r in hlo for r in ("ret.state", "attn.qkv", "attn.out"))
+        assert ("ret.intra" in hlo) == (name == "chunk"), name
+        assert not any(r in hlo for r in ("kv.write", "kv.gather",
+                                          "attn.core", "tpu_custom_call"))
+        f32 = "\n".join(l for l in hlo.splitlines() if "= f32[" in l)
+        assert [op for op in _pool_moving_ops(f32, sizes)
+                if " copy(" in op] == [], name
+        assert not re.search(r"= s8\[[\d,]+\]\S* copy\(", hlo), name
+        mem = compiled.memory_analysis()
+        # the state is donated and comes back as the same buffers
+        assert mem.alias_size_in_bytes >= state, name
+        # decode: 11 MB at PR 36; the chunk's 1.5 GB are phi(q) of 40 heads
+        # x 512 tokens in bfloat16 (338 MB) beside a turned copy of it and
+        # the logits of 512 rows. Beside 5.64 GB of weights and the state
+        assert mem.temp_size_in_bytes < temp_limit[name], (
+            name, mem.temp_size_in_bytes)
+
+
 @pytest.mark.parametrize("program", ["decode", "chunk"])
 def test_tinyllama_paged_programs_compile_for_v5e(program, v5e):
     """What `serve.main --config tinyllama-1.1b` compiles on a TPU at its

@@ -118,8 +118,9 @@ def test_vocabulary_is_closed_and_matches_the_benchmarks_copy():
     assert len(set(scopes.ALL)) == len(scopes.ALL) == 13
     # what one family's block adds is listed in that family's file, and
     # the benchmark's readers charge an op to any of them
-    assert len(set(scopes.EVERY)) == len(scopes.EVERY) == 19
-    assert set(scopes.EXTRA + scopes.CONV) <= trace_scopes.vocabulary()
+    assert len(set(scopes.EVERY)) == len(scopes.EVERY) == 21
+    assert set(scopes.EXTRA + scopes.CONV + scopes.RET) <= (
+        trace_scopes.vocabulary())
 
 
 def test_a_region_name_is_metadata_and_changes_no_arithmetic(monkeypatch):

@@ -11,6 +11,7 @@ Widths: TinyLlama-1.1B (32 heads / 4 kv heads of 64, dim 2048, hidden
 5632), Llama-2-7B (32/32 heads of 128, dim 4096, hidden 11008) and, for
 attention over a cache, Mistral-7B (32 heads / 8 kv heads of 128) and
 LFM2-24B-A2B (32 / 8 of 64, stored two to a row of 128 in the paged pool);
+for a retention state, Brumby-14B (40 / 8 of 128: 8,256 x 128 a head);
 prefill lengths are the engine's power-of-two buckets up to
 EngineConfig.max_prefill_len (16..512) plus the trainer's 1024/2048;
 cache lengths are EngineConfig.max_seq_len (1024) and the old bench's
@@ -341,6 +342,48 @@ def q4_matmul(tag, m, c, n) -> KernelCase:
     )
 
 
+# --- a power-retention layer's decode step over its stacked state ---------------
+
+
+def retention_decode(tag, b, h, kh, d, layers=2, seen=4) -> KernelCase:
+    """One token a row, every slot a row, against the last layer of a
+    float32 state of `layers`: what ops/kvcache.py::retention_read_and_update
+    runs on a TPU (ops/retention_kernel.py) beside ops/retention.py::step.
+    The state is the sum of `seen` keys' `phi(k) v^T`, so the normaliser is
+    a sum of squares as a served one is; row 0 starts afresh, the last row
+    idles (`k = 0`, `log g = 0`)."""
+    from substratus_tpu.ops import retention, retention_kernel
+
+    def make_args(key):
+        ks = jax.random.split(key, 6)
+        pk = retention.phi(_normal(ks[0], (layers, b, kh, seen, d)))
+        state_s = jnp.einsum(
+            "lbkjf,lbkjd->lbkfd", pk,
+            _normal(ks[1], (layers, b, kh, seen, d), jnp.float32))
+        idle = jnp.arange(b) == b - 1
+        k = jnp.where(idle[:, None, None], 0, _normal(ks[3], (b, kh, d)))
+        log_g = jax.nn.log_sigmoid(
+            jax.random.uniform(ks[5], (b, kh), minval=8.0, maxval=10.0))
+        return (
+            state_s, pk.sum(axis=3), jnp.int32(layers - 1),
+            _normal(ks[2], (b, h, d)), k, _normal(ks[4], (b, kh, d)),
+            jnp.where(idle[:, None], 0.0, log_g), jnp.arange(b) == 0,
+        )
+
+    def kernel(state_s, state_z, layer, *row, interpret=False):
+        s, z, o = retention_kernel.step(
+            state_s, state_z, layer, *row, interpret=interpret)
+        return s[-1], z[-1], o
+
+    def reference(state_s, state_z, layer, *row):
+        return retention.step(state_s[-1], state_z[-1], *row)
+
+    return KernelCase(
+        f"retention_decode/{tag}/b{b}-h{h}-d{d}", make_args, kernel,
+        reference, tol=1e-4,
+    )
+
+
 # --- the lists ---------------------------------------------------------------
 
 # What the chip's compiler says of a kernel wrapped in custom_partitioning
@@ -402,6 +445,9 @@ def chip_cases() -> List[KernelCase]:
     cases.append(paged_chunk("mistral-verify", 32, 5, 2048, 32, 8, 128, 1793))
     cases.append(paged_chunk("lfm2-assist", 1, 512, 2048, pages=6145, **LFM2))
     cases.append(paged_chunk("tinyllama", 1, 512, 1024, pages=513, **TINYLLAMA))
+    # The longctx cell's decode step of one retention layer: 16 slots, 40
+    # query heads over 8 of 128, a state of 8,256 x 128 a head.
+    cases.append(retention_decode("brumby-longctx", 16, 40, 8, 128))
     return cases
 
 
@@ -418,6 +464,7 @@ def rehearsal_cases() -> List[KernelCase]:
         q4_matmul("small", 8, 256, 128),
         paged_decode("small", 3, 128, h=4, kh=2, d=64, pages=17),
         paged_chunk("small", 2, 16, 128, h=8, kh=4, d=64, pages=17),
+        retention_decode("small", 3, h=4, kh=2, d=16),
     ]
 
 

@@ -75,7 +75,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from substratus_tpu.ops import retention, scopes
+from substratus_tpu.ops import retention, retention_kernel, scopes
 from substratus_tpu.ops.attention import dot_product_attention
 from substratus_tpu.ops.paged_attention import (
     LANES, paged_chunk_attention, paged_decode_attention,
@@ -473,17 +473,26 @@ def retention_read_and_update(
 
     With `slots` None the layer's rows are read and written as one slab
     where they lie (a decode step: every row, whichever slots are live);
-    with `slots` given, row by row."""
+    with `slots` given, row by row. The decode step (`slots` None, one
+    token a row) lowered for a TPU over a state `_retention_kernel_for`
+    takes is ops/retention_kernel.py::step: every head's `S` crosses HBM
+    once in each direction where the XLA program reads it twice and writes
+    it once. Everything else (a chunk, `slots` given, any other platform,
+    a state the kernel is not written for) is ops/retention.py, which is
+    also what the kernel is tested against."""
     n_slots = state_s.shape[1]
     b, s = positions.shape
     fresh = (positions[:, 0] == 0) & valid[:, 0]
     k = jnp.where(valid[..., None, None], k, 0)
     log_g = jnp.where(valid[..., None], log_g.astype(jnp.float32), 0.0)
     layer = layer.astype(jnp.int32)
-    with jax.named_scope(scopes.RET_STATE):
+    if slots is None and b != n_slots:
+        raise ValueError(f"{b} rows for {n_slots} slots: pass `slots`")
+    kernel = (_retention_kernel_for(state_s)
+              if slots is None and s == 1 else None)
+
+    def in_xla():
         if slots is None:
-            if b != n_slots:
-                raise ValueError(f"{b} rows for {n_slots} slots: pass `slots`")
             old = [jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False)
                    for a in (state_s, state_z)]
         else:
@@ -509,7 +518,68 @@ def retention_read_and_update(
                           + (0,) * (a.ndim - 2))
                     a = jax.lax.dynamic_update_slice(a, new[i][None, None], at)
             out.append(a)
-    return out[0], out[1], o
+        return out[0], out[1], o
+
+    def in_kernel():
+        new_s, new_z, o = kernel(
+            state_s, state_z, layer, q[:, 0], k[:, 0], v[:, 0], log_g[:, 0],
+            fresh)
+        return new_s, new_z, o[:, None]
+
+    with jax.named_scope(scopes.RET_STATE):
+        if kernel is None:
+            return in_xla()
+        return jax.lax.platform_dependent(tpu=in_kernel, default=in_xla)
+
+
+def _retention_state_specs() -> Tuple[P, P]:
+    """The PartitionSpecs of `ret_s` and `ret_z` under the serve rules;
+    [2] is the mesh axis of kv_heads."""
+    axes = retention_state_logical_axes()
+    return SERVE_RULES.mesh_axes(axes[RET_S]), SERVE_RULES.mesh_axes(axes[RET_Z])
+
+
+def _retention_kernel_for(state_s):
+    """ops/retention_kernel.py::step as this state's placement lets it run
+    a decode step (one token a row, row i slot i): as it is on one device;
+    a shard of KV heads a device where the state is sharded over them and
+    nothing else is sharded (a head's state is its own: no collective).
+    None where the kernel is not written for the case: a state that is not
+    float32, values that are no multiple of the 128 lanes wide or not as
+    wide as the keys (the kernel makes `phi` from rotations of a 128-lane
+    row), one slot's heads more than a grid step holds, and any other
+    placement."""
+    n_slots, kv_heads, f, dv = state_s.shape[1:]
+    if (state_s.dtype != jnp.float32 or dv % LANES
+            or f != retention.width(dv)):
+        return None
+    mesh = jax.typeof(state_s).sharding.mesh
+    sharded = {name: n for name, n in mesh.shape.items() if n > 1}
+    spec_s, spec_z = _retention_state_specs()
+    heads = spec_s[2]  # the mesh axis of kv_heads
+    if sharded and (
+        list(sharded) != [heads] or kv_heads % sharded[heads]
+    ):
+        return None
+    if not retention_kernel.slots_a_step(
+            n_slots, kv_heads // sharded.get(heads, 1), f, dv):
+        return None
+    if not sharded:
+        return retention_kernel.step
+    row = P(None, heads)  # q [B, H, d], k, v, log_g [B, KH]: heads second
+    return jax.shard_map(
+        retention_kernel.step, mesh=mesh,
+        in_specs=(spec_s, spec_z, P(), row, row, row, row, P()),
+        out_specs=(spec_s, spec_z, row), check_vma=False,
+    )
+
+
+def retention_step_takes_kernel(state_s: jax.Array) -> bool:
+    """Whether a decode step over this state runs ops/retention_kernel.py:
+    what `retention_read_and_update` chooses for it, on the devices that
+    hold it (for the engine's `state_kernel_steps`)."""
+    return (all(d.platform == "tpu" for d in state_s.devices())
+            and _retention_kernel_for(state_s) is not None)
 
 
 def init_retention_state(n_layers: int, slots: int, kv_heads: int,

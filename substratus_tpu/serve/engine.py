@@ -731,6 +731,16 @@ class Engine:
                 "state_rows_live_sum": 0,
                 "state_rows_sum": 0,
             })
+        self._state_kernel = False
+        if self.slot_state and kvcache.RET_S in self.cache:
+            # Decoding iterations whose program moved the retention state
+            # through ops/retention_kernel.py (every head's S once in,
+            # once out) and not through XLA's two reads and a write:
+            # decided once, by what ops/kvcache.py reads off the state for
+            # the decode program, so all of them or none.
+            self._state_kernel = kvcache.retention_step_takes_kernel(
+                self.cache[kvcache.RET_S])
+            self.stats["state_kernel_steps"] = 0
         if self.slot_state and hasattr(model, "step_counters"):
             # What such a family's forward counts where it has an expert
             # layer (models/hybrid.py::COUNTERS), summed over decode steps
@@ -2995,6 +3005,8 @@ class Engine:
         if self.slot_state:
             self.stats["state_rows_live_sum"] += int(n_active)
             self.stats["state_rows_sum"] += self.ec.max_batch
+        if self._state_kernel:
+            self.stats["state_kernel_steps"] += 1
         if self._ring_rows:
             # rows of a window layer's ring that hold a live sequence's
             # history: min(context, window) a decoding slot

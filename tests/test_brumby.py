@@ -272,6 +272,55 @@ def test_the_engine_serves_the_family_through_submit(params, tokens):
     # slots decoding of the rows a step moves
     assert 0 < st["state_rows_live_sum"] <= st["state_rows_sum"]
     assert st["state_rows_sum"] % SLOTS == 0
+    # values of 16 lanes: the step is ops/retention.py's, not the kernel's
+    assert st["state_kernel_steps"] == 0
+
+
+def test_the_kernel_path_serves_the_tokens_of_the_step_path(
+        monkeypatch, pallas_interpret):
+    """Heads of 128 (the width ops/retention_kernel.py is written for; two
+    layers, one KV head under two query heads, two slots): the same seed
+    served twice, through XLA's step as the CPU takes it and through the
+    kernel, interpreted, as a TPU would. The tokens are equal, the decode
+    program called the kernel, and `state_kernel_steps` counts the
+    decoding iterations of the second engine and none of the first."""
+    cfg = M.BrumbyConfig(
+        vocab_size=256, dim=64, n_layers=2, n_heads=2, n_kv_heads=1,
+        head_dim=128, hidden_dim=128, max_seq_len=64, dtype=jnp.float32)
+    wide = quantize_params(M.init_params(cfg, jax.random.key(2)),
+                           M.quant_contracting(cfg))
+    toks = np.asarray(jax.random.randint(jax.random.key(4), (40,), 0, 256))
+    prompts = [toks[:21], toks[25:34]]
+
+    def served():
+        eng = Engine(cfg, wide, EngineConfig(
+            max_batch=2, max_seq_len=64, max_prefill_len=CHUNK,
+            page_size=PAGE), model=M)
+        assert eng.cache[kvcache.RET_S].shape == (2, 2, 1, 8256, 128)
+        eng.start()
+        outs = submit_all(eng, prompts, 10)
+        eng.stop()
+        assert eng.error is None
+        return outs, eng.stats
+
+    in_xla, st = served()
+    assert st["state_kernel_steps"] == 0 < st["state_rows_sum"]
+    calls = []
+    monkeypatch.setattr(
+        kvcache.retention_kernel, "step",
+        lambda *a, _k=kvcache.retention_kernel.step, **kw:
+        calls.append(1) or _k(*a, **kw))
+    monkeypatch.setattr(jax.lax, "platform_dependent",
+                        lambda *args, tpu, default: tpu(*args))
+    # what the engine asks of a state its TPU holds
+    monkeypatch.setattr(kvcache, "retention_step_takes_kernel",
+                        lambda s: kvcache._retention_kernel_for(s) is not None)
+    jax.clear_caches()
+    in_kernel, st = served()
+    jax.clear_caches()  # no later test meets a program traced here
+    assert calls and in_kernel == in_xla
+    assert all(len(ids) == 10 for ids in in_kernel)
+    assert st["state_kernel_steps"] * 2 == st["state_rows_sum"] > 0
 
 
 def test_the_state_stays_float32_under_bfloat16_activations(params):

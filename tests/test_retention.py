@@ -191,3 +191,106 @@ def test_rows_by_slot_and_the_slab_are_the_same_step(seq):
     for name in slab:
         assert np.array_equal(np.asarray(by_row[name]),
                               np.asarray(slab[name]))
+
+
+# -- the decode step as one kernel (ops/retention_kernel.py) -----------------------
+
+@pytest.mark.parametrize("group", [1, 5], ids=["G1", "G5"])
+@pytest.mark.parametrize("row", ["live", "fresh", "idle"])
+@pytest.mark.parametrize("d", [16, 128], ids=["d16-F136", "d128-F8256"])
+def test_the_state_kernel_is_the_recurrent_step(d, row, group):
+    """The kernel, interpreted, against `step` on layer 1 of a stack of two,
+    two slots, float32 in and out: row 0 is a live row; row 1 is live, or
+    fresh (position 0 over a slot that holds infinities: nothing of them
+    is kept), or idle (`k = 0`, `log g = 0`: its `S` and `z` come back bit
+    for bit). The two differ by the order of float32 sums alone. Layer 0
+    is not touched."""
+    from substratus_tpu.ops import retention_kernel
+
+    kh, f = 2 if d == 16 else 1, R.width(d)
+    ks = jax.random.split(jax.random.key(d + group), 6)
+    # a state as three tokens leave it, so the normaliser is a sum of
+    # squares and the division is as well conditioned as a served one
+    seen = R.phi(jax.random.normal(ks[0], (2, 2, kh, 3, d)))
+    s0 = jnp.einsum("lbkjf,lbkjd->lbkfd", seen,
+                    jax.random.normal(ks[1], (2, 2, kh, 3, d)))
+    z0 = seen.sum(axis=3)
+    q = jax.random.normal(ks[2], (2, kh * group, d))
+    k = jax.random.normal(ks[3], (2, kh, d))
+    v = jax.random.normal(ks[4], (2, kh, d))
+    log_g = jax.nn.log_sigmoid(
+        jax.random.uniform(ks[5], (2, kh), minval=3.0, maxval=7.0))
+    fresh = jnp.array([False, row == "fresh"])
+    if row == "fresh":
+        s0, z0 = s0.at[1, 1].set(jnp.inf), z0.at[1, 1].set(jnp.inf)
+    if row == "idle":
+        k, log_g = k.at[1].set(0), log_g.at[1].set(0)
+    want_s, want_z, want_o = R.step(s0[1], z0[1], q, k, v, log_g, fresh)
+    s1, z1, o = retention_kernel.step(
+        s0, z0, jnp.int32(1), q, k, v, log_g, fresh, interpret=True)
+    assert s1.dtype == z1.dtype == o.dtype == jnp.float32
+    assert s1.shape == s0.shape and z1.shape == z0.shape
+
+    def close(got, want):
+        got, want = np.asarray(got), np.asarray(want)
+        assert np.array_equal(np.isnan(got), np.isnan(want))
+        scale = max(1.0, np.nanmax(np.abs(want)))
+        return np.nanmax(np.abs(got - want)) / scale < TOL
+
+    assert close(s1[1], want_s) and close(z1[1], want_z) and close(o, want_o)
+    assert np.isfinite(np.asarray(s1)).all() and np.isfinite(z1).all()
+    assert np.isfinite(np.asarray(o[0])).all()
+    for got, was in ((s1, s0), (z1, z0)):
+        assert np.array_equal(np.asarray(got[0]), np.asarray(was[0]))
+        if row == "idle":
+            assert np.array_equal(np.asarray(got[1, 1]).view(np.uint32),
+                                  np.asarray(was[1, 1]).view(np.uint32))
+            assert not np.array_equal(np.asarray(got[1, 0]),
+                                      np.asarray(was[1, 0]))
+
+
+def test_the_decode_step_takes_the_kernel_where_it_is_written_for_the_state(
+        monkeypatch, pallas_interpret):
+    """`retention_read_and_update` reads the choice off its inputs: the
+    slab of a float32 state of 128-wide values, one token a row, lowered
+    for a TPU, is the kernel's; a chunk, rows addressed by slot, a narrower
+    value, another type and any other platform are ops/retention.py's."""
+    f = R.width(128)
+    st = {kvcache.RET_S: jnp.ones((1, 2, 1, f, 128)),
+          kvcache.RET_Z: jnp.ones((1, 2, 1, f))}
+    taken = kvcache._retention_kernel_for
+    assert taken(st[kvcache.RET_S]) is not None
+    assert taken(st[kvcache.RET_S].astype(jnp.bfloat16)) is None
+    assert taken(jnp.ones((1, 2, 1, 136, 16))) is None
+    shape = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)  # noqa: E731
+    assert taken(shape(10, 16, 8, f, 128)) is not None  # the longctx cell's
+    assert taken(shape(10, 16, 16, f, 128)) is None  # a slot's heads too many
+    assert taken(shape(10, 16, 8, R.width(64), 128)) is None  # keys of 64
+    # on the CPU that holds it the step is XLA's, whatever the shape
+    assert not kvcache.retention_step_takes_kernel(st[kvcache.RET_S])
+    ks = jax.random.split(jax.random.key(3), 4)
+    args = (jax.random.normal(ks[0], (2, 1, 5, 128)),
+            jax.random.normal(ks[1], (2, 1, 1, 128)),
+            jax.random.normal(ks[2], (2, 1, 1, 128)),
+            jax.nn.log_sigmoid(jnp.full((2, 1, 1), 5.0)))
+    calls = []
+    monkeypatch.setattr(
+        kvcache.retention_kernel, "step",
+        lambda *a, _k=kvcache.retention_kernel.step, **kw:
+        calls.append(1) or _k(*a, **kw))
+    want, o_want = _call(st, None, [[3], [0]], [[True], [True]], *args,
+                         layer=0)
+    assert not calls  # the platform is not a TPU
+    monkeypatch.setattr(jax.lax, "platform_dependent",
+                        lambda *a, tpu, default: tpu(*a))
+    calls.clear()
+    got, o_got = _call(st, None, [[3], [0]], [[True], [True]], *args, layer=0)
+    assert calls
+    assert np.abs(o_got - o_want).max() < TOL
+    for name in st:
+        assert np.abs(np.asarray(got[name] - want[name])).max() < TOL
+    calls.clear()
+    _call(st, [0, 1], [[3], [0]], [[True], [True]], *args, layer=0)
+    two = [jnp.concatenate([a, a], axis=1) for a in args]
+    _call(st, None, [[3, 4], [0, 1]], np.ones((2, 2), bool), *two, layer=0)
+    assert not calls

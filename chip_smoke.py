@@ -38,6 +38,7 @@ import argparse
 import json
 import os
 import random
+import re
 import shutil
 import signal
 import socket
@@ -55,7 +56,8 @@ sys.path.insert(0, HERE)
 
 from substratus_tpu.utils.childenv import child_env, run_child  # noqa: E402
 from substratus_tpu.utils.jaxstart import (  # noqa: E402
-    CACHE_ENV, CHECKOUT_CACHE_DIR, DEVICE_LINE_PREFIX, MEMORY_LINE_PREFIX,
+    BUILD_STAGES, CACHE_ENV, CHECKOUT_CACHE_DIR, DEVICE_LINE_PREFIX,
+    MEMORY_LINE_PREFIX,
 )
 
 RESULT_PREFIX = "chip_smoke result: "
@@ -423,6 +425,20 @@ COMPILE_S = "substratus_jax_compile_seconds_total"
 CACHE_HITS = "substratus_jax_compile_cache_hits_total"
 
 
+BUILD_S = "substratus_jax_build_seconds_total"
+
+
+def stages_of(metrics: Dict[str, float]) -> Dict[str, float]:
+    """Seconds of building by stage (trace, lower, cache_read, compile),
+    summed over the programs of substratus_jax_build_seconds_total."""
+    out = {stage: 0.0 for stage in BUILD_STAGES}
+    for key, v in metrics.items():
+        m = re.match(BUILD_S + r'\{.*stage="(\w+)"', key)
+        if m:
+            out[m.group(1)] = round(out[m.group(1)] + v, 2)
+    return out
+
+
 def memory_of(metrics: Dict[str, float]) -> Dict[str, int]:
     """{"peak_bytes_in_use{device=..}": n, "bytes_in_use{device=..}": n}"""
     prefix = "substratus_device_"
@@ -480,6 +496,7 @@ def phase_serve(run: Run, name: str) -> dict:
         "compilations": warm.get(COMPILES, 0),
         "compile_s": round(warm.get(COMPILE_S, 0.0), 1),
         "cache_hits": warm.get(CACHE_HITS, 0),
+        "build_stages_s": stages_of(warm),
         "round_s": round(round_s, 2), "usage": produced,
         "memory": memory_of(after),
     }
@@ -487,7 +504,7 @@ def phase_serve(run: Run, name: str) -> dict:
         f"{result['compilations']:.0f} executables in "
         f"{result['compile_s']}s of compilation "
         f"({result['cache_hits']:.0f} from the cache at {run.cache_dir}); "
-        f"0 after it")
+        f"0 after it; seconds by stage {result['build_stages_s']}")
     say(f"  {name}: second round of 8 requests in {result['round_s']}s: "
         f"usage short={produced['short']} long={produced['long']} "
         f"chat={produced['chat']} sse_chunks={produced['sse_chunks']} "

@@ -54,6 +54,16 @@ def escape_label_value(value: object) -> str:
     )
 
 
+# One name="value" pair of a canonical label string (_labelstr).
+_PAIR_RE = re.compile(r'([a-zA-Z_][a-zA-Z0-9_]*)="((?:[^"\\]|\\.)*)"')
+
+
+def _unescape_label_value(value: str) -> str:
+    return re.sub(
+        r"\\(.)", lambda m: "\n" if m.group(1) == "n" else m.group(1), value
+    )
+
+
 def _escape_help(text: str) -> str:
     return text.replace("\\", "\\\\").replace("\n", "\\n")
 
@@ -231,6 +241,18 @@ class Metrics:
             if key in self._hists:
                 return float(self._hists[key].count)
             return self.counters.get(key)
+
+    def series(self, name: str) -> List[Tuple[Dict[str, str], float]]:
+        """Every series of one counter or gauge family, as (labels, value):
+        what a scrape of the family would parse, without the text."""
+        with self._lock:
+            found = [(ls, v) for (n, ls), v in self.counters.items()
+                     if n == name]
+        return [
+            ({k: _unescape_label_value(v) for k, v in _PAIR_RE.findall(ls)},
+             value)
+            for ls, value in found
+        ]
 
     def histogram_series(self, name: str) -> Dict[str, dict]:
         """Snapshot of one histogram family, keyed by the canonical label

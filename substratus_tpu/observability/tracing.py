@@ -92,19 +92,22 @@ class Span:
             _current.reset(self._token)
         if exc_type is not None:
             self.status = f"error:{exc_type.__name__}"
+        self._finish(self._start_wall_us, duration_us)
+        return False  # never swallow
+
+    def _finish(self, start_us: int, duration_us: int) -> None:
         self._tracer._record(
             {
                 "trace_id": self.trace_id,
                 "span_id": self.span_id,
                 "parent_id": self.parent_id,
                 "name": self.name,
-                "start_us": self._start_wall_us,
+                "start_us": start_us,
                 "duration_us": duration_us,
                 "attributes": self.attributes,
                 "status": self.status,
             }
         )
-        return False  # never swallow
 
 
 class _Attached:
@@ -143,6 +146,19 @@ class Tracer:
         Explicit always wins — the contextvar is never consulted once the
         caller said what the parent is."""
         return Span(self, name, parent, attributes)
+
+    def record_span(
+        self, name: str, start_s: float, end_s: float, parent=_UNSET,
+        **attributes,
+    ) -> SpanContext:
+        """A finished span that somebody else timed: `start_s` and `end_s`
+        are epoch seconds as that clock gave them (jax.monitoring reports
+        a build's stages so, after the fact). `parent` as for `span`; the
+        span was never current, so nothing can have nested under it."""
+        span = Span(self, name, parent, attributes)
+        start_us = int(start_s * 1e6)
+        span._finish(start_us, max(int(end_s * 1e6) - start_us, 0))
+        return span.context()
 
     def current_context(self) -> Optional[SpanContext]:
         """The active span's context — capture this before handing work to

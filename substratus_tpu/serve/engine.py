@@ -50,6 +50,7 @@ from substratus_tpu.observability.tracing import (
 )
 from substratus_tpu.ops import kvcache, scopes
 from substratus_tpu.ops.sampling import sample
+from substratus_tpu.utils.jaxstart import phase as startup_phase
 
 # Serving latency/utilization histograms (docs/observability.md). Declared
 # once at import so /metrics carries the HELP/TYPE headers even before the
@@ -443,6 +444,7 @@ def _pad_to_bucket(tokens, cap: int):
 
 
 class Engine:
+    @startup_phase("startup.engine")
     def __init__(
         self,
         cfg: LlamaConfig,
@@ -582,76 +584,80 @@ class Engine:
                 params, mesh, model.param_logical_axes(cfg), self._serve_rules
             )
 
-        if self.paged:
-            from substratus_tpu.serve.paged_kv import (
-                PageAllocator,
-                PrefixRegistry,
-                SlotPages,
-            )
-
-            bs = ec.page_size
-            if bs < 1:
-                raise ValueError(f"page_size {bs} invalid")
-            if ec.kv_pool_tokens is not None and ec.kv_pool_tokens < 1:
-                raise ValueError(
-                    f"kv_pool_tokens {ec.kv_pool_tokens} invalid"
+        # The pool, the rings and the per-slot state: what the engine
+        # allocates on the device (docs/observability.md "Start-up").
+        with startup_phase("engine.build.cache"):
+            if self.paged:
+                from substratus_tpu.serve.paged_kv import (
+                    PageAllocator,
+                    PrefixRegistry,
+                    SlotPages,
                 )
-            # A single full-length sequence (+ its pad slot) must always fit.
-            pool_tokens = (
-                B * S if ec.kv_pool_tokens is None else ec.kv_pool_tokens
-            )
-            pool_tokens = max(pool_tokens, S + bs)
-            self.page_size = bs
-            self.n_pages = -(-pool_tokens // bs)
-            self.max_pages = -(-S // bs)  # block-table width per slot
-            # Physical page 0 is the trash page: idle slots' decode writes
-            # land there (their block-table rows are zero), never in a live
-            # page. The allocator hands out ids 1..n_pages.
-            pool = model.init_paged_cache(
-                cfg, self.n_pages + 1, bs, dtype=cache_dtype,
-                kv_shards=kvcache.kv_head_shards(mesh),
-                **({"slots": B} if self.slot_state else {}),
-            )
-            METRICS.set(
-                "substratus_serve_kv_heads_per_pool_row",
-                pool["k"].shape[4] // cfg.head_size,
-            )
-            METRICS.set(
-                "substratus_serve_slot_state_bytes",
-                sum(a.nbytes for name, a in pool.items()
-                    if name not in ("k", "v", "k_scale", "v_scale")),
-            )
-            # Layers that keep pages: none for a family whose every layer
-            # keeps per-slot state, and no attention then reads a page.
-            self._page_layers = pool["k"].shape[0]
-            if mesh is not None:
-                pool = shard_tree(
-                    pool,
+
+                bs = ec.page_size
+                if bs < 1:
+                    raise ValueError(f"page_size {bs} invalid")
+                if ec.kv_pool_tokens is not None and ec.kv_pool_tokens < 1:
+                    raise ValueError(
+                        f"kv_pool_tokens {ec.kv_pool_tokens} invalid"
+                    )
+                # A single full-length sequence (+ its pad slot) must always
+                # fit.
+                pool_tokens = (
+                    B * S if ec.kv_pool_tokens is None else ec.kv_pool_tokens
+                )
+                pool_tokens = max(pool_tokens, S + bs)
+                self.page_size = bs
+                self.n_pages = -(-pool_tokens // bs)
+                self.max_pages = -(-S // bs)  # block-table width per slot
+                # Physical page 0 is the trash page: idle slots' decode writes
+                # land there (their block-table rows are zero), never in a live
+                # page. The allocator hands out ids 1..n_pages.
+                pool = model.init_paged_cache(
+                    cfg, self.n_pages + 1, bs, dtype=cache_dtype,
+                    kv_shards=kvcache.kv_head_shards(mesh),
+                    **({"slots": B} if self.slot_state else {}),
+                )
+                METRICS.set(
+                    "substratus_serve_kv_heads_per_pool_row",
+                    pool["k"].shape[4] // cfg.head_size,
+                )
+                METRICS.set(
+                    "substratus_serve_slot_state_bytes",
+                    sum(a.nbytes for name, a in pool.items()
+                        if name not in ("k", "v", "k_scale", "v_scale")),
+                )
+                # Layers that keep pages: none for a family whose every layer
+                # keeps per-slot state, and no attention then reads a page.
+                self._page_layers = pool["k"].shape[0]
+                if mesh is not None:
+                    pool = shard_tree(
+                        pool,
+                        mesh,
+                        model.paged_cache_logical_axes(cfg, quantized=kv_int8),
+                        self._serve_rules,
+                    )
+                self.cache = pool
+                self.block_table = np.zeros((B, self.max_pages), np.int32)
+                self.alloc = PageAllocator(self.n_pages, first_page=1)
+                # Shared pages cannot hand a layer with per-slot state its rows
+                # at the prefix boundary: for such a family the registry is
+                # off, and stats["prefix_reuse_refused"] counts the admissions
+                # it would have looked up.
+                self.prefix = (
+                    PrefixRegistry(self.alloc)
+                    if ec.prefix_cache and not self.slot_state else None
+                )
+                self.slot_pages = SlotPages(B)
+            elif mesh is not None:
+                self.cache = shard_tree(
+                    model.init_cache(cfg, B, S, dtype=cache_dtype),
                     mesh,
-                    model.paged_cache_logical_axes(cfg, quantized=kv_int8),
+                    model.cache_logical_axes(cfg, quantized=kv_int8),
                     self._serve_rules,
                 )
-            self.cache = pool
-            self.block_table = np.zeros((B, self.max_pages), np.int32)
-            self.alloc = PageAllocator(self.n_pages, first_page=1)
-            # Shared pages cannot hand a layer with per-slot state its rows
-            # at the prefix boundary: for such a family the registry is
-            # off, and stats["prefix_reuse_refused"] counts the admissions
-            # it would have looked up.
-            self.prefix = (
-                PrefixRegistry(self.alloc)
-                if ec.prefix_cache and not self.slot_state else None
-            )
-            self.slot_pages = SlotPages(B)
-        elif mesh is not None:
-            self.cache = shard_tree(
-                model.init_cache(cfg, B, S, dtype=cache_dtype),
-                mesh,
-                model.cache_logical_axes(cfg, quantized=kv_int8),
-                self._serve_rules,
-            )
-        else:
-            self.cache = model.init_cache(cfg, B, S, dtype=cache_dtype)
+            else:
+                self.cache = model.init_cache(cfg, B, S, dtype=cache_dtype)
         # Small per-step state lives as HOST numpy and is fed into the
         # jitted functions each call (jit treats numpy inputs as
         # replicated — in multi-host lockstep serving every process feeds
@@ -805,19 +811,20 @@ class Engine:
                 )
             # Same KV dtype as the target pool: an int8 configuration means
             # int8 for the draft's (larger-per-token-count) traffic too.
-            draft_pool = model.init_paged_cache(
-                self.draft_cfg, self.n_pages + 1, self.page_size,
-                dtype=cache_dtype, kv_shards=kvcache.kv_head_shards(mesh),
-            )
-            if mesh is not None:
-                draft_pool = shard_tree(
-                    draft_pool, mesh,
-                    model.paged_cache_logical_axes(
-                        self.draft_cfg, quantized=kv_int8
-                    ),
-                    self._serve_rules,
+            with startup_phase("engine.build.draft_cache"):
+                draft_pool = model.init_paged_cache(
+                    self.draft_cfg, self.n_pages + 1, self.page_size,
+                    dtype=cache_dtype, kv_shards=kvcache.kv_head_shards(mesh),
                 )
-            self.draft_cache = draft_pool
+                if mesh is not None:
+                    draft_pool = shard_tree(
+                        draft_pool, mesh,
+                        model.paged_cache_logical_axes(
+                            self.draft_cfg, quantized=kv_int8
+                        ),
+                        self._serve_rules,
+                    )
+                self.draft_cache = draft_pool
 
         self.queue: "queue.Queue[Request]" = queue.Queue()
         # Pull-based admission fast-path (serve/batchgen.py): when set,
@@ -915,28 +922,34 @@ class Engine:
         # HTTP handler threads concurrently.
         self._load_seq = itertools.count(1)
 
-        self._decode_fn = self._build_decode()
-        self._sample1_fn = self._build_first_sample()
-        self._chunk_fn = partial(self._chunk_prefill_jit, self.model, self.cfg)
-        if self.spec_draft:
-            self._draft_chunk_fn = partial(
-                self._chunk_prefill_jit, self.model, self.draft_cfg
-            )
-            self._propose_fn = self._build_propose(ec.spec_k)
-            # Width-1 rounds (every stream degraded/sampling) still run
-            # one draft step so the draft cache stays hole-free — the
-            # next wide round's proposal history needs every position
-            # below its start written (the proposals are discarded).
-            self._propose1_fn = self._build_propose(1)
-        if self.spec:
-            self._verify_fn = self._build_verify()
-            self._spec_advance = self._build_spec_advance()
-        if not self.paged:
-            self._prefill_fn = partial(self._prefill_jit, self.model, self.cfg)
-            self._insert_fn = self._build_insert()
-            self._extract_slot, self._restore_slot = self._build_slot_io()
-        else:
-            self._export_fn, self._import_fn = self._build_page_io()
+        # The jitted closures only: each is traced, lowered and compiled
+        # (or read from the cache) at its first launch, where its jax.*
+        # spans hang under the request or iteration that launched it.
+        with startup_phase("engine.build.programs"):
+            self._decode_fn = self._build_decode()
+            self._sample1_fn = self._build_first_sample()
+            self._chunk_fn = partial(
+                self._chunk_prefill_jit, self.model, self.cfg)
+            if self.spec_draft:
+                self._draft_chunk_fn = partial(
+                    self._chunk_prefill_jit, self.model, self.draft_cfg
+                )
+                self._propose_fn = self._build_propose(ec.spec_k)
+                # Width-1 rounds (every stream degraded/sampling) still run
+                # one draft step so the draft cache stays hole-free — the
+                # next wide round's proposal history needs every position
+                # below its start written (the proposals are discarded).
+                self._propose1_fn = self._build_propose(1)
+            if self.spec:
+                self._verify_fn = self._build_verify()
+                self._spec_advance = self._build_spec_advance()
+            if not self.paged:
+                self._prefill_fn = partial(
+                    self._prefill_jit, self.model, self.cfg)
+                self._insert_fn = self._build_insert()
+                self._extract_slot, self._restore_slot = self._build_slot_io()
+            else:
+                self._export_fn, self._import_fn = self._build_page_io()
 
     # --- jitted device functions -----------------------------------------
 

@@ -152,7 +152,7 @@ def _maybe_quantize(family, cfg, params, quantize: str, quiet: bool = False):
     return cfg, params
 
 
-def main(argv=None) -> int:
+def _parse(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--model", default=None, help="checkpoint dir (HF or orbax)")
     ap.add_argument("--config", default=None, help="named config for random-weight smoke")
@@ -197,27 +197,18 @@ def main(argv=None) -> int:
              "(one subdir per adapter id; default /content/adapters "
              "when mounted — docs/serving.md 'Multi-tenant adapters')",
     )
-    args = ap.parse_args(argv)
+    return ap.parse_args(argv)
 
-    # Distributed tracing: join the spawner's trace (TRACEPARENT env —
-    # the controller stamps it into Server workloads) and, when
-    # SUBSTRATUS_TRACE_EXPORT is set, flush buffered spans there as JSONL
-    # on shutdown (hack/trace_lint.py validates the format).
-    from substratus_tpu.observability.propagation import context_from_env
-    from substratus_tpu.observability.tracing import tracer
 
-    with tracer.span("serve.start", parent=context_from_env()):
-        pass
-    trace_export = os.environ.get("SUBSTRATUS_TRACE_EXPORT")
-    if trace_export:
-        import atexit
-
-        atexit.register(tracer.export_jsonl, trace_export)
-
+def _start(args: argparse.Namespace):
+    """Everything between the flags and the listener, which is what the
+    span serve.start times: the backend, the weights, the engine with its
+    scheduler thread. Returns (engine, state, params_json); `state` is
+    None on a follower of a multi-host gang, which binds no HTTP."""
     # Multi-host slice: join the jax.distributed world the operator wired
     # (no-op on single hosts).
     maybe_initialize()
-    from substratus_tpu.utils.jaxstart import jax_startup
+    from substratus_tpu.utils.jaxstart import jax_startup, phase
 
     jax_startup()
 
@@ -245,28 +236,34 @@ def main(argv=None) -> int:
 
     from substratus_tpu.models import llama, registry
     from substratus_tpu.serve.engine import Engine, EngineConfig
-    from substratus_tpu.serve.server import ServerState, serve_forever
+    from substratus_tpu.serve.server import ServerState
     from substratus_tpu.serve.tokenizer import load_tokenizer
 
-    if model_dir:
-        cfg, params = load_checkpoint(model_dir)
-        model_name = os.path.basename(os.path.normpath(model_dir))
-        tokenizer = load_tokenizer(model_dir)
-    else:
-        # Weightless smoke mode (reference parallel: the opt-125m CPU smoke
-        # in test/system.sh) — random init of a named config from any
-        # registered family.
-        name = args.config or params_json.get("config", "tiny")
-        smoke_family, cfg = registry.find_named_config(name)
-        tokenizer = load_tokenizer(None)
-        if cfg.vocab_size < tokenizer.vocab_size:
-            cfg = cfg.replace(vocab_size=tokenizer.vocab_size)
-        params = smoke_family.init_params(cfg, jax.random.key(0))
-        model_name = name
+    # Each phase waits for its tree to be on the device, so that the next
+    # one (and the engine's first launch) is not charged for it.
+    with phase("startup.load"):
+        if model_dir:
+            cfg, params = load_checkpoint(model_dir)
+            model_name = os.path.basename(os.path.normpath(model_dir))
+            tokenizer = load_tokenizer(model_dir)
+        else:
+            # Weightless smoke mode (reference parallel: the opt-125m CPU
+            # smoke in test/system.sh) — random init of a named config from
+            # any registered family.
+            name = args.config or params_json.get("config", "tiny")
+            smoke_family, cfg = registry.find_named_config(name)
+            tokenizer = load_tokenizer(None)
+            if cfg.vocab_size < tokenizer.vocab_size:
+                cfg = cfg.replace(vocab_size=tokenizer.vocab_size)
+            params = smoke_family.init_params(cfg, jax.random.key(0))
+            model_name = name
+        jax.block_until_ready(params)
 
     family = registry.module_of(cfg)
 
-    cfg, params = _maybe_quantize(family, cfg, params, quantize)
+    with phase("startup.quantize", mode=quantize):
+        cfg, params = _maybe_quantize(family, cfg, params, quantize)
+        jax.block_until_ready(params)
 
     kv_layout = params_json.get("kv_layout", "auto")
     if family is llama:
@@ -371,15 +368,18 @@ def main(argv=None) -> int:
               flush=True)
         spec_k = 0
     if draft_dir and spec_k:
-        draft_cfg, draft_params = load_checkpoint(draft_dir)
-        if registry.module_of(draft_cfg) is not family:
-            raise SystemExit("draft model must be the same family as the target")
-        # The draft must ride the same quantization as the target — it
-        # exists to cut HBM traffic, not to add bf16 streams.
-        draft_cfg, draft_params = _maybe_quantize(
-            registry.module_of(draft_cfg), draft_cfg, draft_params, quantize,
-            quiet=True,
-        )
+        with phase("startup.draft"):
+            draft_cfg, draft_params = load_checkpoint(draft_dir)
+            if registry.module_of(draft_cfg) is not family:
+                raise SystemExit(
+                    "draft model must be the same family as the target")
+            # The draft must ride the same quantization as the target — it
+            # exists to cut HBM traffic, not to add bf16 streams.
+            draft_cfg, draft_params = _maybe_quantize(
+                registry.module_of(draft_cfg), draft_cfg, draft_params,
+                quantize, quiet=True,
+            )
+            jax.block_until_ready(draft_params)
         draft = (draft_cfg, draft_params)
         ec.spec_k = spec_k
         print(f"speculative decoding: draft={draft_dir} k={spec_k}", flush=True)
@@ -470,16 +470,8 @@ def main(argv=None) -> int:
         transfer = HandoffServer(engine, host=args.host, port=transfer_port)
         print(f"decode role: KV transfer on :{transfer.port}", flush=True)
     if sync is not None and not sync.leader:
-        # Follower: no HTTP. Mirror the leader's scheduler until it
-        # broadcasts stop (or the process is torn down with the gang).
-        # A crashed follower must exit NON-zero: a Succeeded gang pod
-        # would suppress the JobSet failurePolicy restart while the
-        # leader hangs at its next collective missing a participant.
-        engine._thread.join()
-        if engine.error is not None:
-            print(f"follower engine died: {engine.error!r}", flush=True)
-            return 1
-        return 0
+        return engine, None, params_json
+
     def checkpoint_loader(ref: str):
         """POST /swapz checkpoint ref -> param tree ready to install:
         the exact load + quantize pipeline boot used, so the swapped
@@ -496,7 +488,45 @@ def main(argv=None) -> int:
         engine, tokenizer, model_name,
         checkpoint_loader=checkpoint_loader,
     )
-    print(f"serving {model_name} on {args.host}:{args.port}", flush=True)
+    return engine, state, params_json
+
+
+def main(argv=None) -> int:
+    args = _parse(argv)
+
+    # Distributed tracing: join the spawner's trace (TRACEPARENT env —
+    # the controller stamps it into Server workloads) and, when
+    # SUBSTRATUS_TRACE_EXPORT is set, flush buffered spans there as JSONL
+    # on shutdown (hack/trace_lint.py validates the format).
+    from substratus_tpu.observability.propagation import context_from_env
+    from substratus_tpu.observability.tracing import tracer
+    from substratus_tpu.utils.jaxstart import phase
+
+    trace_export = os.environ.get("SUBSTRATUS_TRACE_EXPORT")
+    if trace_export:
+        import atexit
+
+        atexit.register(tracer.export_jsonl, trace_export)
+
+    # serve.start: from here to where the engine runs and only the bind of
+    # the listener is left (milliseconds); its children are the start-up
+    # phases (docs/observability.md "Start-up").
+    with phase("serve.start", parent=context_from_env()):
+        engine, state, params_json = _start(args)
+    if state is None:
+        # Follower: no HTTP. Mirror the leader's scheduler until it
+        # broadcasts stop (or the process is torn down with the gang).
+        # A crashed follower must exit NON-zero: a Succeeded gang pod
+        # would suppress the JobSet failurePolicy restart while the
+        # leader hangs at its next collective missing a participant.
+        engine._thread.join()
+        if engine.error is not None:
+            print(f"follower engine died: {engine.error!r}", flush=True)
+            return 1
+        return 0
+    from substratus_tpu.serve.server import serve_forever
+
+    print(f"serving {state.model_name} on {args.host}:{args.port}", flush=True)
     serve_forever(
         state, host=args.host, port=args.port,
         drain_grace_s=float(params_json["drain_grace"])

@@ -35,7 +35,7 @@ from substratus_tpu.observability.tracing import tracer
 from substratus_tpu.serve.adapters import UnknownAdapter
 from substratus_tpu.serve.engine import Engine, EngineOverloaded, Request
 from substratus_tpu.serve.tokenizer import Tokenizer
-from substratus_tpu.utils.jaxstart import device_memory
+from substratus_tpu.utils.jaxstart import device_memory, startup_record
 
 # Structured access log: one JSON line per traced request, carrying the
 # trace id so log pipelines join lines to span exports
@@ -720,8 +720,10 @@ def build_app(state: ServerState) -> web.Application:
     async def perfz(request: web.Request) -> web.Response:
         """Performance flight recorder: the scheduler's phase-level
         timing breakdown (admission / broadcast / prefill / decode /
-        sample), first-compile duration, request-latency quantiles, and
-        the engine's live counters — the 'where does an iteration's time
+        sample), first-compile duration, what the process's start was
+        made of (`startup`: phases, and every executable built by stage
+        and program), request-latency quantiles, and the engine's live
+        counters — the 'where does an iteration's time
         go' page, rendered from the shared registry with no scrape
         pipeline required. Phases NEST (admission contains prefill
         contains sample): they time named sections, not a partition."""
@@ -762,6 +764,7 @@ def build_app(state: ServerState) -> web.Application:
                 "first_compile_seconds": METRICS.get(
                     "substratus_serve_first_compile_seconds"
                 ),
+                "startup": startup_record(),
                 "latencies": {
                     short: family(f"substratus_serve_{short}_seconds")
                     for short in ("ttft", "inter_token", "queue_wait")

@@ -720,6 +720,12 @@ class Engine:
             # the table a gather reads whole.
             "prefill_kv_pages_read_sum": 0,
             "prefill_kv_pages_table_sum": 0,
+            # Decode steps and verify rounds dispatched, and those of
+            # them whose `temps` held a row above 0: what
+            # ops/sampling.py::sample branches on, so the steps that paid
+            # the sort over the vocabulary and not the argmax alone.
+            "decode_steps": 0,
+            "decode_steps_sampled": 0,
         }
         # What of the per-slot state this engine observes, read off the
         # family and its cache: rows of history a ring keeps a slot (0: the
@@ -2320,6 +2326,14 @@ class Engine:
             self.slot_pages.append(slot, got[0])
             self.block_table[slot, pn] = got[0]
 
+    def _count_step(self) -> None:
+        """One decode step or verify round is about to be handed
+        `self.temps`: count it, and whether its sampler will take the
+        sampled branch (the same test on the same values)."""
+        self.stats["decode_steps"] += 1
+        # self.temps is a host numpy mirror: no device read
+        self.stats["decode_steps_sampled"] += bool((self.temps > 0).any())
+
     def _dispatch(self) -> Optional[_InFlightStep]:
         """Device-only half of one decode step: grow paged capacity from
         the host_positions mirror, feed the previous step's sampled
@@ -2349,6 +2363,7 @@ class Engine:
             tok_in = self._merge_tokens(
                 self._dev_tokens, self.tokens, self._token_fresh
             )
+        self._count_step()
         next_tokens, self.cache, key_out, *stats = self._decode_fn(
             self.params,
             self.cache,
@@ -2704,6 +2719,7 @@ class Engine:
         else:
             props = lookup_props[:, : width - 1]
         lora, adapter_ids = self._lora_inputs()
+        self._count_step()
         choices, sampled, self.cache, key_out = self._verify_fn(
             self.params, self.cache, bt, tok_in, props,
             pos_in, self.temps, self.top_ps, self.key,
@@ -2847,6 +2863,11 @@ class Engine:
         # decoding slots only; an admission sets the position anew).
         self.positions[slot] = 0
         self.host_positions[slot] = 0
+        # ... and is greedy, as it was born: a finished sampled request
+        # must not hold every later step on the sampler's sorted branch
+        # (ops/sampling.py::sample reads all rows' temperatures).
+        self.temps[slot] = 0.0
+        self.top_ps[slot] = 1.0
         if self.paged:
             self.slot_pages.release(slot, self.alloc)
             # Point the idle slot back at the trash page; its decode writes

@@ -180,6 +180,18 @@ _KERNEL = {"decode": "paged_decode_attention",
            "chunk": "paged_chunk_attention"}
 
 
+def _sorts_only_where_a_row_samples(hlo: str) -> bool:
+    """The decode program kept the sampler's branch as a `conditional`
+    (ops/sampling.py::sample: not flattened into a select that runs both
+    sides), and every sort of the sampler lies in its sampled branch."""
+    sorts = [name for name in
+             re.findall(r" sort\(.*?op_name=\"([^\"]*)\"", hlo)
+             if "/sample/" in name]
+    return (bool(re.search(r" conditional\(.*op_name=\"[^\"]*sample/cond", hlo))
+            and bool(sorts)
+            and all("sample/cond/branch_1_fun/" in name for name in sorts))
+
+
 def _described(v5e, eng, **mesh_axes):
     """(placed, arr): abstract arguments for one described chip or, with
     mesh axes given, sharded over the four by the serve rules (`eng.mesh` is
@@ -303,6 +315,7 @@ def test_serving_programs_leave_the_kv_pool_in_place(
             cfg.head_size, scores // tensor)
         assert in_place == (not quantized), name
         assert ("kv.gather" in hlo) == quantized, name
+        assert _sorts_only_where_a_row_samples(hlo) == (name == "decode")
 
 
 # The reason-mixed cell's engine (benchmarks/traffic/reason-mixed.json):
@@ -390,6 +403,7 @@ def test_exaone_programs_leave_pool_and_rings_in_place(v5e):
         for scope in ("kv.ring", "attn.window", "moe.shared", "moe.router",
                       "moe.experts", "attn.core"):
             assert scope in hlo, (name, scope)
+        assert _sorts_only_where_a_row_samples(hlo) == (name == "decode")
         # the global layers of the step and of the chunk read live pages
         # in place: no gather, no K or V of rows x max_seq_len, no float32
         # scores of 512 x max_seq_len
@@ -480,6 +494,7 @@ def test_lfm2_programs_compile_and_leave_the_conv_state_in_place(v5e):
             cfg.n_heads * _CHUNK * _F_S if name == "chunk" else 0), name
         assert "kv.gather" not in hlo, name
         assert all(s in hlo for s in ("conv.in", "conv.state", "conv.out"))
+        assert _sorts_only_where_a_row_samples(hlo) == (name == "decode")
         bf16 = "\n".join(l for l in hlo.splitlines() if "= bf16[" in l)
         assert _pool_moving_ops(bf16, pool) == [], name
         # (the chunk's one slot is written by a dynamic-update-slice whose
@@ -578,6 +593,7 @@ def test_brumby_programs_compile_and_leave_the_state_in_place(v5e):
                                           "attn.core"))
         assert _state_kernel_calls(hlo) == (name == "decode"), name
         assert ("tpu_custom_call" in hlo) == (name == "decode"), name
+        assert _sorts_only_where_a_row_samples(hlo) == (name == "decode")
         if name == "decode":
             # the kernel's call lies in the region the benchmark reads
             call = re.search(r".*retention_state_step.*", hlo).group(0)

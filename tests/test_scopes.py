@@ -111,6 +111,23 @@ def test_regions_in_the_compiled_decode_and_chunk_programs(case):
                 assert parts == [scopes.LAYERS, inner], name
 
 
+@pytest.mark.parametrize("case", ["llama-paged", "lfm2-moe-paged"])
+def test_both_branches_of_the_sampler_lie_under_sample(case):
+    """ops/sampling.py::sample branches on its temperatures: the
+    conditional, the sort and the draw of its sampled branch and the argmax
+    beside it are all charged to `sample`, and nothing of it is unscoped."""
+    config, layout, _ = CASES[case]
+    decode, _ = _compiled(_engine(config, layout))
+    ops = OP_RE.findall(decode)
+    assert {op for op, _ in ops} >= {"conditional", "sort"}
+    for op, name in ops:
+        # (a scan's `while/cond/` is the loop's test, not a branch)
+        if "/cond/branch_" in name or op in ("conditional", "sort"):
+            assert _scope_of(name) == scopes.SAMPLE, (op, name)
+    # the sort is in the sampled branch alone
+    assert all("/cond/branch_1_fun/" in name for op, name in ops if op == "sort")
+
+
 def test_vocabulary_is_closed_and_matches_the_benchmarks_copy():
     from benchmarks.harness import trace_scopes
 

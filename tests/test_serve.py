@@ -3,6 +3,7 @@ curl of /v1/completions and the `GET /` readiness contract,
 docs/container-contract.md:50-56)."""
 import json
 import threading
+import time
 
 import jax
 import jax.numpy as jnp
@@ -99,6 +100,69 @@ def test_burst_while_decoding(engine):
     while first.out.get(timeout=120) is not None:
         pass
     assert results == solo, (results, solo)
+
+
+def _idle(eng, timeout=30.0):
+    """Wait until no slot is decoding and nothing is in flight."""
+    deadline = time.monotonic() + timeout
+    while time.monotonic() < deadline:
+        if not eng.active.any() and eng.queue.empty():
+            steps = eng.stats["decode_steps"]
+            time.sleep(0.05)
+            if steps == eng.stats["decode_steps"] and not eng.active.any():
+                return
+        time.sleep(0.01)
+    raise AssertionError("the engine did not go idle")
+
+
+@pytest.mark.parametrize("overlap", [True, False], ids=["overlap", "sync"])
+def test_a_finished_sampled_request_leaves_its_row_greedy(overlap):
+    """A request with a temperature holds its decode steps on the sampler's
+    sorted branch (ops/sampling.py::sample reads every row's temperature),
+    and only while it lives: its released slot is greedy again (`temps` 0,
+    `top_ps` 1, as the rows are born), `decode_steps_sampled` stops growing
+    at the release, and the greedy requests that follow get the tokens of
+    an engine that never saw it."""
+    cfg = llama.CONFIGS["tiny"].replace(vocab_size=258, dtype=jnp.float32)
+    params = llama.init_params(cfg, jax.random.key(0))
+    ec = EngineConfig(max_batch=2, max_seq_len=64, eos_token_id=-1,
+                      overlap=overlap)
+    prompts = [[256, 10 + i, 20 + i] for i in range(3)]
+    clean, seen = Engine(cfg, params, ec), Engine(cfg, params, ec)
+    clean.start()
+    seen.start()
+    try:
+        assert seen.stats["decode_steps"] == seen.stats["decode_steps_sampled"] == 0
+        out = seen.generate([256, 1, 2], max_tokens=6, temperature=0.8, top_p=0.9)
+        assert len(out) == 6
+        _idle(seen)
+        st = dict(seen.stats)
+        assert 5 <= st["decode_steps_sampled"] == st["decode_steps"]
+        np.testing.assert_array_equal(seen.temps, np.zeros(2, np.float32))
+        np.testing.assert_array_equal(seen.top_ps, np.ones(2, np.float32))
+
+        want = [clean.generate(p, max_tokens=5, temperature=0.0) for p in prompts]
+        got = [seen.generate(p, max_tokens=5, temperature=0.0) for p in prompts]
+        assert got == want
+        _idle(seen)
+        assert seen.stats["decode_steps_sampled"] == st["decode_steps_sampled"]
+        assert seen.stats["decode_steps"] >= st["decode_steps"] + 3 * 4
+        assert clean.stats["decode_steps_sampled"] == 0 < clean.stats["decode_steps"]
+
+        # a greedy and a sampled request side by side: the step is a sampled
+        # one for both, and the greedy one's tokens do not move
+        side = seen.submit(Request(prompt_tokens=[256, 7], max_tokens=12,
+                                   temperature=1.0, top_p=0.5))
+        assert side.out.get(timeout=120) is not None
+        assert seen.generate(prompts[0], max_tokens=5, temperature=0.0) == want[0]
+        while side.out.get(timeout=120) is not None:
+            pass
+        _idle(seen)
+        assert seen.stats["decode_steps_sampled"] > st["decode_steps_sampled"]
+        assert not seen.temps.any() and (seen.top_ps == 1).all()
+    finally:
+        clean.stop()
+        seen.stop()
 
 
 def test_http_completions(engine):

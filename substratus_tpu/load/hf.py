@@ -460,6 +460,73 @@ def convert_brumby_state_dict(sd: Mapping[str, Any], cfg: Any,
     )
 
 
+def config_from_hf_deepseek_v3(hf_cfg: Any):
+    """A transformers `deepseek_v3` config.json, or the text part of a
+    `dots_vlm` one (dots.vlm1 carries DeepSeek-V3's keys one for one; its
+    vision tower is not built), -> DeepseekV3Config."""
+    from substratus_tpu.models.deepseek_v3 import DeepseekV3Config
+
+    get = lambda name, default=None: getattr(hf_cfg, name, default)
+    if (get("attention_bias", False) or get("moe_layer_freq", 1) != 1
+            or get("scoring_func", "sigmoid") != "sigmoid"
+            or get("topk_method", "noaux_tc") != "noaux_tc"
+            or not get("q_lora_rank")):
+        raise NotImplementedError(
+            "deepseek_v3: attention_bias, a sparse layer every other layer, "
+            "a softmax router, a router without the group limit's bias and "
+            "a full-rank query are not written")
+    scaling = get("rope_scaling") or {}
+    if scaling and scaling.get("type", scaling.get("rope_type")) != "yarn":
+        raise NotImplementedError(f"deepseek_v3: rope_scaling {scaling}")
+    return DeepseekV3Config(
+        vocab_size=hf_cfg.vocab_size,
+        dim=hf_cfg.hidden_size,
+        n_layers=hf_cfg.num_hidden_layers,
+        n_heads=hf_cfg.num_attention_heads,
+        q_lora_rank=hf_cfg.q_lora_rank,
+        kv_lora_rank=hf_cfg.kv_lora_rank,
+        qk_nope_head_dim=hf_cfg.qk_nope_head_dim,
+        qk_rope_head_dim=hf_cfg.qk_rope_head_dim,
+        v_head_dim=hf_cfg.v_head_dim,
+        hidden_dim=hf_cfg.intermediate_size,
+        moe_hidden_dim=hf_cfg.moe_intermediate_size,
+        first_k_dense=get("first_k_dense_replace", 0),
+        n_experts=hf_cfg.n_routed_experts,
+        n_experts_per_token=hf_cfg.num_experts_per_tok,
+        n_shared_experts=get("n_shared_experts", 0) or 0,
+        n_group=get("n_group", 1) or 1,
+        topk_group=get("topk_group", 1) or 1,
+        routed_scaling_factor=float(get("routed_scaling_factor", 1.0)),
+        norm_topk_prob=bool(get("norm_topk_prob", True)),
+        rope_theta=float(get("rope_theta", 1e4)),
+        rope_factor=float(scaling.get("factor", 1.0)),
+        rope_original_max=int(scaling.get(
+            "original_max_position_embeddings", 4096)),
+        rope_beta_fast=float(scaling.get("beta_fast", 32)),
+        rope_beta_slow=float(scaling.get("beta_slow", 1)),
+        rope_mscale=float(scaling.get("mscale", 1.0)),
+        rope_mscale_all_dim=float(scaling.get("mscale_all_dim", 1.0)),
+        norm_eps=get("rms_norm_eps", 1e-6),
+        max_seq_len=get("max_position_embeddings", 163840),
+        tie_embeddings=bool(get("tie_word_embeddings", False)),
+    )
+
+
+def convert_deepseek_v3_state_dict(sd: Mapping[str, Any], cfg: Any,
+                                   dtype=jnp.bfloat16) -> Params:
+    """Not written, as convert_exaone_moe_state_dict is not: a checkpoint's
+    tensors would need their rotary channels de-interleaved and kv_b_proj
+    laid out a head (models/deepseek_v3.py, "Departures"), and none was at
+    hand to check that against (no network). The family is served from a
+    named config (random weights) or an orbax checkpoint of
+    models/deepseek_v3.py's own tree."""
+    raise NotImplementedError(
+        "deepseek_v3: the config.json is read (config_from_hf_deepseek_v3) "
+        "but no converter maps the checkpoint's tensors onto "
+        "models/deepseek_v3.py's tree yet"
+    )
+
+
 def _dispatch_hf(model_type: str):
     """transformers model_type -> (config_fn, convert_fn), via the family
     registry (models/registry.py is the single dispatch table)."""
@@ -478,6 +545,8 @@ def _dispatch_hf(model_type: str):
         return config_from_hf_lfm2_moe, convert_lfm2_moe_state_dict
     if family == "brumby":
         return config_from_hf_brumby, convert_brumby_state_dict
+    if family == "deepseek_v3":
+        return config_from_hf_deepseek_v3, convert_deepseek_v3_state_dict
     raise NotImplementedError(
         f"unsupported HF model_type {model_type!r} "
         f"(supported: {sorted(HF_MODEL_TYPES)})"

@@ -1,5 +1,5 @@
 """What the families with a stack that is not uniform share
-(models/exaone_moe.py, models/lfm2_moe.py): the plan of the stack and the
+(models/exaone_moe.py, models/lfm2_moe.py, models/deepseek_v3.py): the plan of the stack and the
 walk over it, and the sigmoid-routed expert layer.
 
 The expert layer (`moe`): scores in float32, the top k of score + bias
@@ -16,7 +16,9 @@ which one a call takes is read off its static token count alone.
 
 A configuration is anything with the attributes read here: `dtype`,
 `n_experts_per_token`, `norm_topk_prob`, `route_norm_eps`,
-`routed_scaling_factor`, `held_experts`, `n_shared_experts`.
+`routed_scaling_factor`, `held_experts`, `n_shared_experts`; and, where
+its router limits the choice to some groups of experts, `n_group` and
+`topk_group` (models/deepseek_v3.py).
 """
 from __future__ import annotations
 
@@ -117,10 +119,25 @@ def heads_proj(h, w, heads: int, qe, dt):
 def route(h, router, bias, cfg):
     """h [T, D] -> (chosen experts [T, k] int32, their weights [T, k]
     float32). The bias moves the choice and never the weight; the weights
-    are normalised over all k chosen, held here or not."""
+    are normalised over all k chosen, held here or not.
+
+    A configuration with `n_group` > 1 limits the choice to `topk_group`
+    of its groups of neighbouring experts (DeepSeek-V3's `noaux_tc`): a
+    group scores the sum of its two largest score + bias, the best groups
+    stay, and outside them score + bias is masked to zero (as published:
+    to zero, not to minus infinity) before the k largest are taken."""
     s = jax.nn.sigmoid(jnp.einsum(
         "td,de->te", h.astype(jnp.float32), materialize(router, jnp.float32)))
-    _, idx = lax.top_k(s + bias.astype(jnp.float32), cfg.n_experts_per_token)
+    c = s + bias.astype(jnp.float32)
+    groups = getattr(cfg, "n_group", 1)
+    if groups > 1:
+        t, e = c.shape
+        by_group = c.reshape(t, groups, e // groups)
+        best_two, _ = lax.top_k(by_group, 2)
+        _, kept = lax.top_k(jnp.sum(best_two, axis=-1), cfg.topk_group)
+        stays = jnp.sum(jax.nn.one_hot(kept, groups, dtype=jnp.int32), axis=1)
+        c = jnp.where(stays[..., None] > 0, by_group, 0.0).reshape(t, e)
+    _, idx = lax.top_k(c, cfg.n_experts_per_token)
     w = jnp.take_along_axis(s, idx, axis=-1)
     if cfg.norm_topk_prob:
         w = w / (jnp.sum(w, axis=-1, keepdims=True) + cfg.route_norm_eps)

@@ -11,7 +11,7 @@ from __future__ import annotations
 from typing import Any, Optional, Tuple
 
 from substratus_tpu.models import (
-    brumby, exaone_moe, falcon, lfm2_moe, llama, opt,
+    brumby, deepseek_v3, exaone_moe, falcon, lfm2_moe, llama, opt,
 )
 
 FAMILIES = {
@@ -27,6 +27,11 @@ FAMILIES = {
     # Brumby-14B-Base: every layer's operator is power retention, which
     # keeps a float32 state a decode slot and layer and no page at all
     "brumby": brumby,
+    # DeepSeek-V3 and the language model of dots.vlm1: multi-head latent
+    # attention (one shared row a token and layer in the pages, read
+    # absorbed by a decode step and expanded by a chunk), a low-rank query,
+    # YaRN, sigmoid-routed experts under a group limit
+    "deepseek_v3": deepseek_v3,
 }
 
 # transformers `model_type` -> family name (HF checkpoint dispatch).
@@ -39,6 +44,9 @@ HF_MODEL_TYPES = {
     "exaone_moe": "exaone_moe",
     "lfm2_moe": "lfm2_moe",
     "brumby": "brumby",
+    "deepseek_v3": "deepseek_v3",
+    # dots.vlm1: its text part; the vision tower is not built
+    "dots_vlm": "deepseek_v3",
 }
 
 _CONFIG_CLASS_TO_FAMILY = {
@@ -48,6 +56,7 @@ _CONFIG_CLASS_TO_FAMILY = {
     exaone_moe.ExaoneMoeConfig: "exaone_moe",
     lfm2_moe.Lfm2MoeConfig: "lfm2_moe",
     brumby.BrumbyConfig: "brumby",
+    deepseek_v3.DeepseekV3Config: "deepseek_v3",
 }
 
 

@@ -7,6 +7,9 @@ can't fuse well (attention, see ops/flash_attention.py).
 """
 from __future__ import annotations
 
+import math
+from typing import Optional, Tuple
+
 import jax
 import jax.numpy as jnp
 
@@ -20,24 +23,57 @@ def rms_norm(x: jnp.ndarray, scale: jnp.ndarray, eps: float = 1e-5) -> jnp.ndarr
     return (normed * scale.astype(jnp.float32)).astype(dtype)
 
 
-def rope_freqs(head_dim: int, theta: float = 10000.0) -> jnp.ndarray:
-    """Inverse frequencies, shape [head_dim // 2] (float32)."""
+def rope_freqs(head_dim: int, theta: float = 10000.0,
+               yarn: Optional[Tuple[float, int, float, float]] = None
+               ) -> jnp.ndarray:
+    """Inverse frequencies, shape [head_dim // 2] (float32).
+
+    `yarn` = (factor, original positions, beta_fast, beta_slow) stretches
+    the table as YaRN does (DeepSeek-V3's `yarn_find_correction_range` and
+    `yarn_linear_ramp_mask`): pair i keeps f_i = theta^(-2i/d) where the
+    original positions turn it more than beta_fast times, takes f_i /
+    factor where they turn it less than beta_slow times, and between the
+    two pair indices `lo` and `hi` at which they turn it exactly so often
+    a linear ramp of both. Without it: the plain table, as ever."""
     exponent = jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim
-    return 1.0 / (theta**exponent)
+    freqs = 1.0 / (theta**exponent)
+    if yarn is None:
+        return freqs
+    factor, original, beta_fast, beta_slow = yarn
+
+    def pair_at(rotations: float) -> float:
+        return (head_dim * math.log(original / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    lo = max(math.floor(pair_at(beta_fast)), 0)
+    hi = min(math.ceil(pair_at(beta_slow)), head_dim // 2 - 1)
+    if lo == hi:
+        hi += 0.001  # the published code's guard against a ramp of no width
+    ramp = jnp.clip(
+        (jnp.arange(head_dim // 2, dtype=jnp.float32) - lo) / (hi - lo), 0, 1)
+    return freqs / factor * ramp + freqs * (1 - ramp)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """DeepSeek-V3's `yarn_get_mscale`: 0.1 mscale ln(factor) + 1 (1 for a
+    factor of 1 or less). The softmax scale of a YaRN model carries its
+    square at `mscale_all_dim`."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
 
 
 def rope(
-    x: jnp.ndarray, positions: jnp.ndarray, theta: float = 10000.0
+    x: jnp.ndarray, positions: jnp.ndarray, theta: float = 10000.0,
+    yarn: Optional[Tuple[float, int, float, float]] = None,
 ) -> jnp.ndarray:
     """Rotary position embedding, HF-Llama "rotate_half" convention.
 
     x: [..., seq, heads, head_dim]; positions: broadcastable to [..., seq].
     The two rotated halves are x[..., :d/2] and x[..., d/2:] (NOT interleaved
     pairs), matching transformers' LlamaRotaryEmbedding so HF checkpoints load
-    without permutation.
+    without permutation. `yarn`: `rope_freqs`' stretched table.
     """
     head_dim = x.shape[-1]
-    freqs = rope_freqs(head_dim, theta)  # [d/2]
+    freqs = rope_freqs(head_dim, theta, yarn)  # [d/2]
     angles = positions[..., None].astype(jnp.float32) * freqs  # [..., seq, d/2]
     cos = jnp.cos(angles)[..., None, :]  # broadcast over heads
     sin = jnp.sin(angles)[..., None, :]

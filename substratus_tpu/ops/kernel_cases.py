@@ -315,6 +315,77 @@ def paged_chunk(tag, b, s, max_seq, h, kh, d, pages, page=16,
     )
 
 
+# --- latent attention over a pool of shared rows --------------------------------
+
+
+def latent_attend(tag, b, s, max_seq, h, dn, dr, dv, rkv, pages, page=16,
+                  layers=2) -> KernelCase:
+    """ops/kvcache.py::latent_attention over a stacked pool of latent rows
+    [ckv (rkv); kr (dr)], one a token for all `h` heads: `s` = 1 a decode
+    step (absorbed: rows of every length from a full table down, the last
+    idle), `s` > 1 a prefill chunk (expanded: its last token the table's
+    last position, so the walk takes every page). The new rows are written
+    first, as the op does; both realisations return the attention alone."""
+    from substratus_tpu.ops import kvcache
+    from substratus_tpu.ops import latent_attention as LA
+
+    def make_args(key):
+        kq, kn, kw, kp, kt = jax.random.split(key, 5)
+        pool = kvcache.init_latent_cache(layers, pages, page, rkv + dr, BF16)
+        rows = _normal(kp, (layers, pages, page, 1, rkv + dr))
+        pool = pool["k"].at[..., :rkv + dr].set(rows)
+        m = max_seq // page
+        own = jax.random.permutation(kt, jnp.arange(1, pages, dtype=jnp.int32))
+        table = own[jnp.arange(b * m) % (pages - 1)].reshape(b, m)
+        if b > 1:
+            table = table.at[-1].set(0)
+        last = max_seq - 1 - (max_seq - s) * jnp.arange(b) // max(b - 1, 1)
+        positions = last[:, None] - (s - 1) + jnp.arange(s)[None, :]
+        w = _normal(kw, (h, dn + dv, rkv), jnp.float32) * rkv ** -0.5
+        return (
+            _normal(kq, (b, s, h, dn + dr)), _normal(kn, (b, s, rkv + dr)),
+            w[:, :dn].astype(BF16), w[:, dn:].astype(BF16), pool,
+            jnp.int32(layers - 1), table, positions.astype(jnp.int32),
+        )
+
+    scale = (dn + dr) ** -0.5
+
+    def attend(q, new, w_uk, w_uv, pool, layer, table, positions):
+        cache = {"k": pool, "v": pool[:0]}
+        return kvcache.latent_attention(
+            cache, layer, table, positions, q, new, w_uk, w_uv, scale,
+            q.dtype)[1]
+
+    def kernel(*args, interpret=False):
+        if not interpret:
+            return attend(*args)
+        # the CPU rehearsal: the op would take the gather
+        names = ("latent_decode_attention", "latent_chunk_attention")
+        real = jax.lax.platform_dependent, [getattr(kvcache, n) for n in names]
+        jax.lax.platform_dependent = lambda *a, tpu, default: tpu(*a)
+        for n in names:
+            setattr(kvcache, n, partial(getattr(LA, n), interpret=True))
+        try:
+            return attend(*args)
+        finally:
+            jax.lax.platform_dependent = real[0]
+            for n, f in zip(names, real[1]):
+                setattr(kvcache, n, f)
+
+    def reference(*args):
+        real = kvcache._latent_kernels_for
+        kvcache._latent_kernels_for = lambda *a: None
+        try:
+            return attend(*args)
+        finally:
+            kvcache._latent_kernels_for = real
+
+    return KernelCase(
+        f"latent_attend/{tag}/b{b}-q{s}-s{max_seq}-h{h}", make_args, kernel,
+        reference, tol=2e-2,
+    )
+
+
 # --- int4 unpack-dequant matmul ---------------------------------------------
 
 
@@ -448,6 +519,14 @@ def chip_cases() -> List[KernelCase]:
     # The longctx cell's decode step of one retention layer: 16 slots, 40
     # query heads over 8 of 128, a state of 8,256 x 128 a head.
     cases.append(retention_decode("brumby-longctx", 16, 40, 8, 128))
+    # The docqa cell's latent attention (DeepSeek-V3's widths: 128 heads over
+    # one row of 512 + 64 a token, stored 640 wide): the decode step's
+    # absorbed kernel over 12 slots of 14k, the 512 and 256 chunks' expanded
+    # one.
+    mla = dict(h=128, dn=128, dr=64, dv=128, rkv=512, pages=10241)
+    cases.append(latent_attend("dots-docqa", 12, 1, 14336, **mla))
+    cases.append(latent_attend("dots-docqa", 1, 512, 14336, **mla))
+    cases.append(latent_attend("dots-docqa", 1, 256, 14336, **mla))
     return cases
 
 
@@ -465,6 +544,10 @@ def rehearsal_cases() -> List[KernelCase]:
         paged_decode("small", 3, 128, h=4, kh=2, d=64, pages=17),
         paged_chunk("small", 2, 16, 128, h=8, kh=4, d=64, pages=17),
         retention_decode("small", 3, h=4, kh=2, d=16),
+        latent_attend("small", 3, 1, 128, h=8, dn=32, dr=16, dv=32, rkv=128,
+                      pages=25),
+        latent_attend("small", 2, 20, 128, h=8, dn=32, dr=16, dv=32, rkv=128,
+                      pages=25),
     ]
 
 

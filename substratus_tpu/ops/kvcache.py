@@ -57,6 +57,14 @@ model name decides):
   hides unwritten / foreign pages. This is also the reference the kernels
   are tested against (tests/test_paged_attention.py, ops/kernel_cases.py).
 
+A layer whose attention is latent (models/deepseek_v3.py) keeps one row a
+token for all its heads, `[ckv; kr]`: the pool is `k` [layers, pages,
+page_size, 1, w] alone, `v` a pool of no layers (the values are the keys'
+leading part; `init_latent_cache` decides the stored width). Its entry
+point is `latent_attention`: one query token a row reads the rows absorbed,
+more expanded, by ops/latent_attention.py's kernels where `paged_attention`
+would take ops/paged_attention.py's, by a gather everywhere else.
+
 A layer that attends to a window of W positions keeps no pages: each decode
 slot owns a ring of W rows a window layer (`ring_read_and_update`), beside
 the pool in the same cache dict, so the pool holds the global layers alone.
@@ -77,6 +85,9 @@ from jax.sharding import PartitionSpec as P
 
 from substratus_tpu.ops import retention, retention_kernel, scopes
 from substratus_tpu.ops.attention import dot_product_attention
+from substratus_tpu.ops.latent_attention import (
+    latent_chunk_attention, latent_decode_attention,
+)
 from substratus_tpu.ops.paged_attention import (
     LANES, paged_chunk_attention, paged_decode_attention,
 )
@@ -84,9 +95,11 @@ from substratus_tpu.ops.quant import dequantize_kv, quantize_kv
 from substratus_tpu.parallel.sharding import SERVE_RULES
 
 
-def _write(pool, layer, block_table, positions, k_new, v_new):
+def _write(pool, layer, block_table, positions, k_new, v_new=None):
     """The pool with the new entries scattered in place at `positions` of
     `layer` (the caller carries and donates it; never sliced per layer).
+    With no `v_new` the rows of `k` alone (a latent pool, whose values are
+    its keys' leading part: `latent_attention`).
 
     Duplicate positions (bucket-padding clamps) write in unspecified order —
     only ever at the one-past-the-prompt garbage slot, which the first
@@ -111,6 +124,8 @@ def _write(pool, layer, block_table, positions, k_new, v_new):
             kq, ks = quantize_kv(k_new)
             vq, vs = quantize_kv(v_new)
             new = {"k": kq, "v": vq, "k_scale": ks, "v_scale": vs}
+        elif v_new is None:
+            new = {"k": k_new}
         else:
             new = {"k": k_new, "v": v_new}
         for name, vals in new.items():
@@ -293,6 +308,182 @@ def _kernel_for(k_pool, q):
         in_specs=(P(None, None, heads), pool, pool, P(), P(), P()),
         out_specs=P(None, None, heads), check_vma=False,
     )
+
+
+def latent_attention(
+    pool: Dict[str, jnp.ndarray],  # {"k": [L, P, bs, 1, w], "v": no layers}
+    layer: jnp.ndarray,  # scalar int32: the layer whose pages are touched
+    block_table: jnp.ndarray,  # [B, M] int32 page ids
+    positions: jnp.ndarray,  # [B, S] absolute (slot-local) positions
+    q: jnp.ndarray,  # [B, S, H, dn + dr]: [q_nope; q_rope, rotated]
+    row_new: jnp.ndarray,  # [B, S, rkv + dr]: [ckv, normed; kr, rotated]
+    w_uk,  # [H, dn, rkv]: W_UK_i a head, as stored (k_nope = ckv W_UK_i^T)
+    w_uv,  # [H, dv, rkv]: W_UV_i^T a head, as stored (v = ckv W_UV_i)
+    scale: float,
+    dtype,
+) -> Tuple[Dict[str, jnp.ndarray], jnp.ndarray]:
+    """Multi-head latent attention over the paged pool: writes the new
+    rows at `positions` of `layer` (one row a token for all heads; keys and
+    values are the same bytes, V the row's first rkv), then attends from q
+    to each row's context 0..position. Returns (updated pool, attn [B, S,
+    H, dv]).
+
+    Two forms of the same numbers, chosen by the call's own shape. One
+    query token a row (a decode step) runs **absorbed**: q_nope goes
+    through W_UK^T into the latent's space, the scores and the read-out
+    run over the rows as they lie, and the read-out comes back through
+    W_UV (ATTN_ABSORB around ATTN_CORE). More than one (a prefill chunk)
+    runs **expanded**: the context's latents go through W_UKV to per-head
+    keys and values, which a chunk's many queries share (ATTN_EXPAND, then
+    ATTN_CORE).
+
+    Which realisation runs is read off the inputs, as in
+    `paged_attention`. A bfloat16 pool with rows a multiple of 128 wide on
+    one device, lowered for a TPU: ops/latent_attention.py reads each
+    row's live pages in place, and the chunk's expansion happens inside
+    the kernel a block of pages at a time (what stays outside, under
+    ATTN_EXPAND, is W_UKV made bfloat16). Everything else (a float32 pool,
+    rows stored as declared, a mesh, any other platform): the rows of
+    every table position are gathered (KV_GATHER) and the form runs in
+    plain XLA over them, the chunk's context expanded whole; that is also
+    what the kernels are tested against."""
+    from substratus_tpu.ops.quant import QTensor, materialize, qeinsum
+
+    k_pool = pool["k"]
+    w = k_pool.shape[4]
+    b, s, h, dq = q.shape
+    rkv = w_uk.shape[2]
+    dr = row_new.shape[-1] - rkv
+    dn = dq - dr
+    # the row as the pool stores it: zeros behind [ckv; kr]
+    stored = jnp.pad(row_new, ((0, 0), (0, 0), (0, w - rkv - dr)))
+    out = {**pool, **_write(pool, layer, block_table, positions, stored)}
+    k_pool = out["k"]
+    kernel = _latent_kernels_for(k_pool, rkv, dn)
+
+    def gathered_rows():
+        with jax.named_scope(scopes.KV_GATHER):
+            pages, bs = k_pool.shape[1:3]
+            n, m = block_table.shape
+            first = layer.astype(block_table.dtype) * pages
+            starts = ((first + block_table) * bs).reshape(n * m)
+            ctx = jax.vmap(
+                lambda at: jax.lax.dynamic_slice_in_dim(_rows(k_pool), at, bs)
+            )(starts)
+            return ctx.reshape(n, m * bs, w).astype(jnp.float32)
+
+    def seen(t):  # [B, S, T]: key j of the gathered context visible to query
+        return jnp.arange(t, dtype=jnp.int32)[None, None, :] <= positions[
+            :, :, None].astype(jnp.int32)
+
+    if s == 1:
+        with jax.named_scope(scopes.ATTN_ABSORB):
+            if isinstance(w_uk, QTensor):
+                # the scale is a channel's, and W_UK's channels are what
+                # this product sums over: it goes onto q first
+                qn = q[..., :dn] * w_uk.scale[..., 0].astype(dtype)
+                qa = jnp.einsum("bshn,hnc->bshc", qn, w_uk.q.astype(dtype))
+            else:
+                qa = jnp.einsum("bshn,hnc->bshc", q[..., :dn],
+                                materialize(w_uk, dtype))
+            qa = jnp.concatenate([
+                qa.astype(dtype), q[..., dn:],
+                jnp.zeros((b, s, h, w - rkv - dr), dtype)], axis=-1)
+
+        def absorbed_in_xla():
+            lat = gathered_rows()
+            with jax.named_scope(scopes.ATTN_CORE):
+                sc = jnp.einsum("bshw,btw->bsht", qa.astype(jnp.float32),
+                                lat) * scale
+                sc = jnp.where(seen(lat.shape[1])[:, :, None], sc, -1e30)
+                p = jax.nn.softmax(sc, axis=-1)
+                return jnp.einsum("bsht,btc->bshc", p,
+                                  lat[..., :rkv]).astype(dtype)
+
+        def absorbed_in_place():
+            with jax.named_scope(scopes.ATTN_CORE):
+                return latent_decode_attention(
+                    qa[:, 0], k_pool, layer, block_table, positions[:, 0],
+                    rkv=rkv, scale=scale)[:, None]
+
+        ol = (absorbed_in_xla() if kernel is None else
+              jax.lax.platform_dependent(
+                  tpu=absorbed_in_place, default=absorbed_in_xla))
+        with jax.named_scope(scopes.ATTN_ABSORB):
+            o = qeinsum("bshc,hvc->bshv", ol, w_uv, dtype)
+        return out, o.astype(dtype)
+
+    with jax.named_scope(scopes.ATTN_EXPAND):
+        # W_UK_i over W_UV_i^T a head, [H, dn + dv, rkv], as the matmuls
+        # take it
+        dense = jnp.concatenate(
+            [materialize(w_uk, dtype), materialize(w_uv, dtype)], axis=1)
+
+    def expanded_in_xla():
+        lat = gathered_rows()
+        with jax.named_scope(scopes.ATTN_EXPAND):
+            kv = jnp.einsum("btc,hmc->bthm", lat[..., :rkv],
+                            dense.astype(jnp.float32))
+        with jax.named_scope(scopes.ATTN_CORE):
+            qf = q.astype(jnp.float32)
+            sc = (jnp.einsum("bshn,bthn->bsht", qf[..., :dn], kv[..., :dn])
+                  + jnp.einsum("bshr,btr->bsht", qf[..., dn:],
+                               lat[..., rkv:rkv + dr])) * scale
+            sc = jnp.where(seen(lat.shape[1])[:, :, None], sc, -1e30)
+            p = jax.nn.softmax(sc, axis=-1)
+            return jnp.einsum("bsht,bthv->bshv", p, kv[..., dn:]).astype(dtype)
+
+    def expanded_in_place():
+        with jax.named_scope(scopes.ATTN_CORE):
+            return latent_chunk_attention(
+                q, dense, k_pool, layer, block_table, positions, dn=dn,
+                scale=scale)
+
+    if kernel is None:
+        return out, expanded_in_xla()
+    return out, jax.lax.platform_dependent(
+        tpu=expanded_in_place, default=expanded_in_xla)
+
+
+def _latent_kernels_for(k_pool, rkv: int, dn: int) -> Optional[bool]:
+    """Whether ops/latent_attention.py's kernels read this latent pool in
+    place: a bfloat16 pool whose stored row is a multiple of the 128 lanes
+    (`init_latent_cache` makes it so) and whose latent ends on a lane tile
+    (the rotary key then has the row's last tile to itself), on one
+    device. None where they are not written for the case (a float32 pool,
+    a row stored as declared, a latent or a head that Mosaic does not tile,
+    any mesh that shards something: one row serves every head, so no axis
+    of the pool splits over heads)."""
+    if (k_pool.dtype != jnp.bfloat16 or k_pool.shape[4] % LANES
+            or rkv % LANES or dn % 16):
+        return None
+    mesh = jax.typeof(k_pool).sharding.mesh
+    if any(n > 1 for n in mesh.shape.values()):
+        return None
+    return True
+
+
+def init_latent_cache(n_layers: int, pages: int, page_size: int, row: int,
+                      dtype) -> Dict[str, jnp.ndarray]:
+    """The page pool of layers whose attention is latent: `k` [L, P, bs, 1,
+    w], one row a token for every head, and `v` a pool of no layers (the
+    values are the keys' leading part: the dict says that nothing else is
+    kept). The one place that decides the stored row: a bfloat16 pool
+    keeps `row` values in w = the next multiple of 128 lanes, zeros behind
+    them (Mosaic tiles no other width, and the device keeps such a page
+    row-major as a matrix of `page_size` rows); any other pool as
+    declared. Readers take the logical row off their own operands."""
+    w = row
+    if jnp.dtype(dtype) == jnp.bfloat16:
+        w = -(-row // LANES) * LANES
+    return {"k": jnp.zeros((n_layers, pages, page_size, 1, w), dtype),
+            "v": jnp.zeros((0, pages, page_size, 1, w), dtype)}
+
+
+def latent_cache_logical_axes() -> Dict[str, tuple]:
+    """Nothing of a latent pool shards: a row belongs to every head."""
+    ax = ("layers", None, None, None, None)
+    return {"k": ax, "v": ax}
 
 
 def ring_read_and_update(

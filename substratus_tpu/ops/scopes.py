@@ -19,7 +19,11 @@ a trace's table. A layer whose operator is a gated short convolution
 attention layer opens the ATTN_ and KV_ names. A layer whose operator is
 power retention (models/brumby.py) keeps a state a decode slot and no
 history at all: it opens RET_STATE and RET_INTRA between ATTN_QKV and
-ATTN_OUT.
+ATTN_OUT. A layer whose attention is latent (models/deepseek_v3.py)
+keeps one shared row a token in the pages: a decode step opens
+ATTN_ABSORB around ATTN_CORE (the queries carried into the latent's space
+and the read-out carried back), a chunk ATTN_EXPAND ahead of it (what of
+the latents' expansion into keys and values stays outside the kernel).
 """
 
 EMBED = "embed"              # token embedding gather
@@ -50,6 +54,11 @@ RET_STATE = "ret.state"      # a retention layer's per-slot state: read,
 # in a chunk the carried state's read-out and the state's update)
 RET_INTRA = "ret.intra"      # a chunk's in-chunk scores under the decay and
 # their product with the values
+ATTN_ABSORB = "attn.absorb"  # latent attention, a decode step: q_nope
+# through W_UK^T into the latent's space ahead of the kernel, its read-out
+# through W_UV after it
+ATTN_EXPAND = "attn.expand"  # latent attention, a chunk: what of the
+# latents' way through W_UKV to keys and values runs outside the kernel
 LM_HEAD = "lm_head"          # final norm and logits
 SAMPLE = "sample"            # RNG split and ops/sampling.py::sample
 
@@ -60,8 +69,9 @@ ALL = (
 # What one family's block adds to the thirteen every block opens (the
 # benchmark lists them in that family's file, benchmarks/families/):
 # EXTRA models/exaone_moe.py's, CONV models/lfm2_moe.py's, RET
-# models/brumby.py's.
+# models/brumby.py's, LATENT models/deepseek_v3.py's (beside MOE_SHARED).
 EXTRA = (MOE_SHARED, KV_RING, ATTN_WINDOW)
 CONV = (CONV_IN, CONV_STATE, CONV_OUT)
 RET = (RET_STATE, RET_INTRA)
-EVERY = ALL + EXTRA + CONV + RET
+LATENT = (ATTN_ABSORB, ATTN_EXPAND)
+EVERY = ALL + EXTRA + CONV + RET + LATENT

@@ -119,7 +119,18 @@ METRICS.describe(
     "is made (ops/kvcache.py::init_paged_cache): 2 for a bfloat16 pool of "
     "64-wide heads, which the paged-attention kernels read in place; 1 = "
     "stored as declared (heads of 128; or an int8 pool, an odd head count "
-    "or an uneven split of 64-wide heads, whose attention gathers).",
+    "or an uneven split of 64-wide heads, whose attention gathers). A "
+    "family whose pool is not rows of head_size says its own "
+    "(`kv_heads_per_pool_row`): every head, for a latent row.",
+    type="gauge",
+)
+METRICS.describe(
+    "substratus_serve_kv_bytes_per_token",
+    "Bytes one token keeps in the page pool over all layers, as stored "
+    "(k, v and their scales): 131,072 for Mistral-7B's 32 layers of 8 "
+    "bfloat16 K and V heads of 128, 20,480 for 16 layers of one latent "
+    "row stored 640 wide. Set once where the pool is made; 0 for a family "
+    "that keeps no page.",
     type="gauge",
 )
 METRICS.describe(
@@ -559,6 +570,18 @@ class Engine:
                 "carries: disaggregated roles and speculative decoding are "
                 "unsupported"
             )
+        # What a pages-only family has not written (or tested) for its pages
+        # is refused by its name, never found by a user: a verify round of
+        # speculation, the page handoff of the disaggregated roles.
+        if ec.spec_k and not getattr(model, "SUPPORTS_SPECULATION", True):
+            raise ValueError(
+                f"speculative decoding is unsupported for {model.__name__}"
+            )
+        if ec.role != "both" and not getattr(model, "SUPPORTS_ROLES", True):
+            raise ValueError(
+                f"role={ec.role!r} is unsupported for {model.__name__}: "
+                "its pages have no handoff"
+            )
         if ec.role != "both" and not self.paged:
             # The handoff ships pool pages; the dense slot cache has no
             # page-granular export.
@@ -618,9 +641,17 @@ class Engine:
                     kv_shards=kvcache.kv_head_shards(mesh),
                     **({"slots": B} if self.slot_state else {}),
                 )
+                heads_per_row = getattr(model, "kv_heads_per_pool_row", None)
                 METRICS.set(
                     "substratus_serve_kv_heads_per_pool_row",
-                    pool["k"].shape[4] // cfg.head_size,
+                    heads_per_row(cfg, pool) if heads_per_row
+                    else pool["k"].shape[4] // cfg.head_size,
+                )
+                METRICS.set(
+                    "substratus_serve_kv_bytes_per_token",
+                    sum(a.nbytes for name, a in pool.items()
+                        if name in ("k", "v", "k_scale", "v_scale"))
+                    // ((self.n_pages + 1) * bs),
                 )
                 METRICS.set(
                     "substratus_serve_slot_state_bytes",
@@ -720,6 +751,14 @@ class Engine:
             # the table a gather reads whole.
             "prefill_kv_pages_read_sum": 0,
             "prefill_kv_pages_table_sum": 0,
+            # The context attention works through, in tokens, from what is
+            # handed to the program in the same call: a decode step's sum
+            # over decoding slots of position + 1 (_count_step); a chunk's
+            # last query position + 1, and the chunks dispatched
+            # (_run_chunks).
+            "decode_ctx_tokens_sum": 0,
+            "chunk_ctx_tokens_sum": 0,
+            "chunk_count": 0,
             # Decode steps and verify rounds dispatched, and those of
             # them whose `temps` held a row above 0: what
             # ops/sampling.py::sample branches on, so the steps that paid
@@ -753,7 +792,11 @@ class Engine:
             self._state_kernel = kvcache.retention_step_takes_kernel(
                 self.cache[kvcache.RET_S])
             self.stats["state_kernel_steps"] = 0
-        if self.slot_state and hasattr(model, "step_counters"):
+        # A family whose forward counts over its real tokens is told which
+        # they are (`valid`), with or without per-slot state.
+        self._step_counts = self.paged and hasattr(model, "step_counters")
+        self._tells_valid = self.slot_state or self._step_counts
+        if self._step_counts:
             # What such a family's forward counts where it has an expert
             # layer (models/hybrid.py::COUNTERS), summed over decode steps
             # and prefill chunks as they are drained.
@@ -1005,7 +1048,8 @@ class Engine:
         kw = {} if block_table is None else {"block_table": block_table}
         kw.update(Engine._lora_kw(lora, adapter_ids))
         if slot is not None:
-            kw["slots"] = jnp.reshape(slot, (1,)).astype(jnp.int32)
+            if getattr(model, "PAGED_SLOT_STATE", False):
+                kw["slots"] = jnp.reshape(slot, (1,)).astype(jnp.int32)
             kw["valid"] = jnp.arange(c)[None, :] < true_len
         logits, slot_cache = model.forward(
             params, tokens, cfg, positions=positions, cache=slot_cache, **kw
@@ -2074,7 +2118,7 @@ class Engine:
         last_logits, self.cache = self._run_chunks(
             req.id, self._chunk_fn, self.params, self.cache, prompt, reuse,
             bt_row, lora=lora, adapter_ids=ids1,
-            slot=slot if self.slot_state else None,
+            slot=slot if self._tells_valid else None,
         )
         self.stats["prefill_tokens"] += true_len - reuse
         self.stats["prefix_hit_tokens"] += reuse
@@ -2135,6 +2179,8 @@ class Engine:
                     self.stats["prefill_kv_pages_read_sum"] += (
                         last // self.page_size + 1)
                 self.stats["prefill_kv_pages_table_sum"] += self.max_pages
+                self.stats["chunk_ctx_tokens_sum"] += last + 1
+                self.stats["chunk_count"] += 1
             if self._conv_state and slot is not None:
                 self.stats["conv_chunks_sum"] += 1
                 self.stats["conv_chunks_resumed_sum"] += offset > 0
@@ -2333,6 +2379,10 @@ class Engine:
         self.stats["decode_steps"] += 1
         # self.temps is a host numpy mirror: no device read
         self.stats["decode_steps_sampled"] += bool((self.temps > 0).any())
+        if self.paged:
+            # positions and active are host numpy mirrors too
+            ctx = (self.positions[self.active] + 1).sum()
+            self.stats["decode_ctx_tokens_sum"] += int(ctx)
 
     def _dispatch(self) -> Optional[_InFlightStep]:
         """Device-only half of one decode step: grow paged capacity from
@@ -2375,7 +2425,7 @@ class Engine:
             self.key,
             lora,
             adapter_ids,
-            *((self.active.copy(),) if self.slot_state else ()),
+            *((self.active.copy(),) if self._tells_valid else ()),
         )
         if self.overlap:
             # The RNG key stays device-resident between steps: reading

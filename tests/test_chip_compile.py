@@ -636,6 +636,92 @@ def test_brumby_decode_compiles_under_a_tensor_mesh_with_the_kernel(v5e):
     assert mem.temp_size_in_bytes < 0.18e9, mem.temp_size_in_bytes
 
 
+# The docqa cell's engine (benchmarks/traffic/docqa.json): the language
+# model of dots.vlm1.inst, its first 16 layers (3 dense + 13 sparse), 8 of
+# 256 experts held, an eighth of the vocabulary.
+_D_B, _D_S, _D_POOL_PAGES = 12, 14336, 10240
+
+
+def test_deepseek_v3_programs_compile_with_both_latent_kernels(v5e):
+    """The family whose pages hold one latent row a token for all heads:
+    decode and the 512-token chunk compile for the chip at the published
+    widths; the step holds the absorbed kernel and the chunk the expanded
+    one (ops/latent_attention.py), neither gathers a context, no op moves
+    the pool or a layer of it, the chunk holds no context expanded in HBM
+    (at 14k tokens one layer's keys and values are 0.94 GB: no result is
+    that large, and the program's temporaries stay under one layer's W_O
+    beside 13 GB of weights and pool), and the chunk groups its tokens by
+    expert."""
+    from substratus_tpu.models import deepseek_v3
+    from substratus_tpu.ops.quant import quantize_params
+    from substratus_tpu.serve.engine import Engine, EngineConfig
+
+    cfg = deepseek_v3.DeepseekV3Config(
+        n_layers=16, vocab_size=16160, held_experts=(0, 8))
+    assert deepseek_v3.layer_plan(cfg) == (3, 1, 13)
+    eng = Engine(cfg, None, EngineConfig(
+        max_batch=_D_B, max_seq_len=_D_S, max_prefill_len=_CHUNK,
+        page_size=_PAGE, kv_pool_tokens=1,
+    ))
+    assert not eng.slot_state and eng.prefix is not None
+    placed, arr = _described(v5e, eng)
+    params = placed(jax.eval_shape(
+        lambda key: quantize_params(
+            deepseek_v3.init_params(cfg, key),
+            deepseek_v3.quant_contracting(cfg)),
+        jax.random.key(0)), deepseek_v3.param_logical_axes(cfg))
+    cache = placed(jax.eval_shape(
+        lambda: deepseek_v3.init_paged_cache(cfg, _D_POOL_PAGES + 1, _PAGE)),
+        deepseek_v3.paged_cache_logical_axes(cfg))
+    # one row of 576 a token and layer, stored 640 wide; no second pool
+    assert cache["k"].shape == (16, _D_POOL_PAGES + 1, _PAGE, 1, 640)
+    assert cache["v"].shape[0] == 0
+    m = _D_S // _PAGE
+    programs = {
+        "decode": eng._decode_fn.lower(
+            params, cache, arr((_D_B, m)), arr((_D_B,)), arr((_D_B,)),
+            arr((_D_B,), jnp.float32), arr((_D_B,), jnp.float32),
+            arr(eng.key.shape, eng.key.dtype), None, None,
+            arr((_D_B,), jnp.bool_),
+        ),
+        "chunk": Engine._chunk_prefill_jit.lower(
+            deepseek_v3, cfg, params, cache, arr((1, _CHUNK)), arr(()),
+            arr(()), arr((1, m)), None, None, arr(()),
+        ),
+    }
+    kernels = {"decode": "latent_decode_attention",
+               "chunk": "latent_chunk_attention"}
+    pool = {cache["k"].size, cache["k"].size // 16}  # whole, or a layer
+    expanded_layer = _D_S * cfg.n_heads * 256  # one layer's K and V, whole
+    for name, lowered in programs.items():
+        compiled = lowered.compile()
+        hlo = compiled.as_text()
+        assert re.search(
+            r'custom_call_target="tpu_custom_call".*' + kernels[name], hlo
+        ), name
+        other = kernels["chunk" if name == "decode" else "decode"]
+        assert other not in hlo, name
+        assert "kv.gather" not in hlo, name
+        assert ("attn.absorb" in hlo) == (name == "decode"), name
+        assert ("attn.expand" in hlo) == (name == "chunk"), name
+        bf16 = "\n".join(l for l in hlo.splitlines() if "= bf16[" in l)
+        assert _pool_moving_ops(bf16, pool) == [], name
+        # no float result as large as one layer's expanded context
+        for mm in re.finditer(r"= (?:bf16|f32)\[([\d,]+)\]\S* [\w-]+\(", hlo):
+            n = math.prod(map(int, mm.group(1).split(",")))
+            assert n < expanded_layer or n in pool, (name, mm.group(0))
+        # under one layer's W_O (117 MB): no weight is written out anew
+        temp = compiled.memory_analysis().temp_size_in_bytes
+        assert temp < 1.1e8, (name, temp)
+        if name == "decode":
+            # nor a slice of W_UQ (its leaves lie a head apart: as [H dn,
+            # rq] the step copied the layer's 25 MB + 12.6 MB out of the
+            # stack, every layer, and held 62 MB of temporaries)
+            assert temp < 4.5e7, (name, temp)
+        if name == "chunk":
+            assert "moe.experts/while" in hlo
+
+
 @pytest.mark.parametrize("program", ["decode", "chunk"])
 def test_tinyllama_paged_programs_compile_for_v5e(program, v5e):
     """What `serve.main --config tinyllama-1.1b` compiles on a TPU at its

@@ -9,7 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from substratus_tpu.models import exaone_moe, lfm2_moe, llama
+from substratus_tpu.models import deepseek_v3, exaone_moe, lfm2_moe, llama
 from substratus_tpu.ops import scopes
 from substratus_tpu.serve.engine import Engine, EngineConfig
 
@@ -38,14 +38,25 @@ CASES = {
                        DENSE_LLAMA | {scopes.KV_GATHER, scopes.MOE_ROUTER,
                                       scopes.MOE_EXPERTS}
                        | set(scopes.CONV)),
+    # latent attention in every layer, a dense layer before sparse ones:
+    # every base name and the shared expert's; the absorbed form's name in
+    # the step alone and the expanded form's in the chunk alone
+    # (`LATENT_ONLY` below)
+    "deepseek-v3-paged": ("tiny-deepseek-v3", "paged",
+                          DENSE_LLAMA | {scopes.KV_GATHER, scopes.MOE_ROUTER,
+                                         scopes.MOE_EXPERTS,
+                                         scopes.MOE_SHARED}),
 }
+# What one of the two programs of a family opens and the other does not.
+LATENT_ONLY = {"deepseek-v3-paged": {"decode": {scopes.ATTN_ABSORB},
+                                     "chunk": {scopes.ATTN_EXPAND}}}
 OP_RE = re.compile(
     r"^\s*(?:ROOT )?%?[\w.\-]+ = \S+ ([\w\-]+)\(.*?op_name=\"([^\"]*)\"", re.M)
 
 
 def _engine(config: str, layout: str) -> Engine:
-    model = next((m for m in (exaone_moe, lfm2_moe) if config in m.CONFIGS),
-                 llama)
+    model = next((m for m in (exaone_moe, lfm2_moe, deepseek_v3)
+                  if config in m.CONFIGS), llama)
     cfg = model.CONFIGS[config].replace(dtype=jnp.float32)
     params = model.init_params(cfg, jax.random.key(0))
     return Engine(cfg, params, EngineConfig(
@@ -58,7 +69,7 @@ def _compiled(e: Engine):
     bt = e.block_table if e.paged else None
     decode = e._decode_fn.lower(
         e.params, e.cache, bt, e.tokens, e.positions, e.temps, e.top_ps,
-        e.key, None, None, *((e.active,) if e.slot_state else ())
+        e.key, None, None, *((e.active,) if e._tells_valid else ())
     ).compile().as_text()
     if e.paged:
         cache, row = e.cache, e.block_table[:1]
@@ -66,7 +77,7 @@ def _compiled(e: Engine):
         cache, row = e._extract_slot(e.cache, 0), None
     chunk = Engine._chunk_prefill_jit.lower(
         e.model, e.cfg, e.params, cache, np.zeros((1, 16), np.int32), 0, 16,
-        block_table=row, **({"slot": np.int32(0)} if e.slot_state else {})
+        block_table=row, **({"slot": np.int32(0)} if e._tells_valid else {})
     ).compile().as_text()
     return decode, chunk
 
@@ -92,6 +103,7 @@ def test_regions_in_the_compiled_decode_and_chunk_programs(case):
         ops = OP_RE.findall(text)
         assert ops, program
         found = {_scope_of(name) for _, name in ops} - {None}
+        extra = extra | LATENT_ONLY.get(case, {}).get(program, set())
         assert found == want | extra, (program, found ^ (want | extra))
         # the heavy ops each sit under a region
         for opcode, name in ops:
@@ -135,7 +147,7 @@ def test_vocabulary_is_closed_and_matches_the_benchmarks_copy():
     assert len(set(scopes.ALL)) == len(scopes.ALL) == 13
     # what one family's block adds is listed in that family's file, and
     # the benchmark's readers charge an op to any of them
-    assert len(set(scopes.EVERY)) == len(scopes.EVERY) == 21
+    assert len(set(scopes.EVERY)) == len(scopes.EVERY) == 23
     assert set(scopes.EXTRA + scopes.CONV + scopes.RET) <= (
         trace_scopes.vocabulary())
 

@@ -161,7 +161,11 @@ def init_params(cfg: BrumbyConfig, key: jax.Array) -> Params:
     The gate's bias is drawn uniform in [3, 7] (`g` between 0.953 and
     0.999, as a trained gate's): around zero `g` is about 0.5, the state
     forgets in a few tokens and a test could not tell a broken carry from
-    a sound one."""
+    a sound one. The four projections are published flat, [L, heads * hd,
+    D] with the contracted dim last (models/exaone_moe.py::init_params);
+    `forward` views them [L, heads, hd, D] before it slices a layer off
+    (`hybrid.projections_heads_first`), or each program stages the int8
+    layer in VMEM before its dot reads it (PERF.md section 6, PR 41)."""
     k = iter(jax.random.split(key, 16))
 
     def dense(shape, fan_in):
@@ -247,8 +251,7 @@ def _retention(h, lp, l, positions, cfg, cache, slots, valid, qe):
             valid, q, k, v, log_g)
         cache = {**cache, kvcache.RET_S: s, kvcache.RET_Z: z}
     with jax.named_scope(scopes.ATTN_OUT):
-        flat = o.astype(dt).reshape(o.shape[:2] + (-1,))
-        return qeinsum("bsn,nd->bsd", flat, lp["wo"], dt), cache
+        return hybrid.out_proj(o.astype(dt), lp["wo"], dt), cache
 
 
 def _block(x, lp, l, positions, cfg, cache, slots, valid):
@@ -295,9 +298,14 @@ def forward(
     with jax.named_scope(scopes.EMBED):
         x = materialize(params["tok_embed"], cfg.dtype)[tokens]
 
+    # the four projection stacks with their heads a dim of their own,
+    # before a layer is sliced off them (see `init_params`)
+    layers = hybrid.projections_heads_first(
+        params["layers"], cfg.n_heads, cfg.n_kv_heads)
+
     def layer(carry, j, l, at):
         x, cache = carry
-        return _block(x, hybrid.take(params["layers"], l), l, positions, cfg,
+        return _block(x, hybrid.take(layers, l), l, positions, cfg,
                       cache, slots, valid)
 
     x, cache = hybrid.run_stack([("retention",)] * cfg.n_layers, layer,

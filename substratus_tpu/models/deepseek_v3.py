@@ -290,8 +290,14 @@ def init_params(cfg: DeepseekV3Config, key: jax.Array) -> Params:
     v5e, PR 40). Each leaf has its heads as a dimension of their own: as
     [H dn, rq] the scanned body copied the layer's slice of W_UQ out of
     the stack before it split the heads (1.8 ms of a 26 ms step on the
-    chip, PR 40). The router bias is drawn, not zero, so that it moves
-    the choice in a test."""
+    chip, PR 40). That published form is the served one: the engine's
+    door (Engine.serving_tree) has nothing to turn here. What is still
+    laid out anew every layer and step is w_uq_rope's 12.6 MB (0.64 ms of
+    a 23 ms step, under no region's name): viewed two heads to a row of
+    128, before the layer is sliced off or after, the compiled step keeps
+    the same `constant_dynamic-slice_fusion` (described v5e, PR 41), so
+    the leaf stays as it is. The router bias is drawn, not zero, so that
+    it moves the choice in a test."""
     k = iter(jax.random.split(key, 24))
 
     def dense(shape, fan_in):

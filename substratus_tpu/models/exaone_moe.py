@@ -220,8 +220,17 @@ def init_params(cfg: ExaoneMoeConfig, key: jax.Array) -> Params:
     projection [heads * hd, D] too. As int8 [D, heads, hd], and as [D,
     heads * hd], the compiler laid every layer's q, k and v weights out
     anew in each program, contracted dim last (2.9 ms of a 30 ms decode
-    step, outside every region: PERF.md section 6, PR 27). The router bias is drawn, not zero, so
-    that it moves the choice in a test."""
+    step, outside every region: PERF.md section 6, PR 27). That is the
+    published form, and what the engine holds. It is not yet what a layer's
+    dot reads from the stack: sliced by layer from the flat [L, heads * hd,
+    D], the int8 q and output weights of every layer were written out anew
+    before their dots read them (`layers` 1.94 ms of a 16.5 ms decode step:
+    PERF.md section 6, PR 41). `forward` therefore views the four stacks
+    [L, heads, hd, D] before it slices them (`hybrid.projections_heads_first`:
+    a reshape that moves no byte while 128 divides hd) and multiplies
+    "bsd,hkd->bshk" / "bshk,hkd->bsd"; a `Q4Tensor` leaf stays flat. The
+    router bias is drawn, not zero, so that it moves the choice in a
+    test."""
     k = iter(jax.random.split(key, 24))
 
     def dense(shape, fan_in):
@@ -352,8 +361,7 @@ def _block(x, lp, mlp, kinds, idx, positions, cfg, cache, block_table, slots,
             q, kk, vv, dt)
         cache = {**cache, **pool}
     with jax.named_scope(scopes.ATTN_OUT):
-        flat = attn.reshape(attn.shape[:2] + (-1,))
-        x = x + qeinsum("bsn,nd->bsd", flat, lp["wo"], dt)
+        x = x + hybrid.out_proj(attn, lp["wo"], dt)
     with jax.named_scope(scopes.NORM):
         h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
     if mlp_kind == DENSE:
@@ -408,11 +416,18 @@ def forward(
         x = materialize(params["tok_embed"], cfg.dtype)[tokens]
 
     kinds = list(zip(cfg.layer_types, cfg.mlp_layer_types))
+    # The four projection stacks with their heads a dim of their own,
+    # before a layer is sliced off them (see `init_params`).
+    layers = hybrid.projections_heads_first(
+        params["layers"], cfg.n_heads, cfg.n_kv_heads)
 
     def layer(carry, j, l, at):
         x, cache, stats = carry
         attn_kind, mlp_kind = kinds[j]
-        lp = _take(params["layers"], l)
+        # (the index opaque to the compiler: folded, as layer 0's is in
+        # the head, its slices of the four stacks stayed plain copies in
+        # `main`, 113 MB a step at the benchmark's cut)
+        lp = _take(layers, jax.lax.optimization_barrier(l))
         mlp = (params["dense" if mlp_kind == DENSE else "moe"], at(mlp_kind))
         x, cache, st = _block(
             x, lp, mlp, kinds[j], at(attn_kind), positions, cfg, cache,

@@ -30,7 +30,7 @@ from jax import lax
 
 from substratus_tpu.ops import scopes
 from substratus_tpu.ops.basics import swiglu
-from substratus_tpu.ops.quant import materialize
+from substratus_tpu.ops.quant import materialize, qeinsum
 
 Params = Dict[str, Any]
 
@@ -109,9 +109,56 @@ def run_stack(kinds: Sequence[Any], layer, carry):
 
 
 def heads_proj(h, w, heads: int, qe, dt):
-    """h [B, S, D] through w [heads * hd, D] -> [B, S, heads, hd]."""
+    """h [B, S, D] -> [B, S, heads, hd] through w [heads, hd, D] (a layer
+    of a stack `heads_first` viewed: its slice feeds the dot from the
+    stack) or through the flat w [heads * hd, D]."""
+    if len(w.shape) == 3:
+        return qe("bsd,hkd->bshk", h, w, dt)
     out = qe("bsd,nd->bsn", h, w, dt)
     return out.reshape(out.shape[:2] + (heads, out.shape[-1] // heads))
+
+
+def heads_first(w, heads: int):
+    """A projection stack [L, heads * hd, D] viewed [L, heads, hd, D], a
+    QTensor's scale with it (where it is 1 wide, [L, 1, 1, D]): no byte
+    moves on a TPU while 128 divides hd, so a program does it where it
+    takes the tree. Sliced by layer from the flat form, an int8 layer was
+    written out anew before its dot read it; from this one the dot's own
+    fusion slices it (PERF.md section 6, PR 41). A `Q4Tensor` is left as
+    it is: its packed dim is the contracted one, and `heads_proj` and the
+    family's output projection take the flat leaf too."""
+    from substratus_tpu.ops.quant4 import Q4Tensor
+
+    if isinstance(w, Q4Tensor):
+        return w
+
+    def view(a):
+        n = a.shape[1]
+        split = (1, 1) if n == 1 else (heads, n // heads)
+        return a.reshape(a.shape[:1] + split + a.shape[2:])
+
+    return jax.tree.map(view, w)
+
+
+def projections_heads_first(layers: Params, n_heads: int,
+                            n_kv_heads: int) -> Params:
+    """`layers` with its four projection stacks (`wq`, `wk`, `wv` and the
+    output's `wo`, each [L, heads * hd, D]) viewed by `heads_first`."""
+    out = dict(layers)
+    for name, heads in (("wq", n_heads), ("wk", n_kv_heads),
+                        ("wv", n_kv_heads), ("wo", n_heads)):
+        out[name] = heads_first(layers[name], heads)
+    return out
+
+
+def out_proj(o, w, dt):
+    """o [B, S, heads, hd] -> [B, S, D] through w [heads, hd, D] (a layer
+    of a stack `heads_first` viewed) or through the flat w [heads * hd,
+    D]. Weight-only whatever the activations' kind: it contracts two
+    dims."""
+    if len(w.shape) == 3:
+        return qeinsum("bshk,hkd->bsd", o, w, dt)
+    return qeinsum("bsn,nd->bsd", o.reshape(o.shape[:2] + (-1,)), w, dt)
 
 
 # -- the expert layer ----------------------------------------------------------

@@ -35,7 +35,7 @@ from substratus_tpu.ops.basics import (
     swiglu,
 )
 from substratus_tpu.ops import scopes
-from substratus_tpu.ops.quant import materialize, qeinsum, qeinsum_w8a8
+from substratus_tpu.ops.quant import QTensor, materialize, qeinsum, qeinsum_w8a8
 
 Params = Dict[str, Any]
 
@@ -195,7 +195,19 @@ def quant_contracting(cfg: LlamaConfig) -> Params:
 
 
 def init_params(cfg: LlamaConfig, key: jax.Array) -> Params:
-    """Random init (truncated-normal fan-in scaling), stacked layers."""
+    """Random init (truncated-normal fan-in scaling), stacked layers.
+
+    This is the published form, the one checkpoints, training, LoRA and
+    load/hf.py keep: wq, wk, wv [L, D, heads, hd] (HF's [heads * hd, D]
+    transposed, so x @ w needs no transpose), wo [L, heads, hd, D]. It is
+    not the form a serving program reads an int8 q, k or v leaf in: sliced
+    off its stack inside the layer scan, a [D, heads, hd] int8 layer is
+    first staged in VMEM and laid out anew, contracted dim last, before
+    its dot reads it (1.2 ms of Mistral-7B's 12.1 ms decode step and 3.9
+    of a 512 chunk's 49 on a v5e; flat [D, heads * hd] is no better:
+    PERF.md section 6, PR 41). `serving_layout` turns those three leaves
+    heads-first, contracted dim last, once, where the engine takes the
+    tree; `_block` reads either form."""
     hd = cfg.head_size
     k = iter(jax.random.split(key, 16))
 
@@ -236,6 +248,67 @@ def init_params(cfg: LlamaConfig, key: jax.Array) -> Params:
     if not cfg.tie_embeddings:
         params["lm_head"] = dense(next(k), (D, cfg.vocab_size), D)
     return params
+
+
+# The int8 projections the serving form re-lays, and where their contracted
+# dim lies: published [L, D, heads, hd] (scale [L, 1, heads, hd]), served
+# [L, heads, hd, D] (scale [L, heads, hd, 1]).
+_HEADS_LAST = ("wq", "wk", "wv")
+_TO_SERVED = (0, 2, 3, 1)
+
+
+def _published(w: Any) -> bool:
+    """An int8 leaf of `_HEADS_LAST`, stacked, still in the published form:
+    told by where the scale's contracted dim of size 1 lies."""
+    return (isinstance(w, QTensor) and len(w.q.shape) == 4
+            and tuple(w.scale.shape) == (w.q.shape[0], 1) + tuple(w.q.shape[2:])
+            and w.q.shape[1] != 1)
+
+
+def _served(w: Any) -> bool:
+    """A q, k or v leaf (a layer of it, or the stack) that `serving_layout`
+    has turned: an int8 leaf whose scale is 1 wide along the last dim."""
+    return (isinstance(w, QTensor) and w.scale.shape[-1] == 1
+            and w.q.shape[-1] != 1)
+
+
+def serving_layout(params: Params, cfg: LlamaConfig,
+                   donate: bool = False) -> Params:
+    """The tree as the serving programs read it (the engine's door:
+    serve/engine.py::Engine.serving_tree): every name and kind kept, the
+    int8 q, k and v stacks [L, D, heads, hd] turned to [L, heads, hd, D]
+    with their scales, the same numbers transposed once, so that a layer's
+    slice feeds its dot from the stack (see `init_params`). The identity
+    on a leaf that already has that form, on dense and `Q4Tensor` leaves
+    and on every other leaf; works on arrays, host arrays and tracers
+    (`jax.eval_shape` gives the served shapes). With `donate` a turned
+    leaf's old arrays are deleted before the next leaf is turned: the tree
+    never holds two stacks of one projection."""
+    layers = dict(params["layers"])
+    for name in _HEADS_LAST:
+        w = layers.get(name)
+        if not _published(w):
+            continue
+        layers[name] = QTensor(q=w.q.transpose(_TO_SERVED),
+                               scale=w.scale.transpose(_TO_SERVED))
+        if donate:
+            jax.block_until_ready(layers[name])
+            for old in (w.q, w.scale):
+                if isinstance(old, jax.Array):
+                    old.delete()
+    return {**params, "layers": layers}
+
+
+def serving_logical_axes(params: Params, cfg: LlamaConfig) -> Params:
+    """`param_logical_axes` for a tree `serving_layout` returned: the
+    turned leaves' axes turned with them (`heads` / `kv_heads` stay the
+    sharded ones)."""
+    axes = param_logical_axes(cfg)
+    layers = dict(axes["layers"])
+    for name in _HEADS_LAST:
+        if _served(params["layers"].get(name)):
+            layers[name] = tuple(layers[name][i] for i in _TO_SERVED)
+    return {**axes, "layers": layers}
 
 
 def init_cache(
@@ -475,6 +548,8 @@ def _block(
     qe = qeinsum_w8a8 if cfg.quant_activations else qeinsum
 
     def proj(name: str, inp: jnp.ndarray, eq: str, lora_eq: str) -> jnp.ndarray:
+        if name in _HEADS_LAST and _served(lp[name]):
+            eq = "bsd,hkd->bshk"
         out = qe(eq, inp, lp[name], dt)
         if name in lora:
             if adapter_ids is not None:

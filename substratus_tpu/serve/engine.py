@@ -212,6 +212,16 @@ METRICS.describe(
 )
 
 
+METRICS.describe(
+    "substratus_serve_weights_relaid_bytes",
+    "Bytes of weight leaves the engine laid out anew for its programs when "
+    "it last took a parameter tree (Engine.serving_tree: the family's "
+    "serving_layout, e.g. Llama's int8 q, k, v stacks turned heads-first; "
+    "0 for a tree that came in the served form or a family with none).",
+    type="gauge",
+)
+
+
 class EngineOverloaded(RuntimeError):
     """submit() rejected: the waiting queue is at its configured bound.
 
@@ -467,6 +477,9 @@ class Engine:
         sync=None,  # serve.multihost.StepSync for multi-host lockstep
         adapters=None,  # serve.adapters.AdapterStore for multi-tenant LoRA
         handoff=None,  # serve.disagg.HandoffManager for role="prefill"
+        donate_params: bool = False,  # the caller keeps no use for `params`
+        # (nor the draft's): a leaf the engine lays out anew is deleted as
+        # soon as its new form is made (serving_tree)
     ):
         """model: the model-family module (models.llama, models.opt, ...)
         implementing forward/init_cache/param_logical_axes/cache_logical_axes;
@@ -596,6 +609,14 @@ class Engine:
                 )
             handoff.bind_engine(self)
 
+        # The door: the tree in the form the programs read it, before it is
+        # sharded, compared with a swap's or met by a compile.
+        with startup_phase("engine.build.layout") as span:
+            self.params = params = self.serving_tree(
+                params, donate=donate_params, span=span
+            )
+            jax.block_until_ready(params)  # the phase times the transposes
+
         self.mesh = mesh
         if mesh is not None:
             from substratus_tpu.parallel.sharding import (
@@ -604,7 +625,8 @@ class Engine:
 
             self._serve_rules = serve_rules_for(mesh)
             self.params = shard_tree(
-                params, mesh, model.param_logical_axes(cfg), self._serve_rules
+                params, mesh, self._param_axes(params, cfg),
+                self._serve_rules,
             )
 
         # The pool, the rings and the per-slot state: what the engine
@@ -850,13 +872,16 @@ class Engine:
             raise ValueError("draft-model spec_k requires the paged kv layout")
         if self.spec_draft:
             self.draft_cfg, draft_params = draft
-            self.draft_params = draft_params
+            self.draft_params = draft_params = self.serving_tree(
+                draft_params, self.draft_cfg, donate=donate_params
+            )
             if mesh is not None:
                 from substratus_tpu.parallel.sharding import shard_tree
 
                 self.draft_params = shard_tree(
                     draft_params, mesh,
-                    model.param_logical_axes(self.draft_cfg), self._serve_rules,
+                    self._param_axes(draft_params, self.draft_cfg),
+                    self._serve_rules,
                 )
             # Same KV dtype as the target pool: an int8 configuration means
             # int8 for the draft's (larger-per-token-count) traffic too.
@@ -1474,6 +1499,42 @@ class Engine:
         self.source = source
         self._wake.set()
 
+    def serving_tree(self, params, cfg=None, *, donate: bool = False,
+                     span=None):
+        """The door every parameter tree passes on its way in (__init__,
+        swap_params): the family's `serving_layout` (models/llama.py), which
+        keeps every leaf's name and kind and gives the leaves a serving
+        program would otherwise lay out anew in every layer and step the
+        form its dot reads from the stack; the identity for a family
+        without one, on `None` and on a tree that has the form already.
+        With `donate` each old leaf is deleted once its new form is made.
+        For the served model's tree (no `cfg`: a draft's passes its own)
+        sets substratus_serve_weights_relaid_bytes, and on `span` the
+        leaves and bytes re-laid."""
+        door = getattr(self.model, "serving_layout", None)
+        new = params
+        if door is not None and params is not None:
+            new = door(params, self.cfg if cfg is None else cfg, donate)
+        moved = [
+            now for old, now in
+            zip(jax.tree.leaves(params), jax.tree.leaves(new))
+            if now is not old
+        ]
+        nbytes = sum(now.nbytes for now in moved)
+        if cfg is None:
+            METRICS.set("substratus_serve_weights_relaid_bytes", nbytes)
+        if span is not None:
+            span.set_attribute("leaves", len(moved))
+            span.set_attribute("bytes", nbytes)
+        return new
+
+    def _param_axes(self, params, cfg):
+        """Logical axes of a tree `serving_tree` returned."""
+        served = getattr(self.model, "serving_logical_axes", None)
+        if served is not None and params is not None:
+            return served(params, cfg)
+        return self.model.param_logical_axes(cfg)
+
     def swap_params(
         self,
         new_params,
@@ -1486,8 +1547,11 @@ class Engine:
         """Hot weight-swap: replace the served parameter tree in place on
         a live engine (docs/serving.md "Zero-downtime rollout").
 
-        Callable from any thread. The new tree must match the served one
-        in treedef, shapes, and dtypes — that is what keeps every
+        Callable from any thread. The new tree passes the same door the
+        first one did (`serving_tree`, on the caller's thread: a
+        checkpoint in the published form is laid out as the programs read
+        it, the caller's arrays left as they are) and must then match the
+        served one in treedef, shapes, and dtypes — that is what keeps every
         compiled prefill/decode/verify executable (identical avals, no
         recompile); a mismatch is rejected here and the engine keeps
         serving the old weights. Accepted swaps are staged for the
@@ -1516,6 +1580,7 @@ class Engine:
             raise RuntimeError("engine is dead") from self.error
         if self._thread is None or self._stop.is_set():
             raise RuntimeError("swap_params needs a running engine")
+        new_params = self.serving_tree(new_params)
         cur_leaves, cur_def = jax.tree_util.tree_flatten(self.params)
         new_leaves, new_def = jax.tree_util.tree_flatten(new_params)
         mismatch = None
@@ -1565,7 +1630,7 @@ class Engine:
             from substratus_tpu.parallel.sharding import shard_tree
 
             new = shard_tree(
-                new, self.mesh, self.model.param_logical_axes(self.cfg),
+                new, self.mesh, self._param_axes(new, self.cfg),
                 self._serve_rules,
             )
         else:

@@ -451,6 +451,10 @@ def _start(args: argparse.Namespace):
     engine = Engine(
         cfg, params, ec, mesh=mesh, model=family, draft=draft, sync=sync,
         adapters=adapters, handoff=handoff,
+        # nothing here reads the trees again: a leaf the engine lays out
+        # anew (Engine.serving_tree) is freed as its new form is made, so
+        # no stack is held twice
+        donate_params=True,
     )
     # Under a mesh the engine holds its own sharded copy; this name was
     # the last reference to the whole tree on the default device.

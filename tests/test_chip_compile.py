@@ -153,6 +153,37 @@ def _pool_moving_ops(hlo: str, sizes) -> list:
     return found
 
 
+# Ops that move no byte of their own, or whose result is not theirs alone.
+_NO_MOVE = {"parameter", "get-tuple-element", "bitcast", "tuple", "while",
+            "conditional", "call", "custom-call", "copy-done", "slice-done",
+            "optimization-barrier"}
+
+
+def _weights_laid_out_anew(hlo: str, sizes) -> list:
+    """Every op of the optimized HLO that stands outside any fusion (so
+    outside every dot's fusion: a fusion's own result is listed, its inside
+    is not) and whose result is int8 with one of `sizes` elements, the
+    elements of one layer of a projection leaf: a layer's weights written
+    somewhere else before their dot reads them (a
+    `constant_dynamic-slice_fusion` staged in VMEM, a `copy`, a
+    `copy_bitcast_fusion`, a plain `slice`). As "computation: name =
+    type op"."""
+    found, where, fused = [], "", False
+    for line in hlo.splitlines():
+        if line and not line.startswith(" "):
+            where = line.split("(")[0].replace("ENTRY", "").strip(" %")
+            fused = "fused_computation" in where
+            continue
+        m = re.match(r"\s+(?:ROOT )?%?([\w.\-]+) = (.*?) ([\w\-]+)\(", line)
+        if fused or not m or m.group(3) in _NO_MOVE:
+            continue
+        for t in re.finditer(r"s8\[([\d,]+)\](\{[^}]*\})?", m.group(2)):
+            if math.prod(map(int, t.group(1).split(","))) in sizes:
+                found.append(f"{where}: {m.group(1)} = {t.group(0)} "
+                             f"{m.group(3)}")
+    return found
+
+
 def _reads_pages_in_place(hlo: str, kernel: str, rows: int, seq: int,
                           kv_heads: int, head_dim: int,
                           scores: int = 0) -> bool:
@@ -229,16 +260,27 @@ def _described(v5e, eng, **mesh_axes):
 
 
 @pytest.mark.parametrize(
-    "kv_cache_dtype,tensor",
-    [("model", 1), ("int8", 1), ("model", 4)],
-    ids=["bf16", "int8kv", "bf16-tensor4"],
+    "kv_cache_dtype,tensor,door",
+    [("model", 1, True), ("int8", 1, True), ("model", 4, True),
+     ("model", 1, False)],
+    ids=["bf16", "int8kv", "bf16-tensor4", "bf16-published"],
 )
 def test_serving_programs_leave_the_kv_pool_in_place(
-    kv_cache_dtype, tensor, v5e
+    kv_cache_dtype, tensor, door, v5e
 ):
     """decode and the 512-token chunk, for one described chip and for the
     four under a `tensor` mesh (pool sharded over kv_heads): no pool- or
-    layer-of-pool-sized copy or slice, and temporaries under half a pool."""
+    layer-of-pool-sized copy or slice, and temporaries under half a pool.
+
+    The programs are lowered over the tree the engine's door returns
+    (models/llama.py::serving_layout: the int8 q, k and v stacks heads
+    first, contracted dim last), and on one chip no layer of a projection
+    leaf is written anywhere before its dot reads it. `bf16-published`
+    lowers the tree as `init_params` lays it out, without the door: there
+    the decode step stages the three slices in VMEM
+    (`constant_dynamic-slice_fusion`), which shows that the helper sees
+    what it guards (1.2 ms of a 12.1 ms step on the chip: PERF.md section
+    6, PR 41)."""
     from substratus_tpu.models import llama
     from substratus_tpu.ops.quant import quantize_params
     from substratus_tpu.serve.engine import Engine, EngineConfig
@@ -267,8 +309,13 @@ def test_serving_programs_leave_the_kv_pool_in_place(
             dtype=jnp.int8 if quantized else None,
         )
     )
+    if door:
+        params = jax.eval_shape(
+            lambda tree: llama.serving_layout(tree, cfg), params)
+        assert params["layers"]["wq"].q.shape == (32, 32, 128, 4096)
+        assert params["layers"]["wk"].scale.shape == (32, 8, 128, 1)
     placed, arr = _described(v5e, eng, tensor=tensor)
-    params = placed(params, llama.param_logical_axes(cfg))
+    params = placed(params, llama.serving_logical_axes(params, cfg))
     pool = placed(pool, llama.paged_cache_logical_axes(cfg, quantized))
     m = _S // _PAGE
     programs = {
@@ -282,6 +329,11 @@ def test_serving_programs_leave_the_kv_pool_in_place(
             arr((1, m)),
         ),
     }
+    # One layer of each projection leaf, per device (heads, kv_heads and
+    # mlp are the sharded dims).
+    layer_of = {
+        math.prod(w.q.sharding.shard_shape(w.q.shape)) // cfg.n_layers
+        for w in params["layers"].values() if hasattr(w, "q")}
     # Elements per device of each pool array and of one layer of it. The
     # int8 pool's f32 scales [L, P, bs, KH, 1] are the exception the test
     # records: the compiler gives that shape a pages-minor layout and lays
@@ -316,6 +368,15 @@ def test_serving_programs_leave_the_kv_pool_in_place(
         assert in_place == (not quantized), name
         assert ("kv.gather" in hlo) == quantized, name
         assert _sorts_only_where_a_row_samples(hlo) == (name == "decode")
+        anew = _weights_laid_out_anew(hlo, layer_of)
+        if not door:
+            if name == "decode":
+                assert sum("constant_dynamic-slice_fusion" in op
+                           and "S(1)" in op for op in anew) == 3, anew
+        elif (kv_cache_dtype, tensor) == ("model", 1):
+            assert anew == [], (name, anew)
+        else:  # recorded, not refused
+            print(f"{kv_cache_dtype} tensor={tensor} {name}: {anew}")
 
 
 # The reason-mixed cell's engine (benchmarks/traffic/reason-mixed.json):
@@ -416,6 +477,18 @@ def test_exaone_programs_leave_pool_and_rings_in_place(v5e):
         # expert's rows at a time; the decode step every held expert
         grouped = "s8[1,1,6144,2048]" in hlo
         assert grouped == (name == "chunk"), name
+        # no layer of a projection stack is written anywhere before its
+        # dot reads it, in the scan's body or in the head of four layers:
+        # `forward` views the stacks [L, heads, hd, D] before it slices
+        # them (flat, the body held `constant_dynamic-slice_fusion.58`,
+        # three s8[1,8192,6144] a period, and the head the same: 1.9 ms of
+        # a 16.5 ms step) and hands the slices an index the compiler cannot
+        # fold (folded, layer 0's four slices stayed plain copies in
+        # `main`: s8[1,8192,6144] x 2, s8[1,1024,6144] x 2, 113 MB a step)
+        layer_of = {w.q.size // cfg.n_layers
+                    for w in params["layers"].values() if hasattr(w, "q")}
+        assert layer_of == {8192 * 6144, 1024 * 6144}
+        assert _weights_laid_out_anew(hlo, layer_of) == [], name
 
 
 # The assist cell's engine (benchmarks/traffic/assist.json): LFM2-24B-A2B's
@@ -606,6 +679,13 @@ def test_brumby_programs_compile_and_leave_the_state_in_place(v5e):
         assert [op for op in _pool_moving_ops(f32, sizes)
                 if " copy(" in op] == [], name
         assert not re.search(r"= s8\[[\d,]+\]\S* copy\(", hlo), name
+        # no layer of a weight stack is written anywhere before its dot
+        # reads it: `forward` views the four projection stacks [L, heads,
+        # hd, D] before it slices them (flat, each program staged
+        # s8[1,5120,5120] and one or two s8[1,1024,5120] in VMEM, every
+        # layer: `constant_dynamic-slice_fusion`, PR 41)
+        assert _weights_laid_out_anew(
+            hlo, {5120 * 5120, 1024 * 5120, 5120 * 17408}) == [], name
         mem = compiled.memory_analysis()
         # the state is donated and comes back as the same buffers
         assert mem.alias_size_in_bytes >= state, name

@@ -254,14 +254,18 @@ def test_an_engine_is_one_phase_with_its_cache_and_its_programs_inside():
     _tiny_engine()
     spans = {s["name"]: s for s in tracer.finished()[before:]
              if s["name"].startswith(("startup.", "engine.build."))}
-    assert set(spans) == {"startup.engine", "engine.build.cache",
-                          "engine.build.programs"}
+    inner = ("engine.build.layout", "engine.build.cache",
+             "engine.build.programs")
+    assert set(spans) == {"startup.engine", *inner}
     outer = spans["startup.engine"]
-    for name in ("engine.build.cache", "engine.build.programs"):
+    for name in inner:
         assert spans[name]["parent_id"] == outer["span_id"]
         assert outer["duration_us"] > spans[name]["duration_us"]
-    assert outer["duration_us"] >= (spans["engine.build.cache"]["duration_us"]
-                                    + spans["engine.build.programs"]["duration_us"])
+    assert outer["duration_us"] >= sum(
+        spans[name]["duration_us"] for name in inner)
+    # a dense tree has no leaf a serving program lays out anew
+    assert spans["engine.build.layout"]["attributes"] == {
+        "leaves": 0, "bytes": 0}
     phases = jaxstart.startup_record()["phases"]
     for name, span in spans.items():
         assert phases[name] == pytest.approx(span["duration_us"] / 1e6,

@@ -15,6 +15,7 @@ from __future__ import annotations
 
 import json
 import os
+from types import SimpleNamespace
 from typing import Any, Dict, Mapping, Optional, Tuple
 
 import jax.numpy as jnp
@@ -512,6 +513,36 @@ def config_from_hf_deepseek_v3(hf_cfg: Any):
     )
 
 
+def config_from_hf_glm_moe_dsa(hf_cfg: Any):
+    """A transformers `glm_moe_dsa` config.json (GLM-5) -> DeepseekV3Config
+    with its indexer: DeepSeek-V3's keys, the rotary table under
+    `rope_parameters`, no group limit by default, and the four keys of
+    DeepSeek Sparse Attention's index. The checkpoint names the indexer's
+    tensors `self_attn.indexer.{wq_b, wk, k_norm, weights_proj}` = the
+    tree's `w_iq`, `w_ik`, `ik_norm` / `ik_norm_bias`, `w_iw`; like the
+    rest of the family's, no converter maps them yet."""
+    get = lambda name, default=None: getattr(hf_cfg, name, default)
+    rope = dict(get("rope_parameters") or {})
+    kind = rope.get("rope_type", rope.get("type", "default"))
+    if kind != "default":
+        raise NotImplementedError(f"glm_moe_dsa: rope_type {kind!r}")
+    if (get("n_group", 1) or 1) > 1 and not get("topk_group"):
+        raise NotImplementedError(
+            f"glm_moe_dsa: n_group {get('n_group')} without topk_group")
+    if int(get("index_topk", 0) or 0) < 1 or not get("index_n_heads"):
+        raise NotImplementedError(
+            f"glm_moe_dsa: index_topk {get('index_topk')!r} over "
+            f"{get('index_n_heads')!r} index heads")
+    plain = SimpleNamespace(**{
+        **vars(hf_cfg), "rope_scaling": None,
+        "rope_theta": rope.get("rope_theta", get("rope_theta", 1e4))})
+    return config_from_hf_deepseek_v3(plain).replace(
+        index_n_heads=int(hf_cfg.index_n_heads),
+        index_head_dim=int(hf_cfg.index_head_dim),
+        index_topk=int(hf_cfg.index_topk),
+    )
+
+
 def convert_deepseek_v3_state_dict(sd: Mapping[str, Any], cfg: Any,
                                    dtype=jnp.bfloat16) -> Params:
     """Not written, as convert_exaone_moe_state_dict is not: a checkpoint's
@@ -533,6 +564,8 @@ def _dispatch_hf(model_type: str):
     from substratus_tpu.models.registry import HF_MODEL_TYPES
 
     family = HF_MODEL_TYPES.get(model_type)
+    if model_type == "glm_moe_dsa":
+        return config_from_hf_glm_moe_dsa, convert_deepseek_v3_state_dict
     if family == "opt":
         return config_from_hf_opt, convert_opt_state_dict
     if family == "llama":
@@ -563,8 +596,6 @@ def load_pretrained(
     ):
         with open(os.path.join(path_or_name, "config.json")) as f:
             raw = json.load(f)
-        from types import SimpleNamespace
-
         hf_ns = SimpleNamespace(**raw)
         cfg, convert = _dispatch_hf(raw.get("model_type", "llama"))
         cfg = cfg(hf_ns)

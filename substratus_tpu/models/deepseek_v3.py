@@ -24,6 +24,20 @@ anywhere but the router's.
     (`yarn_get_mscale`); the rotary table is YaRN's (ops/basics.py::
     rope_freqs), its own amplitude yarn_get_mscale(factor, mscale) / m,
     which is 1 in every published configuration and is held to 1 here.
+  A learned index over what the layer has kept (DeepSeek Sparse
+  Attention; transformers `glm_moe_dsa`, GLM-5), where `index_n_heads` > 0.
+  Hi = index_n_heads, di = index_head_dim, k = index_topk:
+    qi_t,j = (cq_t W_IQ)_j [di], j = 1..Hi; ki_s = LayerNorm(h_s W_IK; g,
+    b) [di], one a token; of both the first dr channels rotated at the
+    token's position (the MLA's table), the other di - dr not;
+    w_t,j = (h_t W_IW)_j Hi^(-1/2) di^(-1/2), float32;
+    I_t,s = sum_j w_t,j ReLU(qi_t,j . ki_s) for s <= t, float32;
+    S_t = the min(k, t + 1) positions s <= t of largest I_t,s, ties toward
+    the lower position; the MLA's softmax runs over s in S_t only.
+    What a token keeps is then [ckv; kr] and ki. For t < k the set is
+    everything and the layer is plain MLA. Left out of the published
+    inference code: the Hadamard rotation of qi and ki (orthogonal: every
+    qi . ki is the same number) and their FP8 storage.
   FFN, l < first_k_dense: (silu(h W_gate) * (h W_up)) W_down. Beyond:
     sigmoid-routed experts under a group limit beside a shared one
     (models/hybrid.py::route, ::moe), the layer told which experts it
@@ -32,13 +46,16 @@ anywhere but the router's.
 
 Where each lives. The stack is walked by hybrid.run_stack: the leading
 dense layers one by one, the sparse ones under one scan. The cache is the
-paged pool alone (`k`: [L, P, bs, 1, w] latent rows; `v`: no layers,
+paged pool alone (`k`: [L, P, bs, 1, w] latent rows; `v`: no layers, or
+with an indexer the tokens' index keys [L, P, bs, 1, di];
 ops/kvcache.py::init_latent_cache): pages carry everything a sequence has,
 so the family keeps no per-slot state. Two paths, chosen by the call's own
 shape (ops/kvcache.py::latent_attention): a decode step runs absorbed over
 the row's live pages, a chunk expanded, its context's keys and values made
 from the pool's latents a block of pages at a time; without a cache the
-whole sequence at once, expanded.
+whole sequence at once, expanded. With an indexer each of the three runs
+over every query's own set (ops/sparse_index.py): the decode step reads
+the set's rows by position, the other two mask what is outside it.
 
 Departures from the published checkpoints: the rotary pairs are taken
 de-interleaved (rotate-half; a checkpoint interleaves them, a permutation
@@ -62,8 +79,8 @@ import jax.numpy as jnp
 
 from substratus_tpu.models import hybrid
 from substratus_tpu.models.hybrid import gated as _gated, take as _take
-from substratus_tpu.ops import kvcache, scopes
-from substratus_tpu.ops.basics import rms_norm, rope, yarn_mscale
+from substratus_tpu.ops import kvcache, scopes, sparse_index
+from substratus_tpu.ops.basics import layer_norm, rms_norm, rope, yarn_mscale
 from substratus_tpu.ops.quant import materialize, qeinsum, qeinsum_w8a8
 
 Params = Dict[str, Any]
@@ -121,6 +138,12 @@ class DeepseekV3Config:
     rope_mscale: float = 1.0
     rope_mscale_all_dim: float = 1.0
     norm_eps: float = 1e-6
+    # The learned index over a layer's kept rows (the docstring's
+    # equations); 0 heads: none, every query attends all it can see.
+    index_n_heads: int = 0
+    index_head_dim: int = 128
+    index_topk: int = 2048
+    index_norm_eps: float = 1e-6  # of the key's LayerNorm
     max_seq_len: int = 163840
     tie_embeddings: bool = False
     dtype: Any = jnp.bfloat16
@@ -142,6 +165,12 @@ class DeepseekV3Config:
                 1 <= self.topk_group <= self.n_group):
             raise ValueError(f"{self.n_experts} experts in {self.n_group} "
                              f"groups, {self.topk_group} kept")
+        if self.index_n_heads and not (
+                self.index_topk >= 1
+                and self.qk_rope_head_dim <= self.index_head_dim):
+            raise ValueError(
+                f"an index of top {self.index_topk} over keys of "
+                f"{self.index_head_dim} ({self.qk_rope_head_dim} rotated)")
         if self.rope_factor > 1 and self.rope_mscale != self.rope_mscale_all_dim:
             raise ValueError(
                 "a rotary amplitude other than 1 (rope_mscale != "
@@ -195,6 +224,26 @@ CONFIGS: Dict[str, DeepseekV3Config] = {
         rope_factor=4.0, rope_original_max=32, max_seq_len=256,
     ),
     "deepseek-v3": DeepseekV3Config(),
+    # The same block under a learned index that bites at the tests' sizes:
+    # 2 index heads of 16, the 8 best of a context kept; no group limit and
+    # no YaRN, as the published configuration that has the index (GLM-5).
+    "tiny-glm-dsa": DeepseekV3Config(
+        vocab_size=256, dim=64, n_layers=4, n_heads=4, q_lora_rank=32,
+        kv_lora_rank=16, qk_nope_head_dim=24, qk_rope_head_dim=8,
+        v_head_dim=32, hidden_dim=128, moe_hidden_dim=32, first_k_dense=1,
+        n_experts=8, n_experts_per_token=2, n_group=1, topk_group=1,
+        rope_theta=1e6, rope_factor=1.0, norm_eps=1e-5, max_seq_len=256,
+        index_n_heads=2, index_head_dim=16, index_topk=8,
+    ),
+    "glm-5": DeepseekV3Config(
+        vocab_size=154880, dim=6144, n_layers=78, n_heads=64,
+        q_lora_rank=2048, kv_lora_rank=512, qk_nope_head_dim=192,
+        qk_rope_head_dim=64, v_head_dim=256, hidden_dim=12288,
+        moe_hidden_dim=2048, first_k_dense=3, n_experts=256,
+        n_experts_per_token=8, n_group=1, topk_group=1, rope_theta=1e6,
+        rope_factor=1.0, norm_eps=1e-5, max_seq_len=202752,
+        index_n_heads=32, index_head_dim=128, index_topk=2048,
+    ),
 }
 
 
@@ -233,6 +282,15 @@ def param_logical_axes(cfg: DeepseekV3Config) -> Params:
             "w_o": ("layers", "heads", "embed"),
         },
     }
+    if cfg.index_n_heads:
+        axes["layers"].update({
+            # every chip scores every key: nothing of the index shards
+            "w_iq": ("layers", None, None, None),
+            "w_ik": ("layers", None, "embed"),
+            "w_iw": ("layers", None, "embed"),
+            "ik_norm": ("layers", None),
+            "ik_norm_bias": ("layers", None),
+        })
     if cfg.count(DENSE):
         axes["dense"] = {
             "w_gate": ("layers", "embed", "mlp"),
@@ -265,6 +323,9 @@ def quant_contracting(cfg: DeepseekV3Config) -> Params:
                    "w_uq_rope": (3,), "w_dkv": (2,), "w_uk": (3,),
                    "w_uv": (3,), "w_o": (1,)},
     }
+    if cfg.index_n_heads:  # W_IW stays dense: its product is float32
+        q["layers"].update({"w_iq": (3,), "w_ik": (2,), "w_iw": (),
+                            "ik_norm": (), "ik_norm_bias": ()})
     if cfg.count(DENSE):
         q["dense"] = {"w_gate": (1,), "w_up": (1,), "w_down": (1,)}
     if cfg.count(SPARSE):
@@ -298,7 +359,7 @@ def init_params(cfg: DeepseekV3Config, key: jax.Array) -> Params:
     the same `constant_dynamic-slice_fusion` (described v5e, PR 41), so
     the leaf stays as it is. The router bias is drawn, not zero, so that
     it moves the choice in a test."""
-    k = iter(jax.random.split(key, 24))
+    k = iter(jax.random.split(key, 32))
 
     def dense(shape, fan_in):
         return (jax.random.truncated_normal(next(k), -2, 2, shape, jnp.float32)
@@ -327,6 +388,18 @@ def init_params(cfg: DeepseekV3Config, key: jax.Array) -> Params:
             "w_o": dense((L, H * dv, D), H * dv),
         },
     }
+    if cfg.index_n_heads:
+        # as the MLA's own: W_IQ a head apart, contracted dimension last.
+        # The key's LayerNorm has a bias, drawn so that it shows in a test.
+        Hi, di = cfg.index_n_heads, cfg.index_head_dim
+        params["layers"].update({
+            "w_iq": dense((L, Hi, di, rq), rq),
+            "w_ik": dense((L, di, D), D),
+            "w_iw": dense((L, Hi, D), D),
+            "ik_norm": jnp.ones((L, di), cfg.dtype),
+            "ik_norm_bias": (0.1 * jax.random.normal(
+                next(k), (L, di), jnp.float32)).astype(cfg.dtype),
+        })
     if Ld:
         params["dense"] = {
             "w_gate": dense((Ld, D, M), D), "w_up": dense((Ld, D, M), D),
@@ -354,17 +427,25 @@ def init_paged_cache(cfg: DeepseekV3Config, pages: int, page_size: int,
     """The latent pool: `k` [L, P, bs, 1, w], one row [ckv; kr] a token and
     layer for all heads (ops/kvcache.py::init_latent_cache decides the
     stored width w), and `v` a pool of no layers: the values are the keys'
-    leading part."""
+    leading part. With an indexer `v` is the tokens' index keys, [L, P,
+    bs, 1, index_head_dim], under the same page ids."""
     dtype = dtype or cfg.dtype
     if dtype == jnp.int8:
         raise ValueError("deepseek_v3 keeps no int8 latent pool")
     return kvcache.init_latent_cache(
-        cfg.n_layers, pages, page_size, cfg.latent_row, dtype)
+        cfg.n_layers, pages, page_size, cfg.latent_row, dtype,
+        cfg.index_head_dim if cfg.index_n_heads else 0)
 
 
 def paged_cache_logical_axes(cfg: DeepseekV3Config,
                              quantized: bool = False) -> Params:
     return kvcache.latent_cache_logical_axes()
+
+
+def index_topk(cfg: DeepseekV3Config) -> int:
+    """Rows a query attends at most, where the layers pick them by a
+    learned index; 0: every row it can see (for the engine's counters)."""
+    return cfg.index_topk if cfg.index_n_heads else 0
 
 
 def kv_heads_per_pool_row(cfg: DeepseekV3Config, pool: Params) -> int:
@@ -374,11 +455,35 @@ def kv_heads_per_pool_row(cfg: DeepseekV3Config, pool: Params) -> int:
 
 # -- the block -----------------------------------------------------------------
 
-def expanded_attention(q, ckv, kr, w_uk, w_uv, positions, cfg):
+def index_of(h, cq, lp, positions, cfg, qe):
+    """The indexer's projections for the tokens of the call: (qi [B, S,
+    Hi, di], ki [B, S, di], wi [B, S, Hi] float32, k), both rotated in
+    their first dr channels; what ops/kvcache.py::latent_attention takes
+    as `index`."""
+    dt, dr = cfg.dtype, cfg.qk_rope_head_dim
+
+    def rotated(x):  # [B, S, heads, di]
+        return jnp.concatenate([
+            rope(x[..., :dr], positions, cfg.rope_theta, cfg.yarn),
+            x[..., dr:]], axis=-1)
+
+    qi = rotated(qe("bsr,jnr->bsjn", cq, lp["w_iq"], dt))
+    ki = layer_norm(qe("bsd,nd->bsn", h, lp["w_ik"], dt), lp["ik_norm"],
+                    lp["ik_norm_bias"], cfg.index_norm_eps)
+    ki = rotated(ki[:, :, None, :])[:, :, 0]
+    wi = jnp.einsum(
+        "bsd,jd->bsj", h.astype(jnp.float32),
+        materialize(lp["w_iw"], jnp.float32),
+    ) * (cfg.index_n_heads * cfg.index_head_dim) ** -0.5
+    return qi, ki.astype(dt), wi, cfg.index_topk
+
+
+def expanded_attention(q, ckv, kr, w_uk, w_uv, positions, cfg, index=None):
     """The expanded form over a whole sequence held in the call (no
     cache): q [B, S, H, dn + dr], ckv [B, S, rkv], kr [B, S, dr] against
     themselves, key j visible to query i iff positions j <= i. float32
-    softmax, as ops/attention.py."""
+    softmax, as ops/attention.py. With `index` (`index_of`'s) a query
+    attends its own set of the visible keys alone."""
     dn = cfg.qk_nope_head_dim
     ckv = ckv.astype(jnp.float32)
     k_nope = jnp.einsum("bsc,hnc->bshn", ckv, materialize(w_uk, jnp.float32))
@@ -388,6 +493,10 @@ def expanded_attention(q, ckv, kr, w_uk, w_uv, positions, cfg):
          + jnp.einsum("bqhr,bkr->bhqk", qf[..., dn:], kr.astype(jnp.float32))
          ) * cfg.softmax_scale
     seen = positions[:, None, :] <= positions[:, :, None]  # [B, q, k]
+    if index is not None:
+        qi, ki, wi, topk = index
+        seen = sparse_index.select(
+            sparse_index.scores(qi, ki, wi), seen, topk, axis=2)
     p = jax.nn.softmax(jnp.where(seen[:, None], s, -1e30), axis=-1)
     return jnp.einsum("bhqk,bkhv->bqhv", p, v).astype(q.dtype)
 
@@ -414,15 +523,19 @@ def _block(x, lp, mlp, mlp_kind, idx, positions, cfg, cache, block_table,
         q = jnp.concatenate([
             q_nope, rope(q_rope, positions, cfg.rope_theta, cfg.yarn)],
             axis=-1)
+    index = None
+    if cfg.index_n_heads:
+        with jax.named_scope(scopes.ATTN_INDEX):
+            index = index_of(h, cq, lp, positions, cfg, qe)
     if cache is None:
         with jax.named_scope(scopes.ATTN_CORE):
             attn = expanded_attention(q, ckv, kr, lp["w_uk"], lp["w_uv"],
-                                      positions, cfg)
+                                      positions, cfg, index)
     else:
         pool, attn = kvcache.latent_attention(
             {"k": cache["k"], "v": cache["v"]}, idx, block_table, positions,
             q, jnp.concatenate([ckv, kr], axis=-1), lp["w_uk"], lp["w_uv"],
-            cfg.softmax_scale, dt)
+            cfg.softmax_scale, dt, index)
         cache = {**cache, **pool}
     with jax.named_scope(scopes.ATTN_OUT):
         flat = attn.reshape(attn.shape[:2] + (-1,))

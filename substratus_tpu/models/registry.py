@@ -30,7 +30,9 @@ FAMILIES = {
     # DeepSeek-V3 and the language model of dots.vlm1: multi-head latent
     # attention (one shared row a token and layer in the pages, read
     # absorbed by a decode step and expanded by a chunk), a low-rank query,
-    # YaRN, sigmoid-routed experts under a group limit
+    # YaRN, sigmoid-routed experts under a group limit; and GLM-5, the same
+    # block under a learned index that picks the rows a query attends
+    # (index_n_heads > 0: the index keys lie in the pages beside the rows)
     "deepseek_v3": deepseek_v3,
 }
 
@@ -47,6 +49,8 @@ HF_MODEL_TYPES = {
     "deepseek_v3": "deepseek_v3",
     # dots.vlm1: its text part; the vision tower is not built
     "dots_vlm": "deepseek_v3",
+    # GLM-5: DeepSeek-V3's block with DeepSeek Sparse Attention's indexer
+    "glm_moe_dsa": "deepseek_v3",
 }
 
 _CONFIG_CLASS_TO_FAMILY = {

@@ -319,20 +319,29 @@ def paged_chunk(tag, b, s, max_seq, h, kh, d, pages, page=16,
 
 
 def latent_attend(tag, b, s, max_seq, h, dn, dr, dv, rkv, pages, page=16,
-                  layers=2) -> KernelCase:
+                  layers=2, index=None) -> KernelCase:
     """ops/kvcache.py::latent_attention over a stacked pool of latent rows
     [ckv (rkv); kr (dr)], one a token for all `h` heads: `s` = 1 a decode
     step (absorbed: rows of every length from a full table down, the last
     idle), `s` > 1 a prefill chunk (expanded: its last token the table's
     last position, so the walk takes every page). The new rows are written
-    first, as the op does; both realisations return the attention alone."""
+    first, as the op does; both realisations return the attention alone.
+    `index` = (heads, key width, k): the layer picks each query's k rows by
+    a learned index (ops/sparse_index.py), whose keys fill the pool's
+    second array; the step then scores them in place and the chunk runs
+    under each query's own set."""
     from substratus_tpu.ops import kvcache
     from substratus_tpu.ops import latent_attention as LA
+    from substratus_tpu.ops import sparse_index as SI
+
+    hi, di, topk = index or (0, 0, 0)
 
     def make_args(key):
-        kq, kn, kw, kp, kt = jax.random.split(key, 5)
-        pool = kvcache.init_latent_cache(layers, pages, page, rkv + dr, BF16)
+        kq, kn, kw, kp, kt, ki = jax.random.split(key, 6)
+        pool = kvcache.init_latent_cache(layers, pages, page, rkv + dr, BF16,
+                                         di)
         rows = _normal(kp, (layers, pages, page, 1, rkv + dr))
+        keys = _normal(ki, pool["v"].shape) if index else pool["v"]
         pool = pool["k"].at[..., :rkv + dr].set(rows)
         m = max_seq // page
         own = jax.random.permutation(kt, jnp.arange(1, pages, dtype=jnp.int32))
@@ -342,29 +351,36 @@ def latent_attend(tag, b, s, max_seq, h, dn, dr, dv, rkv, pages, page=16,
         last = max_seq - 1 - (max_seq - s) * jnp.arange(b) // max(b - 1, 1)
         positions = last[:, None] - (s - 1) + jnp.arange(s)[None, :]
         w = _normal(kw, (h, dn + dv, rkv), jnp.float32) * rkv ** -0.5
+        ks = jax.random.split(ki, 3)
         return (
             _normal(kq, (b, s, h, dn + dr)), _normal(kn, (b, s, rkv + dr)),
             w[:, :dn].astype(BF16), w[:, dn:].astype(BF16), pool,
-            jnp.int32(layers - 1), table, positions.astype(jnp.int32),
+            jnp.int32(layers - 1), table, positions.astype(jnp.int32), keys,
+            _normal(ks[0], (b, s, hi, di)), _normal(ks[1], (b, s, di)),
+            _normal(ks[2], (b, s, hi), jnp.float32),
         )
 
     scale = (dn + dr) ** -0.5
 
-    def attend(q, new, w_uk, w_uv, pool, layer, table, positions):
-        cache = {"k": pool, "v": pool[:0]}
+    def attend(q, new, w_uk, w_uv, pool, layer, table, positions, keys, qi,
+               ki, wi):
+        cache = {"k": pool, "v": keys}
         return kvcache.latent_attention(
             cache, layer, table, positions, q, new, w_uk, w_uv, scale,
-            q.dtype)[1]
+            q.dtype, (qi, ki, wi, topk) if index else None)[1]
 
     def kernel(*args, interpret=False):
         if not interpret:
             return attend(*args)
         # the CPU rehearsal: the op would take the gather
-        names = ("latent_decode_attention", "latent_chunk_attention")
+        names = ("latent_decode_attention", "latent_chunk_attention",
+                 "index_decode_scores", "index_chunk_scores")
         real = jax.lax.platform_dependent, [getattr(kvcache, n) for n in names]
         jax.lax.platform_dependent = lambda *a, tpu, default: tpu(*a)
         for n in names:
-            setattr(kvcache, n, partial(getattr(LA, n), interpret=True))
+            setattr(kvcache, n, partial(
+                getattr(SI if n.startswith("index") else LA, n),
+                interpret=True))
         try:
             return attend(*args)
         finally:
@@ -381,8 +397,9 @@ def latent_attend(tag, b, s, max_seq, h, dn, dr, dv, rkv, pages, page=16,
             kvcache._latent_kernels_for = real
 
     return KernelCase(
-        f"latent_attend/{tag}/b{b}-q{s}-s{max_seq}-h{h}", make_args, kernel,
-        reference, tol=2e-2,
+        f"latent_attend/{tag}/b{b}-q{s}-s{max_seq}-h{h}"
+        + (f"-top{topk}" if index else ""), make_args, kernel, reference,
+        tol=2e-2,
     )
 
 
@@ -527,6 +544,14 @@ def chip_cases() -> List[KernelCase]:
     cases.append(latent_attend("dots-docqa", 12, 1, 14336, **mla))
     cases.append(latent_attend("dots-docqa", 1, 512, 14336, **mla))
     cases.append(latent_attend("dots-docqa", 1, 256, 14336, **mla))
+    # The repoqa cell's (GLM-5's widths: 64 heads of 192 + 64 against 256,
+    # 32 index heads over keys of 128, the 2,048 best rows a query): the
+    # step scores 4 slots' keys in place and reads the picked rows, the 512
+    # chunk scores its queries and runs under each one's own set.
+    dsa = dict(h=64, dn=192, dr=64, dv=256, rkv=512, pages=4609,
+               index=(32, 128, 2048))
+    cases.append(latent_attend("glm5-repoqa", 4, 1, 18432, **dsa))
+    cases.append(latent_attend("glm5-repoqa", 1, 512, 18432, **dsa))
     return cases
 
 
@@ -548,6 +573,10 @@ def rehearsal_cases() -> List[KernelCase]:
                       pages=25),
         latent_attend("small", 2, 20, 128, h=8, dn=32, dr=16, dv=32, rkv=128,
                       pages=25),
+        latent_attend("small", 3, 1, 128, h=8, dn=48, dr=16, dv=64, rkv=128,
+                      pages=25, index=(4, 128, 24)),
+        latent_attend("small", 2, 20, 128, h=8, dn=48, dr=16, dv=64, rkv=128,
+                      pages=25, index=(4, 128, 24)),
     ]
 
 

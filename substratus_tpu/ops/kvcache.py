@@ -63,7 +63,10 @@ page_size, 1, w] alone, `v` a pool of no layers (the values are the keys'
 leading part; `init_latent_cache` decides the stored width). Its entry
 point is `latent_attention`: one query token a row reads the rows absorbed,
 more expanded, by ops/latent_attention.py's kernels where `paged_attention`
-would take ops/paged_attention.py's, by a gather everywhere else.
+would take ops/paged_attention.py's, by a gather everywhere else. A layer
+that picks what a query attends by a learned index (ops/sparse_index.py)
+keeps the token's index key in `v`, [layers, pages, page_size, 1, di], under
+the same page ids: whatever shares, frees or reuses a page does so for both.
 
 A layer that attends to a window of W positions keeps no pages: each decode
 slot owns a ring of W rows a window layer (`ring_read_and_update`), beside
@@ -83,13 +86,16 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from substratus_tpu.ops import retention, retention_kernel, scopes
+from substratus_tpu.ops import retention, retention_kernel, scopes, sparse_index
 from substratus_tpu.ops.attention import dot_product_attention
 from substratus_tpu.ops.latent_attention import (
     latent_chunk_attention, latent_decode_attention,
 )
 from substratus_tpu.ops.paged_attention import (
-    LANES, paged_chunk_attention, paged_decode_attention,
+    LANES, NEG_INF, paged_chunk_attention, paged_decode_attention,
+)
+from substratus_tpu.ops.sparse_index import (
+    index_chunk_scores, index_decode_scores,
 )
 from substratus_tpu.ops.quant import dequantize_kv, quantize_kv
 from substratus_tpu.parallel.sharding import SERVE_RULES
@@ -321,6 +327,7 @@ def latent_attention(
     w_uv,  # [H, dv, rkv]: W_UV_i^T a head, as stored (v = ckv W_UV_i)
     scale: float,
     dtype,
+    index=None,  # (qi [B, S, Hi, di], ki [B, S, di], wi [B, S, Hi], k)
 ) -> Tuple[Dict[str, jnp.ndarray], jnp.ndarray]:
     """Multi-head latent attention over the paged pool: writes the new
     rows at `positions` of `layer` (one row a token for all heads; keys and
@@ -346,7 +353,21 @@ def latent_attention(
     rows stored as declared, a mesh, any other platform): the rows of
     every table position are gathered (KV_GATHER) and the form runs in
     plain XLA over them, the chunk's context expanded whole; that is also
-    what the kernels are tested against."""
+    what the kernels are tested against.
+
+    With `index` (ops/sparse_index.py: the token's index queries, its
+    index key, a weight a head and the set's size k) the new keys are
+    written to `v` beside the rows, and every query attends its own set
+    S_t alone: the min(k, t + 1) positions of largest index score, ties
+    toward the lower. A decode step scores the row's live keys (ATTN_INDEX:
+    where the kernels run, in place through the block table), takes the
+    set by one stable sort of the scores with the pool's rows carried
+    along (ATTN_SELECT), and reads those rows and no others, by position
+    (ATTN_CORE: a gather of k rows a slot, then the absorbed form over
+    them in XLA). A chunk scores its queries against the gathered keys of
+    the table (ATTN_INDEX), takes each query's set as a mask
+    (ATTN_SELECT, `sparse_index.select`) and runs the expanded form under
+    it: every pair is still computed."""
     from substratus_tpu.ops.quant import QTensor, materialize, qeinsum
 
     k_pool = pool["k"]
@@ -355,22 +376,31 @@ def latent_attention(
     rkv = w_uk.shape[2]
     dr = row_new.shape[-1] - rkv
     dn = dq - dr
+    qi, ki, wi, topk = index if index is not None else (None,) * 4
     # the row as the pool stores it: zeros behind [ckv; kr]
     stored = jnp.pad(row_new, ((0, 0), (0, 0), (0, w - rkv - dr)))
-    out = {**pool, **_write(pool, layer, block_table, positions, stored)}
+    out = {**pool, **_write(
+        pool, layer, block_table, positions, stored,
+        None if index is None else ki[:, :, None, :])}
     k_pool = out["k"]
-    kernel = _latent_kernels_for(k_pool, rkv, dn)
+    kernel = _latent_kernels_for(
+        k_pool, rkv, dn, None if index is None else out["v"].shape[4])
+    pages, bs = k_pool.shape[1:3]
+    first = layer.astype(block_table.dtype) * pages  # the layer's page 0
+
+    def gathered(a):
+        """The rows of every table position out of pool `a`, [B, M * bs,
+        the row]."""
+        n, m = block_table.shape
+        starts = ((first + block_table) * bs).reshape(n * m)
+        ctx = jax.vmap(
+            lambda at: jax.lax.dynamic_slice_in_dim(_rows(a), at, bs)
+        )(starts)
+        return ctx.reshape(n, m * bs, a.shape[4])
 
     def gathered_rows():
         with jax.named_scope(scopes.KV_GATHER):
-            pages, bs = k_pool.shape[1:3]
-            n, m = block_table.shape
-            first = layer.astype(block_table.dtype) * pages
-            starts = ((first + block_table) * bs).reshape(n * m)
-            ctx = jax.vmap(
-                lambda at: jax.lax.dynamic_slice_in_dim(_rows(k_pool), at, bs)
-            )(starts)
-            return ctx.reshape(n, m * bs, w).astype(jnp.float32)
+            return gathered(k_pool).astype(jnp.float32)
 
     def seen(t):  # [B, S, T]: key j of the gathered context visible to query
         return jnp.arange(t, dtype=jnp.int32)[None, None, :] <= positions[
@@ -406,9 +436,58 @@ def latent_attention(
                     qa[:, 0], k_pool, layer, block_table, positions[:, 0],
                     rkv=rkv, scale=scale)[:, None]
 
-        ol = (absorbed_in_xla() if kernel is None else
-              jax.lax.platform_dependent(
-                  tpu=absorbed_in_place, default=absorbed_in_xla))
+        def absorbed_over_the_set():
+            key_pool = out["v"]
+
+            def scores_in_xla():
+                sc = sparse_index.scores(qi, gathered(key_pool), wi)[:, 0]
+                return jnp.where(seen(sc.shape[1])[:, 0], sc, -jnp.inf)
+
+            def scores_in_place():
+                return index_decode_scores(
+                    qi[:, 0], wi[:, 0], key_pool, layer, block_table,
+                    positions[:, 0])
+
+            with jax.named_scope(scopes.ATTN_INDEX):
+                sc = (scores_in_xla() if kernel is None else
+                      jax.lax.platform_dependent(
+                          tpu=scores_in_place, default=scores_in_xla))
+            with jax.named_scope(scopes.ATTN_SELECT):
+                # The best first, of equal scores the lower position first
+                # (a stable sort, which is what `lax.top_k` of so many
+                # compiles to); what is sorted along is not the position
+                # but the row of the layer it lies in, so that no lookup
+                # through the table follows (8,192 of them cost 0.09 ms a
+                # layer on the chip, PR 42). A row shorter than the set
+                # fills it up with -inf: those stand for no row and read
+                # the trash page's first.
+                rows_of = (block_table[:, :, None] * bs + jnp.arange(
+                    bs, dtype=block_table.dtype)).reshape(b, -1)
+                worst, row = jax.lax.sort(
+                    (-sc, rows_of), dimension=1, is_stable=True, num_keys=1)
+                keep = min(topk, sc.shape[1])
+                ok = worst[:, :keep] < jnp.inf
+                flat = jnp.where(ok, first * bs + row[:, :keep], 0)
+            with jax.named_scope(scopes.ATTN_CORE):
+                rows = _rows(k_pool).at[flat].get(
+                    mode="promise_in_bounds")[:, :, 0]  # [B, k, w]
+                rows = jnp.where(ok[..., None], rows, 0)
+                sel = jnp.einsum("bhw,btw->bht", qa[:, 0], rows,
+                                 preferred_element_type=jnp.float32) * scale
+                p = jax.nn.softmax(
+                    jnp.where(ok[:, None], sel, NEG_INF), axis=-1)
+                return jnp.einsum(
+                    "bht,btc->bhc", p.astype(rows.dtype), rows[..., :rkv],
+                    preferred_element_type=jnp.float32
+                ).astype(dtype)[:, None]
+
+        if index is not None:
+            ol = absorbed_over_the_set()
+        elif kernel is None:
+            ol = absorbed_in_xla()
+        else:
+            ol = jax.lax.platform_dependent(
+                tpu=absorbed_in_place, default=absorbed_in_xla)
         with jax.named_scope(scopes.ATTN_ABSORB):
             o = qeinsum("bshc,hvc->bshv", ol, w_uv, dtype)
         return out, o.astype(dtype)
@@ -418,6 +497,27 @@ def latent_attention(
         # take it
         dense = jnp.concatenate(
             [materialize(w_uk, dtype), materialize(w_uv, dtype)], axis=1)
+
+    attended = None  # [B, T, S]: key j in query i's set, keys-major
+    if index is not None:
+        def index_in_xla():
+            return sparse_index.scores(
+                qi, gathered(out["v"]), wi).transpose(0, 2, 1)
+
+        def index_in_kernel():
+            return index_chunk_scores(qi, wi, gathered(out["v"]))
+
+        with jax.named_scope(scopes.ATTN_INDEX):
+            scored = (index_in_xla() if kernel is None else
+                      jax.lax.platform_dependent(
+                          tpu=index_in_kernel, default=index_in_xla))
+        with jax.named_scope(scopes.ATTN_SELECT):
+            attended = sparse_index.select(
+                scored, seen(scored.shape[1]).transpose(0, 2, 1), topk,
+                axis=1)
+
+    def visible(t):  # [B, S, T]
+        return seen(t) if attended is None else attended.transpose(0, 2, 1)
 
     def expanded_in_xla():
         lat = gathered_rows()
@@ -429,15 +529,19 @@ def latent_attention(
             sc = (jnp.einsum("bshn,bthn->bsht", qf[..., :dn], kv[..., :dn])
                   + jnp.einsum("bshr,btr->bsht", qf[..., dn:],
                                lat[..., rkv:rkv + dr])) * scale
-            sc = jnp.where(seen(lat.shape[1])[:, :, None], sc, -1e30)
+            sc = jnp.where(visible(lat.shape[1])[:, :, None], sc, -1e30)
             p = jax.nn.softmax(sc, axis=-1)
             return jnp.einsum("bsht,bthv->bshv", p, kv[..., dn:]).astype(dtype)
 
     def expanded_in_place():
+        bias = None
+        if attended is not None:
+            with jax.named_scope(scopes.ATTN_SELECT):
+                bias = jnp.where(attended, 0.0, NEG_INF).astype(dtype)
         with jax.named_scope(scopes.ATTN_CORE):
             return latent_chunk_attention(
-                q, dense, k_pool, layer, block_table, positions, dn=dn,
-                scale=scale)
+                q, dense, k_pool, layer, block_table, positions, bias,
+                dn=dn, scale=scale)
 
     if kernel is None:
         return out, expanded_in_xla()
@@ -445,17 +549,20 @@ def latent_attention(
         tpu=expanded_in_place, default=expanded_in_xla)
 
 
-def _latent_kernels_for(k_pool, rkv: int, dn: int) -> Optional[bool]:
+def _latent_kernels_for(k_pool, rkv: int, dn: int,
+                        di: Optional[int] = None) -> Optional[bool]:
     """Whether ops/latent_attention.py's kernels read this latent pool in
     place: a bfloat16 pool whose stored row is a multiple of the 128 lanes
     (`init_latent_cache` makes it so) and whose latent ends on a lane tile
     (the rotary key then has the row's last tile to itself), on one
-    device. None where they are not written for the case (a float32 pool,
+    device (and ops/sparse_index.py's an index key of `di` values, where
+    the layer keeps one: whole lane tiles). None where they are not
+    written for the case (a float32 pool,
     a row stored as declared, a latent or a head that Mosaic does not tile,
     any mesh that shards something: one row serves every head, so no axis
     of the pool splits over heads)."""
     if (k_pool.dtype != jnp.bfloat16 or k_pool.shape[4] % LANES
-            or rkv % LANES or dn % 16):
+            or rkv % LANES or dn % 16 or (di or 0) % LANES):
         return None
     mesh = jax.typeof(k_pool).sharding.mesh
     if any(n > 1 for n in mesh.shape.values()):
@@ -464,7 +571,7 @@ def _latent_kernels_for(k_pool, rkv: int, dn: int) -> Optional[bool]:
 
 
 def init_latent_cache(n_layers: int, pages: int, page_size: int, row: int,
-                      dtype) -> Dict[str, jnp.ndarray]:
+                      dtype, index_row: int = 0) -> Dict[str, jnp.ndarray]:
     """The page pool of layers whose attention is latent: `k` [L, P, bs, 1,
     w], one row a token for every head, and `v` a pool of no layers (the
     values are the keys' leading part: the dict says that nothing else is
@@ -472,12 +579,17 @@ def init_latent_cache(n_layers: int, pages: int, page_size: int, row: int,
     keeps `row` values in w = the next multiple of 128 lanes, zeros behind
     them (Mosaic tiles no other width, and the device keeps such a page
     row-major as a matrix of `page_size` rows); any other pool as
-    declared. Readers take the logical row off their own operands."""
+    declared. Readers take the logical row off their own operands. With
+    `index_row` (a layer that picks its rows by a learned index,
+    ops/sparse_index.py) `v` is the tokens' index keys, [L, P, bs, 1,
+    index_row], stored as declared: the same pages under the same ids."""
     w = row
     if jnp.dtype(dtype) == jnp.bfloat16:
         w = -(-row // LANES) * LANES
+    keys = ((n_layers, pages, page_size, 1, index_row) if index_row
+            else (0, pages, page_size, 1, w))
     return {"k": jnp.zeros((n_layers, pages, page_size, 1, w), dtype),
-            "v": jnp.zeros((0, pages, page_size, 1, w), dtype)}
+            "v": jnp.zeros(keys, dtype)}
 
 
 def latent_cache_logical_axes() -> Dict[str, tuple]:

@@ -37,7 +37,11 @@ queries] = `K_i Q_nope_i^T + Lat[:, rkv:] Q_rope_i^T` with a query a lane
 (the softmax's maximum and sum run down sublanes, as in
 ops/paged_attention.py's chunk kernel), `O_i^T += V_i^T P`. A grid step
 holds `HEADS_A_STEP` heads' weights and queries and walks the row's live
-pages once for them; no context is ever held expanded in HBM.
+pages once for them; no context is ever held expanded in HBM. Where every
+query attends a set of its own (a learned index, ops/sparse_index.py) the
+caller hands the sets over as a `bias` [keys, queries] in HBM, a block of
+which arrives beside each block of pages and is added to the scores: every
+pair is still computed.
 """
 from __future__ import annotations
 
@@ -238,11 +242,19 @@ def latent_decode_attention(
 
 
 def _chunk_kernel(layer_ref, last_ref, first_ref, bt_ref, qpos_ref, q_ref,
-                  w_ref, lat_hbm, o_ref, buf, sem, slot_ref, m_ref, l_ref,
-                  acc_ref, *, scale: float, dn: int):
+                  w_ref, lat_hbm, *rest, scale: float, dn: int,
+                  biased: bool = False):
     """One grid step: `heads` heads of row b, query columns i, against the
     row's pages 0 .. last[b] // page_size, each arrived block expanded
-    through the heads' W_UKV where it lies."""
+    through the heads' W_UKV where it lies. `biased`: a [keys, queries]
+    array in HBM is added to the scores, a block of it brought beside each
+    block of pages (0 where the query attends the key, NEG_INF where not:
+    each query's own set, ops/sparse_index.py::select)."""
+    if biased:
+        (bias_hbm, o_ref, buf, sem, slot_ref, m_ref, l_ref, acc_ref,
+         bias_buf, bias_sem) = rest
+    else:
+        o_ref, buf, sem, slot_ref, m_ref, l_ref, acc_ref = rest
     b, g, i = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     n_rows, n_groups, n_tiles = (pl.num_programs(0), pl.num_programs(1),
                                  pl.num_programs(2))
@@ -257,6 +269,11 @@ def _chunk_kernel(layer_ref, last_ref, first_ref, bt_ref, qpos_ref, q_ref,
         return _div(last_ref[row], bs) + 1
 
     each_copy = _block_copies(layer, bt_ref, (lat_hbm,), (buf,), sem, pages_of)
+
+    def bias_copy(j, slot):
+        return pltpu.make_async_copy(
+            bias_hbm.at[b, pl.ds(j * keys, keys), pl.ds(i * width, width)],
+            bias_buf.at[slot], bias_sem.at[slot])
 
     def fold_block(j, slot, masked: bool):
         first_key = j * keys
@@ -281,6 +298,8 @@ def _chunk_kernel(layer_ref, last_ref, first_ref, bt_ref, qpos_ref, q_ref,
                     k_pos = first_key + lax.broadcasted_iota(
                         jnp.int32, s.shape, 0)
                     s = jnp.where(k_pos <= qpos_ref[0, :, cols], s, NEG_INF)
+                if biased:
+                    s = s + bias_buf[slot, :, cols].astype(jnp.float32)
                 # what the scratch holds before a row's first block is
                 # nobody's: selected away, never initialised
                 m_prev = jnp.where(j == 0, NEG_INF, m_ref[h, :, cols])
@@ -288,6 +307,10 @@ def _chunk_kernel(layer_ref, last_ref, first_ref, bt_ref, qpos_ref, q_ref,
                 acc = jnp.where(j == 0, 0.0, acc_ref[h, :, cols])
                 m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
                 p = jnp.exp(s - m_new)  # [keys, fold], 0 where masked
+                if biased:
+                    # a block in which a query attends nothing leaves its
+                    # maximum at NEG_INF: its keys weigh 0, not exp(0)
+                    p = jnp.where(s > 0.5 * NEG_INF, p, 0.0)
                 alpha = jnp.exp(m_prev - m_new)
                 m_ref[h, :, cols] = m_new
                 l_ref[h, :, cols] = alpha * l_prev + jnp.sum(
@@ -305,6 +328,8 @@ def _chunk_kernel(layer_ref, last_ref, first_ref, bt_ref, qpos_ref, q_ref,
     last, n_pages = last_ref[b], pages_of(b)
     n_blocks = pl.cdiv(n_pages, ppb)
     row_ends = (g + 1 == n_groups) & (i + 1 == n_tiles)
+    if biased:  # this step's first block of the bias: no step before knew it
+        bias_copy(0, slot_ref[0]).start()
 
     def block(j, slot):
         more = j + 1 < n_blocks
@@ -312,6 +337,8 @@ def _chunk_kernel(layer_ref, last_ref, first_ref, bt_ref, qpos_ref, q_ref,
         @pl.when(more)
         def _():
             each_copy(b, j + 1, 1 - slot, lambda c: c.start())
+            if biased:
+                bias_copy(j + 1, 1 - slot).start()
 
         # the next grid step's first block: this row again, or the next
         @pl.when(jnp.logical_not(more) & jnp.logical_not(row_ends))
@@ -323,6 +350,8 @@ def _chunk_kernel(layer_ref, last_ref, first_ref, bt_ref, qpos_ref, q_ref,
             each_copy(b + 1, 0, 1 - slot, lambda c: c.start())
 
         each_copy(b, j, slot, lambda c: c.wait())
+        if biased:
+            bias_copy(j, slot).wait()
         first_key = j * keys
 
         @pl.when(first_key + keys > last + 1)
@@ -361,6 +390,8 @@ def latent_chunk_attention(
     layer: jnp.ndarray,  # scalar int32: the layer of the stack to read
     block_table: jnp.ndarray,  # [B, M] int32 page ids
     positions: jnp.ndarray,  # [B, S]: query i sees positions 0..positions[b, i]
+    bias: jnp.ndarray = None,  # [B, T, S], T = the table's reach: added to
+    # the scores keys-major (0: the query attends the key, NEG_INF: not)
     *,
     dn: int,  # of a query's values, those that meet k_nope
     scale: float,  # of the scores
@@ -372,7 +403,9 @@ def latent_chunk_attention(
     latent j through `w_ukv`, made a block of pages at a time inside the
     kernel; [B, S, H, dv] in q.dtype. Row b reads max(positions[b]) // bs
     + 1 pages, whatever the table holds. The positions are read, not
-    assumed, as by ops/paged_attention.py::paged_chunk_attention."""
+    assumed, as by ops/paged_attention.py::paged_chunk_attention. With
+    `bias` a query attends only the keys its column of the bias leaves
+    (every query at least one), beside what its position lets it see."""
     b, s, h, dq = q.shape
     lat = _as_pages(lat_pool)
     bs, w = lat.shape[2:]
@@ -396,6 +429,18 @@ def latent_chunk_attention(
     keys = CHUNK_PAGES * bs
     rows = dn + w - rkv
     hbm = pl.BlockSpec(memory_space=pl.ANY)
+    operands, scratch = (), ()
+    if bias is not None:
+        # whole blocks of keys, the queries' padded columns the last one's
+        t = block_table.shape[1] * bs
+        assert bias.shape == (b, t, s), (bias.shape, (b, t, s))
+        bias = jnp.pad(bias.astype(q.dtype),
+                       ((0, 0), (0, _round_up(t, keys) - t), (0, 0)),
+                       constant_values=NEG_INF)
+        bias = jnp.pad(bias, ((0, 0), (0, 0), (0, padded - s)), mode="edge")
+        operands = (bias,)
+        scratch = (pltpu.VMEM((2, keys, width), bias.dtype),
+                   pltpu.SemaphoreType.DMA((2,)))
     vmem = (
         2 * heads * (rows + dv) * width * q.dtype.itemsize  # q and out, x 2
         + 2 * heads * (dn + dv) * rkv * w_ukv.dtype.itemsize  # the weights
@@ -403,9 +448,11 @@ def latent_chunk_attention(
         + 2 * keys * w * 2  # the DMA blocks
         + 2 * keys * (dn + dv) * 4  # a head's keys and values
         + 3 * keys * FOLD_QUERIES * 4  # a fold's scores, as values
+        + (2 * keys * width * 2 if operands else 0)  # the bias's blocks
     )
     out = pl.pallas_call(
-        functools.partial(_chunk_kernel, scale=scale, dn=dn),
+        functools.partial(_chunk_kernel, scale=scale, dn=dn,
+                          biased=bool(operands)),
         out_shape=jax.ShapeDtypeStruct((b, h, dv, padded), q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
@@ -417,7 +464,7 @@ def latent_chunk_attention(
                 pl.BlockSpec((heads, dn + dv, rkv),
                              lambda b, g, i, *_: (g, 0, 0)),
                 hbm,
-            ],
+            ] + [hbm] * len(operands),
             out_specs=pl.BlockSpec((1, heads, dv, width),
                                    lambda b, g, i, *_: (b, g, 0, i)),
             scratch_shapes=[
@@ -427,6 +474,7 @@ def latent_chunk_attention(
                 pltpu.VMEM((heads, 1, width), jnp.float32),
                 pltpu.VMEM((heads, 1, width), jnp.float32),
                 pltpu.VMEM((heads, dv, width), jnp.float32),
+                *scratch,
             ],
         ),
         compiler_params=pltpu.CompilerParams(
@@ -438,6 +486,6 @@ def latent_chunk_attention(
     )(
         layer.astype(jnp.int32).reshape(1), positions.max(axis=1),
         positions.min(axis=1), block_table.astype(jnp.int32), qpos, qt,
-        w_ukv.astype(q.dtype), lat,
+        w_ukv.astype(q.dtype), lat, *operands,
     )
     return out[..., :s].transpose(0, 3, 1, 2)  # [B, S, H, dv]

@@ -24,6 +24,9 @@ keeps one shared row a token in the pages: a decode step opens
 ATTN_ABSORB around ATTN_CORE (the queries carried into the latent's space
 and the read-out carried back), a chunk ATTN_EXPAND ahead of it (what of
 the latents' expansion into keys and values stays outside the kernel).
+Where such a layer picks the rows a query attends by a learned index
+(ops/sparse_index.py) it opens ATTN_INDEX and ATTN_SELECT between ATTN_QKV
+and ATTN_CORE, and ATTN_CORE then holds the read of the picked rows.
 """
 
 EMBED = "embed"              # token embedding gather
@@ -59,6 +62,11 @@ ATTN_ABSORB = "attn.absorb"  # latent attention, a decode step: q_nope
 # through W_UV after it
 ATTN_EXPAND = "attn.expand"  # latent attention, a chunk: what of the
 # latents' way through W_UKV to keys and values runs outside the kernel
+ATTN_INDEX = "attn.index"    # a learned index over the kept rows: its
+# projections, norm and rotation, the scores of a query against the live
+# index keys (the write of the token's own key is KV_WRITE's)
+ATTN_SELECT = "attn.select"  # the set a query attends: the top-k or the
+# threshold, and positions turned into rows of the pool (or into a mask)
 LM_HEAD = "lm_head"          # final norm and logits
 SAMPLE = "sample"            # RNG split and ops/sampling.py::sample
 
@@ -69,9 +77,11 @@ ALL = (
 # What one family's block adds to the thirteen every block opens (the
 # benchmark lists them in that family's file, benchmarks/families/):
 # EXTRA models/exaone_moe.py's, CONV models/lfm2_moe.py's, RET
-# models/brumby.py's, LATENT models/deepseek_v3.py's (beside MOE_SHARED).
+# models/brumby.py's, LATENT models/deepseek_v3.py's (beside MOE_SHARED),
+# INDEXED what that block adds where its configuration has an indexer.
 EXTRA = (MOE_SHARED, KV_RING, ATTN_WINDOW)
 CONV = (CONV_IN, CONV_STATE, CONV_OUT)
 RET = (RET_STATE, RET_INTRA)
 LATENT = (ATTN_ABSORB, ATTN_EXPAND)
-EVERY = ALL + EXTRA + CONV + RET + LATENT
+INDEXED = (ATTN_INDEX, ATTN_SELECT)
+EVERY = ALL + EXTRA + CONV + RET + LATENT + INDEXED

@@ -129,8 +129,9 @@ METRICS.describe(
     "Bytes one token keeps in the page pool over all layers, as stored "
     "(k, v and their scales): 131,072 for Mistral-7B's 32 layers of 8 "
     "bfloat16 K and V heads of 128, 20,480 for 16 layers of one latent "
-    "row stored 640 wide. Set once where the pool is made; 0 for a family "
-    "that keeps no page.",
+    "row stored 640 wide, 19,968 for 13 layers of such a row and an index "
+    "key of 128 beside it. Set once where the pool is made; 0 for a "
+    "family that keeps no page.",
     type="gauge",
 )
 METRICS.describe(
@@ -803,6 +804,24 @@ class Engine:
                 # max_batch, the rows of state a step over every slot moves
                 "state_rows_live_sum": 0,
                 "state_rows_sum": 0,
+            })
+        # A family whose layers pick the rows a query attends by a learned
+        # index (ops/sparse_index.py) says how many a set holds; 0: every
+        # live row is attended and the counters below do not exist.
+        self._index_topk = (
+            getattr(model, "index_topk", lambda cfg: 0)(cfg)
+            if self.paged else 0
+        )
+        if self._index_topk:
+            self.stats.update({
+                # per decode step (_count_step), over the decoding slots
+                # and once, not per layer: the rows a slot's sequence has
+                # kept (position + 1) and those its query attends (at most
+                # index_topk of them); and the selections the step ran, one
+                # a decoding slot and layer
+                "dsa_rows_live_sum": 0,
+                "dsa_rows_attended_sum": 0,
+                "dsa_selections": 0,
             })
         self._state_kernel = False
         if self.slot_state and kvcache.RET_S in self.cache:
@@ -2446,8 +2465,14 @@ class Engine:
         self.stats["decode_steps_sampled"] += bool((self.temps > 0).any())
         if self.paged:
             # positions and active are host numpy mirrors too
-            ctx = (self.positions[self.active] + 1).sum()
+            live = self.positions[self.active] + 1
+            ctx = live.sum()
             self.stats["decode_ctx_tokens_sum"] += int(ctx)
+            if self._index_topk:
+                attended = np.minimum(live, self._index_topk).sum()
+                self.stats["dsa_rows_live_sum"] += int(ctx)
+                self.stats["dsa_rows_attended_sum"] += int(attended)
+                self.stats["dsa_selections"] += live.size * self._page_layers
 
     def _dispatch(self) -> Optional[_InFlightStep]:
         """Device-only half of one decode step: grow paged capacity from

@@ -22,6 +22,12 @@ import pytest  # noqa: E402
 from substratus_tpu.ops.kvcache import insert_prefill
 
 
+def pytest_configure(config):
+    # tier-1 runs `-m 'not slow'` (ROADMAP.md "Tier-1 verify")
+    config.addinivalue_line(
+        "markers", "slow: a sweep tier-1 does not count on")
+
+
 def greedy_decode(module, params, cfg, prompt, max_tokens, cache_len=256):
     """Shared greedy-decode oracle: prefill, seed the cache, step. The one
     reference implementation of the cache-seeding contract for tests.

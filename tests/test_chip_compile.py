@@ -802,6 +802,87 @@ def test_deepseek_v3_programs_compile_with_both_latent_kernels(v5e):
             assert "moe.experts/while" in hlo
 
 
+# The repoqa cell's engine (benchmarks/traffic/repoqa.json): GLM-5's first
+# 13 layers (3 dense + 10 sparse), 8 of 256 experts held, an eighth of the
+# vocabulary, a learned index in every layer.
+_G_B, _G_S, _G_POOL_PAGES = 4, 18432, 4608
+
+
+@pytest.mark.slow  # a minute; the two kernel cases above stay in tier-1
+def test_glm_dsa_programs_compile_with_the_index_kernels(v5e):
+    """The same family under a learned index (GLM-5's widths: 64 heads of
+    192 + 64 against 256, 32 index heads over keys of 128, the 2,048 best
+    rows a query): the pool's second array holds the index keys under the
+    same page ids; the decode program scores them in place
+    (`index_decode_scores`), sorts once a layer and gathers the picked rows
+    by position, and holds no kernel that walks a row's pages of latents;
+    the 512 chunk scores by `index_chunk_scores` and runs the expanded
+    kernel under the sets; neither moves either array of the pool."""
+    from substratus_tpu.models import deepseek_v3
+    from substratus_tpu.ops.quant import quantize_params
+    from substratus_tpu.serve.engine import Engine, EngineConfig
+
+    cfg = deepseek_v3.CONFIGS["glm-5"].replace(
+        n_layers=13, vocab_size=19360, held_experts=(0, 8))
+    assert deepseek_v3.layer_plan(cfg) == (3, 1, 10)
+    eng = Engine(cfg, None, EngineConfig(
+        max_batch=_G_B, max_seq_len=_G_S, max_prefill_len=_CHUNK,
+        page_size=_PAGE, kv_pool_tokens=1,
+    ))
+    assert not eng.slot_state and eng.prefix is not None
+    assert "dsa_selections" in eng.stats
+    placed, arr = _described(v5e, eng)
+    params = placed(jax.eval_shape(
+        lambda key: quantize_params(
+            deepseek_v3.init_params(cfg, key),
+            deepseek_v3.quant_contracting(cfg)),
+        jax.random.key(0)), deepseek_v3.param_logical_axes(cfg))
+    cache = placed(jax.eval_shape(
+        lambda: deepseek_v3.init_paged_cache(cfg, _G_POOL_PAGES + 1, _PAGE)),
+        deepseek_v3.paged_cache_logical_axes(cfg))
+    assert cache["k"].shape == (13, _G_POOL_PAGES + 1, _PAGE, 1, 640)
+    assert cache["v"].shape == (13, _G_POOL_PAGES + 1, _PAGE, 1, 128)
+    m = _G_S // _PAGE
+    programs = {
+        "decode": eng._decode_fn.lower(
+            params, cache, arr((_G_B, m)), arr((_G_B,)), arr((_G_B,)),
+            arr((_G_B,), jnp.float32), arr((_G_B,), jnp.float32),
+            arr(eng.key.shape, eng.key.dtype), None, None,
+            arr((_G_B,), jnp.bool_),
+        ),
+        "chunk": Engine._chunk_prefill_jit.lower(
+            deepseek_v3, cfg, params, cache, arr((1, _CHUNK)), arr(()),
+            arr(()), arr((1, m)), None, None, arr(()),
+        ),
+    }
+    kernels = {"decode": ("index_decode_scores",),
+               "chunk": ("index_chunk_scores", "latent_chunk_attention")}
+    pool = set()
+    for a in (cache["k"], cache["v"]):
+        pool |= {a.size, a.size // 13}  # whole, or a layer
+    for name, lowered in programs.items():
+        compiled = lowered.compile()
+        hlo = compiled.as_text()
+        for kernel in kernels[name]:
+            assert re.search(
+                r'custom_call_target="tpu_custom_call".*' + kernel, hlo
+            ), (name, kernel)
+        assert "latent_decode_attention" not in hlo, name
+        assert "attn.index" in hlo and "attn.select" in hlo, name
+        assert ("kv.gather" in hlo) == False, name  # noqa: E712
+        bf16 = "\n".join(l for l in hlo.splitlines() if "= bf16[" in l)
+        assert _pool_moving_ops(bf16, pool) == [], name
+        temp = compiled.memory_analysis().temp_size_in_bytes
+        assert temp < 1.6e8, (name, temp)
+        if name == "decode":
+            # one selection a layer: the scanned body's and the three
+            # leading layers' (the 2,048 best of 18,432 by one stable sort
+            # of the scores, the rows of the pool carried along)
+            assert len(re.findall(
+                r"= \(f32\[4,18432\]\S*, s32\[4,18432\]\S*, [^=]*\) sort\(",
+                hlo)) == 4, name
+
+
 @pytest.mark.parametrize("program", ["decode", "chunk"])
 def test_tinyllama_paged_programs_compile_for_v5e(program, v5e):
     """What `serve.main --config tinyllama-1.1b` compiles on a TPU at its

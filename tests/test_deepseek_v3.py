@@ -17,6 +17,7 @@ import numpy as np
 import pytest
 
 from benchmarks.reference import deepseek_v3 as R
+from benchmarks.reference import glm_moe_dsa as R_DSA
 from substratus_tpu.models import deepseek_v3 as M
 from substratus_tpu.models import hybrid
 from substratus_tpu.models import registry
@@ -27,6 +28,12 @@ from substratus_tpu.ops.quant import QTensor, quantize_params
 from substratus_tpu.serve.engine import Engine, EngineConfig, Request
 
 CFG = M.CONFIGS["tiny-deepseek-v3"].replace(dtype=jnp.float32)
+# The same block under a learned index (GLM-5's mechanism: the 8 best rows
+# of a context; contexts here are several times that): what carries pages
+# from one sequence to another has to carry the index keys with them.
+DSA = M.CONFIGS["tiny-glm-dsa"].replace(dtype=jnp.float32)
+BOTH = pytest.mark.parametrize("cfg", [CFG, DSA],
+                               ids=["deepseek_v3", "glm_moe_dsa"])
 CHUNK, PAGE = 16, 4
 # float32 activations, exact int8 weights: the program and the reference
 # differ by summation order alone, and by the absorbed form's other order
@@ -63,8 +70,19 @@ def cfg_dict(cfg: M.DeepseekV3Config, **over):
         routed_scaling_factor=cfg.routed_scaling_factor,
         norm_topk_prob=cfg.norm_topk_prob,
     )
+    if cfg.index_n_heads:  # as `glm_moe_dsa` spells it
+        del d["rope_scaling"], d["rope_theta"]
+        d.update(rope_parameters={"rope_theta": cfg.rope_theta,
+                                  "rope_type": "default"},
+                 index_n_heads=cfg.index_n_heads,
+                 index_head_dim=cfg.index_head_dim,
+                 index_topk=cfg.index_topk)
     d.update(over)
     return d
+
+
+def reference_of(cfg):
+    return R_DSA if cfg.index_n_heads else R
 
 
 def plain(tree):
@@ -76,10 +94,21 @@ def plain(tree):
     return tree
 
 
+_PARAMS = {}
+
+
+def params_of(cfg):
+    """The family's tree from key 0, int8 where the benchmark has int8."""
+    if cfg not in _PARAMS:  # one program: leaf by leaf eagerly takes 20 s
+        _PARAMS[cfg] = jax.jit(lambda key: quantize_params(
+            M.init_params(cfg, key), M.quant_contracting(cfg)))(
+                jax.random.key(0))
+    return _PARAMS[cfg]
+
+
 @pytest.fixture(scope="module")
 def params():
-    p = M.init_params(CFG, jax.random.key(0))
-    return quantize_params(p, M.quant_contracting(CFG))
+    return params_of(CFG)
 
 
 @pytest.fixture(scope="module")
@@ -98,6 +127,11 @@ def table(slots, max_pages=24):
             .reshape(slots, max_pages))
 
 
+# The model's own forward, compiled once a shape (eagerly every call would
+# trace and compile its layer scan again).
+_forward = jax.jit(M.forward, static_argnums=(2,))
+
+
 def prefill(params, cfg, cache, toks, slot, bt, chunk=CHUNK, start=0):
     """Chunks as serve/engine.py::_chunk_prefill_jit cuts them (right-padded
     to the chunk, padded positions clamped one past the prompt), through
@@ -109,7 +143,7 @@ def prefill(params, cfg, cache, toks, slot, bt, chunk=CHUNK, start=0):
         padded = np.zeros((1, chunk), np.int32)
         padded[0, :n] = part
         pos = np.minimum(off + np.arange(chunk), off + n)[None]
-        logits, cache = M.forward(
+        logits, cache = _forward(
             params, jnp.asarray(padded), cfg, positions=jnp.asarray(pos),
             cache=cache, block_table=jnp.asarray(bt[slot:slot + 1]),
             valid=jnp.arange(chunk)[None] < n)
@@ -126,7 +160,7 @@ def decode(params, cfg, cache, tok, pos, slot, bt):
     posv = np.zeros((b,), np.int32)
     posv[slot] = pos
     live = np.arange(b) == slot
-    logits, cache = M.forward(
+    logits, cache = _forward(
         params, jnp.asarray(toks)[:, None], cfg,
         positions=jnp.asarray(posv)[:, None], cache=cache,
         block_table=jnp.asarray(np.where(live[:, None], bt, 0)),
@@ -135,10 +169,10 @@ def decode(params, cfg, cache, tok, pos, slot, bt):
     return np.asarray(logits[slot, 0]), cache, stats
 
 
-def reference_logits(params, cfg, toks):
-    return np.asarray(R.logits_at(plain(params), cfg_dict(cfg), list(toks),
-                                  list(range(len(toks))), pad_to=8, block=16,
-                                  group=2))
+def reference_logits(params, cfg, toks, **kw):
+    return np.asarray(reference_of(cfg).logits_at(
+        plain(params), cfg_dict(cfg), list(toks), list(range(len(toks))),
+        pad_to=8, block=16, group=2, **kw))
 
 
 # -- (a) the whole sequence at once against the reference ------------------------
@@ -480,10 +514,10 @@ def test_without_yarn_the_table_is_todays_bit_for_bit():
 
 # -- (f) the engine ---------------------------------------------------------------
 
-def serve(params, prompts, max_tokens, **ec):
+def serve(params, prompts, max_tokens, cfg=CFG, **ec):
     ec = {"max_batch": 3, "max_seq_len": 96, "max_prefill_len": CHUNK,
           "page_size": PAGE, **ec}
-    eng = Engine(CFG, params, EngineConfig(**ec), model=M)
+    eng = Engine(cfg, params, EngineConfig(**ec), model=M)
     eng.start()
     reqs = [eng.submit(Request(prompt_tokens=[int(t) for t in p],
                                max_tokens=max_tokens, temperature=0.0,
@@ -541,15 +575,20 @@ def test_the_pool_says_what_a_token_keeps(params):
     assert METRICS.get("substratus_serve_slot_state_bytes") == 0
 
 
-def test_a_shared_prefix_is_served_from_its_pages(params, tokens):
+@BOTH
+def test_a_shared_prefix_is_served_from_its_pages(cfg, tokens):
     """Pages carry everything: a second request that shares 32 tokens with
     the first takes their pages from the registry, prefills the rest at an
     offset (the expanded form over pages it did not write), and serves the
-    tokens of an engine that reuses nothing."""
+    tokens of an engine that reuses nothing. Under an index the reused
+    pages bring their index keys: the second request's queries pick their
+    8 rows among 32 tokens it never scored a key for."""
+    params = params_of(cfg)
     prompts = [np.concatenate([tokens[:32], tokens[40:47]]),
                np.concatenate([tokens[:32], tokens[50:61]])]
-    cold = [serve(params, [p], 12, prefix_cache=False)[0][0] for p in prompts]
-    eng = Engine(CFG, params, EngineConfig(
+    cold = [serve(params, [p], 12, cfg, prefix_cache=False)[0][0]
+            for p in prompts]
+    eng = Engine(cfg, params, EngineConfig(
         max_batch=3, max_seq_len=96, max_prefill_len=CHUNK, page_size=PAGE),
         model=M)
     eng.start()
@@ -584,12 +623,15 @@ def test_a_resumed_sequence_gives_the_same_logits(params, tokens):
     assert np.abs(nxt - ref[49]).max() < TOL
 
 
-def test_the_engine_preempts_and_resumes_token_exact(params, tokens):
+@BOTH
+def test_the_engine_preempts_and_resumes_token_exact(cfg, tokens):
     """A pool too small for three sequences: the engine preempts, prefills
-    the victim again from 0, and serves the tokens of a roomy pool."""
+    the victim again from 0 (rows and index keys alike, into pages another
+    sequence has used), and serves the tokens of a roomy pool."""
+    params = params_of(cfg)
     prompts = [tokens[:30], tokens[10:38], tokens[20:45]]
-    roomy, _ = serve(params, prompts, 24, prefix_cache=False)
-    tight, eng = serve(params, prompts, 24, kv_pool_tokens=120,
+    roomy, _ = serve(params, prompts, 24, cfg, prefix_cache=False)
+    tight, eng = serve(params, prompts, 24, cfg, kv_pool_tokens=120,
                        prefix_cache=False)
     assert eng.stats["preemptions"] >= 1
     assert tight == roomy

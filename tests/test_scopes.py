@@ -46,10 +46,20 @@ CASES = {
                           DENSE_LLAMA | {scopes.KV_GATHER, scopes.MOE_ROUTER,
                                          scopes.MOE_EXPERTS,
                                          scopes.MOE_SHARED}),
+    # the same block under a learned index: its two regions in both
+    # programs; the step gathers no context (the keys are scored under
+    # `attn.index`, the picked rows read under `attn.core`), the chunk's
+    # XLA form still does
+    "glm-dsa-paged": ("tiny-glm-dsa", "paged",
+                      DENSE_LLAMA | {scopes.MOE_ROUTER, scopes.MOE_EXPERTS,
+                                     scopes.MOE_SHARED, *scopes.INDEXED}),
 }
 # What one of the two programs of a family opens and the other does not.
 LATENT_ONLY = {"deepseek-v3-paged": {"decode": {scopes.ATTN_ABSORB},
-                                     "chunk": {scopes.ATTN_EXPAND}}}
+                                     "chunk": {scopes.ATTN_EXPAND}},
+               "glm-dsa-paged": {"decode": {scopes.ATTN_ABSORB},
+                                 "chunk": {scopes.ATTN_EXPAND,
+                                           scopes.KV_GATHER}}}
 OP_RE = re.compile(
     r"^\s*(?:ROOT )?%?[\w.\-]+ = \S+ ([\w\-]+)\(.*?op_name=\"([^\"]*)\"", re.M)
 
@@ -147,9 +157,10 @@ def test_vocabulary_is_closed_and_matches_the_benchmarks_copy():
     assert len(set(scopes.ALL)) == len(scopes.ALL) == 13
     # what one family's block adds is listed in that family's file, and
     # the benchmark's readers charge an op to any of them
-    assert len(set(scopes.EVERY)) == len(scopes.EVERY) == 23
-    assert set(scopes.EXTRA + scopes.CONV + scopes.RET) <= (
-        trace_scopes.vocabulary())
+    assert len(set(scopes.EVERY)) == len(scopes.EVERY) == 25
+    assert scopes.INDEXED == ("attn.index", "attn.select")
+    assert set(scopes.EXTRA + scopes.CONV + scopes.RET + scopes.LATENT
+               + scopes.INDEXED) <= trace_scopes.vocabulary()
 
 
 def test_a_region_name_is_metadata_and_changes_no_arithmetic(monkeypatch):

@@ -99,6 +99,19 @@ SUPPORTS_LORA = False
 SUPPORTS_PAGED = True
 SUPPORTS_SPECULATION = False
 SUPPORTS_ROLES = False
+# Tokens a page of the latent pool holds where the engine is given no size
+# (serve/paged_kv.py::page_tokens; every other family keeps 16). A token
+# keeps 1,280 B a layer here and 256 B of index key, a twentieth of a
+# per-head page's, and a page's copy costs its issue and not its bytes.
+# Measured on the chip, each kernel alone, ms a layer at pages of 16 / 32 /
+# 64 / 128 tokens (PERF.md section 6, PR 43): `latent_decode_attention`
+# over 12 rows x 10.7k tokens 0.561 / 0.438 / 0.380 / 0.350 (about 30 ns a
+# copy over 0.32 ms of fold and bytes), `index_decode_scores` over 4 x
+# 17.2k 0.229 / 0.148 / 0.108 / 0.090. 128 reads 8 % and 17 % under 64,
+# and 256 could buy 4 % at most. The price: the prefix registry shares a
+# prompt in steps of a page, and a slot's last page is half empty on
+# average (1.3 MB of a 16-layer pool).
+PAGE_TOKENS = 128
 _STEP_STATS = "step_stats"
 
 

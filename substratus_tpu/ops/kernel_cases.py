@@ -537,10 +537,11 @@ def chip_cases() -> List[KernelCase]:
     # query heads over 8 of 128, a state of 8,256 x 128 a head.
     cases.append(retention_decode("brumby-longctx", 16, 40, 8, 128))
     # The docqa cell's latent attention (DeepSeek-V3's widths: 128 heads over
-    # one row of 512 + 64 a token, stored 640 wide): the decode step's
+    # one row of 512 + 64 a token, stored 640 wide, in the family's pages of
+    # 128 tokens, models/deepseek_v3.py::PAGE_TOKENS): the decode step's
     # absorbed kernel over 12 slots of 14k, the 512 and 256 chunks' expanded
     # one.
-    mla = dict(h=128, dn=128, dr=64, dv=128, rkv=512, pages=10241)
+    mla = dict(h=128, dn=128, dr=64, dv=128, rkv=512, pages=1281, page=128)
     cases.append(latent_attend("dots-docqa", 12, 1, 14336, **mla))
     cases.append(latent_attend("dots-docqa", 1, 512, 14336, **mla))
     cases.append(latent_attend("dots-docqa", 1, 256, 14336, **mla))
@@ -548,7 +549,7 @@ def chip_cases() -> List[KernelCase]:
     # 32 index heads over keys of 128, the 2,048 best rows a query): the
     # step scores 4 slots' keys in place and reads the picked rows, the 512
     # chunk scores its queries and runs under each one's own set.
-    dsa = dict(h=64, dn=192, dr=64, dv=256, rkv=512, pages=4609,
+    dsa = dict(h=64, dn=192, dr=64, dv=256, rkv=512, pages=577, page=128,
                index=(32, 128, 2048))
     cases.append(latent_attend("glm5-repoqa", 4, 1, 18432, **dsa))
     cases.append(latent_attend("glm5-repoqa", 1, 512, 18432, **dsa))

@@ -10,7 +10,10 @@ global page pool, stacked over layers,
 (a bfloat16 pool of heads narrower than a 128-lane row is stored `pack` =
 128 // head_dim neighbouring KV heads to a row, [.., kv_heads // pack, 128]:
 the same bytes in the same order; `init_paged_cache` decides, every reader
-takes `pack` off its operands) and a per-sequence block table [B, max_pages] of page ids. Shapes stay fully
+takes `pack` off its operands) and a per-sequence block table [B, max_pages] of page ids.
+page_size is the pool's own: the family's where the engine is given none
+(serve/paged_kv.py::page_tokens: 16, a latent pool's 128), and every reader
+here takes it off the pool's shape. Shapes stay fully
 static under jit (TPU requirement): dynamism lives in the *contents* of the
 block table. Memory is bounded by actual tokens in flight, not
 batch x max_seq_len, and identical prompt prefixes can share pages

@@ -10,10 +10,13 @@ pool
     lat  [layers, pages, page_size, 1, w]
 
 comes here as it lies, read as [layers, pages, page_size, w] (the same
-bytes: a page is a matrix of page_size rows, one 16-row tile high in
+bytes: a page is a matrix of page_size rows, whole 16-row tiles of
 bfloat16), and row b reads pages block_table[b, 0 .. (its last position)
 // page_size] of `layer` by its own DMAs, as ops/paged_attention.py's
-kernels do (their `_block_copies`): the work follows the live context.
+kernels do (their `_block_copies`): the work follows the live context. A
+copy costs its issue and not its bytes, so the family states a page of 128
+tokens (models/deepseek_v3.py::PAGE_TOKENS) and both kernels size their
+blocks in tokens: the pages of a block follow from the pool's.
 
 **One query token a row, absorbed** (`latent_decode_attention`). The
 caller has carried each head's q_nope through W_UK^T into the latent's
@@ -55,17 +58,17 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from substratus_tpu.ops.paged_attention import (
-    CHUNK_PAGES, FOLD_PAGES, FOLD_QUERIES, LANES, NEG_INF, _block_copies,
-    _div, _round_up,
+    FOLD_QUERIES, LANES, NEG_INF, _block_copies, _div, _round_up,
 )
 
-# Pages a decode step folds at once (static sizes: the smallest that holds
-# what a block brought). The last is the DMA block, 1,024 tokens of 16 a
-# page: twice ops/paged_attention.py's, because half of this kernel's time
-# is its page copies, a start a page, and a longer block has fewer ends
-# (0.658 -> 0.590 ms a layer over 129k rows; one wait a full block,
-# `_wait_block`, 0.607 -> 0.557; my chip runs, PR 40).
-DECODE_FOLD_PAGES = FOLD_PAGES + (64,)
+# Tokens a decode step folds at once (static sizes: the smallest that holds
+# what a block brought, and never less than one page). The last is the DMA
+# block: twice ops/paged_attention.py's 512, because a longer block has
+# fewer ends (0.658 -> 0.590 ms a layer over 129k rows; one wait a full
+# block, `_wait_block`, 0.607 -> 0.557; my chip runs, PR 40).
+DECODE_FOLD_TOKENS = (32, 128, 512, 1024)
+# Keys of one DMA block of the chunk kernel, as ops/paged_attention.py's.
+CHUNK_KEYS = 512
 
 # Heads whose weights, queries and running softmax one grid step of the
 # chunk kernel holds (the row's live latents cross HBM once a step), and
@@ -77,6 +80,12 @@ CHUNK_COLUMNS = 512
 def _as_pages(pool):
     """[L, P, bs, 1, w] -> [L, P, bs, w]: the same bytes."""
     return pool.reshape(pool.shape[:3] + pool.shape[4:])
+
+
+def decode_fold_pages(bs: int):
+    """`DECODE_FOLD_TOKENS` in pages of `bs` tokens, ascending; the last is
+    the pages of a DMA block."""
+    return tuple(sorted({max(t // bs, 1) for t in DECODE_FOLD_TOKENS}))
 
 
 def _wait_block(each_copy, buf, sem, b, j, slot, full):
@@ -96,7 +105,7 @@ def _wait_block(each_copy, buf, sem, b, j, slot, full):
 
 
 def _decode_kernel(layer_ref, pos_ref, bt_ref, q_ref, lat_hbm, o_ref, buf,
-                   sem, m_ref, l_ref, acc_ref, *, scale: float):
+                   sem, m_ref, l_ref, acc_ref, *, scale: float, folds):
     n_rows, n_heads, _ = q_ref.shape
     bs, w = lat_hbm.shape[2:]
     rkv = o_ref.shape[2]
@@ -165,7 +174,7 @@ def _decode_kernel(layer_ref, pos_ref, bt_ref, q_ref, lat_hbm, o_ref, buf,
             held = pages_of(b) - j * ppb  # may pass ppb
             _wait_block(each_copy, buf, sem, b, j, slot, held >= ppb)
             fewer = 0
-            for pages in DECODE_FOLD_PAGES:
+            for pages in folds:
                 fits = held > fewer
                 if pages < ppb:
                     fits &= held <= pages
@@ -205,11 +214,12 @@ def latent_decode_attention(
     bs = lat.shape[2]
     assert lat.shape[3] == w and w % LANES == 0 and rkv % LANES == 0, (
         lat.shape, w, rkv)
-    block = (2, DECODE_FOLD_PAGES[-1], bs, w)
+    folds = decode_fold_pages(bs)
+    block = (2, folds[-1], bs, w)
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
     hbm = pl.BlockSpec(memory_space=pl.ANY)
-    keys = DECODE_FOLD_PAGES[-1] * bs
+    keys = folds[-1] * bs
     need = (
         n_rows * n_heads * (w + rkv) * qa.dtype.itemsize  # q and out
         + 2 * keys * w * lat.dtype.itemsize  # the DMA blocks
@@ -217,7 +227,7 @@ def latent_decode_attention(
         + 4 * n_heads * keys * 4  # a fold's scores, as values
     )
     return pl.pallas_call(
-        functools.partial(_decode_kernel, scale=scale),
+        functools.partial(_decode_kernel, scale=scale, folds=folds),
         out_shape=jax.ShapeDtypeStruct((n_rows, n_heads, rkv), qa.dtype),
         in_specs=[smem, smem, smem, vmem, hbm],
         out_specs=vmem,
@@ -426,7 +436,8 @@ def latent_chunk_attention(
     # padded columns repeat the last query: their output is dropped
     qpos = jnp.pad(positions, ((0, 0), (0, padded - s)), mode="edge")
     qpos = qpos[:, None]  # [B, 1, padded]
-    keys = CHUNK_PAGES * bs
+    ppb = max(CHUNK_KEYS // bs, 1)
+    keys = ppb * bs
     rows = dn + w - rkv
     hbm = pl.BlockSpec(memory_space=pl.ANY)
     operands, scratch = (), ()
@@ -468,7 +479,7 @@ def latent_chunk_attention(
             out_specs=pl.BlockSpec((1, heads, dv, width),
                                    lambda b, g, i, *_: (b, g, 0, i)),
             scratch_shapes=[
-                pltpu.VMEM((2, CHUNK_PAGES, bs, w), lat.dtype),
+                pltpu.VMEM((2, ppb, bs, w), lat.dtype),
                 pltpu.SemaphoreType.DMA((1, 2)),
                 pltpu.SMEM((1,), jnp.int32),
                 pltpu.VMEM((heads, 1, width), jnp.float32),

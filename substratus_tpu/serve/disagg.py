@@ -131,9 +131,13 @@ class PoolSpec:
         """The spec an Engine(cfg, ec=ec) paged pool will have, computed
         BEFORE the engine exists — the HandoffManager is constructed
         first and handed into the Engine constructor."""
+        from substratus_tpu.models.registry import module_of
+        from substratus_tpu.serve.paged_kv import page_tokens
+
         quantized = ec.kv_cache_dtype == "int8"
         return cls(
-            n_layers=int(cfg.n_layers), page_size=int(ec.page_size),
+            n_layers=int(cfg.n_layers),
+            page_size=page_tokens(module_of(cfg), ec.page_size),
             kv_heads=int(cfg.n_kv_heads), head_dim=int(cfg.head_size),
             dtype="int8" if quantized else np.dtype(cfg.dtype).name,
             quantized=quantized,

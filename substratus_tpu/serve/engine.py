@@ -135,6 +135,16 @@ METRICS.describe(
     type="gauge",
 )
 METRICS.describe(
+    "substratus_serve_kv_page_tokens",
+    "Tokens one page of the pool holds, set where the pool is made: "
+    "EngineConfig.page_size where given, else the family's "
+    "(serve/paged_kv.py::page_tokens): 16, and 128 for a latent pool "
+    "(models/deepseek_v3.py::PAGE_TOKENS). A page is what a block-table "
+    "entry names, an attention kernel copies at once and the prefix "
+    "registry shares.",
+    type="gauge",
+)
+METRICS.describe(
     "substratus_serve_prefill_tokens_total",
     "Prompt tokens actually prefilled through the model (prefix-cache "
     "misses; the cold-work half of the reuse ratio).",
@@ -292,7 +302,10 @@ class EngineConfig:
     # preempt-and-resume under pressure), "dense" (one max_seq_len region
     # per slot), or "auto" (paged when the model family supports it).
     kv_layout: str = "auto"
-    page_size: int = 16  # tokens per KV page (paged layout)
+    # Tokens per KV page (paged layout). None = the family's: 16, or what
+    # its module states (`PAGE_TOKENS`: 128 for a latent pool, whose rows
+    # are a twentieth of a per-head page's); serve/paged_kv.py::page_tokens.
+    page_size: Optional[int] = None
     # Total pool size in tokens (paged). None = max_batch * max_seq_len
     # (the dense footprint); set lower to oversubscribe slots against real
     # usage — the scheduler preempts (and later resumes) the youngest slot
@@ -638,9 +651,10 @@ class Engine:
                     PageAllocator,
                     PrefixRegistry,
                     SlotPages,
+                    page_tokens,
                 )
 
-                bs = ec.page_size
+                bs = page_tokens(model, ec.page_size)
                 if bs < 1:
                     raise ValueError(f"page_size {bs} invalid")
                 if ec.kv_pool_tokens is not None and ec.kv_pool_tokens < 1:
@@ -676,6 +690,7 @@ class Engine:
                         if name in ("k", "v", "k_scale", "v_scale"))
                     // ((self.n_pages + 1) * bs),
                 )
+                METRICS.set("substratus_serve_kv_page_tokens", bs)
                 METRICS.set(
                     "substratus_serve_slot_state_bytes",
                     sum(a.nbytes for name, a in pool.items()

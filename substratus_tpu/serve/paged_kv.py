@@ -21,6 +21,19 @@ from __future__ import annotations
 from collections import OrderedDict
 from typing import Dict, List, Optional, Sequence, Tuple
 
+# Tokens a page holds where the family's module states none.
+PAGE_TOKENS = 16
+
+
+def page_tokens(model, given: Optional[int] = None) -> int:
+    """Tokens one page of `model`'s pool holds: the size given, else the
+    family's own (`PAGE_TOKENS` of its module: a pool whose rows are small
+    states a longer page, since a page's copy costs its issue and not its
+    bytes), else 16."""
+    if given is not None:
+        return given
+    return getattr(model, "PAGE_TOKENS", PAGE_TOKENS)
+
 
 class PageAllocator:
     """Free-list page allocator with reference counts (shared prefixes hold

@@ -776,6 +776,7 @@ def build_app(state: ServerState) -> web.Application:
                     "max_slots": eng.ec.max_batch,
                     "queue_depth": eng.queue.qsize(),
                     "kv_layout": "paged" if eng.paged else "dense",
+                    "kv_page_tokens": eng.page_size if eng.paged else None,
                     "stats": dict(eng.stats),
                 },
             }

@@ -223,6 +223,33 @@ def _sorts_only_where_a_row_samples(hlo: str) -> bool:
             and all("sample/cond/branch_1_fun/" in name for name in sorts))
 
 
+def _kernel_vmem(traced) -> dict:
+    """{kernel's name: (vmem_limit_bytes it asks for, its first scratch
+    buffer's shape: the DMA blocks, where it has one)} of every
+    `pallas_call` of a traced program, whichever scan or branch holds it:
+    what a kernel keeps in VMEM is decided where it is traced, from its
+    operands' shapes."""
+    found = {}
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                params = eqn.params["compiler_params"].get("mosaic_tpu")
+                scratch = eqn.params["grid_mapping"].scratch_avals
+                found[eqn.params["name"]] = (
+                    params.vmem_limit_bytes if params else None,
+                    scratch[0].shape if scratch else None)
+            for value in eqn.params.values():
+                for inner in (value if isinstance(value, (list, tuple))
+                              else [value]):
+                    inner = getattr(inner, "jaxpr", inner)
+                    if hasattr(inner, "eqns"):
+                        walk(inner)
+
+    walk(traced.jaxpr.jaxpr)
+    return found
+
+
 def _described(v5e, eng, **mesh_axes):
     """(placed, arr): abstract arguments for one described chip or, with
     mesh axes given, sharded over the four by the serve rules (`eng.mesh` is
@@ -718,8 +745,9 @@ def test_brumby_decode_compiles_under_a_tensor_mesh_with_the_kernel(v5e):
 
 # The docqa cell's engine (benchmarks/traffic/docqa.json): the language
 # model of dots.vlm1.inst, its first 16 layers (3 dense + 13 sparse), 8 of
-# 256 experts held, an eighth of the vocabulary.
-_D_B, _D_S, _D_POOL_PAGES = 12, 14336, 10240
+# 256 experts held, an eighth of the vocabulary; given no page, as the
+# benchmark's harness gives none: the family's.
+_D_B, _D_S, _D_POOL_TOKENS = 12, 14336, 163840
 
 
 def test_deepseek_v3_programs_compile_with_both_latent_kernels(v5e):
@@ -731,7 +759,9 @@ def test_deepseek_v3_programs_compile_with_both_latent_kernels(v5e):
     (at 14k tokens one layer's keys and values are 0.94 GB: no result is
     that large, and the program's temporaries stay under one layer's W_O
     beside 13 GB of weights and pool), and the chunk groups its tokens by
-    expert."""
+    expert. At the family's page of 128 tokens the kernels' blocks hold the
+    tokens they held at 16 (1,024 a decode block, 512 keys a chunk block)
+    and ask for the VMEM they asked for."""
     from substratus_tpu.models import deepseek_v3
     from substratus_tpu.ops.quant import quantize_params
     from substratus_tpu.serve.engine import Engine, EngineConfig
@@ -741,9 +771,12 @@ def test_deepseek_v3_programs_compile_with_both_latent_kernels(v5e):
     assert deepseek_v3.layer_plan(cfg) == (3, 1, 13)
     eng = Engine(cfg, None, EngineConfig(
         max_batch=_D_B, max_seq_len=_D_S, max_prefill_len=_CHUNK,
-        page_size=_PAGE, kv_pool_tokens=1,
+        kv_pool_tokens=1,
     ))
     assert not eng.slot_state and eng.prefix is not None
+    page = eng.page_size
+    assert page == deepseek_v3.PAGE_TOKENS == 128
+    pool_pages = _D_POOL_TOKENS // page
     placed, arr = _described(v5e, eng)
     params = placed(jax.eval_shape(
         lambda key: quantize_params(
@@ -751,30 +784,36 @@ def test_deepseek_v3_programs_compile_with_both_latent_kernels(v5e):
             deepseek_v3.quant_contracting(cfg)),
         jax.random.key(0)), deepseek_v3.param_logical_axes(cfg))
     cache = placed(jax.eval_shape(
-        lambda: deepseek_v3.init_paged_cache(cfg, _D_POOL_PAGES + 1, _PAGE)),
+        lambda: deepseek_v3.init_paged_cache(cfg, pool_pages + 1, page)),
         deepseek_v3.paged_cache_logical_axes(cfg))
     # one row of 576 a token and layer, stored 640 wide; no second pool
-    assert cache["k"].shape == (16, _D_POOL_PAGES + 1, _PAGE, 1, 640)
+    assert cache["k"].shape == (16, pool_pages + 1, page, 1, 640)
     assert cache["v"].shape[0] == 0
-    m = _D_S // _PAGE
+    m = _D_S // page
+    assert eng.block_table.shape == (_D_B, m)
     programs = {
-        "decode": eng._decode_fn.lower(
+        "decode": eng._decode_fn.trace(
             params, cache, arr((_D_B, m)), arr((_D_B,)), arr((_D_B,)),
             arr((_D_B,), jnp.float32), arr((_D_B,), jnp.float32),
             arr(eng.key.shape, eng.key.dtype), None, None,
             arr((_D_B,), jnp.bool_),
         ),
-        "chunk": Engine._chunk_prefill_jit.lower(
+        "chunk": Engine._chunk_prefill_jit.trace(
             deepseek_v3, cfg, params, cache, arr((1, _CHUNK)), arr(()),
             arr(()), arr((1, m)), None, None, arr(()),
         ),
     }
     kernels = {"decode": "latent_decode_attention",
                "chunk": "latent_chunk_attention"}
+    # two DMA blocks of 1,024 tokens (2.6 MB) and 2 MB of scores beside q
+    # and the output; two of 512 keys, 8 heads' weights and a fold's scores
+    vmem = {"decode": (17039360, (2, 1024 // page, page, 640)),
+            "chunk": (25165824, (2, 512 // page, page, 640))}
     pool = {cache["k"].size, cache["k"].size // 16}  # whole, or a layer
     expanded_layer = _D_S * cfg.n_heads * 256  # one layer's K and V, whole
-    for name, lowered in programs.items():
-        compiled = lowered.compile()
+    for name, traced in programs.items():
+        assert _kernel_vmem(traced) == {kernels[name]: vmem[name]}, name
+        compiled = traced.lower().compile()
         hlo = compiled.as_text()
         assert re.search(
             r'custom_call_target="tpu_custom_call".*' + kernels[name], hlo
@@ -805,7 +844,7 @@ def test_deepseek_v3_programs_compile_with_both_latent_kernels(v5e):
 # The repoqa cell's engine (benchmarks/traffic/repoqa.json): GLM-5's first
 # 13 layers (3 dense + 10 sparse), 8 of 256 experts held, an eighth of the
 # vocabulary, a learned index in every layer.
-_G_B, _G_S, _G_POOL_PAGES = 4, 18432, 4608
+_G_B, _G_S, _G_POOL_TOKENS = 4, 18432, 73728
 
 
 @pytest.mark.slow  # a minute; the two kernel cases above stay in tier-1
@@ -817,7 +856,9 @@ def test_glm_dsa_programs_compile_with_the_index_kernels(v5e):
     (`index_decode_scores`), sorts once a layer and gathers the picked rows
     by position, and holds no kernel that walks a row's pages of latents;
     the 512 chunk scores by `index_chunk_scores` and runs the expanded
-    kernel under the sets; neither moves either array of the pool."""
+    kernel under the sets; neither moves either array of the pool. The
+    engine is given no page and takes the family's 128 tokens: a block of
+    the keys' copies holds 1,024 tokens in 8 pages."""
     from substratus_tpu.models import deepseek_v3
     from substratus_tpu.ops.quant import quantize_params
     from substratus_tpu.serve.engine import Engine, EngineConfig
@@ -827,10 +868,13 @@ def test_glm_dsa_programs_compile_with_the_index_kernels(v5e):
     assert deepseek_v3.layer_plan(cfg) == (3, 1, 10)
     eng = Engine(cfg, None, EngineConfig(
         max_batch=_G_B, max_seq_len=_G_S, max_prefill_len=_CHUNK,
-        page_size=_PAGE, kv_pool_tokens=1,
+        kv_pool_tokens=1,
     ))
     assert not eng.slot_state and eng.prefix is not None
     assert "dsa_selections" in eng.stats
+    page = eng.page_size
+    assert page == deepseek_v3.PAGE_TOKENS == 128
+    pool_pages = _G_POOL_TOKENS // page
     placed, arr = _described(v5e, eng)
     params = placed(jax.eval_shape(
         lambda key: quantize_params(
@@ -838,19 +882,19 @@ def test_glm_dsa_programs_compile_with_the_index_kernels(v5e):
             deepseek_v3.quant_contracting(cfg)),
         jax.random.key(0)), deepseek_v3.param_logical_axes(cfg))
     cache = placed(jax.eval_shape(
-        lambda: deepseek_v3.init_paged_cache(cfg, _G_POOL_PAGES + 1, _PAGE)),
+        lambda: deepseek_v3.init_paged_cache(cfg, pool_pages + 1, page)),
         deepseek_v3.paged_cache_logical_axes(cfg))
-    assert cache["k"].shape == (13, _G_POOL_PAGES + 1, _PAGE, 1, 640)
-    assert cache["v"].shape == (13, _G_POOL_PAGES + 1, _PAGE, 1, 128)
-    m = _G_S // _PAGE
+    assert cache["k"].shape == (13, pool_pages + 1, page, 1, 640)
+    assert cache["v"].shape == (13, pool_pages + 1, page, 1, 128)
+    m = _G_S // page
     programs = {
-        "decode": eng._decode_fn.lower(
+        "decode": eng._decode_fn.trace(
             params, cache, arr((_G_B, m)), arr((_G_B,)), arr((_G_B,)),
             arr((_G_B,), jnp.float32), arr((_G_B,), jnp.float32),
             arr(eng.key.shape, eng.key.dtype), None, None,
             arr((_G_B,), jnp.bool_),
         ),
-        "chunk": Engine._chunk_prefill_jit.lower(
+        "chunk": Engine._chunk_prefill_jit.trace(
             deepseek_v3, cfg, params, cache, arr((1, _CHUNK)), arr(()),
             arr(()), arr((1, m)), None, None, arr(()),
         ),
@@ -860,8 +904,19 @@ def test_glm_dsa_programs_compile_with_the_index_kernels(v5e):
     pool = set()
     for a in (cache["k"], cache["v"]):
         pool |= {a.size, a.size // 13}  # whole, or a layer
-    for name, lowered in programs.items():
-        compiled = lowered.compile()
+    for name, traced in programs.items():
+        vmem = _kernel_vmem(traced)
+        assert set(vmem) == set(kernels[name]), name
+        if name == "decode":
+            # two blocks of 1,024 keys (0.5 MB) under the default limit
+            assert vmem["index_decode_scores"] == (
+                None, (2, 1024 // page, page, 128))
+        else:
+            # what it asked for at 16 tokens a page: two blocks of 512
+            # keys and of the bias, 8 heads' weights, a fold's scores
+            assert vmem["latent_chunk_attention"] == (
+                35389440, (2, 512 // page, page, 640))
+        compiled = traced.lower().compile()
         hlo = compiled.as_text()
         for kernel in kernels[name]:
             assert re.search(

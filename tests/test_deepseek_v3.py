@@ -35,6 +35,9 @@ DSA = M.CONFIGS["tiny-glm-dsa"].replace(dtype=jnp.float32)
 BOTH = pytest.mark.parametrize("cfg", [CFG, DSA],
                                ids=["deepseek_v3", "glm_moe_dsa"])
 CHUNK, PAGE = 16, 4
+# The engine's tests run at the page they pinned before the family stated
+# one, and at the family's own (None: what the engine reads off the module).
+PAGES = pytest.mark.parametrize("page", [PAGE, None], ids=["page4", "family"])
 # float32 activations, exact int8 weights: the program and the reference
 # differ by summation order alone, and by the absorbed form's other order
 # of the same products (measured 5e-6 on logits of magnitude 4; the limit
@@ -114,6 +117,13 @@ def params():
 @pytest.fixture(scope="module")
 def tokens():
     return np.asarray(jax.random.randint(jax.random.key(1), (80,), 0,
+                                         CFG.vocab_size))
+
+
+@pytest.fixture(scope="module")
+def long_tokens():
+    """Enough of them to fill a page of the family's own and go on."""
+    return np.asarray(jax.random.randint(jax.random.key(2), (160,), 0,
                                          CFG.vocab_size))
 
 
@@ -338,7 +348,7 @@ def test_the_decode_kernel_is_the_gathered_absorbed_form(monkeypatch, pages,
     that end with a block of 64 pages, one page into the next and in the
     third (every size the kernel folds at once, a full block before a
     short one): bfloat16's rounding of the read-out apart."""
-    assert LA.DECODE_FOLD_PAGES[-1] * 16 == 1024
+    assert LA.decode_fold_pages(16) == (2, 8, 32, 64)
     pool, bt, w_uk, w_uv, kq, kn = _kernel_case(jax.random.key(0),
                                                 pages=pages, m=m)
     q = jax.random.normal(kq, (3, 1, 8, 48)).astype(jnp.bfloat16)
@@ -362,6 +372,115 @@ def test_the_chunk_kernel_is_the_gathered_expanded_form(monkeypatch, s,
     real = slice(0, s - 5)  # the clamped tail's rows are nobody's
     assert np.abs(got[:, real] - want[:, real]).max() < 2e-2 * max(
         1.0, np.abs(want).max())
+
+
+def _paged(key, bs, tokens, width, layers=2):
+    """A seeded bfloat16 pool [layers, pages, bs, 1, width] and, for rows
+    that hold `tokens` tokens each, block tables of scattered pages (a row
+    of no token idles: the trash page in every entry)."""
+    m = max(-(-t // bs) for t in tokens) + 1
+    pages = 1 + sum(-(-t // bs) for t in tokens)
+    kp, kt = jax.random.split(key)
+    pool = jax.random.normal(kp, (layers, pages, bs, 1, width))
+    own = iter(np.asarray(jax.random.permutation(kt, np.arange(1, pages))))
+    bt = np.zeros((len(tokens), m), np.int32)
+    for b, t in enumerate(tokens):
+        for i in range(-(-t // bs)):
+            bt[b, i] = next(own)
+    return pool.astype(jnp.bfloat16), jnp.asarray(bt)
+
+
+def _rows_of(pool, layer, bt):
+    """[B, M * bs, width] float32: every row's context, gathered."""
+    b, m = bt.shape
+    got = np.asarray(pool, np.float32)[layer][np.asarray(bt)]
+    return got.reshape(b, m * pool.shape[2], pool.shape[4])
+
+
+@pytest.mark.parametrize("bs", [16, 64, 128])
+@pytest.mark.parametrize("kernel", ["decode", "chunk", "chunk-bias", "index"])
+def test_a_kernel_reads_pages_of_any_size_as_its_xla_form(kernel, bs):
+    """The three kernels that walk a row's pages, interpreted, against
+    plain float32 forms over the gathered rows, at the size every other
+    family keeps, at this family's 128 and between: contexts that end
+    inside a page, on a page's last token and on the next one's first, past
+    a DMA block, a row of one token and an idle row (position 0 over the
+    trash page)."""
+    from substratus_tpu.ops import sparse_index as SI
+
+    h, dn, dr, dv, rkv, w = 8, 32, 16, 32, 128, 256
+    key = jax.random.key(bs)
+    kq, kp, kw, kb = jax.random.split(key, 4)
+    layer = jnp.int32(1)
+
+    def close(got, want):
+        got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+        assert np.abs(got - want).max() < 2e-2 * max(1.0, np.abs(want).max())
+
+    if kernel in ("decode", "index"):
+        # the last token seen: mid-page, a page's last, the next page's
+        # first, one page into a second DMA block of 1,024 tokens, the only
+        # token of a row, and an idle row
+        last = [3 * bs + 5, 2 * bs - 1, 2 * bs, 1024 + bs + 3, 0, 0]
+        tokens = [p + 1 for p in last[:-1]] + [0]
+        pos = jnp.asarray(last, jnp.int32)
+        pool, bt = _paged(kp, bs, tokens, w if kernel == "decode" else 128)
+        seen = np.arange(bt.shape[1] * bs)[None] <= np.asarray(last)[:, None]
+    if kernel == "decode":
+        pool = pool.at[..., rkv + dr:].set(0)
+        qa = jax.random.normal(kq, (len(last), h, w)).astype(jnp.bfloat16)
+        got = LA.latent_decode_attention(
+            qa, pool, layer, bt, pos, rkv=rkv, scale=0.1, interpret=True)
+        lat = _rows_of(pool, 1, bt)
+        sc = np.einsum("bhw,btw->bht", np.asarray(qa, np.float32), lat) * 0.1
+        p = jax.nn.softmax(jnp.where(seen[:, None], sc, -1e30), axis=-1)
+        close(got, np.einsum("bht,btc->bhc", p, lat[..., :rkv]))
+    elif kernel == "index":
+        hi, di = 4, 128
+        qi = jax.random.normal(kq, (len(last), hi, di)).astype(jnp.bfloat16)
+        wi = jax.random.normal(kw, (len(last), hi))
+        got = SI.index_decode_scores(qi, wi, pool, layer, bt, pos,
+                                     interpret=True)
+        want = SI.scores(qi[:, None].astype(jnp.float32),
+                         jnp.asarray(_rows_of(pool, 1, bt)), wi[:, None])[:, 0]
+        assert got.shape == want.shape
+        assert (np.asarray(got)[~seen] == -np.inf).all()
+        close(np.where(seen, got, 0), np.where(seen, want, 0))
+    else:
+        # S queries a row: from position 0 inside one page, behind a
+        # context that ends on a page's edge, behind one past a block of
+        # 512 keys; the tail clamped onto one position as a padded chunk's
+        s, first = 24, [0, 3 * bs - 24, 512 + bs + 7]
+        pos = np.stack([np.arange(s) + f for f in first])
+        pos = np.minimum(pos, pos[:, :1] + s - 5).astype(np.int32)
+        pool, bt = _paged(kp, bs, [f + s for f in first], w)
+        pool = pool.at[..., rkv + dr:].set(0)
+        q = jax.random.normal(kq, (3, s, h, dn + dr)).astype(jnp.bfloat16)
+        w_ukv = (jax.random.normal(kw, (h, dn + dv, rkv)) * rkv ** -0.5
+                 ).astype(jnp.bfloat16)
+        t = bt.shape[1] * bs
+        seen = np.arange(t)[None, None] <= pos[:, :, None]  # [B, S, T]
+        bias = None
+        if kernel == "chunk-bias":
+            # every query keeps its own position and a random half
+            kept = np.asarray(jax.random.bernoulli(kb, 0.5, (3, s, t)))
+            kept = kept | (np.arange(t)[None, None] == pos[:, :, None])
+            seen = seen & kept
+            bias = jnp.where(kept, 0.0, -1e30).transpose(0, 2, 1)
+        got = LA.latent_chunk_attention(
+            q, w_ukv, pool, layer, bt, jnp.asarray(pos), bias, dn=dn,
+            scale=0.1, interpret=True)
+        lat = _rows_of(pool, 1, bt)
+        kv = np.einsum("btc,hmc->bthm", lat[..., :rkv],
+                       np.asarray(w_ukv, np.float32))
+        qf = np.asarray(q, np.float32)
+        sc = (np.einsum("bshn,bthn->bsht", qf[..., :dn], kv[..., :dn])
+              + np.einsum("bshr,btr->bsht", qf[..., dn:],
+                          lat[..., rkv:rkv + dr])) * 0.1
+        p = jax.nn.softmax(jnp.where(seen[:, :, None], sc, -1e30), axis=-1)
+        want = np.einsum("bsht,bthv->bshv", p, kv[..., dn:])
+        real = slice(0, s - 5)  # the clamped tail's rows are nobody's
+        close(np.asarray(got)[:, real], want[:, real])
 
 
 # -- (c) the shares add up -------------------------------------------------------
@@ -533,13 +652,16 @@ def serve(params, prompts, max_tokens, cfg=CFG, **ec):
     return outs, eng
 
 
-def test_the_engine_serves_the_family_through_submit(params, tokens):
+@PAGES
+def test_the_engine_serves_the_family_through_submit(params, tokens, page):
     """Engine.submit/start, chunked prefill, jit_decode, overlap: every
     served token is the reference's best at its position (float32: a gap
     above 1e-4 is a wrong token, not rounding), three requests in flight
-    whose contexts cross a chunk and YaRN's 32 positions."""
+    whose contexts cross a chunk and YaRN's 32 positions (at the family's
+    page a chunk is an eighth of a page and no context leaves its first)."""
     prompts = [tokens[:37], tokens[3:26], tokens[40:49]]
-    outs, eng = serve(params, prompts, 20)
+    outs, eng = serve(params, prompts, 20, page_size=page)
+    assert eng.page_size == (page or M.PAGE_TOKENS)
     for p, ids in zip(prompts, outs):
         assert len(ids) == 20
         gaps = R.served_gaps(plain(params), cfg_dict(CFG), list(p), ids)
@@ -558,8 +680,8 @@ def test_the_engine_serves_the_family_through_submit(params, tokens):
     # a step attends position + 1 tokens a decoding slot
     assert st["decode_ctx_tokens_sum"] >= st["decode_steps"] > 0
     # one pool of latent rows, every layer in it; no second pool
-    assert eng.cache["k"].shape == (CFG.n_layers, eng.n_pages + 1, PAGE, 1,
-                                    CFG.latent_row)
+    assert eng.cache["k"].shape == (CFG.n_layers, eng.n_pages + 1,
+                                    eng.page_size, 1, CFG.latent_row)
     assert eng.cache["v"].shape[0] == 0
 
 
@@ -575,21 +697,28 @@ def test_the_pool_says_what_a_token_keeps(params):
     assert METRICS.get("substratus_serve_slot_state_bytes") == 0
 
 
+@PAGES
 @BOTH
-def test_a_shared_prefix_is_served_from_its_pages(cfg, tokens):
+def test_a_shared_prefix_is_served_from_its_pages(cfg, tokens, long_tokens,
+                                                  page):
     """Pages carry everything: a second request that shares 32 tokens with
-    the first takes their pages from the registry, prefills the rest at an
+    the first (a page of 128 at the family's: whole pages are what is
+    shared) takes their pages from the registry, prefills the rest at an
     offset (the expanded form over pages it did not write), and serves the
     tokens of an engine that reuses nothing. Under an index the reused
     pages bring their index keys: the second request's queries pick their
-    8 rows among 32 tokens it never scored a key for."""
+    8 rows among tokens it never scored a key for."""
     params = params_of(cfg)
-    prompts = [np.concatenate([tokens[:32], tokens[40:47]]),
-               np.concatenate([tokens[:32], tokens[50:61]])]
-    cold = [serve(params, [p], 12, cfg, prefix_cache=False)[0][0]
-            for p in prompts]
+    if page:
+        shared, a, b, seq = 32, 40, 50, 96
+    else:
+        tokens, shared, a, b, seq = long_tokens, M.PAGE_TOKENS, 128, 133, 192
+    prompts = [np.concatenate([tokens[:shared], tokens[a:a + 7]]),
+               np.concatenate([tokens[:shared], tokens[b:b + 11]])]
+    cold = [serve(params, [p], 12, cfg, prefix_cache=False, page_size=page,
+                  max_seq_len=seq)[0][0] for p in prompts]
     eng = Engine(cfg, params, EngineConfig(
-        max_batch=3, max_seq_len=96, max_prefill_len=CHUNK, page_size=PAGE),
+        max_batch=3, max_seq_len=seq, max_prefill_len=CHUNK, page_size=page),
         model=M)
     eng.start()
     warm = []
@@ -603,7 +732,7 @@ def test_a_shared_prefix_is_served_from_its_pages(cfg, tokens):
         warm.append(ids)
     eng.stop()
     assert eng.error is None
-    assert eng.stats["prefix_hit_tokens"] == 32
+    assert eng.stats["prefix_hit_tokens"] == shared
     assert warm == cold
 
 
@@ -623,16 +752,25 @@ def test_a_resumed_sequence_gives_the_same_logits(params, tokens):
     assert np.abs(nxt - ref[49]).max() < TOL
 
 
+@PAGES
 @BOTH
-def test_the_engine_preempts_and_resumes_token_exact(cfg, tokens):
+def test_the_engine_preempts_and_resumes_token_exact(cfg, tokens,
+                                                     long_tokens, page):
     """A pool too small for three sequences: the engine preempts, prefills
     the victim again from 0 (rows and index keys alike, into pages another
-    sequence has used), and serves the tokens of a roomy pool."""
+    sequence has used), and serves the tokens of a roomy pool. At the
+    family's page the pool is three pages and every sequence grows into a
+    second."""
     params = params_of(cfg)
-    prompts = [tokens[:30], tokens[10:38], tokens[20:45]]
-    roomy, _ = serve(params, prompts, 24, cfg, prefix_cache=False)
+    if page:
+        n, seq = 30, 96
+    else:
+        tokens, n, seq = long_tokens, 110, 192
+    prompts = [tokens[:n], tokens[10:n + 8], tokens[20:n + 15]]
+    roomy, _ = serve(params, prompts, 24, cfg, prefix_cache=False,
+                     page_size=page, max_seq_len=seq)
     tight, eng = serve(params, prompts, 24, cfg, kv_pool_tokens=120,
-                       prefix_cache=False)
+                       prefix_cache=False, page_size=page, max_seq_len=seq)
     assert eng.stats["preemptions"] >= 1
     assert tight == roomy
 

@@ -18,8 +18,8 @@ from substratus_tpu.models import deepseek_v3 as M
 from substratus_tpu.models import hybrid, registry
 from substratus_tpu.ops import sparse_index
 from test_deepseek_v3 import (
-    DSA, PAGE, TOL, _forward, cfg_dict, decode, new_cache, params_of, plain,
-    prefill, reference_logits, serve, table,
+    DSA, PAGE, PAGES, TOL, _forward, cfg_dict, decode, new_cache, params_of,
+    plain, prefill, reference_logits, serve, table,
 )
 
 
@@ -219,15 +219,16 @@ def test_every_share_of_256_experts_adds_up_to_the_uncut_layer():
     assert held == 12 * 8  # every pair landed once
 
 
-def test_the_engine_serves_the_index_and_counts_what_it_selected(params,
-                                                                 tokens):
+@PAGES
+def test_the_engine_serves_the_index_and_counts_what_it_selected(
+        params, tokens, page):
     """Engine.submit/start over the second pool: every served token is the
     reference's best (float32), the pool keeps both arrays a token, and
     the counters say what the steps' queries attended of what was live."""
     from substratus_tpu.observability.metrics import METRICS
 
     prompts = [tokens[:37], tokens[3:26]]
-    outs, eng = serve(params, prompts, 12, DSA)
+    outs, eng = serve(params, prompts, 12, DSA, page_size=page)
     for p, ids in zip(prompts, outs):
         gaps = R.served_gaps(plain(params), cfg_dict(DSA), list(p), ids)
         assert gaps.max() < 1e-4

@@ -191,3 +191,29 @@ def test_pool_pages_all_recovered_after_load(setup):
     held = sum(eng.alloc.refs(eng.prefix._map[h]) for h in eng.prefix._map)
     assert eng.alloc.free_pages + len(eng.prefix) == eng.n_pages
     assert held == len(eng.prefix)
+
+
+@pytest.mark.parametrize("name,given,page", [
+    ("tiny-deepseek-v3", None, 128), ("tiny-glm-dsa", None, 128),
+    ("tiny", None, 16), ("tiny-exaone-moe", None, 16),
+    ("tiny-lfm2-moe", None, 16), ("tiny-brumby", None, 16),
+    ("tiny-deepseek-v3", 16, 16), ("tiny-glm-dsa", 64, 64), ("tiny", 32, 32),
+])
+def test_a_page_is_the_familys_unless_one_is_given(name, given, page):
+    """An engine given no `page_size` reads the page off the family's
+    module: 128 tokens where one latent row a token serves every head (with
+    and without an index), 16 where the module states nothing; a size
+    given wins. The pool, the block tables and the gauge all have it."""
+    from substratus_tpu.models import registry
+    from substratus_tpu.observability.metrics import METRICS
+    from substratus_tpu.serve.paged_kv import page_tokens
+
+    model, cfg = registry.find_named_config(name)
+    eng = Engine(cfg, None, EngineConfig(
+        max_batch=2, max_seq_len=128, max_prefill_len=16, page_size=given,
+        kv_pool_tokens=1), model=model)
+    assert eng.paged and eng.page_size == page
+    assert page_tokens(model, given) == page
+    assert eng.cache["k"].shape[2] == page
+    assert eng.block_table.shape == (2, 128 // page)
+    assert METRICS.get("substratus_serve_kv_page_tokens") == page

@@ -298,6 +298,8 @@ def test_perfz_endpoint_shape(engine):
     assert doc["latencies"]["ttft"]["all"]["count"] >= 1
     assert doc["engine"]["max_slots"] == 4
     assert doc["engine"]["kv_layout"] in ("paged", "dense")
+    # a page of the family's own: llama states none
+    assert doc["engine"]["kv_page_tokens"] == engine.page_size == 16
     assert "stats" in doc["engine"]
 
 

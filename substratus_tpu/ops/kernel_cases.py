@@ -328,8 +328,9 @@ def latent_attend(tag, b, s, max_seq, h, dn, dr, dv, rkv, pages, page=16,
     first, as the op does; both realisations return the attention alone.
     `index` = (heads, key width, k): the layer picks each query's k rows by
     a learned index (ops/sparse_index.py), whose keys fill the pool's
-    second array; the step then scores them in place and the chunk runs
-    under each query's own set."""
+    second array; the step then scores them in place (and, at a page of
+    128 tokens, takes its set in VMEM) and the chunk runs under each
+    query's own set."""
     from substratus_tpu.ops import kvcache
     from substratus_tpu.ops import latent_attention as LA
     from substratus_tpu.ops import sparse_index as SI
@@ -374,7 +375,8 @@ def latent_attend(tag, b, s, max_seq, h, dn, dr, dv, rkv, pages, page=16,
             return attend(*args)
         # the CPU rehearsal: the op would take the gather
         names = ("latent_decode_attention", "latent_chunk_attention",
-                 "index_decode_scores", "index_chunk_scores")
+                 "index_decode_scores", "index_chunk_scores",
+                 "index_select_rows")
         real = jax.lax.platform_dependent, [getattr(kvcache, n) for n in names]
         jax.lax.platform_dependent = lambda *a, tpu, default: tpu(*a)
         for n in names:
@@ -578,6 +580,9 @@ def rehearsal_cases() -> List[KernelCase]:
                       pages=25, index=(4, 128, 24)),
         latent_attend("small", 2, 20, 128, h=8, dn=48, dr=16, dv=64, rkv=128,
                       pages=25, index=(4, 128, 24)),
+        # a page a lane tile: the step takes its set in VMEM too
+        latent_attend("small", 3, 1, 1024, h=8, dn=48, dr=16, dv=64,
+                      rkv=128, pages=25, page=128, index=(4, 128, 128)),
     ]
 
 

@@ -98,7 +98,8 @@ from substratus_tpu.ops.paged_attention import (
     LANES, NEG_INF, paged_chunk_attention, paged_decode_attention,
 )
 from substratus_tpu.ops.sparse_index import (
-    index_chunk_scores, index_decode_scores,
+    index_chunk_scores, index_decode_scores, index_select_rows,
+    select_rows_kernel_takes,
 )
 from substratus_tpu.ops.quant import dequantize_kv, quantize_kv
 from substratus_tpu.parallel.sharding import SERVE_RULES
@@ -364,13 +365,15 @@ def latent_attention(
     S_t alone: the min(k, t + 1) positions of largest index score, ties
     toward the lower. A decode step scores the row's live keys (ATTN_INDEX:
     where the kernels run, in place through the block table), takes the
-    set by one stable sort of the scores with the pool's rows carried
-    along (ATTN_SELECT), and reads those rows and no others, by position
-    (ATTN_CORE: a gather of k rows a slot, then the absorbed form over
-    them in XLA). A chunk scores its queries against the gathered keys of
-    the table (ATTN_INDEX), takes each query's set as a mask
-    (ATTN_SELECT, `sparse_index.select`) and runs the expanded form under
-    it: every pair is still computed."""
+    set as the chunk does, by a threshold, and its positions' rows of the
+    pool by a compaction, in ascending position (ATTN_SELECT,
+    `sparse_index.select_rows`; where the kernels run and a page is a lane
+    tile, in one kernel over the scores in VMEM), and reads those rows and
+    no others, by position (ATTN_CORE: a gather of k rows a slot, then the
+    absorbed form over them in XLA). A chunk scores its queries against
+    the gathered keys of the table (ATTN_INDEX), takes each query's set as
+    a mask (ATTN_SELECT, `sparse_index.select`) and runs the expanded form
+    under it: every pair is still computed."""
     from substratus_tpu.ops.quant import QTensor, materialize, qeinsum
 
     k_pool = pool["k"]
@@ -456,21 +459,27 @@ def latent_attention(
                       jax.lax.platform_dependent(
                           tpu=scores_in_place, default=scores_in_xla))
             with jax.named_scope(scopes.ATTN_SELECT):
-                # The best first, of equal scores the lower position first
-                # (a stable sort, which is what `lax.top_k` of so many
-                # compiles to); what is sorted along is not the position
-                # but the row of the layer it lies in, so that no lookup
-                # through the table follows (8,192 of them cost 0.09 ms a
-                # layer on the chip, PR 42). A row shorter than the set
-                # fills it up with -inf: those stand for no row and read
-                # the trash page's first.
-                rows_of = (block_table[:, :, None] * bs + jnp.arange(
-                    bs, dtype=block_table.dtype)).reshape(b, -1)
-                worst, row = jax.lax.sort(
-                    (-sc, rows_of), dimension=1, is_stable=True, num_keys=1)
-                keep = min(topk, sc.shape[1])
-                ok = worst[:, :keep] < jnp.inf
-                flat = jnp.where(ok, first * bs + row[:, :keep], 0)
+                # The chunk's mask, then its positions' rows in the layer
+                # by ascending position: attention over a set reads no
+                # order, so nothing is sorted (a stable sort of 18,432
+                # scores a slot cost 0.196 ms a layer on the chip, and
+                # 8,192 lookups through the table 0.09, PR 42). A row
+                # shorter than the set leaves places over: those stand
+                # for no row and read the trash page's first.
+                def rows_in_xla():
+                    return sparse_index.select_rows(
+                        sc, positions[:, 0], block_table, topk)
+
+                def rows_in_vmem():
+                    return index_select_rows(
+                        sc, positions[:, 0], block_table, topk)
+
+                row, ok = (
+                    jax.lax.platform_dependent(
+                        tpu=rows_in_vmem, default=rows_in_xla)
+                    if kernel is not None and select_rows_kernel_takes(
+                        block_table, bs, topk) else rows_in_xla())
+                flat = jnp.where(ok, first * bs + row, 0)
             with jax.named_scope(scopes.ATTN_CORE):
                 rows = _rows(k_pool).at[flat].get(
                     mode="promise_in_bounds")[:, :, 0]  # [B, k, w]

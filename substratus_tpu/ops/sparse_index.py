@@ -11,7 +11,7 @@ init_latent_cache). A query t brings `qi` [Hi, di] and a weight a head
     S_t   = the min(k, t + 1) positions s <= t of largest I_t,s,
             ties toward the lower position
 
-is the set its attention runs over. Three pieces, each with a plain XLA
+is the set its attention runs over. Four pieces, each with a plain XLA
 form that is also what the kernels are tested against:
 
 * `scores` (XLA) / `index_decode_scores` (Pallas): `I` for one query a row
@@ -26,13 +26,18 @@ form that is also what the kernels are tested against:
 * `select` : the set as a mask, by bisection on the scores' bit patterns
   (32 counts for the k-th largest value, then as many as the positions
   have bits for the ties that stay, only where a tie straddles the set's
-  edge): exact, no sort, any layout. A decode step sorts instead
-  (ops/kvcache.py::latent_attention: it needs the positions themselves,
-  and a stable sort breaks ties the same way).
+  edge): exact, no sort, any layout.
+* `select_rows` (XLA) / `index_select_rows` (Pallas): a decode step's set
+  as the pool's rows, which its attention gathers: `select`'s mask, then
+  `compact`, the mask's positions in ascending order through the block
+  table, by counts, compares and one-hot products (attention over a set
+  reads no order, so nothing is sorted). The kernel holds the rows' scores
+  in VMEM through every count of the bisection and the compaction.
 """
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -107,6 +112,189 @@ def select(index: jnp.ndarray, seen: jnp.ndarray, k: int, axis: int
     tight = count(u >= tau) == need  # every equal entry fits
     return lax.cond(jnp.all(tight), lambda _: (u >= tau) & (need > 0),
                     with_ties, None)
+
+
+def compact(mask: jnp.ndarray, block_table: jnp.ndarray, keep: int
+            ) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """A set as the pool's rows: for mask [B, M * bs] over the positions of
+    block_table [B, M]'s pages, (row [B, keep] int32, ok [B, keep]): the
+    rows `block_table[b, s // bs] * bs + s % bs` of the mask's first `keep`
+    positions s in ascending order, and which places hold one (the others'
+    rows mean nothing). No sort, no scatter and no lookup through the
+    table: a position's place is the count of the mask before it, so place
+    j finds its page by comparing j with the pages' counts (one page of M
+    holds it: what a product with that one-hot row brings is the page's
+    own), and its lane by comparing its rank in the page with the page's
+    running count. Every number in a product is a whole one that its type
+    holds exactly."""
+    b, m = block_table.shape
+    bs = mask.shape[1] // m
+    f32 = jnp.float32
+    # a page's running count is at most bs
+    small, exact = ((jnp.bfloat16, None) if bs <= 256
+                    else (f32, lax.Precision.HIGHEST))
+    at = jnp.arange(bs)
+    within = jnp.einsum(  # [B, M, bs]: the mask's count up to each lane
+        "bml,lj->bmj", mask.reshape(b, m, bs).astype(small),
+        (at[:, None] <= at[None, :]).astype(small),
+        preferred_element_type=f32, precision=exact)
+    total = within[..., -1]
+    page = jnp.arange(m)
+    end = jnp.einsum(  # [B, M]: the places before a page's end
+        "bm,mn->bn", total, (page[:, None] <= page[None, :]).astype(f32),
+        precision=lax.Precision.HIGHEST)
+    start = end - total
+    j = jnp.arange(keep, dtype=f32)[None, :, None]
+    hot = (start[:, None] <= j) & (j < end[:, None])  # [B, keep, M]
+    counts = jnp.einsum(  # [B, keep, bs]: the running count of j's page
+        "bjm,bml->bjl", hot.astype(small), within.astype(small),
+        preferred_element_type=f32, precision=exact)
+    first = jnp.sum(jnp.where(hot, start[:, None], 0), axis=-1)
+    pid = jnp.sum(jnp.where(hot, block_table[:, None], 0), axis=-1)
+    lane = jnp.sum(counts <= (j[..., 0] - first)[..., None], axis=-1,
+                   dtype=jnp.int32)
+    return pid * bs + lane, j[..., 0] < end[:, -1:]
+
+
+def select_rows(index: jnp.ndarray, positions: jnp.ndarray,
+                block_table: jnp.ndarray, k: int
+                ) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """A decode step's sets as the pool's rows: for I [B, M * bs] of one
+    query a row at positions [B], `compact` of `select` over positions 0
+    .. positions[b]: (row, ok) [B, min(k, M * bs)], ok in the first min(k,
+    positions[b] + 1) places. A score of -0.0 counts as 0.0, as it does to
+    a sort (`select` orders bit patterns, which tell them apart)."""
+    t = index.shape[1]
+    seen = jnp.arange(t, dtype=jnp.int32)[None] <= positions[:, None]
+    index = jnp.where(index == 0, 0.0, index)
+    return compact(select(index, seen, k, axis=1), block_table, min(k, t))
+
+
+# --- a decode step's sets, the scores held in VMEM ---------------------------
+
+
+def _select_rows_kernel(pos_ref, sc_ref, bt_ref, o_ref, key_ref, *, k):
+    """`select_rows` for the grid step's rows: `select`'s bisection (the
+    float32 order as int32's, every count one pass over a row's scores
+    where they lie, the rows' passes interleaved), then `compact` with the
+    places on the lanes."""
+    g, m, bs = sc_ref.shape
+    keep = o_ref.shape[2]
+    row0 = pl.program_id(0) * g
+    low = jnp.int32(-(1 << 31))
+    at = (lax.broadcasted_iota(jnp.int32, (m, bs), 0) * bs
+          + lax.broadcasted_iota(jnp.int32, (m, bs), 1))
+
+    def count(which):
+        return jnp.sum(which.astype(jnp.int32))
+
+    need = []
+    for b in range(g):
+        pos = pos_ref[row0 + b]
+        x = sc_ref[b]
+        bits = pltpu.bitcast(jnp.where(x == 0.0, 0.0, x), jnp.int32)
+        key = jnp.where(bits < 0, bits ^ jnp.int32(0x7FFFFFFF), bits)
+        key_ref[b] = jnp.where(at <= pos, key, low)  # unseen: below all
+        need.append(jnp.minimum(pos + 1, k))
+
+    def value_bit(i, taus):  # tau as `select` has it, a uint32's bits
+        bit = jnp.int32(1) << (31 - i)
+        out = []
+        for b in range(g):
+            cand = taus[b] | bit
+            enough = count(key_ref[b] >= (cand ^ low)) >= need[b]
+            out.append(jnp.where(enough, cand, taus[b]))
+        return tuple(out)
+
+    taus = lax.fori_loop(0, 32, value_bit, (jnp.int32(0),) * g)
+    n_bits = max(m * bs - 1, 1).bit_length()
+    upto = (lax.broadcasted_iota(jnp.int32, (bs, bs), 1)
+            <= lax.broadcasted_iota(jnp.int32, (bs, bs), 0)
+            ).astype(jnp.bfloat16)  # [lane, lane']: lane' <= lane
+    before = (lax.broadcasted_iota(jnp.int32, (m, m), 1)
+              < lax.broadcasted_iota(jnp.int32, (m, m), 0)
+              ).astype(jnp.bfloat16)  # [page, page']: page' < page
+    j = lax.broadcasted_iota(jnp.int32, (1, keep), 1).astype(jnp.float32)
+    for b in range(g):
+        key = key_ref[b]
+        tau = taus[b] ^ low
+        above, equal = key > tau, key == tau
+        room = need[b] - count(above)
+
+        def last_that_fits(equal=equal, room=room):
+            def position_bit(i, p):
+                cand = p | (jnp.int32(1) << (n_bits - 1 - i))
+                return jnp.where(count(equal & (at < cand)) < room, cand, p)
+
+            return lax.fori_loop(0, n_bits, position_bit, jnp.int32(0))
+
+        p = lax.cond(count(equal) > room, last_that_fits,
+                     lambda: jnp.int32(m * bs))
+        chosen = (above | (equal & (at <= p))).astype(jnp.float32)
+        packed = chosen.astype(jnp.bfloat16)
+        within = lax.dot_general(  # [bs, m]: a page's count up to a lane
+            upto, packed, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        total = jnp.sum(chosen, axis=1, keepdims=True)  # [m, 1]
+        start = jnp.dot(
+            before, jnp.broadcast_to(total, (m, bs)).astype(jnp.bfloat16),
+            preferred_element_type=jnp.float32)[:, :1]
+        hot = (start <= j) & (j < start + total)  # [m, keep]
+        counts = jnp.dot(within.astype(jnp.bfloat16),
+                         hot.astype(jnp.bfloat16),
+                         preferred_element_type=jnp.float32)  # [bs, keep]
+        first = jnp.sum(jnp.where(hot, start, 0.0), axis=0, keepdims=True)
+        pid = jnp.sum(jnp.where(hot, bt_ref[b], 0), axis=0, keepdims=True)
+        lane = jnp.sum((counts <= j - first).astype(jnp.int32), axis=0,
+                       keepdims=True)
+        o_ref[b] = pid * bs + lane
+
+
+def select_rows_kernel_takes(block_table: jnp.ndarray, bs: int, k: int
+                             ) -> bool:
+    """Whether `index_select_rows` compiles for these shapes: a page a
+    lane tile, the table's pages whole sublane tiles, the places whole
+    lane tiles."""
+    m = block_table.shape[1]
+    return bs == LANES and m % 8 == 0 and min(k, m * bs) % LANES == 0
+
+
+@functools.partial(jax.jit, static_argnames=("k", "interpret"))
+def index_select_rows(
+    index: jnp.ndarray,  # [B, M * bs] float32: I, -inf past a row's position
+    positions: jnp.ndarray,  # [B]
+    block_table: jnp.ndarray,  # [B, M] int32 page ids
+    k: int,
+    *,
+    interpret: bool = False,
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """`select_rows` in one kernel: a row's scores stay in VMEM through
+    the threshold's 32 (+ up to 15) counts and the compaction."""
+    b, m = block_table.shape
+    bs = index.shape[1] // m
+    keep = min(k, m * bs)
+    g = math.gcd(b, 8)  # rows a grid step, their passes interleaved
+    row = pl.pallas_call(
+        functools.partial(_select_rows_kernel, k=k),
+        out_shape=jax.ShapeDtypeStruct((b, 1, keep), jnp.int32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b // g,),
+            in_specs=[
+                pl.BlockSpec((g, m, bs), lambda i, pos: (i, 0, 0)),
+                pl.BlockSpec((g, m, 1), lambda i, pos: (i, 0, 0)),
+            ],
+            out_specs=pl.BlockSpec((g, 1, keep), lambda i, pos: (i, 0, 0)),
+            scratch_shapes=[pltpu.VMEM((g, m, bs), jnp.int32)],
+        ),
+        interpret=interpret,
+        name="index_select_rows",
+    )(positions.astype(jnp.int32),
+      index.astype(jnp.float32).reshape(b, m, bs),
+      block_table.astype(jnp.int32)[:, :, None])
+    ok = (jnp.arange(keep, dtype=jnp.int32)[None]
+          < jnp.minimum(positions.astype(jnp.int32) + 1, k)[:, None])
+    return row[:, 0], ok
 
 
 # --- one query a row, the keys read in place ---------------------------------

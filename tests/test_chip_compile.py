@@ -853,12 +853,14 @@ def test_glm_dsa_programs_compile_with_the_index_kernels(v5e):
     192 + 64 against 256, 32 index heads over keys of 128, the 2,048 best
     rows a query): the pool's second array holds the index keys under the
     same page ids; the decode program scores them in place
-    (`index_decode_scores`), sorts once a layer and gathers the picked rows
-    by position, and holds no kernel that walks a row's pages of latents;
-    the 512 chunk scores by `index_chunk_scores` and runs the expanded
-    kernel under the sets; neither moves either array of the pool. The
-    engine is given no page and takes the family's 128 tokens: a block of
-    the keys' copies holds 1,024 tokens in 8 pages."""
+    (`index_decode_scores`), takes each slot's set by a threshold and a
+    compaction over the scores held in VMEM (`index_select_rows`: no sort
+    but the sampler's) and gathers the picked rows by position, and holds
+    no kernel that walks a row's pages of latents; the 512 chunk scores by
+    `index_chunk_scores` and runs the expanded kernel under the sets;
+    neither moves either array of the pool. The engine is given no page
+    and takes the family's 128 tokens: a block of the keys' copies holds
+    1,024 tokens in 8 pages."""
     from substratus_tpu.models import deepseek_v3
     from substratus_tpu.ops.quant import quantize_params
     from substratus_tpu.serve.engine import Engine, EngineConfig
@@ -899,7 +901,7 @@ def test_glm_dsa_programs_compile_with_the_index_kernels(v5e):
             arr(()), arr((1, m)), None, None, arr(()),
         ),
     }
-    kernels = {"decode": ("index_decode_scores",),
+    kernels = {"decode": ("index_decode_scores", "index_select_rows"),
                "chunk": ("index_chunk_scores", "latent_chunk_attention")}
     pool = set()
     for a in (cache["k"], cache["v"]):
@@ -911,6 +913,9 @@ def test_glm_dsa_programs_compile_with_the_index_kernels(v5e):
             # two blocks of 1,024 keys (0.5 MB) under the default limit
             assert vmem["index_decode_scores"] == (
                 None, (2, 1024 // page, page, 128))
+            # the four slots' scores as ordered keys (0.3 MB), one slot's
+            # one-hot and running counts (2 MB) under the default limit
+            assert vmem["index_select_rows"] == (None, (_G_B, m, page))
         else:
             # what it asked for at 16 tokens a page: two blocks of 512
             # keys and of the bias, 8 heads' weights, a fold's scores
@@ -929,13 +934,11 @@ def test_glm_dsa_programs_compile_with_the_index_kernels(v5e):
         assert _pool_moving_ops(bf16, pool) == [], name
         temp = compiled.memory_analysis().temp_size_in_bytes
         assert temp < 1.6e8, (name, temp)
-        if name == "decode":
-            # one selection a layer: the scanned body's and the three
-            # leading layers' (the 2,048 best of 18,432 by one stable sort
-            # of the scores, the rows of the pool carried along)
-            assert len(re.findall(
-                r"= \(f32\[4,18432\]\S*, s32\[4,18432\]\S*, [^=]*\) sort\(",
-                hlo)) == 4, name
+        # no selection sorts: the step's only sort is the sampler's, in
+        # its sampled branch
+        assert _sorts_only_where_a_row_samples(hlo) == (name == "decode")
+        assert "sort(" not in "\n".join(
+            l for l in hlo.splitlines() if "attn.select" in l), name
 
 
 @pytest.mark.parametrize("program", ["decode", "chunk"])

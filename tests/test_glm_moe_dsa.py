@@ -143,17 +143,21 @@ def test_a_step_reads_no_row_it_did_not_select(params, tokens):
     assert np.abs(got - want).max() < 1e-6
 
 
-@pytest.mark.parametrize("s", [1, 20], ids=["step", "chunk"])
-def test_the_index_kernels_are_the_xla_forms(pallas_interpret, s):
-    """ops/sparse_index.py's two kernels and the chunk kernel under a
-    bias, interpreted, through `latent_attention` against its gathered
-    forms (ops/kernel_cases.py's small case: rows of every length, 24 of
-    up to 128 kept)."""
+@pytest.mark.parametrize("s,seq,page,topk", [
+    (1, 128, 16, 24), (20, 128, 16, 24), (1, 1024, 128, 128)],
+    ids=["step", "chunk", "step-page-128"])
+def test_the_index_kernels_are_the_xla_forms(pallas_interpret, s, seq, page,
+                                             topk):
+    """ops/sparse_index.py's kernels and the chunk kernel under a bias,
+    interpreted, through `latent_attention` against its gathered forms
+    (ops/kernel_cases.py's small cases: rows of every length, 24 of up to
+    128 kept; at a page of 128 tokens, where a step's selection runs in
+    `index_select_rows`, 128 of up to 1,024)."""
     from substratus_tpu.ops import kernel_cases as KC
 
-    case = KC.latent_attend("small", 3 if s == 1 else 2, s, 128, h=8, dn=48,
-                            dr=16, dv=64, rkv=128, pages=25,
-                            index=(4, 128, 24))
+    case = KC.latent_attend("small", 3 if s == 1 else 2, s, seq, h=8, dn=48,
+                            dr=16, dv=64, rkv=128, pages=25, page=page,
+                            index=(4, 128, topk))
     args = case.make_args(jax.random.key(0))
     got = np.asarray(case.kernel(*args, interpret=True), np.float32)
     want = np.asarray(case.reference(*args), np.float32)
@@ -176,6 +180,93 @@ def test_select_is_top_k_with_ties_toward_the_lower_position():
     turned = sparse_index.select(x.transpose(0, 2, 1),
                                  seen.transpose(0, 2, 1), 6, axis=1)
     assert (np.asarray(turned).transpose(0, 2, 1) == want).all()
+
+
+def _rows_by_the_sort(sc, block_table, k):
+    """The decode step's selection as it stood until PR 44, the plain
+    reference: one stable sort of the scores, best first, with the pool's
+    rows carried along; (row, ok) [B, min(k, T)]."""
+    b, t = sc.shape
+    bs = t // block_table.shape[1]
+    rows_of = (block_table[:, :, None] * bs + jnp.arange(
+        bs, dtype=block_table.dtype)).reshape(b, -1)
+    worst, row = jax.lax.sort(
+        (-sc, rows_of), dimension=1, is_stable=True, num_keys=1)
+    keep = min(k, t)
+    return row[:, :keep], worst[:, :keep] < jnp.inf
+
+
+def _selection_case(name, page):
+    """(scores [B, M * page], positions [B], block table [B, M], k): what
+    a decode step hands its selection, -inf past each row's position; at
+    tier-1's page of 4 tokens or the chip's of 128, a lane tile."""
+    m, k, rows = (12, 8, 4) if page == 4 else (8, 128, 4)
+    key = jax.random.key(7)
+    t = page * m
+    sc = jax.random.normal(key, (rows, t), jnp.float32)
+    # the table's last token, inside a page, a page's last and its first
+    pos = jnp.array([t - 1, t - page - 2, 5 * page - 1, 5 * page], jnp.int32)
+    bt = jax.random.permutation(key, jnp.arange(1, 70000, dtype=jnp.int32))[
+        :rows * m].reshape(rows, m)
+    if name == "ties at the edge":
+        # a few values in all: a set's edge falls inside a run of equals
+        sc = jnp.round(sc)
+    elif name == "rows shorter than the set":
+        pos = jnp.array([0, 3, k - 2, k - 1], jnp.int32)
+    elif name == "-inf past the position":
+        # rows just longer than the set: most of the table is nobody's
+        pos = jnp.array([k, k + 1, k + page, t // 2], jnp.int32)
+    elif name == "negative and zero scores":
+        # 0.0 and -0.0 are one value to the sort, and straddle the edge
+        sc = -jnp.abs(jnp.round(sc))
+        sc = sc.at[:, ::3].multiply(-1.0)
+    elif name == "page ids that descend":
+        bt = jnp.sort(bt, axis=1)[:, ::-1]
+    else:
+        assert name == "random scores", name
+    live = jnp.arange(t)[None] <= pos[:, None]
+    return jnp.where(live, sc, -jnp.inf), pos, bt, k
+
+
+@pytest.mark.parametrize("form", [
+    "xla, pages of 4", "xla, pages of 128", "kernel, pages of 128"])
+@pytest.mark.parametrize("name", [
+    "random scores", "ties at the edge", "rows shorter than the set",
+    "-inf past the position", "negative and zero scores",
+    "page ids that descend"])
+def test_a_steps_selection_is_the_stable_sorts_set(name, form):
+    """The decode step's threshold and compaction (`select_rows`; on the
+    chip one kernel, `index_select_rows`, here interpreted) return, as a
+    set, the pool rows one stable sort of the scores returns, row for row:
+    min(k, t + 1) of them, in the first places, by ascending position."""
+    sc, pos, bt, k = _selection_case(name, 4 if form.endswith(" 4") else 128)
+    if form.startswith("kernel"):
+        assert sparse_index.select_rows_kernel_takes(
+            bt, sc.shape[1] // bt.shape[1], k)
+        row, ok = sparse_index.index_select_rows(sc, pos, bt, k,
+                                                 interpret=True)
+    else:
+        row, ok = jax.jit(sparse_index.select_rows, static_argnums=3)(
+            sc, pos, bt, k)
+    want_row, want_ok = _rows_by_the_sort(sc, bt, k)
+    row, ok, want_row, want_ok = map(np.asarray, (row, ok, want_row, want_ok))
+    assert row.shape == want_row.shape == (len(pos), min(k, sc.shape[1]))
+    size = np.minimum(k, np.asarray(pos) + 1)
+    assert (ok.sum(1) == size).all() and (size < k).any() == (
+        name == "rows shorter than the set")
+    page = sc.shape[1] // bt.shape[1]
+    for b in range(len(pos)):
+        assert (ok[b] == want_ok[b]).all()
+        assert set(row[b, ok[b]]) == set(want_row[b, want_ok[b]]), (name, b)
+        # ascending position: undo the table
+        at = {int(p) * page + i: j * page + i
+              for j, p in enumerate(np.asarray(bt[b])) for i in range(page)}
+        where = [at[r] for r in row[b, ok[b]]]
+        assert where == sorted(where) and where[-1] <= pos[b], (name, b)
+    if name in ("ties at the edge", "negative and zero scores"):
+        # the case bites: more equals at some row's edge than fit
+        edge = np.sort(np.asarray(sc), axis=1)[:, -k]
+        assert ((np.asarray(sc) >= edge[:, None]).sum(1) > k).any()
 
 
 def test_every_share_of_256_experts_adds_up_to_the_uncut_layer():

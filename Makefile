@@ -3,7 +3,7 @@
 
 PY ?= python
 
-.PHONY: test test-int lint lint-fast metrics-lint trace-lint manifests api-docs protogen nbwatch spm bench bench-train bench-smoke bench-compare gateway-smoke fleet-smoke journey-smoke autoscale-smoke rollout-smoke gateway-bench adapter-bench disagg-bench overlap-bench spec-bench prefix-bench batchgen-bench chip-smoke chip-smoke-rehearse image install-manifests
+.PHONY: test test-int lint lint-fast metrics-lint trace-lint manifests api-docs protogen nbwatch spm gateway-smoke fleet-smoke journey-smoke autoscale-smoke rollout-smoke chip-smoke chip-smoke-rehearse image install-manifests
 
 test:
 	$(PY) -m pytest tests/ -x -q
@@ -65,25 +65,6 @@ nbwatch:
 spm:
 	g++ -O2 -Wall -shared -fPIC -o native/libspm_tokenizer.so native/spm_tokenizer.cc
 
-bench:
-	$(PY) bench.py
-
-# The second BASELINE primary metric: 7B LoRA finetune step-time.
-bench-train:
-	$(PY) tools/bench_train.py
-
-# CPU-scaled runs of both bench scripts plus the 2-process lockstep gang
-# bench, each piped through the schema validator — proves every script
-# emits one valid JSON line (platform "cpu": a shape check, not a speed).
-bench-smoke:
-	JAX_PLATFORMS=cpu $(PY) bench.py --config tiny --batch 4 --cache-len 128 \
-	  --steps 8 --quantize int8 | $(PY) hack/bench_compare.py --validate -
-	JAX_PLATFORMS=cpu $(PY) tools/bench_train.py --smoke \
-	  | $(PY) hack/bench_compare.py --validate -
-	JAX_PLATFORMS=cpu $(PY) tools/engine_bench.py --smoke --gang 2 \
-	  --transport tcp --long-admission 8200 \
-	  | $(PY) hack/bench_compare.py --validate -
-
 # Gateway chaos smoke: 2 in-process CPU replicas behind the routing
 # gateway, scripted kill mid-stream / hedge / recover-after-backoff
 # (tools/gateway_smoke.py; the pytest chaos test drives the same
@@ -127,85 +108,6 @@ autoscale-smoke:
 # (tools/rollout_smoke.py, controller/rollout.py).
 rollout-smoke:
 	JAX_PLATFORMS=cpu $(PY) tools/rollout_smoke.py
-
-# Routed-2-replica vs direct throughput/TTFT capture (ISSUE 5
-# acceptance: routed aggregate tok/s >= 1.7x single replica on the
-# smoke shape). Spawns replica server subprocesses; heavier than
-# gateway-smoke, so not part of the CI tests workflow.
-gateway-bench:
-	JAX_PLATFORMS=cpu $(PY) tools/engine_bench.py --smoke --gateway 2 \
-	  --max-tokens 32 | $(PY) hack/bench_compare.py --validate -
-
-# Multi-tenant adapter packing capture (ISSUE 6 acceptance): a mixed
-# 4-adapter engine vs a base-only engine on the same shape with the
-# simulated device step — packed aggregate tok/s must stay within 15%
-# of base (tests/test_adapters.py asserts the ratio; this target
-# validates the capture schema).
-adapter-bench:
-	JAX_PLATFORMS=cpu $(PY) tools/engine_bench.py --smoke --adapters 4 \
-	  | $(PY) hack/bench_compare.py --validate -
-
-# Disaggregated prefill/decode capture (ISSUE 7 acceptance): a
-# 1-prefill + 1-decode pair over the real TCP KV handoff vs 2
-# monolithic engines on the same shape under a prompt-burst workload
-# with the simulated device step — burst-window p99 inter-token
-# latency must drop >=30% with aggregate tok/s within 10%
-# (docs/serving.md "Disaggregated prefill/decode").
-disagg-bench:
-	JAX_PLATFORMS=cpu $(PY) tools/engine_bench.py --smoke --disagg \
-	  | $(PY) hack/bench_compare.py --validate -
-
-# Overlapped decode scheduler capture (ISSUE 10 acceptance): one-step-
-# ahead dispatch with on-device token feedback vs the synchronous
-# scheduler on the same shape, simulated device step + real per-token
-# detokenize host work in the emit path — steady-state inter-token
-# mean must hold <= 1.15x the device floor with aggregate tok/s within
-# 5% or better, greedy outputs token-exact (tests/test_overlap.py
-# asserts; docs/performance.md "Overlapped scheduling"). The capture
-# also embeds hard gates bench_compare --validate evaluates (ISSUE 11):
-# bubble ratio <= 0.15, bubble attribution coverage >= 0.9, tok/s vs
-# sync >= 0.95 — a host-path regression fails here WITH a cause
-# (docs/performance.md "Pipeline-bubble attribution").
-overlap-bench:
-	JAX_PLATFORMS=cpu $(PY) tools/engine_bench.py --smoke --overlap \
-	  | $(PY) hack/bench_compare.py --validate -
-
-# Speculation x overlap composition capture (ISSUE 14 acceptance):
-# plain / spec-only / overlap-only / spec+overlap engines on the same
-# repetitive-prompt shape, simulated device step + the overlap leg's
-# per-token host work — the composed engine's aggregate tok/s must
-# beat BOTH single-lever legs (the pipelined spec rounds amortize the
-# floor across accepted drafts while the one-step-ahead dispatch hides
-# the proposal scan + emit work), greedy outputs token-exact across
-# all four engines, and pipeline_flushes_total{reason="spec"} must not
-# move (docs/performance.md "Speculative decoding";
-# tests/test_spec_overlap.py asserts the same invariants in-process).
-spec-bench:
-	JAX_PLATFORMS=cpu $(PY) tools/engine_bench.py --smoke --spec-overlap \
-	  | $(PY) hack/bench_compare.py --validate -
-
-# Shared-prefix KV reuse capture (ROADMAP item 1 evidence): repeated
-# system-prompt workload, prefix registry on vs off — TTFT and
-# aggregate tok/s.
-prefix-bench:
-	JAX_PLATFORMS=cpu $(PY) tools/engine_bench.py --smoke --prefix-reuse \
-	  | $(PY) hack/bench_compare.py --validate -
-
-# Batch-generation actor gang capture (ISSUE 9 acceptance): a 2-actor
-# gang draining one shared prompt manifest through the continuous-
-# refill driver vs one identical actor, simulated device step — gang
-# aggregate tok/s must reach >=1.8x single AND steady-state decode
-# slot occupancy >=0.9 (tests/test_batchgen.py asserts both; this
-# target validates the capture schema — docs/batch-generation.md).
-batchgen-bench:
-	JAX_PLATFORMS=cpu $(PY) tools/engine_bench.py --smoke --batchgen 2 \
-	  | $(PY) hack/bench_compare.py --validate -
-
-# Bench JSON schema + >10% regression gate (hack/bench_compare.py):
-# self-tests that a synthetic 20% regression fails and that any
-# BENCH_*.json history beside it loads.
-bench-compare:
-	$(PY) hack/bench_compare.py --self-test
 
 # Does the system still start on the chip? Serve and finetune
 # TinyLlama-1.1B through the normal entry points and run every Pallas

@@ -129,9 +129,13 @@ def main() -> int:
     ))
     scaler.plan(fleet.signals(), ScaleTargets(replicas=1), now=1.0)
     scaler.plan(None, ScaleTargets(replicas=1), now=2.0)  # frozen
-    StepTimeline().record_iteration(
-        t_start=0.0, wall_s=0.02, admit_s=0.004, admitted=1,
-        dispatch_s=0.001, drain_s=0.01, configured_floor_s=0.015,
+    # The first iteration sets the floor; the second's gap over it
+    # renders the bubble counter.
+    tl = StepTimeline()
+    tl.record_iteration(t_start=0.0, wall_s=0.015, dispatch_s=0.001)
+    tl.record_iteration(
+        t_start=0.02, wall_s=0.02, admit_s=0.004, admitted=1,
+        dispatch_s=0.001, drain_s=0.01,
     )
     client = sci.FakeSCIClient()
     client.get_object_md5("gs://bucket", "obj")

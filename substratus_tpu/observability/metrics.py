@@ -262,7 +262,7 @@ class Metrics:
                          (inf, count)], "sum": float, "count": int}}
 
         Empty dict when the family is unknown or has no observations.
-        This is the read API behind /debug/perfz and the gang bench —
+        This is the read API behind /debug/perfz —
         consumers get the same cumulative-bucket data a Prometheus scrape
         would, without parsing the text exposition."""
         with self._lock:

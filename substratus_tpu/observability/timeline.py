@@ -23,11 +23,8 @@ The attribution feeds ``substratus_serve_pipeline_bubble_seconds``
 and the ring renders as Chrome-trace JSON on ``GET /debug/stepz``
 (load chrome://tracing or Perfetto on the payload).
 
-The device floor: the configured ``step_floor_s`` when the engine
-simulates a device window (CPU bench/smoke), else the minimum
-iteration wall over a sliding window — self-calibrating against the
-best the hardware recently did, so production bubbles are measured
-against reality, not a config guess.
+The device floor is the minimum iteration wall over a sliding window:
+bubbles are measured against the best the hardware recently did.
 
 One timing site per scheduler phase: ``phase(name)`` opens a
 ``jax.profiler.TraceAnnotation("engine.<name>")`` (a flag check unless a
@@ -41,7 +38,7 @@ the histogram and a ``POST /debug/profile`` capture share one measurement
 
 Thread contract: ``phase``, ``pool_dry``, ``commit`` and
 ``record_iteration`` are called by the engine scheduler thread only;
-readers (``/debug/stepz``, the bench) snapshot under the lock.
+readers (``/debug/stepz``) snapshot under the lock.
 """
 from __future__ import annotations
 
@@ -164,8 +161,8 @@ class StepTimeline:
         admission time is a capacity bubble, not host speed."""
         self._pool_dry = True
 
-    def commit(self, *, admitted: int, active_slots: int, max_slots: int,
-               configured_floor_s: float = 0.0) -> dict:
+    def commit(self, *, admitted: int, active_slots: int,
+               max_slots: int) -> dict:
         """Record the iteration in progress from what its phases
         accumulated (called inside the "iter" phase, as its last act)."""
         s = self._phase_s
@@ -182,7 +179,6 @@ class StepTimeline:
             pool_dry=self._pool_dry,
             active_slots=active_slots,
             max_slots=max_slots,
-            configured_floor_s=configured_floor_s,
         )
 
     def record_iteration(
@@ -200,7 +196,6 @@ class StepTimeline:
         pool_dry: bool = False,
         active_slots: int = 0,
         max_slots: int = 1,
-        configured_floor_s: float = 0.0,
     ) -> dict:
         """Record one scheduler iteration and attribute its bubble.
 
@@ -215,10 +210,7 @@ class StepTimeline:
         wall_s = max(0.0, float(wall_s))
         with self._lock:
             self._walls.append(wall_s)
-            if configured_floor_s > 0.0:
-                floor_s = float(configured_floor_s)
-            else:
-                floor_s = min(self._walls)
+            floor_s = min(self._walls)
             gap = max(0.0, wall_s - floor_s)
             remaining = gap
             bubble: Dict[str, float] = {}
@@ -271,7 +263,7 @@ class StepTimeline:
             )
         return rec
 
-    # -- readers (debug endpoints, bench) ---------------------------------
+    # -- readers (debug endpoints) ----------------------------------------
 
     def records(self) -> List[dict]:
         with self._lock:

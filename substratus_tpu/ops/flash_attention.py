@@ -55,7 +55,7 @@ DEFAULT_BLOCK_K = 256
 # dkv kernel loops over q blocks per kv block, so its sweet spot differs
 # from dq's; not measured since). None =
 # inherit (block_q, block_k); set via set_dkv_blocks() or the env var
-# SUBSTRATUS_FLASH_DKV_BLOCKS="bq,bk"; swept by tools/flash_dkv_tune.py.
+# SUBSTRATUS_FLASH_DKV_BLOCKS="bq,bk".
 _DKV_BLOCKS = None
 if os.environ.get("SUBSTRATUS_FLASH_DKV_BLOCKS"):
     _parts = os.environ["SUBSTRATUS_FLASH_DKV_BLOCKS"].split(",")

@@ -573,8 +573,6 @@ def main(argv=None) -> int:
                     help="serve /loadz + /metrics on this port (0 = "
                          "ephemeral; default off — batch runs need no "
                          "HTTP path)")
-    ap.add_argument("--step-floor-ms", type=float, default=0.0,
-                    help="simulated device-step floor (bench/tests)")
     ap.add_argument("--params", default="/content/params.json")
     args = ap.parse_args(argv)
 
@@ -652,7 +650,6 @@ def main(argv=None) -> int:
         ),
         kv_cache_dtype=params_json.get("kv_cache_dtype", "model"),
         kv_layout=params_json.get("kv_layout", "auto"),
-        step_floor_s=args.step_floor_ms / 1e3,
     )
 
     mesh = None

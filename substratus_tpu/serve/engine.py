@@ -276,15 +276,6 @@ class EngineConfig:
     # queueing beyond this many waiters. None = unbounded (legacy
     # behavior; serve.main defaults it to 4x max_batch).
     max_queue: Optional[int] = None
-    # Bench/smoke knob: minimum wall time per decode iteration AND per
-    # prefill chunk dispatch, simulating accelerator step latency on CPU
-    # hosts where the tiny model's math is instant (the control-plane
-    # analogue of multihost.TcpSync). With it, a CPU gateway bench
-    # measures what the routing tier controls — keeping N replicas
-    # concurrently busy — instead of the host's core count; the prefill
-    # floor makes prompt-vs-decode contention measurable (the effect
-    # disaggregation removes). 0 = off (production).
-    step_floor_s: float = 0.0
     # Disaggregated serving role (serve/disagg.py, ROADMAP item 3):
     # "both" = the monolithic engine (default); "prefill" = run chunked
     # prefill + first-token sampling, then export the request's KV pages
@@ -2286,7 +2277,7 @@ class Engine:
             with self.timeline.phase(
                 "prefill", request_id=rid, bucket=padded.shape[1],
                 chunk=(offset - start) // chunk, tokens=clen,
-            ) as ph:
+            ):
                 last_logits, cache, stats = fn(
                     params, cache, padded, offset, clen, block_table=bt_row,
                     lora=lora, adapter_ids=adapter_ids, **slot_kw,
@@ -2294,19 +2285,7 @@ class Engine:
             if stats is not None:
                 self._chunk_stats.append(stats)
             offset += clen
-            # Simulated device-step latency applies to prefill chunks
-            # too: on a real accelerator every chunk occupies the device,
-            # which is exactly the decode-stalling contention the
-            # disaggregated split removes (see EngineConfig).
-            self._floor_wait(ph.seconds)
         return last_logits, cache
-
-    def _floor_wait(self, spent_s: float) -> None:
-        """Sleep out what is left of the simulated device step
-        (EngineConfig.step_floor_s; 0 on a real accelerator)."""
-        if self.ec.step_floor_s > spent_s:
-            with self.timeline.phase("wait.floor"):
-                time.sleep(self.ec.step_floor_s - spent_s)
 
     def _finalize_admit(self, req: Request, slot: int, last_logits,
                         true_len: int) -> None:
@@ -2650,34 +2629,26 @@ class Engine:
             self._drain(step, wait)
 
     def _decode_step(self) -> None:
-        """One synchronous iteration: dispatch, model the device step's
-        latency, then drain immediately (the overlap-off path —
-        lockstep gangs and the forced-sync escape hatch). The simulated
-        device-step floor lands BEFORE the host read and the emits: on a
-        real accelerator tokens only exist once the device step
-        finishes, so a slot freed by an emit is admissible in the very
-        next iteration with no artificial dead time."""
+        """One synchronous iteration: dispatch, then drain immediately
+        (the overlap-off path — lockstep gangs and the forced-sync
+        escape hatch)."""
         # The compiling first launch stays out of phase="decode"
         # (substratus_serve_first_compile_seconds has it).
         with self.timeline.phase(
             "dispatch", observe=self._first_decode_done
-        ) as ph:
+        ):
             pending = self._dispatch_any()
         if pending is None:
             return
-        self._floor_wait(ph.seconds)
         with self.timeline.phase("drain"):
             self._drain_any(pending)
 
     def _step_overlapped(self) -> None:
         """One pipelined iteration: launch step N, then run step N-1's
-        host work while N occupies the device. On a real chip the
-        deferred np.asarray overlaps the transfer with compute via JAX
-        async dispatch; on CPU the step_floor_s sleep models the device
-        window — the floor discounts whatever host work ran under it,
-        so steady-state inter-token latency settles at
+        host work while N occupies the device: the deferred np.asarray
+        overlaps the transfer with compute via JAX async dispatch, so
+        steady-state inter-token latency settles at
         max(device_step, host_work) instead of their sum."""
-        t_step = time.perf_counter()
         # Dispatch FIRST, then pick up whatever is still pending: the
         # dispatch's capacity handling may _flush("preempt") the
         # previous step itself, and draining it again here would emit
@@ -2695,7 +2666,6 @@ class Engine:
                 METRICS.observe(
                     "substratus_serve_host_overlap_seconds", ph.seconds
                 )
-        self._floor_wait(time.perf_counter() - t_step)
 
     @staticmethod
     def _prompt_lookup(ctx, k: int, max_n: int = 3):
@@ -3221,7 +3191,6 @@ class Engine:
         tl.commit(
             admitted=admitted, active_slots=n_active,
             max_slots=self.ec.max_batch,
-            configured_floor_s=self.ec.step_floor_s,
         )
 
     def _loop(self):

@@ -83,10 +83,9 @@ class _TimedSync:
     """Shared broadcast timing for every sync transport: wall time lands
     in the shared registry (`substratus_serve_phase_seconds{phase=
     "broadcast"}`) and the last few thousand `(payload_bytes, seconds)`
-    samples stay on `timings`, so the gang bench (tools/engine_bench.py
-    --gang) reports wall-time percentiles — including the bucket-padded
-    overflow path a >=8k-token admission takes — without scraping
-    /metrics mid-run."""
+    samples stay on `timings`, so a caller can read wall-time
+    percentiles — including the bucket-padded overflow path a >=8k-token
+    admission takes — without scraping /metrics mid-run."""
 
     timings: "deque[tuple]"
 
@@ -162,9 +161,8 @@ class TcpSync(_TimedSync):
     length-prefixed message out to every follower; followers block on
     recv). The scheduler only ever sees the `broadcast` interface, so
     this is a drop-in StepSync for environments whose backend has no
-    multi-process collectives — notably CPU jaxlib, where the gang bench
-    (tools/engine_bench.py --gang --transport tcp) still measures a real
-    2-process lockstep gang: identical mirrored schedulers, a real
+    multi-process collectives — notably CPU jaxlib, where it still gives
+    a real 2-process lockstep gang: identical mirrored schedulers, a real
     inter-process hop per iteration, only the ICI transfer time missing.
     Production multi-host serving stays on StepSync (the XLA collective
     needs no extra network plumbing and rides the proven fabric)."""

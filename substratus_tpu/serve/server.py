@@ -800,9 +800,6 @@ def build_app(state: ServerState) -> web.Application:
         body["otherData"]["floor_estimate_s"] = (
             round(floor, 6) if floor is not None else None
         )
-        body["otherData"]["configured_step_floor_s"] = (
-            state.engine.ec.step_floor_s
-        )
         return web.json_response(body)
 
     @routes.get("/debug/slowz")

@@ -21,6 +21,9 @@ jax.config.update("jax_platforms", "cpu")
 import pytest  # noqa: E402
 from substratus_tpu.ops.kvcache import insert_prefill
 
+# The TPU compiler a `v5e` test loads would log under /tmp otherwise.
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
 
 def pytest_configure(config):
     # tier-1 runs `-m 'not slow'` (ROADMAP.md "Tier-1 verify")
@@ -70,6 +73,33 @@ def pallas_interpret(monkeypatch):
         return compiled(*args, **kwargs)
 
     monkeypatch.setattr(pl, "pallas_call", interpreted)
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """The four described chips of a v5e:2x2 host, with the persistent
+    compilation cache off: an executable compiled for a described device
+    is written there but cannot be read back without one, and the next
+    compile would warn. For the `test_chip_compile*.py` files alone, and
+    never autouse: the worker that runs one of them loads the TPU's
+    library and keeps it. Several workers do so side by side only under
+    `ALLOW_MULTIPLE_LIBTPU_LOAD=1`, which the tier-1 command sets; without
+    it run those files in one process."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 — no libtpu, no topology: skip
+        pytest.skip(f"cannot describe a v5e topology here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield topo.devices
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
 
 
 @pytest.fixture(scope="session")

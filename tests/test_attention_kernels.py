@@ -63,8 +63,8 @@ def test_flash_backward_matches_reference(kh, causal):
 
 def test_flash_backward_dkv_block_override_parity():
     """Retuning the dkv grid independently (set_dkv_blocks /
-    SUBSTRATUS_FLASH_DKV_BLOCKS, swept by tools/flash_dkv_tune.py) must
-    not change gradients — only the schedule."""
+    SUBSTRATUS_FLASH_DKV_BLOCKS) must not change gradients — only the
+    schedule."""
     from substratus_tpu.ops.flash_attention import set_dkv_blocks
 
     q, k, v = _qkv(s=128, kh=2)

@@ -14,12 +14,12 @@ import numpy as np
 import pytest
 
 from benchmarks.reference import brumby as R
+from family_harness import Family, plain, seeded_params, submit_all
 from substratus_tpu.models import brumby as M
 from substratus_tpu.models import registry
 from substratus_tpu.observability.metrics import METRICS
 from substratus_tpu.ops import kvcache, retention
-from substratus_tpu.ops.quant import QTensor, quantize_params
-from substratus_tpu.serve.engine import Engine, EngineConfig, Request
+from substratus_tpu.serve.engine import Engine, EngineConfig
 
 CFG = M.CONFIGS["tiny-brumby"].replace(dtype=jnp.float32)
 CHUNK, PAGE, SLOTS = 16, 4, 3
@@ -28,6 +28,9 @@ CHUNK, PAGE, SLOTS = 16, 4, 3
 # order alone (measured 9e-6 on logits of magnitude 3; the limit leaves a
 # factor of five). w8a8 reads 2e-2, bfloat16 3e-2.
 TOL = 5e-5
+# The family reads no page: prefill and decode are handed no block table.
+F = Family(M, CFG, chunk=CHUNK, page=PAGE, slots=SLOTS)
+prefill, decode, serve = F.prefill, F.decode, F.serve
 
 
 def cfg_dict(cfg: M.BrumbyConfig, **over):
@@ -45,21 +48,12 @@ def cfg_dict(cfg: M.BrumbyConfig, **over):
     return d
 
 
-def plain(tree):
-    """The program's tree as the harness's: QTensor -> {"q", "scale"}."""
-    if isinstance(tree, QTensor):
-        return {"q": tree.q, "scale": tree.scale}
-    if isinstance(tree, dict):
-        return {k: plain(v) for k, v in tree.items()}
-    return tree
-
-
 @pytest.fixture(scope="module")
 def params():
-    p = M.init_params(CFG, jax.random.key(0))
+    p = seeded_params(M, CFG)
     b = np.asarray(p["layers"]["b_gamma"])
     assert b.dtype == np.float32 and 3 <= b.min() and b.max() <= 7
-    return quantize_params(p, M.quant_contracting(CFG))
+    return p
 
 
 @pytest.fixture(scope="module")
@@ -70,40 +64,6 @@ def tokens():
 
 def new_cache(cfg=CFG, slots=SLOTS):
     return M.init_paged_cache(cfg, 8, PAGE, slots=slots)
-
-
-def prefill(params, cfg, cache, toks, slot, chunk=CHUNK, start=0):
-    """Chunks as serve/engine.py::_chunk_prefill_jit cuts them (right-padded
-    to the chunk, padded positions clamped one past the prompt), through
-    the model's own forward: every real row's logits."""
-    rows = []
-    for off in range(start, len(toks), chunk):
-        part = toks[off:off + chunk]
-        n = len(part)
-        padded = np.zeros((1, chunk), np.int32)
-        padded[0, :n] = part
-        pos = np.minimum(off + np.arange(chunk), off + n)[None]
-        logits, cache = M.forward(
-            params, jnp.asarray(padded), cfg, positions=jnp.asarray(pos),
-            cache=cache, block_table=jnp.zeros((1, 4), jnp.int32),
-            slots=jnp.asarray([slot]), valid=jnp.arange(chunk)[None] < n)
-        rows.append(np.asarray(logits[0, :n]))
-    return np.concatenate(rows), cache
-
-
-def decode(params, cfg, cache, tok, pos, slot, slots=SLOTS):
-    """One decode step of a batch in which only `slot` is live."""
-    toks = np.zeros((slots,), np.int32)
-    toks[slot] = tok
-    posv = np.zeros((slots,), np.int32)
-    posv[slot] = pos
-    live = np.arange(slots) == slot
-    logits, cache = M.forward(
-        params, jnp.asarray(toks)[:, None], cfg,
-        positions=jnp.asarray(posv)[:, None], cache=cache,
-        block_table=jnp.zeros((slots, 4), jnp.int32),
-        valid=jnp.asarray(live)[:, None])
-    return np.asarray(logits[slot, 0]), cache
 
 
 def reference_logits(params, cfg, toks):
@@ -118,7 +78,7 @@ def test_forward_matches_the_reference(params, tokens):
     """The whole sequence at once, no cache (the attention form in both):
     logits of every row."""
     ref = reference_logits(params, CFG, tokens[:40])
-    got, kv = M.forward(params, jnp.asarray(tokens[:40])[None], CFG)
+    got, kv = F.forward(params, jnp.asarray(tokens[:40])[None], CFG)
     assert kv == {}
     assert np.abs(np.asarray(got[0]) - ref).max() < TOL
     assert np.std(ref) > 0.3  # the logits are not degenerate
@@ -139,7 +99,7 @@ def test_chunked_prefill_then_decode_matches_the_reference(
     got, cache = prefill(params, CFG, cache, tokens[:prompt_len], slot)
     assert np.abs(got - ref[:prompt_len]).max() < TOL
     for pos in range(prompt_len, n):
-        row, cache = decode(params, CFG, cache, tokens[pos], pos, slot)
+        row, cache, _ = decode(params, CFG, cache, tokens[pos], pos, slot)
         assert np.abs(row - ref[pos]).max() < TOL, pos
 
 
@@ -160,7 +120,7 @@ def test_decode_step_is_forward_for_one_token_a_slot(params, tokens):
     """The family's jitted decode_step (row i = slot i, cache donated)
     gives the logits of the same step through forward."""
     _, cache = prefill(params, CFG, new_cache(), tokens[:21], 0)
-    want, cache = decode(params, CFG, cache, tokens[21], 21, 0)
+    want, cache, _ = decode(params, CFG, cache, tokens[21], 21, 0)
     _, cache = prefill(params, CFG, cache, tokens[:21], 0)
     got, cache = M.decode_step(
         params, cache, jnp.asarray([tokens[21], 0, 0], jnp.int32),
@@ -188,7 +148,7 @@ def test_an_idle_row_and_a_padded_tail_leave_the_state(params, tokens):
     _, cache = prefill(params, CFG, cache, tokens[5:30], 2)
     _, cache = prefill(params, CFG, cache, tokens[9:22], 1)
     before = {n: np.asarray(a) for n, a in cache.items()}
-    _, cache = decode(params, CFG, cache, tokens[22], 13, 1)
+    _, cache, _ = decode(params, CFG, cache, tokens[22], 13, 1)
     for name in (kvcache.RET_S, kvcache.RET_Z):
         after = np.asarray(cache[name])
         assert np.array_equal(after[:, 0], before[name][:, 0])
@@ -211,30 +171,6 @@ def test_an_idle_row_and_a_padded_tail_leave_the_state(params, tokens):
 
 
 # -- (b) through the engine ------------------------------------------------------
-
-def serve(params, prompts, max_tokens, **ec):
-    ec = {"max_batch": SLOTS, "max_seq_len": 96, "max_prefill_len": CHUNK,
-          "page_size": PAGE, **ec}
-    eng = Engine(CFG, params, EngineConfig(**ec), model=M)
-    eng.start()
-    outs = submit_all(eng, prompts, max_tokens)
-    eng.stop()
-    assert eng.error is None
-    return outs, eng
-
-
-def submit_all(eng, prompts, max_tokens):
-    reqs = [eng.submit(Request(prompt_tokens=[int(t) for t in p],
-                               max_tokens=max_tokens, temperature=0.0,
-                               eos_token_id=-1)) for p in prompts]
-    outs = []
-    for r in reqs:
-        ids = []
-        while (t := r.out.get(timeout=300)) is not None:
-            ids.append(t)
-        outs.append(ids)
-    return outs
-
 
 def test_the_engine_serves_the_family_through_submit(params, tokens):
     """Engine.submit/start, chunked prefill, jit_decode, overlap: every
@@ -287,8 +223,7 @@ def test_the_kernel_path_serves_the_tokens_of_the_step_path(
     cfg = M.BrumbyConfig(
         vocab_size=256, dim=64, n_layers=2, n_heads=2, n_kv_heads=1,
         head_dim=128, hidden_dim=128, max_seq_len=64, dtype=jnp.float32)
-    wide = quantize_params(M.init_params(cfg, jax.random.key(2)),
-                           M.quant_contracting(cfg))
+    wide = seeded_params(M, cfg, 2)
     toks = np.asarray(jax.random.randint(jax.random.key(4), (40,), 0, 256))
     prompts = [toks[:21], toks[25:34]]
 
@@ -399,8 +334,8 @@ def test_the_gate_shift_moves_the_gate_and_nothing_else(params, tokens):
     layers["b_gamma"] = layers["b_gamma"] - 5.0
     moved = {**p, "layers": layers}
     toks = jnp.asarray(tokens[:24])[None]
-    want, _ = M.forward(params, toks, CFG)
-    got, _ = M.forward(moved, toks, CFG.replace(gate_shift=5.0))
+    want, _ = F.forward(params, toks, CFG)
+    got, _ = F.forward(moved, toks, CFG.replace(gate_shift=5.0))
     assert np.abs(np.asarray(got - want)).max() < TOL
     ref = np.asarray(R.logits_at(
         plain(moved), cfg_dict(CFG.replace(gate_shift=5.0)),

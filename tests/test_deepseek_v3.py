@@ -9,6 +9,7 @@ positions beyond which YaRN's interpolation shows.
 Everything here runs in float32 with int8 weights (the precision the
 benchmark's cell states, less bfloat16 rounding), so the tolerances are
 those of float32 summation order, and a lower precision fails them."""
+import functools
 import math
 
 import jax
@@ -18,13 +19,14 @@ import pytest
 
 from benchmarks.reference import deepseek_v3 as R
 from benchmarks.reference import glm_moe_dsa as R_DSA
+from family_harness import Family, plain, seeded_params
+from family_harness import table as _table
 from substratus_tpu.models import deepseek_v3 as M
 from substratus_tpu.models import hybrid
 from substratus_tpu.models import registry
 from substratus_tpu.ops import kvcache
 from substratus_tpu.ops import latent_attention as LA
 from substratus_tpu.ops.basics import rope_freqs, yarn_mscale
-from substratus_tpu.ops.quant import QTensor, quantize_params
 from substratus_tpu.serve.engine import Engine, EngineConfig, Request
 
 CFG = M.CONFIGS["tiny-deepseek-v3"].replace(dtype=jnp.float32)
@@ -43,6 +45,11 @@ PAGES = pytest.mark.parametrize("page", [PAGE, None], ids=["page4", "family"])
 # of the same products (measured 5e-6 on logits of magnitude 4; the limit
 # leaves a factor of six). w8a8 reads 2e-2, bfloat16 1e-2.
 TOL = 3e-5
+F = Family(M, CFG, chunk=CHUNK, page=PAGE)
+# `_forward`: the model's own forward, compiled once a shape.
+_forward, prefill, decode, serve = F.forward, F.prefill, F.decode, F.serve
+table = functools.partial(_table, max_pages=24)
+params_of = functools.partial(seeded_params, M)
 
 
 def cfg_dict(cfg: M.DeepseekV3Config, **over):
@@ -88,27 +95,6 @@ def reference_of(cfg):
     return R_DSA if cfg.index_n_heads else R
 
 
-def plain(tree):
-    """The program's tree as the harness's: QTensor -> {"q", "scale"}."""
-    if isinstance(tree, QTensor):
-        return {"q": tree.q, "scale": tree.scale}
-    if isinstance(tree, dict):
-        return {k: plain(v) for k, v in tree.items()}
-    return tree
-
-
-_PARAMS = {}
-
-
-def params_of(cfg):
-    """The family's tree from key 0, int8 where the benchmark has int8."""
-    if cfg not in _PARAMS:  # one program: leaf by leaf eagerly takes 20 s
-        _PARAMS[cfg] = jax.jit(lambda key: quantize_params(
-            M.init_params(cfg, key), M.quant_contracting(cfg)))(
-                jax.random.key(0))
-    return _PARAMS[cfg]
-
-
 @pytest.fixture(scope="module")
 def params():
     return params_of(CFG)
@@ -131,54 +117,6 @@ def new_cache(cfg=CFG, pages=80):
     return M.init_paged_cache(cfg, pages, PAGE)
 
 
-def table(slots, max_pages=24):
-    """Slot s owns pages 1 + s * max_pages ..: page 0 is the trash page."""
-    return (1 + np.arange(slots * max_pages, dtype=np.int32)
-            .reshape(slots, max_pages))
-
-
-# The model's own forward, compiled once a shape (eagerly every call would
-# trace and compile its layer scan again).
-_forward = jax.jit(M.forward, static_argnums=(2,))
-
-
-def prefill(params, cfg, cache, toks, slot, bt, chunk=CHUNK, start=0):
-    """Chunks as serve/engine.py::_chunk_prefill_jit cuts them (right-padded
-    to the chunk, padded positions clamped one past the prompt), through
-    the model's own forward: every real row's logits."""
-    rows = []
-    for off in range(start, len(toks), chunk):
-        part = toks[off:off + chunk]
-        n = len(part)
-        padded = np.zeros((1, chunk), np.int32)
-        padded[0, :n] = part
-        pos = np.minimum(off + np.arange(chunk), off + n)[None]
-        logits, cache = _forward(
-            params, jnp.asarray(padded), cfg, positions=jnp.asarray(pos),
-            cache=cache, block_table=jnp.asarray(bt[slot:slot + 1]),
-            valid=jnp.arange(chunk)[None] < n)
-        M.step_counters(cache)
-        rows.append(np.asarray(logits[0, :n]))
-    return np.concatenate(rows), cache
-
-
-def decode(params, cfg, cache, tok, pos, slot, bt):
-    """One decode step of a batch in which only `slot` is live."""
-    b = bt.shape[0]
-    toks = np.zeros((b,), np.int32)
-    toks[slot] = tok
-    posv = np.zeros((b,), np.int32)
-    posv[slot] = pos
-    live = np.arange(b) == slot
-    logits, cache = _forward(
-        params, jnp.asarray(toks)[:, None], cfg,
-        positions=jnp.asarray(posv)[:, None], cache=cache,
-        block_table=jnp.asarray(np.where(live[:, None], bt, 0)),
-        valid=jnp.asarray(live)[:, None])
-    stats = M.step_counters(cache)
-    return np.asarray(logits[slot, 0]), cache, stats
-
-
 def reference_logits(params, cfg, toks, **kw):
     return np.asarray(reference_of(cfg).logits_at(
         plain(params), cfg_dict(cfg), list(toks), list(range(len(toks))),
@@ -191,7 +129,7 @@ def test_forward_matches_the_reference(params, tokens):
     """No cache: the expanded form over the whole sequence, 70 tokens (past
     the 32 positions YaRN stretches), logits at every position."""
     toks = tokens[:70]
-    got, left = M.forward(params, jnp.asarray(toks)[None], CFG)
+    got, left = F.forward(params, jnp.asarray(toks)[None], CFG)
     assert left == {}
     ref = reference_logits(params, CFG, toks)
     assert np.abs(np.asarray(got[0]) - ref).max() < TOL
@@ -285,7 +223,7 @@ def test_a_stored_row_wider_than_the_logical_one_changes_nothing(
 def test_a_lower_precision_fails_the_tolerance(params, tokens, lower):
     cfg = (CFG.replace(quant_activations=True) if lower == "w8a8"
            else CFG.replace(dtype=jnp.bfloat16))
-    got, _ = M.forward(params, jnp.asarray(tokens[:40])[None], cfg)
+    got, _ = F.forward(params, jnp.asarray(tokens[:40])[None], cfg)
     ref = reference_logits(params, CFG, tokens[:40])
     assert np.abs(np.asarray(got[0]) - ref).max() > 10 * TOL
 
@@ -632,25 +570,6 @@ def test_without_yarn_the_table_is_todays_bit_for_bit():
 
 
 # -- (f) the engine ---------------------------------------------------------------
-
-def serve(params, prompts, max_tokens, cfg=CFG, **ec):
-    ec = {"max_batch": 3, "max_seq_len": 96, "max_prefill_len": CHUNK,
-          "page_size": PAGE, **ec}
-    eng = Engine(cfg, params, EngineConfig(**ec), model=M)
-    eng.start()
-    reqs = [eng.submit(Request(prompt_tokens=[int(t) for t in p],
-                               max_tokens=max_tokens, temperature=0.0,
-                               eos_token_id=-1)) for p in prompts]
-    outs = []
-    for r in reqs:
-        ids = []
-        while (t := r.out.get(timeout=300)) is not None:
-            ids.append(t)
-        outs.append(ids)
-    eng.stop()
-    assert eng.error is None
-    return outs, eng
-
 
 @PAGES
 def test_the_engine_serves_the_family_through_submit(params, tokens, page):

@@ -34,8 +34,7 @@ def test_phases_accumulate_into_one_iteration_record():
             with tl.phase("emit"):
                 pass
         tl.pool_dry()
-        rec = tl.commit(admitted=0, active_slots=3, max_slots=4,
-                        configured_floor_s=1e-9)
+        rec = tl.commit(admitted=0, active_slots=3, max_slots=4)
     assert rec["flush_reasons"] == ["preempt"] and rec["pool_dry"] is True
     assert rec["dispatch_s"] >= rec["flush_s"] > 0  # a flush nests in it
     assert rec["drain_s"] > 0 and 0 < rec["drain_off_s"] <= rec["wall_s"]

@@ -13,11 +13,11 @@ import numpy as np
 import pytest
 
 from benchmarks.reference import exaone_moe as R
+from family_harness import Family, plain, seeded_params, table
 from substratus_tpu.models import exaone_moe as M
 from substratus_tpu.models import hybrid
 from substratus_tpu.models import registry
-from substratus_tpu.ops.quant import QTensor, quantize_params
-from substratus_tpu.serve.engine import Engine, EngineConfig, Request
+from substratus_tpu.serve.engine import Engine, EngineConfig
 
 CFG = M.CONFIGS["tiny-exaone-moe"].replace(dtype=jnp.float32)
 W, CHUNK, PAGE = CFG.sliding_window, 16, 4
@@ -25,6 +25,8 @@ W, CHUNK, PAGE = CFG.sliding_window, 16, 4
 # differ by summation order alone (measured 6e-6 on logits of magnitude 4;
 # the limit leaves a factor of five). w8a8 reads 2e-2, bfloat16 1e-2.
 TOL = 3e-5
+F = Family(M, CFG, chunk=CHUNK, page=PAGE)
+prefill, decode, serve = F.prefill, F.decode, F.serve
 
 
 def cfg_dict(cfg: M.ExaoneMoeConfig, **over):
@@ -50,19 +52,9 @@ def cfg_dict(cfg: M.ExaoneMoeConfig, **over):
     return d
 
 
-def plain(tree):
-    """The program's tree as the harness's: QTensor -> {"q", "scale"}."""
-    if isinstance(tree, QTensor):
-        return {"q": tree.q, "scale": tree.scale}
-    if isinstance(tree, dict):
-        return {k: plain(v) for k, v in tree.items()}
-    return tree
-
-
 @pytest.fixture(scope="module")
 def params():
-    p = M.init_params(CFG, jax.random.key(0))
-    return quantize_params(p, M.quant_contracting(CFG))
+    return seeded_params(M, CFG)
 
 
 @pytest.fixture(scope="module")
@@ -73,49 +65,6 @@ def tokens():
 
 def new_cache(cfg, slots=3, pages=64):
     return M.init_paged_cache(cfg, pages, PAGE, slots=slots)
-
-
-def table(slots, max_pages=16):
-    """Slot s owns pages 1 + s * max_pages ..: page 0 is the trash page."""
-    return (1 + np.arange(slots * max_pages, dtype=np.int32)
-            .reshape(slots, max_pages))
-
-
-def prefill(params, cfg, cache, toks, slot, bt, chunk=CHUNK, start=0):
-    """Chunks as serve/engine.py::_chunk_prefill_jit cuts them (right-padded
-    to the chunk, padded positions clamped one past the prompt), through
-    the model's own forward: every real row's logits."""
-    rows = []
-    for off in range(start, len(toks), chunk):
-        part = toks[off:off + chunk]
-        n = len(part)
-        padded = np.zeros((1, chunk), np.int32)
-        padded[0, :n] = part
-        pos = np.minimum(off + np.arange(chunk), off + n)[None]
-        logits, cache = M.forward(
-            params, jnp.asarray(padded), cfg, positions=jnp.asarray(pos),
-            cache=cache, block_table=jnp.asarray(bt[slot:slot + 1]),
-            slots=jnp.asarray([slot]), valid=jnp.arange(chunk)[None] < n)
-        M.step_counters(cache)
-        rows.append(np.asarray(logits[0, :n]))
-    return np.concatenate(rows), cache
-
-
-def decode(params, cfg, cache, tok, pos, slot, bt):
-    """One decode step of a batch in which only `slot` is live."""
-    b = bt.shape[0]
-    toks = np.zeros((b,), np.int32)
-    toks[slot] = tok
-    posv = np.zeros((b,), np.int32)
-    posv[slot] = pos
-    live = np.arange(b) == slot
-    logits, cache = M.forward(
-        params, jnp.asarray(toks)[:, None], cfg,
-        positions=jnp.asarray(posv)[:, None], cache=cache,
-        block_table=jnp.asarray(np.where(live[:, None], bt, 0)),
-        valid=jnp.asarray(live)[:, None])
-    stats = M.step_counters(cache)
-    return np.asarray(logits[slot, 0]), cache, stats
 
 
 def reference_logits(params, cfg, toks):
@@ -179,25 +128,6 @@ def test_an_int8_cache_is_refused(params):
         Engine(CFG, params, EngineConfig(kv_cache_dtype="int8"), model=M)
 
 
-def serve(params, prompts, max_tokens, **ec):
-    ec = {"max_batch": 3, "max_seq_len": 96, "max_prefill_len": CHUNK,
-          "page_size": PAGE, **ec}
-    eng = Engine(CFG, params, EngineConfig(**ec), model=M)
-    eng.start()
-    reqs = [eng.submit(Request(prompt_tokens=[int(t) for t in p],
-                               max_tokens=max_tokens, temperature=0.0,
-                               eos_token_id=-1)) for p in prompts]
-    outs = []
-    for r in reqs:
-        ids = []
-        while (t := r.out.get(timeout=300)) is not None:
-            ids.append(t)
-        outs.append(ids)
-    eng.stop()
-    assert eng.error is None
-    return outs, eng
-
-
 def test_the_engine_serves_the_family_through_submit(params, tokens):
     """Engine.submit/start, chunked prefill, jit_decode, overlap: every
     served token is the reference's best at its position (float32: a gap
@@ -257,8 +187,7 @@ def test_layer_kinds_come_from_the_config(params, tokens):
                      M.WINDOW),
         mlp_layer_types=(M.DENSE, M.DENSE) + (M.SPARSE,) * 4)
     assert M.layer_plan(cfg) == (2, 2, 2)
-    p = quantize_params(M.init_params(cfg, jax.random.key(2)),
-                        M.quant_contracting(cfg))
+    p = seeded_params(M, cfg, 2)
     ref = reference_logits(p, cfg, tokens[:30])
     got, cache = prefill(p, cfg, new_cache(cfg), tokens[:27], 2, table(3))
     assert np.abs(got - ref[:27]).max() < TOL
@@ -334,8 +263,8 @@ def test_a_share_changes_nothing_outside_the_expert_sum(params, tokens):
         for name in ("w_gate", "w_up", "w_down"):
             share["moe"][name] = jax.tree.map(
                 lambda a: a[:, first:first + 8], params["moe"][name])
-        outs.append(np.asarray(M.forward(share, toks, cfg)[0]))
-    whole = np.asarray(M.forward(params, toks, CFG)[0])
+        outs.append(np.asarray(F.forward(share, toks, cfg)[0]))
+    whole = np.asarray(F.forward(params, toks, CFG)[0])
     assert np.abs(outs[0] - whole).max() > 1e-2  # a share is not the model
 
 

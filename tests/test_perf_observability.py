@@ -1,16 +1,12 @@
 """Performance-observability subsystem (PR 3).
 
-The bench scripts measure or fail (no default peak, no quiet exit), the
-gang bench measures a real 2-process lockstep gang, phase-level timings
-land in the shared registry and surface on /debug/perfz, and the
-bench_compare regression gate actually gates.
+A device kind with no peak is an error and not a default, phase-level
+timings land in the shared registry and surface on /debug/perfz, and the
+lockstep transports carry what they are given.
 """
 import asyncio
 import json
-import os
 import re
-import subprocess
-import sys
 import threading
 
 import jax.numpy as jnp
@@ -21,37 +17,14 @@ from substratus_tpu.observability.metrics import (
     quantile_from_buckets,
 )
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-BENCH_TRAIN = os.path.join(REPO, "tools", "bench_train.py")
-BENCH_COMPARE = os.path.join(REPO, "hack", "bench_compare.py")
-
-
-# --- the bench scripts measure or fail ---------------------------------------
-
-def _load_bench():
-    import importlib.util
-
-    spec = importlib.util.spec_from_file_location(
-        "bench_under_test", os.path.join(REPO, "bench.py")
-    )
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    return bench
-
 
 def test_unknown_device_kind_is_an_error_not_a_default_peak(monkeypatch):
-    """bench.py and train/telemetry.py divide by the peak of the device JAX
-    reports. A TPU that is not in the table raises; only the CPU (shape
-    checks, test meshes) runs without a utilization."""
+    """train/telemetry.py divides by the peak of the device JAX reports.
+    A TPU that is not in the table raises; only the CPU (shape checks,
+    test meshes) runs without a utilization."""
     import jax
 
     from substratus_tpu.train import telemetry
-
-    bench = _load_bench()
-    assert bench.peak_for("tpu", "TPU v5 lite") == (197e12, 819e9)
-    assert bench.peak_for("cpu", "cpu") == (None, None)
-    with pytest.raises(KeyError, match="TPU v9"):
-        bench.peak_for("tpu", "TPU v9 imaginary")
 
     assert telemetry.device_peak_flops() is None  # the 8-device CPU mesh
 
@@ -64,96 +37,6 @@ def test_unknown_device_kind_is_an_error_not_a_default_peak(monkeypatch):
         telemetry.device_peak_flops()
     FakeTpu.device_kind = "TPU v5 lite"
     assert telemetry.device_peak_flops() == 4 * 197e12
-
-
-@pytest.mark.parametrize("script", ["bench.py", "tools/bench_train.py"])
-def test_bench_scripts_have_no_probe_ladder_or_quiet_exit(script):
-    """What the two scripts lost with the device transport they were
-    built around stays lost: no backend probe budget, simulated-hang knob,
-    fallback tier, default peak, latency subtraction or exit 0 on failure.
-    A failed measurement is an exception and a non-zero exit."""
-    src = open(os.path.join(REPO, script)).read()
-    for gone in ("probe_backend", "probe-budget", "SUBSTRATUS_BENCH_SIM",
-                 "no-fallback", "tiers", "DEFAULT_PEAK", "rpc_latency",
-                 "emit_failure", "hard_sync", '"auto"', "run_child"):
-        assert gone not in src, f"{script} still has {gone!r}"
-    proc = subprocess.run(
-        [sys.executable, os.path.join(REPO, script), "--config", "nope"],
-        capture_output=True, text=True, timeout=120,
-    )
-    assert proc.returncode != 0 and not proc.stdout.strip()
-
-
-def test_bench_train_reads_example_yaml_shape():
-    """batch/seq/lora_rank default to the 7B finetune example CR — the
-    bench measures the exact workload the Model CR runs."""
-    sys.path.insert(0, os.path.join(REPO, "tools"))
-    try:
-        import bench_train
-    finally:
-        sys.path.pop(0)
-    d = bench_train.example_defaults()
-    # Must agree with examples/llama2-7b/finetuned-model.yaml.
-    assert d == {"batch_size": 8, "seq_len": 1024, "lora_rank": 16}
-
-
-# --- bench_compare regression gate ------------------------------------------
-
-def test_bench_compare_self_test_and_gate(tmp_path):
-    """The synthetic-regression self-test passes, a 20% regression against
-    a real history file fails the CLI, and an unchanged capture passes."""
-    r = subprocess.run(
-        [sys.executable, BENCH_COMPARE, "--self-test"],
-        capture_output=True, text=True,
-    )
-    assert r.returncode == 0, r.stderr
-
-    hist = tmp_path / "BENCH_r90.json"
-    hist.write_text(json.dumps({
-        "n": 90, "rc": 0,
-        "parsed": {"metric": "m_throughput", "value": 100.0,
-                   "unit": "tokens/sec/chip"},
-    }))
-
-    def gate(value):
-        return subprocess.run(
-            [sys.executable, BENCH_COMPARE, "--new", "-",
-             "--history", str(hist)],
-            input=json.dumps({"metric": "m_throughput", "value": value,
-                              "unit": "tokens/sec/chip"}),
-            capture_output=True, text=True,
-        )
-
-    bad = gate(80.0)
-    assert bad.returncode == 1 and "regression" in bad.stderr
-    good = gate(100.0)
-    assert good.returncode == 0, good.stderr
-
-
-def test_bench_compare_accepts_driver_wrapper_history(tmp_path):
-    """History files in the driver's wrapper shape — a null-value round
-    and a round with a parsed capture — load cleanly: the gate can't
-    reject its own history."""
-    sys.path.insert(0, os.path.join(REPO, "hack"))
-    try:
-        import bench_compare
-    finally:
-        sys.path.pop(0)
-    (tmp_path / "BENCH_r01.json").write_text(json.dumps({
-        "n": 1, "rc": 0,
-        "parsed": {"metric": "m_throughput", "value": None,
-                   "unit": "tokens/sec/chip", "error": "no backend"},
-    }))
-    (tmp_path / "BENCH_r02.json").write_text(json.dumps({
-        "n": 2, "rc": 0,
-        "parsed": {"metric": "m_throughput", "value": 100.0,
-                   "unit": "tokens/sec/chip"},
-    }))
-    history, problems = bench_compare.load_history(
-        [str(tmp_path / "BENCH_r0*.json")]
-    )
-    assert problems == [], problems
-    assert history["m_throughput"][1] == 100.0
 
 
 # --- quantile helper --------------------------------------------------------

@@ -68,7 +68,9 @@ def _engine(config: str, layout: str) -> Engine:
     model = next((m for m in (exaone_moe, lfm2_moe, deepseek_v3)
                   if config in m.CONFIGS), llama)
     cfg = model.CONFIGS[config].replace(dtype=jnp.float32)
-    params = model.init_params(cfg, jax.random.key(0))
+    # one program: eagerly an expert family's tree is built leaf by leaf
+    params = jax.jit(lambda key: model.init_params(cfg, key))(
+        jax.random.key(0))
     return Engine(cfg, params, EngineConfig(
         max_batch=2, max_seq_len=64, max_prefill_len=16, kv_layout=layout))
 

@@ -6,6 +6,8 @@ form their dot reads from the stack (models/llama.py: q, k, v heads first,
 contracted dim last; models/exaone_moe.py views its four stacks so at the
 top of `forward`). Checkpoints, training and LoRA keep the published form:
 an engine given it serves the tokens `forward` gives on it."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -33,18 +35,29 @@ def _cfg(family):
     return model, model.CONFIGS[name].replace(dtype=jnp.float32)
 
 
+@functools.cache
+def _builder(family, kind):
+    """One program a family and kind: leaf by leaf, eagerly, an expert
+    family's tree takes 15 s."""
+    model, cfg = _cfg(family)
+
+    def build(key):
+        params = model.init_params(cfg, key)
+        if kind in ("int8", "int4"):
+            fn = quantize_params if kind == "int8" else quantize4_params
+            return fn(params, model.quant_contracting(cfg))
+        return jax.tree.map(
+            lambda a: a.astype(jnp.bfloat16)
+            if jnp.issubdtype(a.dtype, jnp.floating) else a, params)
+
+    return jax.jit(build)
+
+
 def _tree(family, kind, seed=0):
     """The published tree: `int8` as ops.quant.quantize_params makes it,
     `int4` as ops.quant4.quantize4_params does (the benchmark's control),
-    `dense` with bfloat16 leaves."""
-    model, cfg = _cfg(family)
-    params = model.init_params(cfg, jax.random.key(seed))
-    if kind in ("int8", "int4"):
-        fn = quantize_params if kind == "int8" else quantize4_params
-        return fn(params, model.quant_contracting(cfg))
-    return jax.tree.map(
-        lambda a: a.astype(jnp.bfloat16)
-        if jnp.issubdtype(a.dtype, jnp.floating) else a, params)
+    `dense` with bfloat16 leaves. A new tree every call."""
+    return _builder(family, kind)(jax.random.key(seed))
 
 
 def _engine(family, params, **ec):

@@ -461,6 +461,121 @@ def convert_brumby_state_dict(sd: Mapping[str, Any], cfg: Any,
     )
 
 
+def config_from_hf_granitemoehybrid(hf_cfg: Any):
+    """A transformers `granitemoehybrid` config.json with no experts
+    (Granite-4.0-H-Micro) -> GraniteHybridConfig, key for key. What the
+    mixer's published code leaves to be read off it is
+    models/granitemoehybrid.py's `assumed`."""
+    from substratus_tpu.models.granitemoehybrid import GraniteHybridConfig
+
+    get = lambda name, default=None: getattr(hf_cfg, name, default)
+    if (get("num_local_experts", 0) or get("attention_bias", False)
+            or get("mamba_proj_bias", False)
+            or not get("mamba_conv_bias", True)
+            or get("position_embedding_type", "nope") != "nope"
+            or get("hidden_act", "silu") != "silu"
+            or get("normalization_function", "rmsnorm") != "rmsnorm"):
+        raise NotImplementedError(
+            "granitemoehybrid: routed experts, attention_bias, "
+            "mamba_proj_bias, a convolution without its bias, rotary "
+            "positions and another activation or norm are not written")
+    d = hf_cfg.hidden_size
+    if get("mamba_n_heads") * get("mamba_d_head") != get("mamba_expand", 2) * d:
+        raise NotImplementedError(
+            "granitemoehybrid: mamba_n_heads x mamba_d_head is not "
+            "mamba_expand x hidden_size")
+    n = hf_cfg.num_hidden_layers
+    return GraniteHybridConfig(
+        vocab_size=hf_cfg.vocab_size,
+        dim=d,
+        n_layers=n,
+        n_heads=hf_cfg.num_attention_heads,
+        n_kv_heads=get("num_key_value_heads") or hf_cfg.num_attention_heads,
+        head_dim=get("head_dim") or d // hf_cfg.num_attention_heads,
+        hidden_dim=get("shared_intermediate_size"),
+        layer_types=tuple(hf_cfg.layer_types[:n]),
+        mamba_n_heads=get("mamba_n_heads"),
+        mamba_d_head=get("mamba_d_head"),
+        mamba_d_state=get("mamba_d_state"),
+        mamba_d_conv=get("mamba_d_conv"),
+        mamba_n_groups=get("mamba_n_groups", 1),
+        embedding_multiplier=float(get("embedding_multiplier", 1.0)),
+        residual_multiplier=float(get("residual_multiplier", 1.0)),
+        attention_multiplier=float(get("attention_multiplier")),
+        logits_scaling=float(get("logits_scaling", 1.0)),
+        norm_eps=get("rms_norm_eps", 1e-5),
+        max_seq_len=get("max_position_embeddings", 131072),
+        tie_embeddings=bool(get("tie_word_embeddings", True)),
+    )
+
+
+def convert_granitemoehybrid_state_dict(sd: Mapping[str, Any], cfg: Any,
+                                        dtype=jnp.bfloat16) -> Params:
+    """The published tensor names (`mamba.{in_proj, conv1d, A_log, D,
+    dt_bias, norm, out_proj}`, `self_attn.{q, k, v, o}_proj`,
+    `shared_mlp.{input_linear, output_linear}`) -> models/
+    granitemoehybrid.py's tree. Written from the names and
+    torch.nn.Linear's [out, in] alone and never run on the published
+    checkpoint (none is at hand and none is fetched: the family is served
+    on seeded weights); tests/test_granitemoehybrid.py turns a tree into
+    these names and back."""
+    from substratus_tpu.models.granitemoehybrid import ATTN, MAMBA
+
+    def get(name: str) -> np.ndarray:
+        for prefix in ("", "model."):
+            if prefix + name in sd:
+                return _np(sd[prefix + name])
+        raise KeyError(name)
+
+    def stack(kind, fmt: str, transform=lambda w: w, to=dtype) -> jnp.ndarray:
+        return jnp.asarray(np.stack(
+            [transform(get(fmt.format(i=i)))
+             for i, k in enumerate(cfg.layer_types) if kind in (None, k)]), to)
+
+    m = cfg.hidden_dim
+    f32 = jnp.float32
+    params: Params = {
+        "tok_embed": jnp.asarray(get("embed_tokens.weight"), dtype),
+        "out_norm": jnp.asarray(get("norm.weight"), dtype),
+        "layers": {
+            "input_norm": stack(None, "layers.{i}.input_layernorm.weight"),
+            "post_norm": stack(
+                None, "layers.{i}.post_attention_layernorm.weight"),
+            # input_linear [2 M, D]: the gate's rows, then the up's
+            "w_gate": stack(None, "layers.{i}.shared_mlp.input_linear.weight",
+                            lambda w: w[:m].T),
+            "w_up": stack(None, "layers.{i}.shared_mlp.input_linear.weight",
+                          lambda w: w[m:].T),
+            "w_down": stack(
+                None, "layers.{i}.shared_mlp.output_linear.weight",
+                lambda w: w.T),
+        },
+    }
+    if cfg.count(MAMBA):
+        mm = "layers.{i}.mamba."
+        params["ssm"] = {
+            "w_in": stack(MAMBA, mm + "in_proj.weight", lambda w: w.T),
+            # conv1d.weight [W, 1, K]: tap j of the equations is column j
+            "taps": stack(MAMBA, mm + "conv1d.weight", lambda w: w[:, 0].T),
+            "conv_bias": stack(MAMBA, mm + "conv1d.bias", to=f32),
+            "a_log": stack(MAMBA, mm + "A_log", to=f32),
+            "d_skip": stack(MAMBA, mm + "D", to=f32),
+            "dt_bias": stack(MAMBA, mm + "dt_bias", to=f32),
+            "norm": stack(MAMBA, mm + "norm.weight"),
+            "w_out": stack(MAMBA, mm + "out_proj.weight", lambda w: w.T),
+        }
+    if cfg.count(ATTN):
+        aa = "layers.{i}.self_attn."
+        params["attn"] = {
+            # [heads * hd, D], as torch.nn.Linear keeps them
+            "wq": stack(ATTN, aa + "q_proj.weight"),
+            "wk": stack(ATTN, aa + "k_proj.weight"),
+            "wv": stack(ATTN, aa + "v_proj.weight"),
+            "wo": stack(ATTN, aa + "o_proj.weight", lambda w: w.T),
+        }
+    return params
+
+
 def config_from_hf_deepseek_v3(hf_cfg: Any):
     """A transformers `deepseek_v3` config.json, or the text part of a
     `dots_vlm` one (dots.vlm1 carries DeepSeek-V3's keys one for one; its
@@ -580,6 +695,9 @@ def _dispatch_hf(model_type: str):
         return config_from_hf_brumby, convert_brumby_state_dict
     if family == "deepseek_v3":
         return config_from_hf_deepseek_v3, convert_deepseek_v3_state_dict
+    if family == "granitemoehybrid":
+        return (config_from_hf_granitemoehybrid,
+                convert_granitemoehybrid_state_dict)
     raise NotImplementedError(
         f"unsupported HF model_type {model_type!r} "
         f"(supported: {sorted(HF_MODEL_TYPES)})"

@@ -11,7 +11,8 @@ from __future__ import annotations
 from typing import Any, Optional, Tuple
 
 from substratus_tpu.models import (
-    brumby, deepseek_v3, exaone_moe, falcon, lfm2_moe, llama, opt,
+    brumby, deepseek_v3, exaone_moe, falcon, granitemoehybrid, lfm2_moe,
+    llama, opt,
 )
 
 FAMILIES = {
@@ -34,6 +35,10 @@ FAMILIES = {
     # block under a learned index that picks the rows a query attends
     # (index_n_heads > 0: the index keys lie in the pages beside the rows)
     "deepseek_v3": deepseek_v3,
+    # Granite-4.0-H-Micro: Mamba-2 mixers that keep a float32 state and
+    # three convolution rows a decode slot and layer, beside a few attention
+    # layers without positions in pages; dense MLPs, three multipliers
+    "granitemoehybrid": granitemoehybrid,
 }
 
 # transformers `model_type` -> family name (HF checkpoint dispatch).
@@ -51,6 +56,8 @@ HF_MODEL_TYPES = {
     "dots_vlm": "deepseek_v3",
     # GLM-5: DeepSeek-V3's block with DeepSeek Sparse Attention's indexer
     "glm_moe_dsa": "deepseek_v3",
+    # Granite-4.0-H with `num_local_experts` 0 (load/hf.py refuses the rest)
+    "granitemoehybrid": "granitemoehybrid",
 }
 
 _CONFIG_CLASS_TO_FAMILY = {
@@ -61,6 +68,7 @@ _CONFIG_CLASS_TO_FAMILY = {
     lfm2_moe.Lfm2MoeConfig: "lfm2_moe",
     brumby.BrumbyConfig: "brumby",
     deepseek_v3.DeepseekV3Config: "deepseek_v3",
+    granitemoehybrid.GraniteHybridConfig: "granitemoehybrid",
 }
 
 

@@ -11,7 +11,9 @@ Widths: TinyLlama-1.1B (32 heads / 4 kv heads of 64, dim 2048, hidden
 5632), Llama-2-7B (32/32 heads of 128, dim 4096, hidden 11008) and, for
 attention over a cache, Mistral-7B (32 heads / 8 kv heads of 128) and
 LFM2-24B-A2B (32 / 8 of 64, stored two to a row of 128 in the paged pool);
-for a retention state, Brumby-14B (40 / 8 of 128: 8,256 x 128 a head);
+for a retention state, Brumby-14B (40 / 8 of 128: 8,256 x 128 a head); for a
+state-space state, Granite-4.0-H-Micro (64 heads of 64 over 128: 128 x 4,096
+a slot);
 prefill lengths are the engine's power-of-two buckets up to
 EngineConfig.max_prefill_len (16..512) plus the trainer's 1024/2048;
 cache lengths are EngineConfig.max_seq_len (1024) and the old bench's
@@ -474,6 +476,45 @@ def retention_decode(tag, b, h, kh, d, layers=2, seen=4) -> KernelCase:
     )
 
 
+# --- a Mamba-2 layer's decode step over its stacked state -----------------------
+
+
+def ssm_decode(tag, b, h, p, n, layers=2, seen=4) -> KernelCase:
+    """One token a row, every slot a row, against the last layer of a
+    float32 state of `layers`: what ops/kvcache.py::ssm_read_and_update
+    runs on a TPU (ops/ssd_kernel.py) beside ops/ssd.py::step. The state is
+    the sum of `seen` tokens' `B u^T`; row 0 starts afresh, the last row
+    idles (`dt = 0`)."""
+    from substratus_tpu.ops import ssd, ssd_kernel
+
+    def make_args(key):
+        ks = jax.random.split(key, 8)
+        state = jnp.einsum(
+            "lbjn,lbjr->lbnr", _normal(ks[0], (layers, b, seen, n), jnp.float32),
+            0.01 * _normal(ks[1], (layers, b, seen, h * p), jnp.float32))
+        dt = jax.nn.softplus(_normal(ks[5], (b, h), jnp.float32) - 5.0)
+        return (
+            state, jnp.int32(layers - 1), _normal(ks[2], (b, h, p)),
+            _normal(ks[3], (b, n)), _normal(ks[4], (b, n)),
+            jnp.where((jnp.arange(b) == b - 1)[:, None], 0.0, dt),
+            0.01 * _normal(ks[6], (h,), jnp.float32),
+            1.0 + 0.1 * _normal(ks[7], (h,), jnp.float32),
+            jnp.arange(b) == 0,
+        )
+
+    def kernel(state, layer, *row, interpret=False):
+        s, o = ssd_kernel.step(state, layer, *row, interpret=interpret)
+        return s[-1], o
+
+    def reference(state, layer, *row):
+        return ssd.step(state[-1], *row)
+
+    return KernelCase(
+        f"ssm_decode/{tag}/b{b}-h{h}-p{p}-n{n}", make_args, kernel,
+        reference, tol=1e-4,
+    )
+
+
 # --- the lists ---------------------------------------------------------------
 
 # What the chip's compiler says of a kernel wrapped in custom_partitioning
@@ -538,6 +579,9 @@ def chip_cases() -> List[KernelCase]:
     # The longctx cell's decode step of one retention layer: 16 slots, 40
     # query heads over 8 of 128, a state of 8,256 x 128 a head.
     cases.append(retention_decode("brumby-longctx", 16, 40, 8, 128))
+    # The rag cell's decode step of one Mamba-2 layer: 48 slots, 64 heads of
+    # 64 over a state of 128, 2 MiB a slot.
+    cases.append(ssm_decode("granite-rag", 48, 64, 64, 128))
     # The docqa cell's latent attention (DeepSeek-V3's widths: 128 heads over
     # one row of 512 + 64 a token, stored 640 wide, in the family's pages of
     # 128 tokens, models/deepseek_v3.py::PAGE_TOKENS): the decode step's
@@ -572,6 +616,7 @@ def rehearsal_cases() -> List[KernelCase]:
         paged_decode("small", 3, 128, h=4, kh=2, d=64, pages=17),
         paged_chunk("small", 2, 16, 128, h=8, kh=4, d=64, pages=17),
         retention_decode("small", 3, h=4, kh=2, d=16),
+        ssm_decode("small", 3, h=4, p=32, n=128),
         latent_attend("small", 3, 1, 128, h=8, dn=32, dr=16, dv=32, rkv=128,
                       pages=25),
         latent_attend("small", 2, 20, 128, h=8, dn=32, dr=16, dv=32, rkv=128,

@@ -78,7 +78,12 @@ A gated short convolution of L taps keeps less still: the L - 1 rows of its
 input just before the next position, a decode slot and layer
 (`conv_read_and_update`, under `CONV_STATE` of the same dict), read and
 rewritten every step under the rings' contract: the caller says which slot
-a batch row is and which of its tokens are real.
+a batch row is and which of its tokens are real. A layer that keeps a
+recurrent state keeps a float32 matrix a decode slot and layer under the
+same contract: power retention's `S` and `z` (`retention_read_and_update`),
+a Mamba-2 layer's `S` beside its convolution's rows (`ssm_read_and_update`,
+under `SSM_STATE`; models/granitemoehybrid.py holds pages, rows and state
+in one dict).
 """
 from __future__ import annotations
 
@@ -89,7 +94,9 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from substratus_tpu.ops import retention, retention_kernel, scopes, sparse_index
+from substratus_tpu.ops import (
+    retention, retention_kernel, scopes, sparse_index, ssd, ssd_kernel,
+)
 from substratus_tpu.ops.attention import dot_product_attention
 from substratus_tpu.ops.latent_attention import (
     latent_chunk_attention, latent_decode_attention,
@@ -744,6 +751,66 @@ def conv_read_and_update(
     return out, ctx
 
 
+def _take_slots(state, layer, slots, rows: int):
+    """[B, ...]: what this call's slots own of `layer` of a per-slot stack
+    [L, slots, ...]: the layer's slab where it lies with `slots` None (a
+    decode step: row i is slot i), else row by row."""
+    if slots is None:
+        if rows != state.shape[1]:
+            raise ValueError(
+                f"{rows} rows for {state.shape[1]} slots: pass `slots`")
+        return jax.lax.dynamic_index_in_dim(state, layer, 0, keepdims=False)
+    tail = state.shape[2:]
+    return jnp.concatenate([jax.lax.dynamic_slice(
+        state, (layer, slots[i].astype(jnp.int32)) + (0,) * len(tail),
+        (1, 1) + tail)[0] for i in range(rows)])
+
+
+def _put_slots(state, new, layer, slots):
+    """The stack with `_take_slots`' rows written back where they lay."""
+    if slots is None:
+        return jax.lax.dynamic_update_index_in_dim(state, new, layer, 0)
+    for i in range(new.shape[0]):
+        state = jax.lax.dynamic_update_slice(
+            state, new[i][None, None],
+            (layer, slots[i].astype(jnp.int32)) + (0,) * (new.ndim - 1))
+    return state
+
+
+def conv_rows_read_and_update(
+    state: jnp.ndarray,  # [Lc, slots, (L - 1) * D]: a slot's rows end to end
+    layer: jnp.ndarray,  # scalar int32: index among the convolution layers
+    slots: Optional[jnp.ndarray],  # [B] int32, or None: row i is slot i
+    positions: jnp.ndarray,  # [B, S]
+    valid: jnp.ndarray,  # [B, S] bool
+    u: jnp.ndarray,  # [B, S, D]
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """`conv_read_and_update` over a stack that keeps a slot's L - 1 rows
+    end to end, one row of (L - 1) * D values: the rows of this call's
+    slots are taken out of the stack (a layer's slab where `slots` is None:
+    a decode step over every slot), handed to `conv_read_and_update` as a
+    state of one layer, and written back where they lay. Same contract,
+    same returns.
+
+    Why a family would keep them so: the stack's layout is then nobody's
+    to choose. Compiled for a v5e, a stack [36, 48, 3, 4352] was padded to
+    four rows, held in VMEM and packed and unpacked whole around every
+    layer's read of a decode step; and in a 512-token chunk, whose
+    convolution input the compiler lays tokens-innermost, that layout
+    reached the stack through the one slot's update and padded its rows to
+    128 (1.9 GB of temporaries, copied whole eight times a scan iteration:
+    PERF.md section 6, PR 46). What is relaid here is a call's own rows."""
+    bsz, _, d = u.shape
+    layer = layer.astype(jnp.int32)
+    with jax.named_scope(scopes.CONV_STATE):
+        rows = _take_slots(state, layer, slots, bsz)  # [B, (L - 1) * D]
+    rows, ctx = conv_read_and_update(
+        rows.reshape(1, bsz, -1, d), jnp.zeros((), jnp.int32),
+        jnp.arange(bsz, dtype=jnp.int32), positions, valid, u)
+    with jax.named_scope(scopes.CONV_STATE):
+        return _put_slots(state, rows.reshape(bsz, -1), layer, slots), ctx
+
+
 def init_conv_state(n_layers: int, slots: int, taps: int, dim: int, dtype
                     ) -> Dict[str, jnp.ndarray]:
     """Per-slot rows of the convolution layers: [Lc, slots, taps - 1, D]."""
@@ -915,6 +982,102 @@ def init_retention_state(n_layers: int, slots: int, kv_heads: int,
 def retention_state_logical_axes() -> Dict[str, tuple]:
     return {RET_S: ("layers", None, "kv_heads", None, "head_dim"),
             RET_Z: ("layers", None, "kv_heads", None)}
+
+
+SSM_STATE = "ssm"  # the cache dict's key of a state-space layer's state
+
+
+def ssm_read_and_update(
+    state: jnp.ndarray,  # [Lm, slots, N, H x P] float32
+    layer: jnp.ndarray,  # scalar int32: index among the state-space layers
+    slots: Optional[jnp.ndarray],  # [B] int32, or None: row i is slot i
+    positions: jnp.ndarray,  # [B, S] absolute positions, ascending in a row
+    valid: jnp.ndarray,  # [B, S] bool: real tokens; they lead their row
+    x: jnp.ndarray,  # [B, S, H, P]
+    b: jnp.ndarray,  # [B, S, N]
+    c: jnp.ndarray,  # [B, S, N]
+    dt: jnp.ndarray,  # [B, S, H] float32: the step size, softplus applied
+    a_log: jnp.ndarray,  # [H] float32
+    d_skip: jnp.ndarray,  # [H] float32
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """The state of a Mamba-2 layer (ops/ssd.py): each slot owns one `S [N,
+    H x P]` of every such layer. Returns (state, o [B, S, H, P] float32),
+    the state updated in place (the caller carries and donates it, as the
+    paged pool): one token a row takes the recurrent step, more the chunked
+    form. `retention_read_and_update`'s contract word for word: a row whose
+    first token is real and at position 0 starts from zero whatever its
+    slot holds and nothing is zeroed at admission; a token that is not real
+    (`dt` is made 0 there) decays nothing and adds nothing, so a row with
+    no real token leaves its slot's state bit for bit; the state is assumed
+    to hold the positions below positions[:, 0].
+
+    With `slots` None the layer's rows are read and written as one slab
+    where they lie (a decode step: every row, whichever slots are live);
+    with `slots` given, row by row. The decode step (`slots` None, one
+    token a row) lowered for a TPU over a state `_ssm_kernel_for` takes is
+    ops/ssd_kernel.py::step: a slot's `S` crosses HBM once in each
+    direction. Everything else is ops/ssd.py, which is also what the kernel
+    is tested against."""
+    bsz, s = positions.shape
+    fresh = (positions[:, 0] == 0) & valid[:, 0]
+    dt = jnp.where(valid[..., None], dt.astype(jnp.float32), 0.0)
+    layer = layer.astype(jnp.int32)
+    kernel = _ssm_kernel_for(state) if slots is None and s == 1 else None
+
+    def in_xla():
+        old = _take_slots(state, layer, slots, bsz)
+        if s == 1:
+            new, o = ssd.step(old, x[:, 0], b[:, 0], c[:, 0], dt[:, 0],
+                              a_log, d_skip, fresh)
+            o = o[:, None]
+        else:
+            new, o = ssd.chunk(old, x, b, c, dt, a_log, d_skip, fresh)
+        return _put_slots(state, new, layer, slots), o
+
+    def in_kernel():
+        new, o = kernel(state, layer, x[:, 0], b[:, 0], c[:, 0], dt[:, 0],
+                        a_log, d_skip, fresh)
+        return new, o[:, None]
+
+    with jax.named_scope(scopes.SSM_STATE):
+        if kernel is None:
+            return in_xla()
+        return jax.lax.platform_dependent(tpu=in_kernel, default=in_xla)
+
+
+def _ssm_kernel_for(state):
+    """ops/ssd_kernel.py::step where it is written for this state's
+    decode step (one token a row, row i slot i): a float32 state on one
+    device, N and H x P both multiples of the 128 lanes (the kernel turns
+    `B` and `C` by a [128, N] transpose). None otherwise (a sharded state,
+    `tiny-granite-hybrid`'s 8 x 64): ops/ssd.py::step."""
+    n, r = state.shape[2:]
+    mesh = jax.typeof(state).sharding.mesh
+    if (state.dtype != jnp.float32 or n % LANES or r % LANES
+            or any(size > 1 for size in mesh.shape.values())):
+        return None
+    return ssd_kernel.step
+
+
+def ssm_step_takes_kernel(state: jax.Array) -> bool:
+    """Whether a decode step over this state runs ops/ssd_kernel.py: what
+    `ssm_read_and_update` chooses for it, on the devices that hold it (for
+    the engine's `state_kernel_steps`)."""
+    return (all(d.platform == "tpu" for d in state.devices())
+            and _ssm_kernel_for(state) is not None)
+
+
+def init_ssm_state(n_layers: int, slots: int, heads: int, head_dim: int,
+                   d_state: int) -> Dict[str, jnp.ndarray]:
+    """Per-slot state of the Mamba-2 layers, float32 whatever the
+    activations' type (a sum over hundreds of rank-one terms under a decay
+    near 1): `ssm` [Lm, slots, N, H x P], ops/ssd.py's layout."""
+    return {SSM_STATE: jnp.zeros(
+        (n_layers, slots, d_state, heads * head_dim), jnp.float32)}
+
+
+def ssm_state_logical_axes() -> Dict[str, tuple]:
+    return {SSM_STATE: ("layers", None, None, None)}
 
 
 def init_paged_cache(

@@ -26,7 +26,13 @@ and the read-out carried back), a chunk ATTN_EXPAND ahead of it (what of
 the latents' expansion into keys and values stays outside the kernel).
 Where such a layer picks the rows a query attends by a learned index
 (ops/sparse_index.py) it opens ATTN_INDEX and ATTN_SELECT between ATTN_QKV
-and ATTN_CORE, and ATTN_CORE then holds the read of the picked rows.
+and ATTN_CORE, and ATTN_CORE then holds the read of the picked rows. A layer whose
+operator is a Mamba-2 mixer (models/granitemoehybrid.py) keeps a state a
+decode slot beside its convolution's rows: it opens SSM_IN, the held
+CONV_STATE (the rows, the taps, their bias and SiLU), SSM_STATE, in a chunk
+SSM_INTRA (inside SSM_STATE, whose region holds the chunk's scan: the one
+pair that nests, as RET_INTRA in RET_STATE), and SSM_OUT where an attention
+layer opens the ATTN_ and KV_ names.
 """
 
 EMBED = "embed"              # token embedding gather
@@ -57,6 +63,14 @@ RET_STATE = "ret.state"      # a retention layer's per-slot state: read,
 # in a chunk the carried state's read-out and the state's update)
 RET_INTRA = "ret.intra"      # a chunk's in-chunk scores under the decay and
 # their product with the values
+SSM_IN = "ssm.in"            # a Mamba-2 mixer's input projection: the gate z,
+# the convolution's input [x; B; C] and the step size's pre-activation
+SSM_STATE = "ssm.state"      # its per-slot state: the step size and the decay,
+# read, update, write-back, read-out and D (ops/ssd.py::step; in a chunk the
+# carried state's read-out and the state's update)
+SSM_INTRA = "ssm.intra"      # a chunk's in-block scores C . B under the decay
+# and their product with dt x
+SSM_OUT = "ssm.out"          # the gated norm and the output projection
 ATTN_ABSORB = "attn.absorb"  # latent attention, a decode step: q_nope
 # through W_UK^T into the latent's space ahead of the kernel, its read-out
 # through W_UV after it
@@ -78,10 +92,12 @@ ALL = (
 # benchmark lists them in that family's file, benchmarks/families/):
 # EXTRA models/exaone_moe.py's, CONV models/lfm2_moe.py's, RET
 # models/brumby.py's, LATENT models/deepseek_v3.py's (beside MOE_SHARED),
-# INDEXED what that block adds where its configuration has an indexer.
+# INDEXED what that block adds where its configuration has an indexer, SSM
+# models/granitemoehybrid.py's (beside CONV_STATE).
 EXTRA = (MOE_SHARED, KV_RING, ATTN_WINDOW)
 CONV = (CONV_IN, CONV_STATE, CONV_OUT)
 RET = (RET_STATE, RET_INTRA)
 LATENT = (ATTN_ABSORB, ATTN_EXPAND)
 INDEXED = (ATTN_INDEX, ATTN_SELECT)
-EVERY = ALL + EXTRA + CONV + RET + LATENT + INDEXED
+SSM = (SSM_IN, SSM_STATE, SSM_INTRA, SSM_OUT)
+EVERY = ALL + EXTRA + CONV + RET + LATENT + INDEXED + SSM

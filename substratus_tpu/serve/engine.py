@@ -158,9 +158,10 @@ METRICS.describe(
 )
 # A family whose paged cache holds per-slot state (PAGED_SLOT_STATE:
 # models/exaone_moe.py's rings, models/lfm2_moe.py's convolution rows,
-# models/brumby.py's retention state). Where it has an expert layer that may
-# hold a share of the experts (it then has `step_counters`): what its
-# forward counts, read with the step's tokens.
+# models/brumby.py's retention state, models/granitemoehybrid.py's
+# convolution rows and state-space state). Where it has an expert layer
+# that may hold a share of the experts (it then has `step_counters`): what
+# its forward counts, read with the step's tokens.
 METRICS.describe(
     "substratus_serve_moe_pairs_total",
     "Token-expert pairs the router made, by whether the chosen expert is "
@@ -179,8 +180,8 @@ METRICS.describe(
     "substratus_serve_slot_state_bytes",
     "Bytes of per-slot state in the paged cache dict: every leaf beside "
     "the page pool (window layers' rings, convolution rows, a retention "
-    "layer's state). Set once at start-up; 0 for a family that keeps "
-    "pages alone.",
+    "or state-space layer's state). Set once at start-up; 0 for a family "
+    "that keeps pages alone.",
     type="gauge",
 )
 METRICS.histogram(
@@ -574,11 +575,11 @@ class Engine:
             )
         # A family whose paged cache holds per-slot state: state addressed
         # by decode slot beside the pages or in their place (window layers'
-        # rings, convolution layers' input rows, a retention layer's
-        # matrix). The engine tells its forward which slot a row is and
-        # which tokens are real, and takes its per-step counters where it
-        # has any; pages alone do not carry such a sequence, so what moves
-        # or shares pages is refused or off.
+        # rings, convolution layers' input rows, a retention or
+        # state-space layer's matrix). The engine tells its forward which
+        # slot a row is and which tokens are real, and takes its per-step
+        # counters where it has any; pages alone do not carry such a
+        # sequence, so what moves or shares pages is refused or off.
         self.slot_state = self.paged and getattr(
             model, "PAGED_SLOT_STATE", False
         )
@@ -830,15 +831,19 @@ class Engine:
                 "dsa_selections": 0,
             })
         self._state_kernel = False
-        if self.slot_state and kvcache.RET_S in self.cache:
-            # Decoding iterations whose program moved the retention state
-            # through ops/retention_kernel.py (every head's S once in,
-            # once out) and not through XLA's two reads and a write:
-            # decided once, by what ops/kvcache.py reads off the state for
-            # the decode program, so all of them or none.
-            self._state_kernel = kvcache.retention_step_takes_kernel(
-                self.cache[kvcache.RET_S])
-            self.stats["state_kernel_steps"] = 0
+        for leaf, takes_kernel in (
+            (kvcache.RET_S, kvcache.retention_step_takes_kernel),
+            (kvcache.SSM_STATE, kvcache.ssm_step_takes_kernel),
+        ):
+            if self.slot_state and leaf in self.cache:
+                # Decoding iterations whose program moved the recurrent
+                # state through its Pallas kernel (ops/retention_kernel.py,
+                # ops/ssd_kernel.py: every head's S once in, once out) and
+                # not through XLA's two reads and a write: decided once, by
+                # what ops/kvcache.py reads off the state for the decode
+                # program, so all of them or none.
+                self._state_kernel = takes_kernel(self.cache[leaf])
+                self.stats["state_kernel_steps"] = 0
         # A family whose forward counts over its real tokens is told which
         # they are (`valid`), with or without per-slot state.
         self._step_counts = self.paged and hasattr(model, "step_counters")

@@ -9,7 +9,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from substratus_tpu.models import deepseek_v3, exaone_moe, lfm2_moe, llama
+from substratus_tpu.models import (
+    deepseek_v3, exaone_moe, granitemoehybrid, lfm2_moe, llama,
+)
 from substratus_tpu.ops import scopes
 from substratus_tpu.serve.engine import Engine, EngineConfig
 
@@ -53,10 +55,19 @@ CASES = {
     "glm-dsa-paged": ("tiny-glm-dsa", "paged",
                       DENSE_LLAMA | {scopes.MOE_ROUTER, scopes.MOE_EXPERTS,
                                      scopes.MOE_SHARED, *scopes.INDEXED}),
+    # Mamba-2 mixers beside attention layers without positions: every base
+    # name, the held `conv.state` and the mixer's three; the in-block
+    # scores in the chunk alone (`LATENT_ONLY` below)
+    "granite-hybrid-paged": ("tiny-granite-hybrid", "paged",
+                             DENSE_LLAMA | {scopes.KV_GATHER,
+                                            scopes.CONV_STATE, scopes.SSM_IN,
+                                            scopes.SSM_STATE,
+                                            scopes.SSM_OUT}),
 }
 # What one of the two programs of a family opens and the other does not.
 LATENT_ONLY = {"deepseek-v3-paged": {"decode": {scopes.ATTN_ABSORB},
                                      "chunk": {scopes.ATTN_EXPAND}},
+               "granite-hybrid-paged": {"chunk": {scopes.SSM_INTRA}},
                "glm-dsa-paged": {"decode": {scopes.ATTN_ABSORB},
                                  "chunk": {scopes.ATTN_EXPAND,
                                            scopes.KV_GATHER}}}
@@ -65,7 +76,8 @@ OP_RE = re.compile(
 
 
 def _engine(config: str, layout: str) -> Engine:
-    model = next((m for m in (exaone_moe, lfm2_moe, deepseek_v3)
+    model = next((m for m in (exaone_moe, lfm2_moe, deepseek_v3,
+                              granitemoehybrid)
                   if config in m.CONFIGS), llama)
     cfg = model.CONFIGS[config].replace(dtype=jnp.float32)
     # one program: eagerly an expert family's tree is built leaf by leaf
@@ -132,7 +144,10 @@ def test_regions_in_the_compiled_decode_and_chunk_programs(case):
                          scopes.LAYERS):
                 assert parts == [inner], name
             elif name.startswith("jit("):  # reducers carry the bare scope
-                assert parts == [scopes.LAYERS, inner], name
+                # (a chunk's in-block part sits in the region of its scan)
+                assert parts in ([scopes.LAYERS, inner],
+                                 [scopes.LAYERS, scopes.SSM_STATE,
+                                  scopes.SSM_INTRA]), name
 
 
 @pytest.mark.parametrize("case", ["llama-paged", "lfm2-moe-paged"])
@@ -159,10 +174,11 @@ def test_vocabulary_is_closed_and_matches_the_benchmarks_copy():
     assert len(set(scopes.ALL)) == len(scopes.ALL) == 13
     # what one family's block adds is listed in that family's file, and
     # the benchmark's readers charge an op to any of them
-    assert len(set(scopes.EVERY)) == len(scopes.EVERY) == 25
+    assert len(set(scopes.EVERY)) == len(scopes.EVERY) == 29
     assert scopes.INDEXED == ("attn.index", "attn.select")
+    assert scopes.SSM == ("ssm.in", "ssm.state", "ssm.intra", "ssm.out")
     assert set(scopes.EXTRA + scopes.CONV + scopes.RET + scopes.LATENT
-               + scopes.INDEXED) <= trace_scopes.vocabulary()
+               + scopes.INDEXED + scopes.SSM) <= trace_scopes.vocabulary()
 
 
 def test_a_region_name_is_metadata_and_changes_no_arithmetic(monkeypatch):

@@ -41,7 +41,8 @@ period of Granite-4.0-H-Micro is ten layers, `mamba` x 5, `attention`,
 layers write the paged pool (`k`, `v`, two heads of 64 to a stored row:
 ops/kvcache.py::paged_attention, as LFM2's), Mamba layers K - 1 rows of `u`
 a decode slot (`conv`: [Lm, slots, (K - 1) x W], a slot's rows end to end;
-ops/kvcache.py::conv_rows_read_and_update hands them to LFM2's
+ops/kvcache.py::conv_rows_read_and_update: a decode step shifts the layer's
+slab where it lies, a chunk hands its slot's rows to LFM2's
 conv_read_and_update) and the state `S` a decode slot, float32
 whatever the activations' type (`ssm`: [Lm, slots, N, H x P], ops/ssd.py's
 layout; ops/kvcache.py::ssm_read_and_update). The engine says which slot a

@@ -78,7 +78,11 @@ A gated short convolution of L taps keeps less still: the L - 1 rows of its
 input just before the next position, a decode slot and layer
 (`conv_read_and_update`, under `CONV_STATE` of the same dict), read and
 rewritten every step under the rings' contract: the caller says which slot
-a batch row is and which of its tokens are real. A layer that keeps a
+a batch row is and which of its tokens are real. A family may keep a slot's
+rows end to end, one row of (L - 1) x D values
+(`conv_rows_read_and_update`, the same contract): a chunk hands its slots'
+rows to `conv_read_and_update`, a decode step over every slot shifts the
+layer's slab where it lies. A layer that keeps a
 recurrent state keeps a float32 matrix a decode slot and layer under the
 same contract: power retention's `S` and `z` (`retention_read_and_update`),
 a Mamba-2 layer's `S` beside its convolution's rows (`ssm_read_and_update`,
@@ -786,22 +790,58 @@ def conv_rows_read_and_update(
     u: jnp.ndarray,  # [B, S, D]
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """`conv_read_and_update` over a stack that keeps a slot's L - 1 rows
-    end to end, one row of (L - 1) * D values: the rows of this call's
-    slots are taken out of the stack (a layer's slab where `slots` is None:
-    a decode step over every slot), handed to `conv_read_and_update` as a
-    state of one layer, and written back where they lay. Same contract,
-    same returns.
+    end to end, one row of (L - 1) * D values. Same contract, same
+    returns, two call shapes.
 
-    Why a family would keep them so: the stack's layout is then nobody's
-    to choose. Compiled for a v5e, a stack [36, 48, 3, 4352] was padded to
-    four rows, held in VMEM and packed and unpacked whole around every
-    layer's read of a decode step; and in a 512-token chunk, whose
+    A decode step over every slot (`slots` None and one token a row: what
+    `ssm_read_and_update` reads to choose its kernel) shifts the layer's
+    slab where it lies, elementwise: context row r is the slab's r-th
+    segment of D values, zero where it would stand for a position below 0,
+    and the last one is `u`; the rows kept are the slab less its oldest
+    segment with `u` behind it where the row's token is real, and the slab
+    as it was where it is not. Every segment starts on a lane tile when D
+    is a multiple of 128, so the compiler makes it six small fusions a
+    layer with no gather, no scatter and no relaid copy, and the mixer's
+    activations around it stay slots down the sublanes (PERF.md section
+    6, PR 47).
+
+    A chunk (`slots` given, or more than one token a row) takes its slots'
+    rows out of the stack, hands them to `conv_read_and_update` as a state
+    of one layer, and writes them back where they lay: that function is
+    written for any count of real tokens, and pays for it a gather by row,
+    a padded concatenate, a second gather for the rows kept and a scatter.
+
+    Why a family would keep its rows so: the stack's layout is then
+    nobody's to choose. Compiled for a v5e, a stack [36, 48, 3, 4352] was
+    padded to four rows, held in VMEM and packed and unpacked whole around
+    every layer's read of a decode step; and in a 512-token chunk, whose
     convolution input the compiler lays tokens-innermost, that layout
     reached the stack through the one slot's update and padded its rows to
     128 (1.9 GB of temporaries, copied whole eight times a scan iteration:
     PERF.md section 6, PR 46). What is relaid here is a call's own rows."""
-    bsz, _, d = u.shape
+    bsz, s, d = u.shape
     layer = layer.astype(jnp.int32)
+    if slots is None and s == 1:
+        with jax.named_scope(scopes.CONV_STATE):
+            old = _take_slots(state, layer, None, bsz)  # [B, (L - 1) * D]
+            keep = old.shape[1] // d
+            new = u[:, 0].astype(state.dtype)
+            # segment r stands for position positions[:, 0] - (L - 1) + r
+            first = positions[:, :1].astype(jnp.int32) - keep
+            ctx = jnp.stack(
+                [jnp.where(first + r >= 0, old[:, r * d:(r + 1) * d], 0)
+                 for r in range(keep)] + [new], axis=1)
+            # the rows kept, written where they lie as two pieces: all but
+            # the oldest segment moved down one, and `u` behind them. (One
+            # concatenate of the two cost a layer two more kernels, which
+            # wrote its operands out whole first.)
+            live, cut = valid[:, :1], (keep - 1) * d
+            for at, piece in (
+                    (0, jnp.where(live, old[:, d:], old[:, :cut])),
+                    (cut, jnp.where(live, new, old[:, cut:]))):
+                state = jax.lax.dynamic_update_slice(
+                    state, piece[None], (layer, 0, at))
+            return state, ctx
     with jax.named_scope(scopes.CONV_STATE):
         rows = _take_slots(state, layer, slots, bsz)  # [B, (L - 1) * D]
     rows, ctx = conv_read_and_update(

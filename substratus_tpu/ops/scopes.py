@@ -56,7 +56,9 @@ ATTN_WINDOW = "attn.window"  # scores, softmax, values of a window layer
 CONV_IN = "conv.in"          # a convolution layer's input projection and
 # the gate B * X
 CONV_STATE = "conv.state"    # the read of the slot's rows, the taps, the rows
-# written back (ops/kvcache.py::conv_read_and_update and the taps beside it)
+# written back (ops/kvcache.py::conv_read_and_update and the taps beside it;
+# ::conv_rows_read_and_update where a slot's rows lie end to end: a decode
+# step shifts the layer's slab there, elementwise)
 CONV_OUT = "conv.out"        # the gate C * v and the output projection
 RET_STATE = "ret.state"      # a retention layer's per-slot state: read,
 # decay, update, write-back, read-out and normaliser (ops/retention.py::step;

@@ -34,6 +34,20 @@ _NO_MOVE = {"parameter", "get-tuple-element", "bitcast", "tuple", "while",
             "optimization-barrier"}
 
 
+def _ops(hlo: str):
+    """(computation, whether it is a fusion's inside, op's name, result
+    type, opcode, the line) of every op of the optimized HLO."""
+    where = ""
+    for line in hlo.splitlines():
+        if line and not line.startswith(" "):
+            where = line.split("(")[0].replace("ENTRY", "").strip(" %")
+            continue
+        m = re.match(r"\s+(?:ROOT )?%?([\w.\-]+) = (.*?) ([\w\-]+)\(", line)
+        if m:
+            yield (where, "fused_computation" in where, *m.groups(),
+                   line.strip())
+
+
 def weights_laid_out_anew(hlo: str, sizes) -> list:
     """Every op of the optimized HLO that stands outside any fusion (so
     outside every dot's fusion: a fusion's own result is listed, its inside
@@ -43,20 +57,29 @@ def weights_laid_out_anew(hlo: str, sizes) -> list:
     `constant_dynamic-slice_fusion` staged in VMEM, a `copy`, a
     `copy_bitcast_fusion`, a plain `slice`). As "computation: name =
     type op"."""
-    found, where, fused = [], "", False
-    for line in hlo.splitlines():
-        if line and not line.startswith(" "):
-            where = line.split("(")[0].replace("ENTRY", "").strip(" %")
-            fused = "fused_computation" in where
+    found = []
+    for where, fused, name, result, opcode, _ in _ops(hlo):
+        if fused or opcode in _NO_MOVE:
             continue
-        m = re.match(r"\s+(?:ROOT )?%?([\w.\-]+) = (.*?) ([\w\-]+)\(", line)
-        if fused or not m or m.group(3) in _NO_MOVE:
-            continue
-        for t in re.finditer(r"s8\[([\d,]+)\](\{[^}]*\})?", m.group(2)):
+        for t in re.finditer(r"s8\[([\d,]+)\](\{[^}]*\})?", result):
             if math.prod(map(int, t.group(1).split(","))) in sizes:
-                found.append(f"{where}: {m.group(1)} = {t.group(0)} "
-                             f"{m.group(3)}")
+                found.append(f"{where}: {name} = {t.group(0)} {opcode}")
     return found
+
+
+def region_ops(hlo: str, region: str) -> tuple:
+    """(kernels, inside) of a region (ops/scopes.py: its name in an op's
+    `op_name`), as text lines with `_NO_MOVE` and constants left out:
+    {computation: the region's ops outside any fusion}, what the device
+    runs one after the other, a kernel each, and the list of its ops
+    inside the fusions, what those kernels are made of."""
+    kernels, inside = {}, []
+    for where, fused, _, _, opcode, line in _ops(hlo):
+        scope = re.search(r'op_name="([^"]*)"', line)
+        if (scope and region in scope.group(1)
+                and opcode not in _NO_MOVE | {"constant"}):
+            (inside if fused else kernels.setdefault(where, [])).append(line)
+    return kernels, inside
 
 
 def reads_pages_in_place(hlo: str, kernel: str, rows: int, seq: int,

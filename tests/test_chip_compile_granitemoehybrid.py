@@ -8,10 +8,11 @@ import re
 
 import jax
 import jax.numpy as jnp
+import pytest
 
 from chip_compile import (
     CHUNK, KERNEL, PAGE, described, pool_moving_ops, reads_pages_in_place,
-    sorts_only_where_a_row_samples, weights_laid_out_anew,
+    region_ops, sorts_only_where_a_row_samples, weights_laid_out_anew,
 )
 
 # The rag cell's engine (benchmarks/traffic/rag.json): all 40 layers, 48
@@ -56,12 +57,20 @@ def _granite_programs(v5e):
     return decode, chunk, cache
 
 
+@pytest.fixture(scope="module")
+def granite(v5e):
+    """({"decode" | "chunk": the program compiled for one described chip},
+    the cache's shapes): compiled once for the tests of this file."""
+    decode, chunk, cache = _granite_programs(v5e)
+    return {"decode": decode.compile(), "chunk": chunk.compile()}, cache
+
+
 def _state_kernel_calls(hlo: str) -> int:
     return len(re.findall(
         r'custom_call_target="tpu_custom_call".*ssm_state_step', hlo))
 
 
-def test_granite_programs_compile_and_leave_three_histories_in_place(v5e):
+def test_granite_programs_compile_and_leave_three_histories_in_place(granite):
     """The family whose cache holds pages, convolution rows and a float32
     state a slot: decode (48 slots) and the 512-token chunk compile for the
     chip at the published widths, whole depth. The 3.62 GB of state is the
@@ -77,7 +86,7 @@ def test_granite_programs_compile_and_leave_three_histories_in_place(v5e):
     the compiler padded, packed and unpacked the whole stack around every
     layer, ops/kvcache.py::conv_rows_read_and_update), and lay no large
     int8 weight out anew."""
-    decode, chunk, cache = _granite_programs(v5e)
+    programs, cache = granite
     assert cache["k"].shape == (4, _G_PAGES + 1, PAGE, 4, 128)
     assert cache["conv"].shape == (36, _G_B, 3 * 4352)
     assert cache["ssm"].shape == (36, _G_B, 128, 4096)
@@ -89,8 +98,7 @@ def test_granite_programs_compile_and_leave_three_histories_in_place(v5e):
     s_all, c_all, p_all = (cache[n].size for n in ("ssm", "conv", "k"))
     sizes = {s_all, s_all // 36, c_all, p_all, p_all // 4}
     temp_limit = {"decode": 0.25e9, "chunk": 1.5e9}
-    for name, lowered in (("decode", decode), ("chunk", chunk)):
-        compiled = lowered.compile()
+    for name, compiled in programs.items():
         hlo = compiled.as_text()
         assert all(r in hlo for r in (
             "ssm.in", "conv.state", "ssm.state", "ssm.out", "attn.qkv",
@@ -126,3 +134,31 @@ def test_granite_programs_compile_and_leave_three_histories_in_place(v5e):
         assert mem.alias_size_in_bytes >= state + pool, name
         assert mem.temp_size_in_bytes < temp_limit[name], (
             name, mem.temp_size_in_bytes)
+
+
+# `conv.state` kernels in the decode program's scan iteration (a period's
+# nine Mamba layers), as compiled for a v5e: six a layer (the slab staged
+# in VMEM, two cuts of the tap rows, the taps, the kept rows' two pieces
+# written in place) and what the period shares.
+_CONV_KERNELS = 56
+
+
+def test_a_decode_step_shifts_its_conv_rows_as_a_slab(granite):
+    """A decode step over every slot shifts a layer's convolution rows
+    where they lie (ops/kvcache.py::conv_rows_read_and_update, `slots` None
+    and one token a row): nothing in the decode program's `conv.state`
+    region, in a fusion or outside one, is a gather, a scatter or a copy
+    into a slots-innermost layout (`{2,0,1}`), which is what LFM2's general
+    form costs a layer (two gathers, a scatter and two relaid copies:
+    PERF.md section 6, PR 47), and the region's kernels all lie in the one
+    scan body, at most `_CONV_KERNELS` of them and no fewer than four a
+    layer (fewer would mean the form lost the region's name). A chunk
+    (`slots` given) keeps the general form: its program is not this
+    test's."""
+    kernels, inside = region_ops(granite[0]["decode"].as_text(), "conv.state")
+    assert len(kernels) == 1, list(kernels)
+    (body,) = kernels.values()
+    assert 9 * 4 <= len(body) <= _CONV_KERNELS, len(body)
+    assert not [op for op in body + inside
+                if re.search(r" (gather|scatter)\(", op)
+                or re.search(r"\{2,0,1[:}]\S* copy\(", op)]

@@ -259,6 +259,67 @@ def test_an_idle_row_and_a_padded_tail_leave_state_and_rows(
         assert np.array_equal(np.asarray(a[name]), np.asarray(b[name]))
 
 
+# what a decode step's rows stand for, a row of four slots: position, real
+_ROW_CASES = {
+    "position-0-over-stale-rows": ([0, 0, 0, 0], [1, 1, 1, 1]),
+    "position-1": ([1, 1, 1, 1], [1, 1, 1, 1]),
+    "position-2": ([2, 2, 2, 2], [1, 1, 1, 1]),
+    "positions-3-and-up": ([3, 4, 17, 400], [1, 1, 1, 1]),
+    "idle-rows-beside-live-ones": ([0, 9, 2, 0], [1, 0, 1, 0]),
+}
+
+
+@pytest.mark.parametrize("keep", [2, 3])
+@pytest.mark.parametrize("case", sorted(_ROW_CASES))
+def test_a_decode_steps_slab_form_is_the_general_form_bit_for_bit(case, keep):
+    """ops/kvcache.py::conv_rows_read_and_update over every slot with one
+    token a row (`slots` None: the layer's slab shifted where it lies)
+    returns, bit for bit, the stack and the context of the general form
+    (`slots` = arange: LFM2's conv_read_and_update a call's rows at a time,
+    which is what a chunk takes and what a decode step took until PR 47),
+    whatever the rows stand for and for two rows a slot as for Granite's
+    three. The stack holds non-zero rows everywhere, as a slot's last
+    occupant leaves them: a context row that would stand for a position
+    below 0 reads zero (the mask is on the read), the rows kept are the
+    general form's, stale ones among them, and a row whose token is not
+    real keeps its rows as they were."""
+    d, layers = 256, 3
+    positions, real = (np.asarray(a) for a in _ROW_CASES[case])
+    rows = len(positions)
+    k_state, k_u = jax.random.split(jax.random.key(keep))
+    state = jax.random.normal(
+        k_state, (layers, rows, keep * d), jnp.float32).astype(jnp.bfloat16)
+    assert bool(jnp.all(state != 0))
+    u = jax.random.normal(k_u, (rows, 1, d), jnp.float32)
+    args = (jnp.asarray(positions, jnp.int32)[:, None],
+            jnp.asarray(real, bool)[:, None], u)
+    layer = jnp.asarray(1, jnp.int32)
+    slab, slab_ctx = kvcache.conv_rows_read_and_update(
+        state, layer, None, *args)
+    general, general_ctx = kvcache.conv_rows_read_and_update(
+        state, layer, jnp.arange(rows, dtype=jnp.int32), *args)
+    assert slab.dtype == general.dtype == state.dtype
+    assert slab_ctx.shape == general_ctx.shape == (rows, keep + 1, d)
+    assert slab_ctx.dtype == general_ctx.dtype == state.dtype
+    assert np.array_equal(np.asarray(slab), np.asarray(general))
+    assert np.array_equal(np.asarray(slab_ctx), np.asarray(general_ctx))
+    # and what the contract says of them, on the slab form's own returns
+    before = np.asarray(state.astype(jnp.float32))
+    after = np.asarray(slab.astype(jnp.float32))
+    ctx = np.asarray(slab_ctx.astype(jnp.float32))
+    new = np.asarray(u[:, 0].astype(jnp.bfloat16).astype(jnp.float32))
+    assert np.array_equal(after[[0, 2]], before[[0, 2]])  # other layers
+    for i, (pos, live) in enumerate(zip(positions, real)):
+        for r in range(keep):
+            seg = before[1, i, r * d:(r + 1) * d]
+            assert np.array_equal(
+                ctx[i, r], seg if pos - keep + r >= 0 else np.zeros(d))
+        assert np.array_equal(ctx[i, keep], new[i])
+        kept = (np.concatenate([before[1, i, d:], new[i]]) if live
+                else before[1, i])
+        assert np.array_equal(after[1, i], kept)
+
+
 # -- (b) what makes the block Granite's ------------------------------------------
 
 @pytest.mark.parametrize("name, plain_value", [

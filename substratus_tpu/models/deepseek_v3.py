@@ -100,7 +100,9 @@ SUPPORTS_PAGED = True
 SUPPORTS_SPECULATION = False
 SUPPORTS_ROLES = False
 # Tokens a page of the latent pool holds where the engine is given no size
-# (serve/paged_kv.py::page_tokens; every other family keeps 16). A token
+# (serve/paged_kv.py::page_tokens; a pool of per-head rows keeps 16, or 64
+# and 128 where a stored row holds two heads of 64: models/lfm2_moe.py,
+# models/granitemoehybrid.py). A token
 # keeps 1,280 B a layer here and 256 B of index key, a twentieth of a
 # per-head page's, and a page's copy costs its issue and not its bytes.
 # Measured on the chip, each kernel alone, ms a layer at pages of 16 / 32 /

@@ -95,6 +95,18 @@ SUPPORTS_PAGED = True
 # `slots` and `valid`. The family counts nothing on the device, so it has
 # no `step_counters`.
 PAGED_SLOT_STATE = True
+# Tokens a page of the pool holds where the engine is given no size
+# (serve/paged_kv.py::page_tokens): LFM2's stored row, [bs, 4, 128]
+# bfloat16, and its reason (models/lfm2_moe.py::PAGE_TOKENS), over contexts
+# six times as long. Measured on the chip, `paged_decode_attention` alone
+# over the rag cell's shape (48 rows: 17 of 2,048-4,800 tokens, 31 idle), ms
+# a layer at pages of 16 / 32 / 64 / 128 tokens (PERF.md section 6, PR 48):
+# 0.429 / 0.336 / 0.296 / 0.279 for 7,350 / 3,714 / 1,896 / 990 copies (19-25
+# ns a copy over 0.26 ms of folds); in the cell `decode_attn_ms` read 1.480 /
+# - / 0.976 / 0.922. 128 reads 5.7 % and 5.6 % under 64, and 256 could buy 3
+# % at most. The price: a slot's last page is half empty on average (512 KB
+# beside 75 MB of state a slot); the prefix registry is off for this family.
+PAGE_TOKENS = 128
 
 
 @dataclass(frozen=True)

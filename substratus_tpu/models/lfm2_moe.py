@@ -64,6 +64,17 @@ SUPPORTS_PAGED = True
 # the cache dict forward returned (serve/engine.py calls it inside its jit,
 # before the cache is carried on).
 PAGED_SLOT_STATE = True
+# Tokens a page of the pool holds where the engine is given no size
+# (serve/paged_kv.py::page_tokens). Heads of 64 lie two to a stored row, so
+# a page of 16 tokens is [16, 4, 128] bfloat16 = 16 KB, half of what a pool
+# of 8 heads of 128 copies at once, and a page's copy costs its issue and
+# not its bytes. Measured on the chip, `paged_decode_attention` alone over
+# the assist cell's shape (64 rows: 31 of 150-1,030 tokens, 33 idle), ms a
+# layer at pages of 16 / 32 / 64 / 128 tokens (PERF.md section 6, PR 48):
+# 0.195 / 0.170 / 0.166 / 0.164 for 2,376 / 1,238 / 668 / 382 copies (20-25
+# ns a copy over 0.16 ms of folds). 128 reads 1 % under 64, and a slot's
+# last page is half empty on average (256 KB at 64).
+PAGE_TOKENS = 64
 _STEP_STATS = "step_stats"
 
 

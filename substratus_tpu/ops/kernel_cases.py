@@ -562,11 +562,15 @@ def chip_cases() -> List[KernelCase]:
         cases.append(q4_matmul(f"{tag}-lm_head", 8, dim, 32000))
     # The decode step of the benchmark's four cells (benchmarks/traffic/):
     # max_batch x max_seq_len, query / KV heads, the cell's pool. LFM2's
-    # heads of 64 lie two to a stored row of 128.
+    # and Granite's heads of 64 lie two to a stored row of 128, in the
+    # families' own pages (64 and 128 tokens: their modules' PAGE_TOKENS).
     cases.append(paged_decode("mistral-chat", 32, 2048, 32, 8, 128, 1793))
     cases.append(paged_decode("mistral-longdoc", 5, 8192, 32, 8, 128, 1921))
     cases.append(paged_decode("k-exaone", 64, 4096, 64, 8, 128, 10241))
-    cases.append(paged_decode("lfm2-assist", 64, 2048, pages=6145, **LFM2))
+    cases.append(paged_decode(
+        "lfm2-assist", 64, 2048, pages=1537, page=64, **LFM2))
+    cases.append(paged_decode(
+        "granite-rag", 48, 9216, pages=3457, page=128, **LFM2))
     # Their largest chunk programs' attention (the chat cell's median prompt
     # is one chunk of 256), a spec_k=4 verify round over the chat cell's
     # batch, and TinyLlama's chunk (4 KV heads of 64: two rows a token).
@@ -574,7 +578,10 @@ def chip_cases() -> List[KernelCase]:
     cases.append(paged_chunk("mistral-chat", 1, 256, 2048, 32, 8, 128, 1793))
     cases.append(paged_chunk("k-exaone", 1, 512, 4096, 64, 8, 128, 10241))
     cases.append(paged_chunk("mistral-verify", 32, 5, 2048, 32, 8, 128, 1793))
-    cases.append(paged_chunk("lfm2-assist", 1, 512, 2048, pages=6145, **LFM2))
+    cases.append(paged_chunk(
+        "lfm2-assist", 1, 512, 2048, pages=1537, page=64, **LFM2))
+    cases.append(paged_chunk(
+        "granite-rag", 1, 512, 9216, pages=3457, page=128, **LFM2))
     cases.append(paged_chunk("tinyllama", 1, 512, 1024, pages=513, **TINYLLAMA))
     # The longctx cell's decode step of one retention layer: 16 slots, 40
     # query heads over 8 of 128, a state of 8,256 x 128 a head.
@@ -615,6 +622,8 @@ def rehearsal_cases() -> List[KernelCase]:
         q4_matmul("small", 8, 256, 128),
         paged_decode("small", 3, 128, h=4, kh=2, d=64, pages=17),
         paged_chunk("small", 2, 16, 128, h=8, kh=4, d=64, pages=17),
+        paged_decode("small", 3, 256, h=8, kh=4, d=64, pages=9, page=64),
+        paged_chunk("small", 2, 16, 256, h=8, kh=4, d=64, pages=9, page=64),
         retention_decode("small", 3, h=4, kh=2, d=16),
         ssm_decode("small", 3, h=4, p=32, n=128),
         latent_attend("small", 3, 1, 128, h=8, dn=32, dr=16, dv=32, rkv=128,

@@ -12,7 +12,8 @@ global page pool, stacked over layers,
 the same bytes in the same order; `init_paged_cache` decides, every reader
 takes `pack` off its operands) and a per-sequence block table [B, max_pages] of page ids.
 page_size is the pool's own: the family's where the engine is given none
-(serve/paged_kv.py::page_tokens: 16, a latent pool's 128), and every reader
+(serve/paged_kv.py::page_tokens: 16, 64 or 128 for a pool that stores heads
+of 64 two to a row, a latent pool's 128), and every reader
 here takes it off the pool's shape. Shapes stay fully
 static under jit (TPU requirement): dynamism lives in the *contents* of the
 block table. Memory is bounded by actual tokens in flight, not

@@ -58,7 +58,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from substratus_tpu.ops.paged_attention import (
-    FOLD_QUERIES, LANES, NEG_INF, _block_copies, _div, _round_up,
+    FOLD_QUERIES, LANES, NEG_INF, _block_copies, _div, _round_up, fold_pages,
 )
 
 # Tokens a decode step folds at once (static sizes: the smallest that holds
@@ -80,12 +80,6 @@ CHUNK_COLUMNS = 512
 def _as_pages(pool):
     """[L, P, bs, 1, w] -> [L, P, bs, w]: the same bytes."""
     return pool.reshape(pool.shape[:3] + pool.shape[4:])
-
-
-def decode_fold_pages(bs: int):
-    """`DECODE_FOLD_TOKENS` in pages of `bs` tokens, ascending; the last is
-    the pages of a DMA block."""
-    return tuple(sorted({max(t // bs, 1) for t in DECODE_FOLD_TOKENS}))
 
 
 def _wait_block(each_copy, buf, sem, b, j, slot, full):
@@ -214,7 +208,7 @@ def latent_decode_attention(
     bs = lat.shape[2]
     assert lat.shape[3] == w and w % LANES == 0 and rkv % LANES == 0, (
         lat.shape, w, rkv)
-    folds = decode_fold_pages(bs)
+    folds = fold_pages(bs, DECODE_FOLD_TOKENS)  # the last: a DMA block
     block = (2, folds[-1], bs, w)
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     vmem = pl.BlockSpec(memory_space=pltpu.VMEM)

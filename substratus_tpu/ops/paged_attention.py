@@ -72,13 +72,22 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
-# Pages one fold of the running softmax takes: the smallest of these that
-# holds what a block has of its row (a fold costs 0.35 us however small, then
-# 0.08 us a page: an idle row's one page is folded as 2, a row's full
-# blocks as 32). The largest is also the pages of a DMA block, of which two
-# are in VMEM. Measured on the chip: PERF.md section 6, PR 28.
-FOLD_PAGES = (2, 8, 32)
+# Tokens one fold of the running softmax takes, in whole pages of the pool
+# (`fold_pages`): the smallest of these that holds what a block has of its
+# row (a fold costs 0.35 us however small, then 0.08 us for 16 tokens: at
+# the page of 16 an idle row's one page is folded as 2, a row's full blocks
+# as 32; at a page of 64 as 1 and as 8). The largest is also the tokens of a
+# DMA block, of which two are in VMEM: the blocks, the folds and VMEM are
+# the same at every page that divides them, and a longer page is fewer
+# copies. Measured on the chip: PERF.md section 6, PR 28 and PR 48.
+FOLD_TOKENS = (32, 128, 512)
 LANES = 128
+
+
+def fold_pages(bs: int, tokens=FOLD_TOKENS) -> tuple:
+    """`tokens` in pages of `bs` tokens, ascending, each at least one page
+    and none twice: (2, 8, 32) at 16, (1, 2, 8) at 64, (1, 4) at 128."""
+    return tuple(sorted({max(t // bs, 1) for t in tokens}))
 
 
 def _div(x, n: int):
@@ -189,7 +198,7 @@ def _kernel(layer_ref, pos_ref, bt_ref, q_ref, k_hbm, v_hbm, o_ref,
             each_copy(b, j, slot, lambda c: c.wait())
             held = pages_of(b) - j * ppb  # may pass ppb
             fewer = 0
-            for pages in FOLD_PAGES:
+            for pages in fold_pages(bs):
                 fits = held > fewer
                 if pages < ppb:
                     fits &= held <= pages
@@ -226,7 +235,7 @@ def paged_decode_attention(
     n_rows, n_heads, hd = q.shape
     bs, kh = k_pool.shape[2:4]
     assert n_heads % kh == 0, (n_heads, kh)
-    block = (2, FOLD_PAGES[-1], bs, kh, hd)
+    block = (2, fold_pages(bs)[-1], bs, kh, hd)
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
     hbm = pl.BlockSpec(memory_space=pl.ANY)
@@ -254,10 +263,10 @@ def paged_decode_attention(
 
 # --- more than one query token a row: a prefill chunk, a verify round --------
 
-# Pages of one DMA block of the chunk kernel (two such blocks of K and of V
-# are in VMEM, the next on its way while one is folded): 512 keys at the
-# engine's page size of 16.
-CHUNK_PAGES = 32
+# Keys of one DMA block of the chunk kernel, in whole pages of the pool (two
+# such blocks of K and of V are in VMEM, the next on its way while one is
+# folded): 32 pages of 16 tokens, 8 of 64.
+CHUNK_TOKENS = 512
 # Query columns (S x group, padded to lanes) one grid step keeps the running
 # softmax of, and those one fold takes of them. Measured on the chip: PERF.md
 # section 6, PR 30.
@@ -438,11 +447,12 @@ def paged_chunk_attention(
     positions = jnp.minimum(positions.astype(jnp.int32), reach)
     qpos = jnp.pad(jnp.tile(positions, (1, group)),
                    ((0, 0), (0, padded - cols)))[:, None]  # [B, 1, padded]
-    keys = CHUNK_PAGES * bs
+    ppb = max(CHUNK_TOKENS // bs, 1)
+    keys = ppb * bs
     tile = lambda *shape: pl.BlockSpec(  # noqa: E731
         (1,) + shape + (width,), lambda b, i, *_: (b,) + (0,) * len(shape) + (i,))
     hbm = pl.BlockSpec(memory_space=pl.ANY)
-    block = (2, CHUNK_PAGES, bs, kh, hd)
+    block = (2, ppb, bs, kh, hd)
     vmem = (
         2 * 2 * kh * hd * width * q.dtype.itemsize  # q and out, two buffers
         + kh * hd * width * 4  # acc

@@ -138,10 +138,11 @@ METRICS.describe(
     "substratus_serve_kv_page_tokens",
     "Tokens one page of the pool holds, set where the pool is made: "
     "EngineConfig.page_size where given, else the family's "
-    "(serve/paged_kv.py::page_tokens): 16, and 128 for a latent pool "
-    "(models/deepseek_v3.py::PAGE_TOKENS). A page is what a block-table "
-    "entry names, an attention kernel copies at once and the prefix "
-    "registry shares.",
+    "(serve/paged_kv.py::page_tokens): 16, 64 and 128 where a stored row "
+    "holds two heads of 64 (models/lfm2_moe.py, models/granitemoehybrid.py), "
+    "128 for a latent pool (models/deepseek_v3.py). A page is what a "
+    "block-table entry names, an attention kernel copies at once and the "
+    "prefix registry shares.",
     type="gauge",
 )
 METRICS.describe(
@@ -295,8 +296,9 @@ class EngineConfig:
     # per slot), or "auto" (paged when the model family supports it).
     kv_layout: str = "auto"
     # Tokens per KV page (paged layout). None = the family's: 16, or what
-    # its module states (`PAGE_TOKENS`: 128 for a latent pool, whose rows
-    # are a twentieth of a per-head page's); serve/paged_kv.py::page_tokens.
+    # its module states (`PAGE_TOKENS`: 64 or 128 where a stored row holds
+    # two heads of 64, 128 for a latent pool, whose rows are a twentieth of
+    # a per-head page's); serve/paged_kv.py::page_tokens.
     page_size: Optional[int] = None
     # Total pool size in tokens (paged). None = max_batch * max_seq_len
     # (the dense footprint); set lower to oversubscribe slots against real

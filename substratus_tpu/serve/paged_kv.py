@@ -29,7 +29,8 @@ def page_tokens(model, given: Optional[int] = None) -> int:
     """Tokens one page of `model`'s pool holds: the size given, else the
     family's own (`PAGE_TOKENS` of its module: a pool whose rows are small
     states a longer page, since a page's copy costs its issue and not its
-    bytes), else 16."""
+    bytes: 128 for a latent pool, 64 or 128 for one that stores heads of 64
+    two to a row), else 16."""
     if given is not None:
         return given
     return getattr(model, "PAGE_TOKENS", PAGE_TOKENS)

@@ -9,8 +9,10 @@ import re
 import jax
 import jax.numpy as jnp
 
-# Every cell's engine: pages of 16 tokens (the latent family states its own),
-# chunks of 512.
+# Every cell's engine: chunks of 512, and pages of 16 tokens where the family
+# states no page of its own (the latent family states 128, the two whose
+# stored row holds two heads of 64 state 64 and 128: their files take the
+# engine's).
 PAGE, CHUNK = 16, 512
 
 
@@ -84,12 +86,12 @@ def region_ops(hlo: str, region: str) -> tuple:
 
 def reads_pages_in_place(hlo: str, kernel: str, rows: int, seq: int,
                           kv_heads: int, head_dim: int,
-                          scores: int = 0) -> bool:
+                          scores: int = 0, page: int = PAGE) -> bool:
     """The program's attention is the named kernel of
     ops/paged_attention.py, and nothing in the program is a gathered K or V
     (a result [..., kv_heads, head_dim] of rows x seq positions, flat or
-    as pages of PAGE: gone, not moved) nor a float32 result of `scores` = heads x S x seq elements (a
-    chunk's scores never reach HBM)."""
+    as pages of `page`: gone, not moved) nor a float32 result of `scores` =
+    heads x S x seq elements (a chunk's scores never reach HBM)."""
     sized = []
     for m in re.finditer(r"= (\w+)\[([\d,]+)\]\S* [\w-]+\(", hlo):
         dims = list(map(int, m.group(2).split(",")))
@@ -97,7 +99,7 @@ def reads_pages_in_place(hlo: str, kernel: str, rows: int, seq: int,
         lead = set(dims[:-2])
         context = (dims[-2:] == [kv_heads, head_dim]
                    and n == rows * seq * kv_heads * head_dim
-                   and (seq in lead or {rows * seq // PAGE, PAGE} <= lead))
+                   and (seq in lead or {rows * seq // page, page} <= lead))
         if context or (m.group(1) == "f32" and n == scores):
             sized.append(m.group(0))
     found = re.search(

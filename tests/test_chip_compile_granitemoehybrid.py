@@ -11,13 +11,15 @@ import jax.numpy as jnp
 import pytest
 
 from chip_compile import (
-    CHUNK, KERNEL, PAGE, described, pool_moving_ops, reads_pages_in_place,
+    CHUNK, KERNEL, described, pool_moving_ops, reads_pages_in_place,
     region_ops, sorts_only_where_a_row_samples, weights_laid_out_anew,
 )
 
 # The rag cell's engine (benchmarks/traffic/rag.json): all 40 layers, 48
-# slots of 9,216, every slot's pages whole.
-_G_B, _G_S = 48, 9216
+# slots of 9,216, every slot's pages whole, in the family's own pages of 128
+# tokens (the harness gives no page_size: models/granitemoehybrid.py::
+# PAGE_TOKENS).
+_G_B, _G_S, PAGE = 48, 9216, 128
 _G_PAGES = _G_B * _G_S // PAGE
 
 
@@ -32,9 +34,11 @@ def _granite_programs(v5e):
     assert (cfg.count(M.MAMBA), cfg.count(M.ATTN)) == (36, 4)
     eng = Engine(cfg, None, EngineConfig(
         max_batch=_G_B, max_seq_len=_G_S, max_prefill_len=CHUNK,
-        page_size=PAGE, kv_pool_tokens=1,
+        kv_pool_tokens=1,
     ))
     assert eng.slot_state and eng.prefix is None and eng._page_layers == 4
+    assert eng.page_size == M.PAGE_TOKENS == PAGE
+    assert eng.block_table.shape == (_G_B, 72)
     placed, arr = described(v5e, eng)
     params = placed(jax.eval_shape(
         lambda key: quantize_params(
@@ -107,7 +111,8 @@ def test_granite_programs_compile_and_leave_three_histories_in_place(granite):
         assert "kv.gather" not in hlo, name
         assert reads_pages_in_place(
             hlo, KERNEL[name], _G_B if name == "decode" else 1, _G_S, 8, 64,
-            scores=0 if name == "decode" else 32 * CHUNK * _G_S), name
+            scores=0 if name == "decode" else 32 * CHUNK * _G_S,
+            page=PAGE), name
         assert _state_kernel_calls(hlo) == (9 if name == "decode" else 0)
         assert sorts_only_where_a_row_samples(hlo) == (name == "decode")
         if name == "decode":

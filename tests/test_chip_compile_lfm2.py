@@ -8,14 +8,16 @@ import jax
 import jax.numpy as jnp
 
 from chip_compile import (
-    CHUNK, KERNEL, PAGE, pool_moving_ops, reads_pages_in_place,
+    CHUNK, KERNEL, pool_moving_ops, reads_pages_in_place,
     sorts_only_where_a_row_samples,
 )
 
 
 # The assist cell's engine (benchmarks/traffic/assist.json): LFM2-24B-A2B's
-# first 16 layers, all 64 experts, the whole vocabulary.
-_F_POOL_PAGES, _F_B, _F_S = 6144, 64, 2048
+# first 16 layers, all 64 experts, the whole vocabulary, a pool of 98,304
+# tokens in the family's own pages of 64 (the harness gives no page_size:
+# models/lfm2_moe.py::PAGE_TOKENS).
+_F_POOL_PAGES, _F_B, _F_S, PAGE = 1536, 64, 2048, 64
 
 
 def test_lfm2_programs_compile_and_leave_the_conv_state_in_place(v5e):
@@ -36,9 +38,11 @@ def test_lfm2_programs_compile_and_leave_the_conv_state_in_place(v5e):
     assert (cfg.count(lfm2_moe.CONV), cfg.count(lfm2_moe.ATTN)) == (12, 4)
     eng = Engine(cfg, None, EngineConfig(
         max_batch=_F_B, max_seq_len=_F_S, max_prefill_len=CHUNK,
-        page_size=PAGE, kv_pool_tokens=1,
+        kv_pool_tokens=1,
     ))
     assert eng.slot_state and eng.prefix is None
+    assert eng.page_size == lfm2_moe.PAGE_TOKENS == PAGE
+    assert eng.block_table.shape == (_F_B, 32)
     rep = SingleDeviceSharding(v5e[0])
 
     def placed(tree):
@@ -86,7 +90,8 @@ def test_lfm2_programs_compile_and_leave_the_conv_state_in_place(v5e):
         rows = _F_B if name == "decode" else 1
         assert reads_pages_in_place(
             hlo, KERNEL[name], rows, _F_S, cfg.n_kv_heads, cfg.head_size,
-            cfg.n_heads * CHUNK * _F_S if name == "chunk" else 0), name
+            cfg.n_heads * CHUNK * _F_S if name == "chunk" else 0,
+            page=PAGE), name
         assert "kv.gather" not in hlo, name
         assert all(s in hlo for s in ("conv.in", "conv.state", "conv.out"))
         assert sorts_only_where_a_row_samples(hlo) == (name == "decode")

@@ -286,7 +286,7 @@ def test_the_decode_kernel_is_the_gathered_absorbed_form(monkeypatch, pages,
     that end with a block of 64 pages, one page into the next and in the
     third (every size the kernel folds at once, a full block before a
     short one): bfloat16's rounding of the read-out apart."""
-    assert LA.decode_fold_pages(16) == (2, 8, 32, 64)
+    assert LA.fold_pages(16, LA.DECODE_FOLD_TOKENS) == (2, 8, 32, 64)
     pool, bt, w_uk, w_uv, kq, kn = _kernel_case(jax.random.key(0),
                                                 pages=pages, m=m)
     q = jax.random.normal(kq, (3, 1, 8, 48)).astype(jnp.bfloat16)

@@ -8,8 +8,12 @@ to the kernels as they are (interpret mode tiles anything); the `kh*` pools
 are stored as ops/kvcache.py::init_paged_cache stores them, two heads to a
 row of 128, and reach the kernels the way `paged_attend` takes them there
 (q widened to the row, the head's own lanes kept), against the gather over
-the same packed pool. The whole engine through the kernels, token for
-token, is tests/test_paged_attention_engine.py.
+the same packed pool. A page holds 16 tokens, or the 64 and 128 of the
+families whose stored row holds two heads of 64 (models/lfm2_moe.py,
+models/granitemoehybrid.py: PAGE_TOKENS): the kernels size their DMA blocks
+and folds in tokens, so the same table of 640 positions is two blocks at
+each. The whole engine through the
+kernels, token for token, is tests/test_paged_attention_engine.py.
 """
 from functools import partial
 
@@ -20,15 +24,17 @@ import pytest
 
 from substratus_tpu.ops import kvcache
 from substratus_tpu.ops.paged_attention import (
-    CHUNK_PAGES, FOLD_PAGES, paged_chunk_attention,
+    CHUNK_TOKENS, FOLD_TOKENS, fold_pages, paged_chunk_attention,
 )
 
-BS, M, KH, HD, LAYERS = 16, 40, 2, 64, 3  # a table of 640 positions: two
-PAGES = 1 + 4 * M                          # DMA blocks of FOLD_PAGES[-1]
-FULL = M * BS
+BS, KH, HD, LAYERS = 16, 2, 64, 3
+FULL = 640  # a table of 640 positions: two DMA blocks of either kernel
 TOL = {jnp.bfloat16: 2e-2, jnp.float32: 1e-5}
-assert FOLD_PAGES[-1] < M < 2 * FOLD_PAGES[-1]
-assert CHUNK_PAGES < M < 2 * CHUNK_PAGES
+assert FOLD_TOKENS[-1] < FULL < 2 * FOLD_TOKENS[-1]
+assert CHUNK_TOKENS < FULL < 2 * CHUNK_TOKENS
+# The page of a case: 16 tokens, and the 64 and 128 of pools of 64-wide heads.
+PAGE = pytest.mark.parametrize(
+    "bs", [16, 64, 128], ids=["page16", "page64", "page128"])
 BUCKETS = [16, 32, 64, 128, 256, 512]  # the engine's prefill buckets
 # KV heads of a pool stored as init_paged_cache stores it (None: declared).
 # On a TPU the op takes a kernel over 2, 4 or a multiple of 8 rows a token
@@ -43,17 +49,19 @@ def _normal(key, shape, dtype):
 
 
 def _case(dtype, group, lengths, layer=1, tables=None, seed=0, s=1, real=None,
-          kv_heads=KH, packed=None, hd=HD):
+          kv_heads=KH, packed=None, hd=HD, bs=BS):
     """A seeded pool, a call's q and new K/V rows for rows of `lengths`
     tokens (the `s` new rows are the last of them; of these only the first
     `real` are a prompt's and the padded tail is clamped onto the position
     after them, as the engine's chunk program clamps it), and block tables
     of scattered pages unless given. `packed`: that many KV heads, in the
-    shape init_paged_cache stores them."""
+    shape init_paged_cache stores them. `bs`: the tokens of a page."""
     keys = jax.random.split(jax.random.key(seed), 6)
     b = len(lengths)
     kv_heads = packed or kv_heads
-    shape = (LAYERS, PAGES, BS, kv_heads, hd)
+    m = FULL // bs
+    pages = 1 + 4 * m
+    shape = (LAYERS, pages, bs, kv_heads, hd)
     if packed:
         shape = jax.eval_shape(
             lambda: kvcache.init_paged_cache(*shape, dtype))["k"].shape
@@ -65,8 +73,8 @@ def _case(dtype, group, lengths, layer=1, tables=None, seed=0, s=1, real=None,
     v_new = _normal(keys[4], (b, s, kv_heads, hd), dtype)
     if tables is None:
         tables = np.asarray(
-            jax.random.permutation(keys[5], np.arange(1, PAGES))[: b * M]
-        ).reshape(b, M)
+            jax.random.permutation(keys[5], np.arange(1, pages))[: b * m]
+        ).reshape(b, m)
     first = jnp.asarray(lengths, jnp.int32)[:, None] - s
     positions = first + jnp.arange(s, dtype=jnp.int32)[None, :]
     if real is not None:
@@ -102,20 +110,25 @@ def test_a_row_of_every_length_matches_the_gathered_attention(
     dtype, group, length
 ):
     pool, layer, table, pos, q, k_new, v_new = _case(
-        dtype, group, [length, 3 * BS + 5, FOLD_PAGES[-1] * BS + 1])
+        dtype, group, [length, 3 * BS + 5, FOLD_TOKENS[-1] + 1])
     out, want = _reference(pool, layer, table, pos, q, k_new, v_new)
     _close(_kernel(out, layer, table, pos, q), want, dtype)
 
 
-@pytest.mark.parametrize("length", [1, BS - 1, BS, BS + 1, FULL],
-                         ids=["one", "page-1", "page", "page+1", "full"])
+@PAGE
+@pytest.mark.parametrize("length", ["one", "page-1", "page", "page+1", "full"])
 @pytest.mark.parametrize("packed", [2, 4, 8], ids=["kh2", "kh4", "kh8"])
-def test_a_row_of_every_length_out_of_a_packed_pool(packed, length):
+def test_a_row_of_every_length_out_of_a_packed_pool(packed, length, bs):
     """The same over rows that hold two KV heads of 64 each: the query
-    heads of a pair share the pair's row and each keeps its own lanes."""
+    heads of a pair share the pair's row and each keeps its own lanes. At
+    every page: an idle row's one token, a row that ends a token short of
+    its page's end, on it and a token into the next, a full table, beside
+    a row that ends mid-page and one a token longer than a DMA block."""
+    length = {"one": 1, "page-1": bs - 1, "page": bs, "page+1": bs + 1,
+              "full": FULL}[length]
     pool, layer, table, pos, q, k_new, v_new = _case(
-        jnp.bfloat16, 4, [length, 3 * BS + 5, FOLD_PAGES[-1] * BS + 1],
-        packed=packed)
+        jnp.bfloat16, 4, [length, 3 * bs + 5, FOLD_TOKENS[-1] + 1],
+        packed=packed, bs=bs)
     out, want = _reference(pool, layer, table, pos, q, k_new, v_new)
     assert out["k"].shape == pool["k"].shape
     _close(_kernel(out, layer, table, pos, q), want, jnp.bfloat16)
@@ -141,19 +154,23 @@ def test_the_layer_is_an_offset_into_the_stack(layer, s):
     [(1, None), (1, 2), (1, 4), (1, 8), (32, None), (32, 4), (32, 8)],
     ids=["step", "step-kh2", "step-kh4", "step-kh8", "chunk", "chunk-kh4",
          "chunk-kh8"])
-def test_scattered_pages_and_a_prefix_two_rows_share(s, packed):
-    """Rows 0 and 1 hold the same first five pages (a prefix hit) and
-    their own after them; every page lies somewhere else in the pool. (A
-    chunk's rows are written after the shared pages.)"""
-    tables = np.zeros((3, M), np.int32)
-    order = np.random.default_rng(3).permutation(np.arange(1, PAGES))
-    tables[0, :9] = order[:9]
-    tables[1, :5] = order[:5]
-    tables[1, 5:12] = order[20:27]
-    tables[2] = order[40:40 + M][::-1]
-    lengths = [9 * BS - 3, 12 * BS, FULL - 7]
+@PAGE
+def test_scattered_pages_and_a_prefix_two_rows_share(s, packed, bs):
+    """Rows 0 and 1 hold the same first pages (a prefix hit: five of 16
+    tokens, two of 64, one of 128) and their own after them; every page lies somewhere
+    else in the pool. (A chunk's rows are written after the shared
+    pages.)"""
+    m = FULL // bs
+    shared, a, b = {16: (5, 9, 12), 64: (2, 4, 6), 128: (1, 2, 3)}[bs]
+    tables = np.zeros((3, m), np.int32)
+    order = np.random.default_rng(3).permutation(np.arange(1, 1 + 4 * m))
+    tables[0, :a] = order[:a]
+    tables[1, :shared] = order[:shared]
+    tables[1, shared:b] = order[2 * m:2 * m + b - shared]
+    tables[2] = order[-m:][::-1]
+    lengths = [a * bs - 3, b * bs, FULL - 7]
     pool, layer, table, pos, q, k_new, v_new = _case(
-        jnp.bfloat16, 4, lengths, tables=tables, s=s, packed=packed)
+        jnp.bfloat16, 4, lengths, tables=tables, s=s, packed=packed, bs=bs)
     out, want = _reference(pool, layer, table, pos, q, k_new, v_new)
     _close(_kernel(out, layer, table, pos, q), want, jnp.bfloat16)
 
@@ -162,7 +179,10 @@ def test_scattered_pages_and_a_prefix_two_rows_share(s, packed):
 @pytest.mark.parametrize("s", [1, 16], ids=["step", "chunk"])
 @pytest.mark.parametrize("garbage", [float("nan"), 3e37, -3e37],
                          ids=["nan", "huge", "-huge"])
-def test_a_row_sees_its_own_pages_up_to_its_own_position(garbage, s, packed):
+@PAGE
+def test_a_row_sees_its_own_pages_up_to_its_own_position(
+    garbage, s, packed, bs
+):
     """An idle row as the engine leaves it (position 0, a table of the
     trash page: it costs the one page its own write landed on; for a chunk,
     a row whose `s` tokens are all it holds), a row of one page (a chunk:
@@ -171,22 +191,23 @@ def test_a_row_sees_its_own_pages_up_to_its_own_position(garbage, s, packed):
     layers, pages nobody owns, the rest of each row's last page, and the
     first row's table past its first entry pointing at pages of garbage
     too. The answer is the clean pool's."""
-    lengths = [s, BS - 4 + (s > 1) * s, FULL]
+    lengths = [s, bs - 4 + (s > 1) * s, FULL]
     pool, layer, table, pos, q, k_new, v_new = _case(
-        jnp.bfloat16, 8, lengths, s=s, packed=packed)
+        jnp.bfloat16, 8, lengths, s=s, packed=packed, bs=bs)
     table = table.at[0].set(0)
     clean, want = _reference(pool, layer, table, pos, q, k_new, v_new)
-    seen = np.zeros((LAYERS, PAGES, BS), bool)
+    pages, m = pool["k"].shape[1], table.shape[1]
+    seen = np.zeros((LAYERS, pages, bs), bool)
     for row, n in enumerate(lengths):
         for p in range(n):
-            seen[1, int(table[row, p // BS]), p % BS] = True
+            seen[1, int(table[row, p // bs]), p % bs] = True
     dirty = {
         name: jnp.where(seen[..., None, None], a, garbage).astype(a.dtype)
         for name, a in clean.items()
     }
     # the idle row's stale entries lead to garbage, which it never reads
-    unowned = [p for p in range(1, PAGES) if not seen[1, p].any()]
-    stale = table.at[0, 1:].set(jnp.asarray(unowned[: M - 1], jnp.int32))
+    unowned = [p for p in range(1, pages) if not seen[1, p].any()]
+    stale = table.at[0, 1:].set(jnp.asarray(unowned[: m - 1], jnp.int32))
     got = _kernel(dirty, layer, stale, pos, q)
     assert np.isfinite(np.asarray(got, np.float32)).all()
     _close(got, want, jnp.bfloat16)
@@ -198,10 +219,11 @@ def test_a_row_sees_its_own_pages_up_to_its_own_position(garbage, s, packed):
 
 @pytest.mark.parametrize("where", ["start", "mid-page", "table-end"])
 @pytest.mark.parametrize("bucket", BUCKETS)
-@pytest.mark.parametrize("group,packed", [(4, None), (8, None), (4, 8)],
-                         ids=["4", "8", "4-kh8"])
+@pytest.mark.parametrize(
+    "group,packed,bs", [(4, None, 16), (8, None, 16), (4, 8, 16), (4, 8, 64)],
+    ids=["4", "8", "4-kh8", "4-kh8-page64"])
 def test_a_chunk_of_every_bucket_matches_the_gathered_attention(
-    group, packed, bucket, where
+    group, packed, bs, bucket, where
 ):
     """A prefill chunk of each of the engine's buckets: the prompt's first
     (nothing before it), one that starts in the middle of a page, and one
@@ -211,36 +233,38 @@ def test_a_chunk_of_every_bucket_matches_the_gathered_attention(
               "table-end": FULL - bucket}[where]
     pool, layer, table, pos, q, k_new, v_new = _case(
         jnp.bfloat16, group, [before + bucket, bucket + BS + 3], s=bucket,
-        packed=packed)
+        packed=packed, bs=bs)
     out, want = _reference(pool, layer, table, pos, q, k_new, v_new)
     _close(_kernel(out, layer, table, pos, q), want, jnp.bfloat16)
 
 
+@PAGE
 @PACKED
 @pytest.mark.parametrize("real", [1, 19, 31])
-def test_a_chunks_padded_tail_is_clamped_onto_one_position(real, packed):
+def test_a_chunks_padded_tail_is_clamped_onto_one_position(real, packed, bs):
     """The engine pads a prompt's last chunk to its bucket and clamps the
     tail onto the one position after the prompt: the kernel reads the
     positions it is given, consecutive or not."""
     s = 32
     pool, layer, table, pos, q, k_new, v_new = _case(
-        jnp.bfloat16, 4, [3 * BS + 5 + s, FULL], s=s, real=real,
-        packed=packed)
-    assert int(pos[0, -1]) == int(pos[0, real]) == 3 * BS + 5 + real
+        jnp.bfloat16, 4, [3 * bs + 5 + s, FULL], s=s, real=real,
+        packed=packed, bs=bs)
+    assert int(pos[0, -1]) == int(pos[0, real]) == 3 * bs + 5 + real
     out, want = _reference(pool, layer, table, pos, q, k_new, v_new)
     _close(_kernel(out, layer, table, pos, q), want, jnp.bfloat16)
 
 
+@PAGE
 @PACKED
 @pytest.mark.parametrize("s", [2, 5], ids=["k1", "k4"])
-def test_a_verify_round_matches_the_gathered_attention(s, packed):
+def test_a_verify_round_matches_the_gathered_attention(s, packed, bs):
     """A speculative round: every slot brings k + 1 consecutive positions
     from its own length on, one of them past the table's reach (its writes
     go to the trash page, ops/kvcache.py::_write; its queries see the whole
     table), one idle at position 0."""
-    lengths = [s, 7 * BS + s, FULL - 1, FULL + 2]
+    lengths = [s, 7 * bs + s, FULL - 1, FULL + 2]
     pool, layer, table, pos, q, k_new, v_new = _case(
-        jnp.bfloat16, 4, lengths, s=s, packed=packed)
+        jnp.bfloat16, 4, lengths, s=s, packed=packed, bs=bs)
     out, want = _reference(pool, layer, table, pos, q, k_new, v_new)
     _close(_kernel(out, layer, table, pos, q), want, jnp.bfloat16)
 
@@ -255,14 +279,15 @@ def test_what_is_not_packed_still_gathers(kv_heads, hd, dtype, s):
     """An odd count of 64-wide heads, a head width that does not divide a
     row of 128, an int8 pool and a float32 pool are stored as declared, and
     no kernel takes them: on a TPU too the op gathers, as before."""
+    pages = 1 + 4 * FULL // BS
     pool = kvcache.init_paged_cache(
-        LAYERS, PAGES, BS, kv_heads, hd, dtype, quantized=dtype == jnp.int8)
-    assert pool["k"].shape == (LAYERS, PAGES, BS, kv_heads, hd)
+        LAYERS, pages, BS, kv_heads, hd, dtype, quantized=dtype == jnp.int8)
+    assert pool["k"].shape == (LAYERS, pages, BS, kv_heads, hd)
     # nor rows that do not fill the sublane tile Mosaic gives a page: two
     # heads of 64 packed into one row, six heads of 128
     for heads, width in ((2, 64), (6, 128)):
         untiled = kvcache.init_paged_cache(
-            LAYERS, PAGES, BS, heads, width, jnp.bfloat16)
+            LAYERS, pages, BS, heads, width, jnp.bfloat16)
         assert untiled["k"].shape[4] == 128
         assert kvcache._kernel_for(
             untiled["k"], jnp.zeros((2, s, 24, width), jnp.bfloat16)) is None
@@ -272,7 +297,7 @@ def test_what_is_not_packed_still_gathers(kv_heads, hd, dtype, s):
     out, got = _reference(pool, layer, table, pos, q, k_new, v_new)
     # the context of a pool that held nothing is the new rows alone
     plain = kvcache.init_paged_cache(
-        LAYERS, PAGES, BS, kv_heads, hd, jnp.float32)
+        LAYERS, pages, BS, kv_heads, hd, jnp.float32)
     _, want = _reference(plain, layer, table, pos, q, k_new, v_new)
     assert out["k"].dtype == dtype
     np.testing.assert_allclose(
@@ -280,11 +305,49 @@ def test_what_is_not_packed_still_gathers(kv_heads, hd, dtype, s):
         atol=6e-2 if dtype == jnp.int8 else 2e-2, rtol=0)
 
 
+@PAGE
 @pytest.mark.parametrize("kv_heads", [2, 4, 8])
-def test_a_chunk_reads_each_kv_head_out_of_the_pages(kv_heads):
+def test_a_chunk_reads_each_kv_head_out_of_the_pages(kv_heads, bs):
     """The chunk kernel takes the heads out of a page two to a 32-bit word,
     every (KH / 2)th word-row: one pair, two and four."""
     pool, layer, table, pos, q, k_new, v_new = _case(
-        jnp.bfloat16, 4, [5 * BS + 7, FULL], s=64, kv_heads=kv_heads)
+        jnp.bfloat16, 4, [5 * bs + 7, FULL], s=64, kv_heads=kv_heads, bs=bs)
     out, want = _reference(pool, layer, table, pos, q, k_new, v_new)
     _close(_kernel(out, layer, table, pos, q), want, jnp.bfloat16)
+
+
+def test_blocks_and_folds_are_sized_in_tokens():
+    """The kernels state their folds (32 / 128 / 512 tokens) and DMA blocks
+    (512) in tokens and take the pages off the pool: at the page of 16 they
+    are the constants the kernels were measured with (folds of 2, 8 and 32
+    pages, blocks of 32), so a family that keeps 16 runs the programs it
+    ran; at a longer page a block holds the same tokens in fewer copies
+    and VMEM holds what it held (the decode kernel's two buffers of K, the
+    chunk kernel's and its limit), and no fold is less than a page."""
+    from chip_compile import kernel_vmem
+
+    assert (FOLD_TOKENS, CHUNK_TOKENS) == ((32, 128, 512), 512)
+    assert fold_pages(16) == (2, 8, 32)
+    assert fold_pages(32) == (1, 4, 16)
+    assert fold_pages(64) == (1, 2, 8)
+    assert fold_pages(128) == (1, 4)
+    assert fold_pages(1024) == (1,)
+
+    def vmem(bs, s):
+        pool = jax.ShapeDtypeStruct((2, 9, bs, 4, 128), jnp.bfloat16)
+        q = jax.ShapeDtypeStruct((3, s, 32, 128), jnp.bfloat16)
+        table = jax.ShapeDtypeStruct((3, 2048 // bs), jnp.int32)
+        pos = jax.ShapeDtypeStruct((3, s), jnp.int32)
+        kernel = paged_chunk_attention if s > 1 else kvcache._one_token
+        traced = jax.jit(partial(kernel, scale=0.125)).trace(
+            q, pool, pool, jax.ShapeDtypeStruct((), jnp.int32), table, pos)
+        (found,) = kernel_vmem(traced).values()
+        return found
+
+    assert vmem(16, 1) == (None, (2, 32, 16, 4, 128))
+    assert vmem(64, 1) == (None, (2, 8, 64, 4, 128))
+    limit, block = vmem(16, 512)
+    assert block == (2, 32, 16, 4, 128)
+    assert vmem(64, 512) == (limit, (2, 8, 64, 4, 128))
+    assert vmem(128, 512) == (limit, (2, 4, 128, 4, 128))
+    assert vmem(1024, 512)[1] == (2, 1, 1024, 4, 128)

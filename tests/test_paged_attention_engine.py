@@ -31,7 +31,8 @@ def _greedy(model, cfg, params, prompts, max_tokens, **ec):
     return outs, eng
 
 
-@pytest.mark.parametrize("family", ["llama", "exaone_moe", "llama-hd64"])
+@pytest.mark.parametrize(
+    "family", ["llama", "exaone_moe", "llama-hd64", "llama-hd64-page64"])
 def test_the_engine_serves_the_same_tokens_through_the_kernel(
     family, monkeypatch, pallas_interpret
 ):
@@ -40,8 +41,10 @@ def test_the_engine_serves_the_same_tokens_through_the_kernel(
     requests of unlike lengths over four slots, so one row idles
     throughout; the longest prompt takes three chunks. Heads are 128 wide,
     or 64 wide in a pool that stores them two to a row (4 KV heads: two
-    rows a token); any other pool the op leaves on the gather path. The
-    sparse family
+    rows a token; once more in pages of 64 tokens, what the families with
+    such a pool state, over prompts four times as long: rows of three
+    pages, two and one, blocks of 8 pages); any other pool the op leaves on
+    the gather path. The sparse family
     routes every token to all its experts here: the kernels' outputs lie
     within one bfloat16 rounding of the gather's, and on random weights
     that flips a top-4-of-16 choice every few tokens, which says nothing
@@ -52,23 +55,25 @@ def test_the_engine_serves_the_same_tokens_through_the_kernel(
     gather's as at heads of 128: 0.03.)"""
     from substratus_tpu.models import exaone_moe, llama
 
+    long = 4 if family.endswith("page64") else 1
     if family == "llama":
         model, cfg = llama, llama.CONFIGS["tiny"].replace(dim=512)
-    elif family == "llama-hd64":
+    elif family.startswith("llama-hd64"):
         model, cfg = llama, llama.CONFIGS["tiny"].replace(
-            dim=512, n_heads=8, n_kv_heads=4)
+            dim=512, n_heads=8, n_kv_heads=4, max_seq_len=96 * long)
     else:
         model = exaone_moe
         cfg = exaone_moe.CONFIGS["tiny-exaone-moe"].replace(
             head_dim=128, n_experts_per_token=16)
     assert cfg.dtype == jnp.bfloat16
-    assert cfg.head_size == (64 if family == "llama-hd64" else 128)
+    assert cfg.head_size == (64 if family.startswith("llama-hd64") else 128)
     params = model.init_params(cfg, jax.random.key(0))
     toks = np.asarray(jax.random.randint(
-        jax.random.key(5 if family == "llama-hd64" else 3), (64,), 0,
-        cfg.vocab_size))
-    prompts = [toks[:37], toks[3:26], toks[40:49]]
-    ec = dict(max_batch=4, max_seq_len=96, max_prefill_len=16, page_size=4)
+        jax.random.key(5 if family.startswith("llama-hd64") else 3),
+        (64 * long,), 0, cfg.vocab_size))
+    prompts = [toks[:37 * long], toks[3 * long:26 * long], toks[40:49]]
+    ec = dict(max_batch=4, max_seq_len=96 * long, max_prefill_len=16,
+              page_size=4 if long == 1 else 64)
     want, _ = _greedy(model, cfg, params, prompts, 12, **ec)
     _force_the_kernel(monkeypatch)
     picked = []

@@ -195,15 +195,18 @@ def test_pool_pages_all_recovered_after_load(setup):
 
 @pytest.mark.parametrize("name,given,page", [
     ("tiny-deepseek-v3", None, 128), ("tiny-glm-dsa", None, 128),
+    ("tiny-lfm2-moe", None, 64), ("tiny-granite-hybrid", None, 128),
     ("tiny", None, 16), ("tiny-exaone-moe", None, 16),
-    ("tiny-lfm2-moe", None, 16), ("tiny-brumby", None, 16),
+    ("tiny-brumby", None, 16),
     ("tiny-deepseek-v3", 16, 16), ("tiny-glm-dsa", 64, 64), ("tiny", 32, 32),
+    ("tiny-lfm2-moe", 16, 16), ("tiny-granite-hybrid", 32, 32),
 ])
 def test_a_page_is_the_familys_unless_one_is_given(name, given, page):
     """An engine given no `page_size` reads the page off the family's
     module: 128 tokens where one latent row a token serves every head (with
-    and without an index), 16 where the module states nothing; a size
-    given wins. The pool, the block tables and the gauge all have it."""
+    and without an index), 64 and 128 where a stored row holds two heads of
+    64 (LFM2; Granite-4.0-H, whose cell's contexts are six times as long),
+    16 where the module states nothing; a size given wins. The pool, the block tables and the gauge all have it."""
     from substratus_tpu.models import registry
     from substratus_tpu.observability.metrics import METRICS
     from substratus_tpu.serve.paged_kv import page_tokens
